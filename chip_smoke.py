@@ -1,0 +1,274 @@
+"""Chip smoke: the compiled training path, once, on every chip JAX sees.
+
+The quickest proof that the system still starts on the accelerator.  One
+process, the entry points every user calls (``hvd.init``,
+``init_train_state``, ``shard_batch``, ``make_train_step``), two models
+at full width, each for one compile plus three ``k=1`` steps on a re-fed
+batch of seeded random data:
+
+* GPT-2-small (12 layers, d 768, 12 heads, vocab 50257), bf16, 8
+  sequences x 1024 tokens per chip, ``optax.adam``, default attention —
+  so the three Pallas flash kernels compile;
+* ResNet-50, bf16, 224x224, batch 128 per chip, SGD momentum 0.9, through
+  ``examples.synthetic_benchmark.run``, the function ``bench.py`` calls.
+
+It checks: the device is a TPU; ``flash_attention`` agrees with
+``softmax_attention`` on the chip (forward and gradients); the GPT step's
+compiled module holds Mosaic custom calls; every loss is finite and the
+third is below the first; and, on more than one chip, that the batch is
+sharded one shard per device, the state replicated on all of them, the
+step holds an all-reduce, and the first-step loss equals the same global
+batch's loss on a one-device world.
+
+Any failed check raises, so the exit code is non-zero and no result line
+is printed.  The timings it prints are a smoke's, never benchmark numbers.
+The last line of a passing run is
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": n}}``.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+import time
+from importlib import metadata
+
+import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+GPT_SEQ = 1024
+GPT_SEQS_PER_CHIP = 8
+RESNET_BATCH_PER_CHIP = 128
+#: flash vs softmax reference, bf16 inputs: max |a - b| / max |b|.  bf16
+#: keeps 8 significant bits (2^-8 = 0.4%) and the two paths round the
+#: probabilities at different points, so a few percent of the largest
+#: element is the agreement bf16 can give.
+FLASH_TOL = 3e-2
+#: n-chip vs one-device first-step loss (absolute; the loss is ~11):
+#: the same rows through programs tiled for different batch shapes
+LOSS_TOL = 1e-2
+
+
+def check(cond: bool, msg: str) -> None:
+    if not cond:
+        raise RuntimeError(f"chip_smoke: {msg}")
+
+
+def report(phase: str, **fields) -> None:
+    print(json.dumps({"phase": phase, **fields}), flush=True)
+
+
+def peak_bytes() -> int:
+    import jax
+
+    return max(d.memory_stats()["peak_bytes_in_use"]
+               for d in jax.local_devices())
+
+
+def cache_entries(path: str) -> set:
+    return set(os.listdir(path)) if os.path.isdir(path) else set()
+
+
+def flash_phase() -> None:
+    """flash_attention vs softmax_attention at one shape on the chip."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.flash_attention import (
+        flash_attention, softmax_attention,
+    )
+
+    b, s, h, d = 2, GPT_SEQ, 12, 64
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k, v, w = (jax.random.normal(kk, (b, s, h, d), jnp.bfloat16)
+                  for kk in keys)
+
+    def loss_of(attn):
+        return lambda q, k, v: jnp.sum(
+            (attn(q, k, v, causal=True) * w).astype(jnp.float32))
+
+    got = {}
+    for name, fn in (("flash", flash_attention), ("ref", softmax_attention)):
+        out = jax.jit(lambda q, k, v: fn(q, k, v, causal=True))(q, k, v)
+        grads = jax.jit(jax.grad(loss_of(fn), argnums=(0, 1, 2)))(q, k, v)
+        got[name] = [np.asarray(a, np.float32) for a in (out, *grads)]
+    errs = {}
+    for label, a, b_ in zip(("out", "dq", "dk", "dv"),
+                            got["flash"], got["ref"]):
+        check(np.isfinite(a).all(), f"flash {label} is not finite")
+        errs[label] = float(np.abs(a - b_).max() / np.abs(b_).max())
+        check(errs[label] <= FLASH_TOL,
+              f"flash {label} differs from softmax_attention by "
+              f"{errs[label]:.3g} of its largest element (> {FLASH_TOL})")
+    report("flash_vs_reference", shape=[b, s, h, d], dtype="bfloat16",
+           causal=True, tolerance=FLASH_TOL, rel_max_err=errs)
+
+
+def gpt_phase(n: int) -> None:
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    import horovod_tpu as hvd
+    from horovod_tpu.models.gpt import gpt2_small, next_token_loss
+    from horovod_tpu.training import (
+        init_train_state, make_train_step, shard_batch,
+    )
+
+    model = gpt2_small(dtype=jnp.bfloat16)
+    opt = optax.adam(1e-4)
+    sample = jnp.zeros((2, GPT_SEQ), jnp.int32)
+    rows = GPT_SEQS_PER_CHIP * n
+    ids_host = np.random.default_rng(0).integers(
+        0, model.vocab_size, size=(rows, GPT_SEQ)).astype(np.int32)
+
+    def build():
+        step = make_train_step(
+            apply_fn=lambda v, x, train=True: model.apply(v, x),
+            loss_fn=next_token_loss, optimizer=opt)
+        return step, init_train_state(model, opt, sample), \
+            shard_batch(ids_host)
+
+    def run_step(step, state, ids):
+        t0 = time.perf_counter()
+        state, loss = step(state, ids, ids)
+        loss = float(np.asarray(jax.device_get(loss)))
+        return state, loss, time.perf_counter() - t0
+
+    t0 = time.perf_counter()
+    step, state, ids = build()
+    init_s = time.perf_counter() - t0
+
+    if n > 1:
+        shards = ids.addressable_shards
+        check(len(shards) == n and len({s.device for s in shards}) == n,
+              f"batch has {len(shards)} shards on "
+              f"{len({s.device for s in shards})} devices, want {n}")
+        check(all(s.data.shape == (rows // n, GPT_SEQ) for s in shards),
+              f"batch shards are {[s.data.shape for s in shards]}, want "
+              f"{rows // n} rows each")
+        for leaf in jax.tree_util.tree_leaves(state):
+            check(leaf.sharding.is_fully_replicated
+                  and len(leaf.addressable_shards) == n,
+                  f"state leaf {leaf.shape} is not replicated on {n} chips")
+
+    # first call = compile + one step; the next two are steady k=1 steps
+    losses, secs = [], []
+    for _ in range(3):
+        state, loss, dt = run_step(step, state, ids)
+        losses.append(loss)
+        secs.append(dt)
+    check(all(np.isfinite(losses)), f"GPT loss not finite: {losses}")
+    check(losses[2] < losses[0], f"GPT loss did not fall: {losses}")
+
+    # the module the chip ran: kernels compiled by Mosaic, not interpreted
+    t0 = time.perf_counter()
+    compiled = jax.jit(step).lower(state, ids, ids).compile()
+    hlo_s = time.perf_counter() - t0
+    hlo = compiled.as_text()
+    mem = compiled.memory_analysis()
+    mosaic_calls = hlo.count("tpu_custom_call")
+    check(mosaic_calls >= 3,
+          f"GPT step holds {mosaic_calls} Mosaic custom calls, want the "
+          "flash forward, dq and dkv kernels (>= 3)")
+    if n > 1:
+        check("all-reduce" in hlo, "GPT step holds no all-reduce")
+
+    one_device_loss = None
+    if n > 1:
+        # the same global batch on a one-device world, same process; the
+        # n-chip state goes first, chip 0 needs the room for n times the rows
+        del state, ids, step, compiled
+        hvd.shutdown()
+        hvd.init(platform="tpu", comm=[0])
+        step1, state1, ids1 = build()
+        _, one_device_loss, _ = run_step(step1, state1, ids1)
+        hvd.shutdown()
+        hvd.init(platform="tpu")
+        check(abs(one_device_loss - losses[0]) <= LOSS_TOL,
+              f"first-step loss on {n} chips {losses[0]} differs from the "
+              f"one-device world's {one_device_loss} by more than {LOSS_TOL}")
+
+    report("gpt2_small", global_batch=[rows, GPT_SEQ], optimizer="adam",
+           init_seconds=round(init_s, 2),
+           compile_plus_first_step_seconds=round(secs[0], 2),
+           smoke_step_ms=[round(s * 1e3, 1) for s in secs[1:]],
+           module_compile_seconds=round(hlo_s, 2),
+           mosaic_custom_calls=mosaic_calls, losses=losses,
+           one_device_first_loss=one_device_loss,
+           module_bytes_per_chip={
+               "arguments": mem.argument_size_in_bytes,
+               "temporaries": mem.temp_size_in_bytes,
+               "outputs": mem.output_size_in_bytes},
+           peak_bytes_in_use=peak_bytes())
+
+
+def resnet_phase() -> None:
+    from examples.synthetic_benchmark import parse_args, run
+
+    result = run(parse_args([
+        "--platform", "tpu",
+        "--batch-size", str(RESNET_BATCH_PER_CHIP),
+        "--num-warmup-batches", "1",
+        "--num-batches-per-iter", "1",
+        "--num-iters", "2",
+    ]))
+    losses = result["losses"]
+    check(len(losses) == 3, f"ResNet-50 took {len(losses)} steps, want 3")
+    check(all(np.isfinite(losses)), f"ResNet-50 loss not finite: {losses}")
+    check(losses[2] < losses[0], f"ResNet-50 loss did not fall: {losses}")
+    report("resnet50", batch_per_chip=RESNET_BATCH_PER_CHIP,
+           optimizer="sgd momentum 0.9",
+           compile_plus_first_step_seconds=round(result["warmup_sec"], 2),
+           smoke_step_ms=round(RESNET_BATCH_PER_CHIP * 1e3
+                               / result["img_sec_per_chip"], 1),
+           losses=losses, peak_bytes_in_use=peak_bytes())
+
+
+def main() -> int:
+    import jax
+
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": jax.device_count()}
+    report("device", **device,
+           versions={p: metadata.version(p)
+                     for p in ("jax", "jaxlib", "libtpu")})
+    if dev.platform != "tpu":
+        print(f"chip_smoke: needs a TPU, JAX found platform "
+              f"{dev.platform!r} ({dev.device_kind})", file=sys.stderr)
+        return 2
+
+    import horovod_tpu as hvd
+    from horovod_tpu import core
+    from horovod_tpu.utils import flops
+
+    n = device["count"]
+    # platform="tpu": a machine without a chip raises here instead of
+    # falling back to the CPU with a warning
+    hvd.init(platform="tpu")
+    check(hvd.size() == n, f"world is {hvd.size()} ranks on {n} chips")
+    cache_dir = core.compile_cache_dir()
+    entries_before = cache_entries(cache_dir)
+    report("setup", compile_cache_dir=cache_dir,
+           compile_cache_entries=len(entries_before),
+           peak_flops=flops.require_peak_flops())
+
+    flash_phase()
+    gpt_phase(n)
+    resnet_phase()
+
+    entries_after = cache_entries(cache_dir)
+    report("compile_cache", dir=cache_dir,
+           entries_before=len(entries_before),
+           entries_after=len(entries_after),
+           new_entries=sorted(entries_after - entries_before))
+    hvd.shutdown()
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

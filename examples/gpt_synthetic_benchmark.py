@@ -181,8 +181,10 @@ def run(args) -> dict:
         per_chip, param_count(state.params), model.num_layers,
         model.hidden_dim, args.seq_len, causal=True,
     )
+    # None: the device kind is not in utils/flops.DEVICE_PEAKS
+    mfu_txt = f"{mfu:.1%}" if mfu is not None else "n/a"
     log(f"sequences/sec per chip: {per_chip:.1f}  "
-        f"(analytic MFU {mfu:.1%} of v5e bf16 peak)")
+        f"(analytic MFU {mfu_txt} of the device's bf16 peak)")
     return {"seq_sec_per_chip": per_chip,
             "mfu": mfu,
             "final_loss": float(np.asarray(jax.device_get(loss)))}

@@ -161,14 +161,15 @@ def run(args) -> dict:
     log(f"Batch size: {args.batch_size} (global {global_batch})")
     log(f"Number of chips: {hvd.size()}")
 
-    # NOTE: sync via device_get of the chained loss, not block_until_ready —
-    # on tunneled/remote platforms block_until_ready can return before remote
-    # execution finishes, which silently inflates throughput. Fetching the
-    # scalar forces the whole sequential step chain to complete.
+    # Sync by fetching the chained loss: the scalar depends on the whole
+    # sequential step chain, so the timed region ends when the device does.
+    # Every fetched value is kept, so a caller can see the loss move.
     log("Running warmup...")
+    t0 = time.perf_counter()
     for _ in range(max(args.num_warmup_batches, 1)):
         state, loss = step(state, x, y)
-    float(np.asarray(jax.device_get(loss)))
+    losses = [float(np.asarray(jax.device_get(loss)))]
+    warmup_sec = time.perf_counter() - t0
 
     log("Running benchmark...")
     imgs_per_call = (args.batch_size * hvd.size()
@@ -178,7 +179,7 @@ def run(args) -> dict:
         t0 = time.perf_counter()
         for _ in range(args.num_batches_per_iter):
             state, loss = step(state, x, y)
-        float(np.asarray(jax.device_get(loss)))
+        losses.append(float(np.asarray(jax.device_get(loss))))
         dt = time.perf_counter() - t0
         img_sec = imgs_per_call * args.num_batches_per_iter / dt
         log(f"Iter: Img/sec total: {img_sec:.1f}")
@@ -200,7 +201,11 @@ def run(args) -> dict:
         "img_sec_per_chip": img_sec_mean / hvd.size(),
         "conf": img_sec_conf,
         "size": hvd.size(),
-        "final_loss": float(np.asarray(jax.device_get(loss))),
+        "final_loss": losses[-1],
+        # one value per sync point: after warm-up, then after each iter
+        "losses": losses,
+        # warm-up wall time: the compile plus the warm-up steps
+        "warmup_sec": warmup_sec,
     }
 
 

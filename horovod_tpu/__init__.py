@@ -22,12 +22,6 @@ Typical use::
 
 __version__ = "0.1.0"
 
-# Must run before any module touches jax.shard_map (core/spmd/eager do):
-# bridges the public-API spelling onto older experimental releases.
-from .utils import jax_compat as _jax_compat
-
-_jax_compat.install()
-
 from .core import (  # noqa: F401
     init,
     shutdown,

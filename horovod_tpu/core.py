@@ -110,6 +110,39 @@ def _pick_devices(platform: Optional[str]) -> list:
     return list(jax.devices())
 
 
+#: in-checkout home of the persistent compile cache when
+#: ``JAX_COMPILATION_CACHE_DIR`` does not place it.  The path is part of
+#: the cache key, so it is fixed (derived the way runtime/native.py
+#: derives ``build/``), never a temp dir.
+_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def compile_cache_dir() -> str:
+    """Where compiled programs persist: ``JAX_COMPILATION_CACHE_DIR``
+    when the environment sets it, else ``<checkout>/.jax_cache``."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _COMPILE_CACHE_DIR
+
+
+def _place_compile_cache(platform: str) -> None:
+    """Point JAX's persistent compile cache at :func:`compile_cache_dir`
+    before the first compile.  With ``JAX_COMPILATION_CACHE_DIR`` set JAX
+    reads it itself and no directory is set here.  Every program is
+    cached, not only those that compiled for over a second (JAX's
+    default): a flax ``model.init`` is hundreds of small programs, and
+    what gets cached must not depend on how long a compile happened to
+    take.  The CPU mesh is the test plane: its programs are small, and a
+    fresh checkout would pay the writes without ever reading them back,
+    so it stays uncached."""
+    if platform == "cpu":
+        return
+    if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", _COMPILE_CACHE_DIR)
+
+
 def init(
     *,
     platform: Optional[str] = None,
@@ -195,6 +228,7 @@ def init(
         size = len(devs)
         if size == 0:
             raise RuntimeError("no devices available for horovod_tpu.init()")
+        _place_compile_cache(devs[0].platform)
 
         # identity must come from the backend the mesh devices live on —
         # jax.process_count()/process_index() default to the default

@@ -16,7 +16,7 @@ Design constraints, in order:
    pre-interned label tuple plus a small per-child lock (the GIL makes
    the lock nearly free when uncontended).  Call sites additionally gate
    on ``registry.enabled`` so a disabled registry costs one attribute
-   read (the < 2% overhead budget, docs/PERF.md).
+   read (the < 2% overhead budget, docs/metrics.md).
 2. **thread safety**: the eager plane, the ring dispatcher thread, the
    stall-inspector daemon, and the metrics pusher all touch the registry
    concurrently.

@@ -74,7 +74,7 @@ class PallasConvBN3x3(nn.Module):
     """Fused stride-1 3x3 conv + BatchNorm + ReLU over the Pallas kernels
     (ops/conv_bn.py): train mode runs the conv+stats-epilogue kernel with
     the full-BN-backward custom VJP; eval mode runs the folded-affine
-    kernel.  The round-4 conv+BN experiment module (docs/PERF.md) —
+    kernel.  The round-4 conv+BN experiment module (root PERF.md) —
     selected by ``ResNet(conv_bn="pallas")``; its parameter layout is its
     own (kernel/scale/bias + batch_stats mean/var), so checkpoints do NOT
     interchange with the (Conv, BatchNorm) pair it replaces."""
@@ -190,7 +190,7 @@ def _norm_relu(norm, norm_relu, y):
 
 def _residual_join(residual, y, kind: str):
     """The block output ``relu(residual + y)``: XLA elementwise fusion by
-    default, or the Pallas single-pass kernel (the docs/PERF.md §56×56
+    default, or the Pallas single-pass kernel (the root PERF.md 56×56
     experiment — measured by scripts/pallas_residual_experiment.py)."""
     if kind == "pallas":
         from ..ops.elementwise import residual_relu

@@ -334,8 +334,7 @@ def broadcast(tensor, root_rank: int = 0, *, name: Optional[str] = None,
     moves 2(n-1)/n x bytes over ICI (~2x a textbook broadcast's
     (n-1)/n) in ONE collective, vs n x bytes for all_gather-and-index or
     (n-1) serial latency hops for a ppermute pipeline.  On ICI the 2x is
-    noise (broadcast traffic is start-up parameter sync, docs/PERF.md
-    measures the gradient allreduce at 102 MB vs ~1 ms); across DCN
+    noise (broadcast traffic is start-up parameter sync); across DCN
     prefer the host-plane ``eager.process_broadcast``, which sends the
     payload once.
     """

@@ -1,9 +1,9 @@
 """Pallas fused 3x3-conv + BatchNorm kernels — the round-4 named-lever
-experiment (docs/PERF.md "custom Pallas conv+BN kernels could shave part
+experiment ("custom Pallas conv+BN kernels could shave part
 of the elementwise traffic" on the HBM-bound 56x56 ResNet stage).
 
 Two variants, matching the two halves of XLA's own training-BN
-structure (PERF.md trace: `convert_reduce_fusion` = conv with fused
+structure (an earlier trace: `convert_reduce_fusion` = conv with fused
 BN-stat epilogues, `multiply_add_fusion` = conv fused with BN-apply
 chains):
 
@@ -18,7 +18,7 @@ lives in VMEM (~430 KB bf16 at C=64) and each of the 9 taps is a
 ``[H*W, Cin] @ [Cin, Cout]`` MXU matmul accumulated in f32 — the
 classic shift-and-matmul conv lowering.  Measured against XLA's fused
 equivalents by ``scripts/pallas_conv_bn_experiment.py``; the verdict
-(positive or negative) is recorded in docs/PERF.md.
+(positive or negative) is recorded in the root PERF.md.
 
 Off-TPU the kernels run in interpreter mode, same policy as
 ops/flash_attention.py.

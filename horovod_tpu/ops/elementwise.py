@@ -1,16 +1,16 @@
 """Pallas elementwise kernels for the HBM-bound ResNet joins.
 
-docs/PERF.md's profile names the 56×56 residual-add fusions (3 × 5.45 ms
+An earlier profile named the 56×56 residual-add fusions (3 × 5.45 ms
 at batch 256) as the one untried framework-side lever on the ResNet-50
 headline; this module is that experiment's kernel.  ``residual_relu``
 computes ``relu(x + y)`` in one HBM pass with explicit [rows, 256]
 blocking; ``scripts/pallas_residual_experiment.py`` measures it against
 XLA's own elementwise fusion standalone and end-to-end (the result —
-either a headline move or a measured negative — is recorded in
-docs/PERF.md).
+a measured negative on an earlier chip path — is recorded in the root
+PERF.md).
 
-``scale_bias_relu`` is the compute-tier companion (docs/PERF.md
-"compute tier"): the norm+activation join ``relu(x * scale + bias)`` —
+``scale_bias_relu`` is the compute-tier companion: the
+norm+activation join ``relu(x * scale + bias)`` —
 the elementwise half of every BatchNorm→ReLU pair once the per-channel
 statistics are folded — in one HBM pass with a custom VJP whose
 backward reuses the masked-grad kernel.  models/resnet.py wires it in

@@ -28,8 +28,9 @@ Three public entry points:
 All kernels take/return the ``[batch, heads, seq, head_dim]`` layout; the
 callers transpose from the model-facing ``[batch, seq, heads, head_dim]``.
 
-Off-TPU (the CPU test mesh) the kernels run in Pallas interpreter mode,
-which keeps every test oracle-checkable on the 8-device virtual slice.
+On the CPU test mesh, and only there, the kernels run in Pallas
+interpreter mode, which keeps every test oracle-checkable on the
+8-device virtual slice.
 """
 
 from __future__ import annotations
@@ -48,8 +49,8 @@ from jax.experimental.pallas import tpu as pltpu
 # masked rows (exp(NEG_INF - NEG_INF) = 1, then zeroed by the mask select).
 NEG_INF = -1e30
 
-# 512x512 measured best on the v5e across 128..1024 sweeps (beats both
-# smaller blocks and XLA's fused attention at seq>=2048, docs/PERF.md);
+# 512x512 won a 128..1024 sweep at one shape (b4 h12 d64 s2048) on an
+# earlier chip path; root PERF.md lists it as a hypothesis to re-measure.
 # _fit_block shrinks automatically for shorter sequences
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
@@ -63,17 +64,14 @@ def _on_tpu() -> bool:
     The mesh devices, not ``jax.devices()[0]``, are authoritative: the test
     harness runs an 8-device *CPU* mesh even when a TPU backend is present
     (conftest.py), and there the kernels must take the interpreter path.
+    A backend that fails to come up raises here: turning that into
+    interpreter mode would hide a broken chip behind a slow correct run.
     """
-    try:
-        from .. import core
+    from .. import core
 
-        dev = (core.mesh().devices.flat[0] if core.is_initialized()
-               else jax.devices()[0])
-    except Exception:  # pragma: no cover - no backend at all
-        return False
-    return "tpu" in dev.platform.lower() or "TPU" in getattr(
-        dev, "device_kind", ""
-    )
+    dev = (core.mesh().devices.flat[0] if core.is_initialized()
+           else jax.devices()[0])
+    return dev.platform == "tpu"
 
 
 def _resolve_interpret(interpret: Optional[bool]) -> bool:

@@ -198,7 +198,7 @@ class TunableParams:
       flipped flag therefore always maps to a different GP; it can
       never silently share one.
 
-    **Compute knobs** (the PR 9→14 compute tier, docs/PERF.md):
+    **Compute knobs** (the PR 9→14 compute tier, docs/autotune.md):
     ``fused_optimizer`` selects the flat fused update kernel over the
     per-leaf optax traversal (optim/fused_update.py) and
     ``remat_policy`` rematerializes the loss closure

@@ -17,7 +17,7 @@ bench A/B rather than fitted):
   ``FUSED_UPDATE_SAVE_FRAC`` of the ``optimizer_update`` block's
   per-step device time (the per-leaf path's overhead is dispatch + HBM
   round-trips on sub-tile tensors, roughly half the block on the
-  profiled ResNet run — docs/PERF.md compute-tier table).
+  profiled ResNet run).
 * ``loss_fetch_steps`` — the trailing async loss fetch (training.py)
   removes the per-step host sync; modeled to recover
   ``ASYNC_GAP_SAVE_FRAC`` of the anatomy's measured host gap (the gap

@@ -52,8 +52,14 @@ SGD, MOMENTUM, ADAM = "sgd", "momentum", "adam"
 
 #: flat buffers are blocked [rows, _LANES] for the Pallas path
 _LANES = 128
-#: per-buffer VMEM budget, same sizing rule as ops/elementwise.py
-_BLOCK_BYTES = 2 << 20
+#: VMEM one kernel's pipelined blocks may hold in total.  The Pallas
+#: pipeline double-buffers every operand, inputs and outputs alike, and
+#: the default scoped limit on v5e is 16 MiB; half of it leaves the
+#: compiler room for the kernel's temporaries.  The block is sized from
+#: the operand count (momentum has 5, Adam 7) instead of raising the limit.
+_VMEM_BUDGET_BYTES = 8 << 20
+#: block rows are a multiple of the deepest sublane tile (8-bit: 32 rows)
+_ROW_TILE = 32
 
 
 class FusedOptState(NamedTuple):
@@ -183,7 +189,8 @@ def _pallas_elementwise(kernel, flats, n_out: int, *, scalars=()):
         blocked.append(b2)
     rows = blocked[0].shape[0]
     dtype = blocked[0].dtype
-    cap = max(8, _BLOCK_BYTES // (_LANES * dtype.itemsize))
+    per_operand = _VMEM_BUDGET_BYTES // (2 * (len(blocked) + n_out))
+    cap = per_operand // (_LANES * dtype.itemsize) // _ROW_TILE * _ROW_TILE
     block = min(cap, rows)
     in_specs = [pl.BlockSpec((block, _LANES), lambda i: (i, 0))
                 for _ in blocked]

@@ -39,34 +39,15 @@ from jax import lax
 
 
 def _to_varying(x, axis):
-    """Mark ``x`` varying over ``axis`` for the replication checker
-    (pcast on current jax; pvary on older releases)."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, (axis,), to="varying")
-    return lax.pvary(x, (axis,))
+    """Mark ``x`` varying over ``axis`` for the replication checker."""
+    return lax.pcast(x, (axis,), to="varying")
 
 
-def _vma_state(x, axis) -> str:
-    """'on' when the replication checker recorded ``x`` as varying over
-    ``axis`` (shard_map check_vma=True), 'off' when the checker is
-    demonstrably disabled, 'unknown' when this JAX can't tell (no false
-    alarms in that case)."""
-    from ..utils import jax_compat
-
-    if getattr(lax, "pvary", None) is jax_compat._compat_pvary:
-        # the compat identity shim means NO VMA machinery exists on this
-        # JAX: the backward psum→pbroadcast rewrite cannot happen
-        # (measured: gradients scale by the stage count) — warn loudly
-        return "off"
-    if not hasattr(jax, "typeof"):
-        return "unknown"
-    try:
-        vma = getattr(jax.typeof(x), "vma", None)
-    except Exception:
-        return "unknown"
-    if vma is None:
-        return "unknown"
-    return "on" if axis in vma else "off"
+def _vma_on(x, axis) -> bool:
+    """True when the replication checker recorded ``x`` as varying over
+    ``axis`` (shard_map check_vma=True); under check_vma=False the
+    marking is dropped and the set stays empty."""
+    return axis in jax.typeof(x).vma
 
 
 def pipeline_apply(stage_fn: Callable, stage_params, x_mbs, *,
@@ -117,9 +98,9 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_mbs, *,
     # (shard_map(check_vma=True), the default): the final psum's
     # transpose is then the correct pbroadcast.  Under check_vma=False
     # the backward pass mis-scales (measured) — hence the explicit
-    # pvary marking on the carries and the injected microbatch.
+    # varying marking on the carries and the injected microbatch.
     state0 = _to_varying(jnp.zeros_like(x_mbs[0]), axis)
-    if _vma_state(state0, axis) == "off":
+    if not _vma_on(state0, axis):
         warnings.warn(
             "pipeline_apply requires shard_map(check_vma=True): the "
             "replication checker is off in this trace, so gradients "

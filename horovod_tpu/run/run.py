@@ -960,6 +960,36 @@ def run_commandline(argv: Optional[List[str]] = None) -> int:
 # ---------------------------------------------------------------------------
 # function mode: horovod_tpu.run.run(fn, args=(), np=...)
 # ---------------------------------------------------------------------------
+def function_mode_env(pid: int, np: int, port: int, secret: bytes,
+                      extra_env: Dict[str, str]) -> Dict[str, str]:
+    """Environment of function-mode worker ``pid`` of ``np``.
+
+    A chip belongs to one process at a time and function mode hands out
+    no chips, so several local workers cannot all take the accelerator:
+    on a chip host each ``hvd.init()`` would claim every chip and the
+    second claimant fails or hangs.  With ``np > 1`` the workers are
+    therefore pinned to the host platform, unless the caller placed them
+    itself through ``extra_env["JAX_PLATFORMS"]``.  A single worker
+    inherits the parent's platform and owns all local chips — the same
+    one-process-per-host rule ``tpurun`` applies (``worker_envs``)."""
+    env = dict(os.environ)
+    env.update(extra_env)
+    if np > 1 and "JAX_PLATFORMS" not in extra_env:
+        env["JAX_PLATFORMS"] = "cpu"
+    env.update({
+        "HVD_RUN_KV_ADDR": "127.0.0.1",
+        "HVD_RUN_KV_PORT": str(port),
+        "HVD_RUN_SECRET": secret.hex(),
+        "HVD_RUN_PID": str(pid),
+        "HVD_RUN_NP": str(np),
+        env_util.HVD_RANK: str(pid),
+        env_util.HVD_SIZE: str(np),
+        env_util.HVD_NUM_PROCESSES: str(np),
+        env_util.HVD_PROCESS_ID: str(pid),
+    })
+    return env
+
+
 def run(fn, args=(), kwargs=None, np: int = 1,
         extra_env: Optional[Dict[str, str]] = None):
     """Run ``fn(*args, **kwargs)`` on ``np`` local worker processes and
@@ -1014,19 +1044,7 @@ def run(fn, args=(), kwargs=None, np: int = 1,
     procs = []
     try:
         for pid in range(np):
-            env = dict(os.environ)
-            env.update(extra_env)
-            env.update({
-                "HVD_RUN_KV_ADDR": "127.0.0.1",
-                "HVD_RUN_KV_PORT": str(port),
-                "HVD_RUN_SECRET": secret.hex(),
-                "HVD_RUN_PID": str(pid),
-                "HVD_RUN_NP": str(np),
-                env_util.HVD_RANK: str(pid),
-                env_util.HVD_SIZE: str(np),
-                env_util.HVD_NUM_PROCESSES: str(np),
-                env_util.HVD_PROCESS_ID: str(pid),
-            })
+            env = function_mode_env(pid, np, port, secret, extra_env)
             procs.append(subprocess.Popen(
                 [sys.executable, "-m", "horovod_tpu.run.task_fn"], env=env,
             ))

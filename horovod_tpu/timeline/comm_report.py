@@ -553,10 +553,12 @@ def model_scaling(
 def collective_report(
     step_fn,
     *args,
-    # None → utils/flops.peak_flops(): the ONE peak constant (v5e
-    # 197e12 unless HVD_PEAK_FLOPS overrides) every MFU number divides
-    # by — a hardware change can't desync this report from bench.py or
-    # the compute-anatomy profiler
+    # None → utils/flops.peak_flops(): the ONE peak table (keyed by the
+    # mesh devices' kind, HVD_PEAK_FLOPS overrides) every MFU number
+    # divides by — a hardware change can't desync this report from
+    # bench.py or the compute-anatomy profiler.  On a device with no
+    # known peak the flops/peak fallback below has nothing to divide by:
+    # pass measured_step_seconds (or the peak of the chip being modelled)
     peak_flops: Optional[float] = None,
     ici_bytes_per_sec: float = DEFAULT_ICI_BYTES_PER_SEC,
     ici_hop_latency: float = DEFAULT_ICI_HOP_LATENCY,
@@ -604,7 +606,7 @@ def collective_report(
     flops = float((cost or {}).get("flops", 0.0))
 
     t_compute = measured_step_seconds if measured_step_seconds \
-        else (flops / peak_flops if flops else None)
+        else (flops / peak_flops if flops and peak_flops else None)
     comm_seconds, scaling = model_scaling(
         cols, t_compute, sizes=sizes,
         ici_bytes_per_sec=ici_bytes_per_sec,

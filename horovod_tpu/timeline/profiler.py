@@ -68,8 +68,8 @@ COMPUTE_PID_BASE = 100000
 # roofline accounting
 # ---------------------------------------------------------------------------
 def roofline_verdict(flops: Optional[float], nbytes: Optional[float],
-                     device_us: float, *, peak_flops: float,
-                     hbm_bytes_per_sec: float) -> Dict[str, Any]:
+                     device_us: float, *, peak_flops: Optional[float],
+                     hbm_bytes_per_sec: Optional[float]) -> Dict[str, Any]:
     """Price one segment against the roofline.
 
     The ridge point is ``peak_flops / hbm_bytes_per_sec`` flops/byte: a
@@ -79,23 +79,29 @@ def roofline_verdict(flops: Optional[float], nbytes: Optional[float],
     segment still counts device time).  Alongside the verdict: achieved
     FLOP/s and its peak fraction (the segment's MFU), achieved bytes/s
     and its bandwidth fraction — the "how far from the roof" numbers the
-    next perf PR needs as targets."""
+    next perf PR needs as targets.  A device with no known peaks
+    (utils/flops.py) gets the achieved rates only: no fraction, and no
+    verdict where the ridge would have to decide it."""
     out: Dict[str, Any] = {"verdict": "unknown"}
     if device_us <= 0.0:
         return out
     secs = device_us * 1e-6
     if flops is not None:
         out["achieved_flops_per_sec"] = flops / secs
-        out["mfu"] = flops / secs / peak_flops
+        if peak_flops:
+            out["mfu"] = flops / secs / peak_flops
     if nbytes is not None:
         out["achieved_bytes_per_sec"] = nbytes / secs
-        out["hbm_fraction"] = nbytes / secs / hbm_bytes_per_sec
-    ridge = peak_flops / hbm_bytes_per_sec
+        if hbm_bytes_per_sec:
+            out["hbm_fraction"] = nbytes / secs / hbm_bytes_per_sec
+    ridge = peak_flops / hbm_bytes_per_sec \
+        if peak_flops and hbm_bytes_per_sec else None
     if flops is not None and nbytes is not None:
         if nbytes > 0:
             out["intensity_flops_per_byte"] = flops / nbytes
-            out["verdict"] = ("compute-bound"
-                              if flops / nbytes >= ridge else "memory-bound")
+            if ridge is not None:
+                out["verdict"] = ("compute-bound" if flops / nbytes >= ridge
+                                  else "memory-bound")
         elif flops > 0:
             out["verdict"] = "compute-bound"
     elif flops is not None and flops > 0:
@@ -108,7 +114,8 @@ def roofline_verdict(flops: Optional[float], nbytes: Optional[float],
 # ---------------------------------------------------------------------------
 # the parser: trace events -> anatomy
 # ---------------------------------------------------------------------------
-def _empty_anatomy(peak_flops: float, hbm_bytes_per_sec: float,
+def _empty_anatomy(peak_flops: Optional[float],
+                   hbm_bytes_per_sec: Optional[float],
                    gap_threshold_us: float) -> Dict[str, Any]:
     return {
         "steps": 0,
@@ -272,7 +279,7 @@ def reduce_trace_events(
     verdict = "host-bound" if gap_fraction >= host_bound_fraction else (
         segments[top]["verdict"] if top else "empty")
     mfu = flops_known / (wall_us * 1e-6 * peak) \
-        if any_flops and wall_us > 0 else None
+        if any_flops and wall_us > 0 and peak else None
     return {
         "steps": n_steps,
         "wall_us": round(wall_us, 3),

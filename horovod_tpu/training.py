@@ -37,7 +37,8 @@ class TrainState(NamedTuple):
 
 
 class TrailingLossFetcher:
-    """The async-host-pipeline loss fetch (docs/PERF.md compute tier).
+    """The async-host-pipeline loss fetch (docs/profiling.md host-gap
+    section).
 
     ``push(loss)`` is called with every dispatched step's loss handle;
     every ``every`` steps ONE handle is retained, and the retained
@@ -86,8 +87,8 @@ class TrailingLossFetcher:
 
 def scan_steps(step_fn: Callable, k: int) -> Callable:
     """Compile ``k`` optimizer steps into one program via ``lax.scan``
-    (amortizes per-step host dispatch — the round-2 ResNet profiling win,
-    docs/PERF.md; the transformer benches reuse it).  ``step_fn(carry,
+    (amortizes per-step host dispatch; the ResNet and transformer
+    benches share it).  ``step_fn(carry,
     *args) -> (carry, loss)``; the returned fn has the same signature and
     yields the LAST step's loss.  ``k <= 1``: identity."""
     if k <= 1:
@@ -178,8 +179,8 @@ def make_train_step(
       optimizer steps over the SAME batch into one program, so host
       dispatch is amortized away (the synthetic-benchmark mode: the
       reference's timed inner loop also re-feeds one synthetic batch,
-      examples/tensorflow2_synthetic_benchmark.py:72-97; measured +6%
-      on the v5e, docs/PERF.md).  Real data pipelines keep the default 1.
+      examples/tensorflow2_synthetic_benchmark.py:72-97).  Real data
+      pipelines keep the default 1.
     * ``fused_optimizer`` (default: ``HVD_FUSED_OPTIMIZER``, on when
       ``optimizer`` is a :class:`~horovod_tpu.optim.fused_update.
       FusedOptimizer`) routes the update through the flat fused
@@ -214,7 +215,7 @@ def make_train_step(
     if two_level is None:
         two_level = use_two_level_default()
 
-    # -- compute tier defaults (docs/PERF.md "compute tier") ----------------
+    # -- compute tier defaults (docs/autotune.md "compute knobs") -----------
     from .optim.fused_update import FusedOptimizer
 
     fusable = isinstance(optimizer, FusedOptimizer)
@@ -942,8 +943,8 @@ def make_train_step(
             warm_start_manager(pm, box["grad_bytes"])
         t0 = _time.perf_counter()
         state, loss = _invoke(state, x, y, _under_trace=under_trace)
-        # honest timing while tuning: force the step chain to complete
-        # (block_until_ready can return early on tunneled platforms)
+        # honest timing while tuning: fetching the loss forces the whole
+        # step chain to complete
         jax.device_get(loss)
         dt = _time.perf_counter() - t0
         if box.get("profiled_last"):

@@ -117,8 +117,8 @@ HVD_PROFILE_START_STEP = "HVD_PROFILE_START_STEP"      # window start (default H
 HVD_PROFILE_END_STEP = "HVD_PROFILE_END_STEP"          # window end (default start + 2: a 3-step window)
 HVD_PROFILE_XLA = "HVD_PROFILE_XLA"                    # 1 also runs jax.profiler trace capture into <rank>/xla_trace
 HVD_PROFILE_GAP_THRESHOLD_US = "HVD_PROFILE_GAP_THRESHOLD_US"  # inter-dispatch gap flagged as a host-gap span past this (default 25)
-HVD_PROFILE_HBM_GBPS = "HVD_PROFILE_HBM_GBPS"          # roofline HBM bandwidth, GB/s (default 819, v5e)
-HVD_PEAK_FLOPS = "HVD_PEAK_FLOPS"                      # per-chip peak FLOP/s for every MFU number (default 197e12, v5e bf16)
+HVD_PROFILE_HBM_GBPS = "HVD_PROFILE_HBM_GBPS"          # roofline HBM bandwidth, GB/s (default: utils/flops.DEVICE_PEAKS by device kind)
+HVD_PEAK_FLOPS = "HVD_PEAK_FLOPS"                      # per-chip peak FLOP/s for every MFU number (default: utils/flops.DEVICE_PEAKS by device kind; none for an unknown device)
 # dPRO-style replay engine (horovod_tpu/timeline/replay/)
 HVD_REPLAY_CLOCK_SYNC = "HVD_REPLAY_CLOCK_SYNC"        # 0 skips the init-time clock handshake
 HVD_REPLAY_CLOCK_SAMPLES = "HVD_REPLAY_CLOCK_SAMPLES"  # handshake round trips (default 8)
@@ -178,7 +178,7 @@ HVD_SERVE_DRAIN_TIMEOUT_SECONDS = "HVD_SERVE_DRAIN_TIMEOUT_SECONDS"  # drain han
 HVD_SERVE_WEIGHT_COMPRESSION = "HVD_SERVE_WEIGHT_COMPRESSION"  # none|bf16|int8|fp8 at-rest weight format
 HVD_BENCH_SERVE = "HVD_BENCH_SERVE"                    # 0 skips bench.py's serving leg
 # compute-path optimization tier (optim/fused_update.py, training.py,
-# data/loader.py, optim/compute_knobs.py; docs/PERF.md "compute tier"):
+# data/loader.py, optim/compute_knobs.py; docs/autotune.md "Compute knobs"):
 # fused step kernels + async host pipeline + compute-knob autotuning
 HVD_FUSED_OPTIMIZER = "HVD_FUSED_OPTIMIZER"            # 0 forces the per-leaf optax path even for a FusedOptimizer
 HVD_FUSED_UPDATE_PALLAS = "HVD_FUSED_UPDATE_PALLAS"    # force the Pallas (1) / jnp (0) fused-update backend; default: Pallas on TPU only

@@ -10,23 +10,35 @@ accounting (PaLM appendix B): 6·N FLOPs per token of parameter math
 blocks.
 
 This module is also the single source of the hardware peak numbers
-every MFU/roofline consumer divides by: bench.py, the compute-anatomy
-profiler (timeline/profiler.py), and the comm report's flops/peak
-fallback (timeline/comm_report.py) all route through
-:func:`peak_flops` / :func:`hbm_bytes_per_sec`, so a hardware change
-(or an ``HVD_PEAK_FLOPS`` override) moves every published MFU number
-at once instead of desyncing them.
+every MFU/roofline consumer divides by: bench.py, chip_smoke.py, the
+compute-anatomy profiler (timeline/profiler.py), and the comm report's
+flops/peak fallback (timeline/comm_report.py) all route through
+:func:`peak_flops` / :func:`hbm_bytes_per_sec`.  The peaks are keyed by
+the mesh devices' ``device_kind``: a device that is not in
+:data:`DEVICE_PEAKS` has no peak (no MFU is reported) unless
+``HVD_PEAK_FLOPS`` / ``HVD_PROFILE_HBM_GBPS`` name one explicitly.
 """
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import numpy as np
 
-V5E_PEAK_FLOPS = 197e12       # bf16 nameplate, per chip
-V5E_HBM_BYTES_PER_SEC = 819e9  # HBM bandwidth, per chip
+
+class DevicePeak(NamedTuple):
+    flops: float              # bf16 FLOP/s, per chip
+    hbm_bytes_per_sec: float  # HBM bandwidth, per chip
+
+
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``.
+DEVICE_PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s
+    # HBM.  The kind string is what jax 0.9.0 / libtpu 0.0.34 reports on
+    # the chip (chip_smoke.py output, PR 21).
+    "TPU v5 lite": DevicePeak(197e12, 819e9),
+}
 
 #: ResNet-50 training ≈ 3 × 4.09 GFLOPs forward of model math per image
 #: (the usual analytic count bench.py's headline MFU is built on; XLA's
@@ -35,23 +47,59 @@ V5E_HBM_BYTES_PER_SEC = 819e9  # HBM bandwidth, per chip
 RESNET50_TRAIN_FLOPS_PER_IMG = 12.27e9
 
 
-def peak_flops(default: float = V5E_PEAK_FLOPS) -> float:
-    """Per-chip peak FLOP/s for MFU math.  ``HVD_PEAK_FLOPS`` overrides
-    (set it when the job runs on different hardware than the v5e
-    default) — every consumer reads THIS function, never the raw
-    constant, so the override cannot miss one report."""
+def _mesh_device_kind() -> Optional[str]:
+    """``device_kind`` of the devices the framework runs on; None before
+    ``hvd.init()`` (asking JAX here would start a backend as a side
+    effect, and on a chip host take the chip)."""
+    from .. import core
+
+    if not core.is_initialized():
+        return None
+    return core.mesh().devices.flat[0].device_kind
+
+
+def _table_peak(kind: Optional[str]) -> Optional[DevicePeak]:
+    return DEVICE_PEAKS.get(kind if kind is not None
+                            else _mesh_device_kind())
+
+
+def peak_flops(kind: Optional[str] = None) -> Optional[float]:
+    """Per-chip peak FLOP/s for MFU math: ``HVD_PEAK_FLOPS`` when set,
+    else the :data:`DEVICE_PEAKS` entry for ``kind`` (default: the mesh
+    devices' kind), else None — there is no default device."""
     from .env import HVD_PEAK_FLOPS, get_float
 
-    return get_float(HVD_PEAK_FLOPS, default)
+    override = get_float(HVD_PEAK_FLOPS, 0.0)
+    if override > 0:
+        return override
+    peak = _table_peak(kind)
+    return peak.flops if peak else None
 
 
-def hbm_bytes_per_sec(default: float = V5E_HBM_BYTES_PER_SEC) -> float:
+def hbm_bytes_per_sec(kind: Optional[str] = None) -> Optional[float]:
     """Per-chip HBM bandwidth for roofline math (the ridge point is
-    ``peak_flops / hbm_bytes_per_sec`` flops/byte).
-    ``HVD_PROFILE_HBM_GBPS`` overrides, in GB/s."""
+    ``peak_flops / hbm_bytes_per_sec`` flops/byte), resolved like
+    :func:`peak_flops`; ``HVD_PROFILE_HBM_GBPS`` overrides, in GB/s."""
     from .env import HVD_PROFILE_HBM_GBPS, get_float
 
-    return get_float(HVD_PROFILE_HBM_GBPS, default / 1e9) * 1e9
+    override = get_float(HVD_PROFILE_HBM_GBPS, 0.0)
+    if override > 0:
+        return override * 1e9
+    peak = _table_peak(kind)
+    return peak.hbm_bytes_per_sec if peak else None
+
+
+def require_peak_flops() -> float:
+    """:func:`peak_flops` for callers that publish an MFU (bench.py,
+    chip_smoke.py): an unknown device is an error, not a default."""
+    peak = peak_flops()
+    if peak is None:
+        raise RuntimeError(
+            f"no peak FLOP/s for device kind {_mesh_device_kind()!r}: "
+            f"known kinds are {sorted(DEVICE_PEAKS)}; add the device to "
+            "horovod_tpu/utils/flops.py DEVICE_PEAKS with its source, or "
+            "set HVD_PEAK_FLOPS")
+    return peak
 
 
 def param_count(params) -> int:
@@ -61,11 +109,14 @@ def param_count(params) -> int:
 
 def image_model_mfu(img_per_sec_per_chip: float,
                     flops_per_image: float = RESNET50_TRAIN_FLOPS_PER_IMG,
-                    *, peak: Optional[float] = None) -> float:
+                    *, peak: Optional[float] = None) -> Optional[float]:
     """MFU of an image model from measured per-chip throughput — the
     bench.py headline math, single-sourced so the bench JSON and the
-    ``hvd_mfu`` gauge agree by construction."""
+    ``hvd_mfu`` gauge agree by construction.  None when the device has
+    no known peak."""
     peak = peak if peak is not None else peak_flops()
+    if peak is None:
+        return None
     return float(img_per_sec_per_chip) * float(flops_per_image) / peak
 
 
@@ -81,10 +132,13 @@ def transformer_train_flops_per_seq(n_params: int, num_layers: int,
 def transformer_mfu(seq_per_sec_per_chip: float, n_params: int,
                     num_layers: int, hidden_dim: int, seq_len: int, *,
                     causal: bool = False,
-                    peak_flops: Optional[float] = None) -> float:
+                    peak: Optional[float] = None) -> Optional[float]:
+    """Analytic transformer MFU; None when the device has no known
+    peak."""
+    peak = peak if peak is not None else peak_flops()
+    if peak is None:
+        return None
     fps = transformer_train_flops_per_seq(
         n_params, num_layers, hidden_dim, seq_len, causal=causal,
     )
-    if peak_flops is None:
-        peak_flops = globals()["peak_flops"]()
-    return seq_per_sec_per_chip * fps / peak_flops
+    return seq_per_sec_per_chip * fps / peak

@@ -38,6 +38,7 @@ def main(argv=None) -> dict:
     import horovod_tpu as hvd
     from horovod_tpu.models import MODELS
     from horovod_tpu.timeline.comm_report import collective_report
+    from horovod_tpu.utils.flops import DEVICE_PEAKS
     from horovod_tpu.training import (
         init_train_state, make_train_step, shard_batch,
     )
@@ -78,6 +79,8 @@ def main(argv=None) -> dict:
     report = collective_report(
         lambda s, a, b: step(s, a, b), state, x, y,
         measured_step_seconds=args.step_ms / 1e3 if args.step_ms else None,
+        # the chip being modelled, not the CPU mesh this compiles on
+        peak_flops=DEVICE_PEAKS["TPU v5 lite"].flops,
     )
     print(json.dumps(report, indent=2))
     return report

@@ -145,7 +145,7 @@ def bench_allreduce(np_: int, payload_mb: float, iters: int, ring: bool):
         "gb_per_sec_per_rank": per_rank,
         # on one host all ranks share loopback + memory bandwidth, so the
         # scalability signal is the AGGREGATE staying flat as np grows
-        # (per-rank flatness needs per-host NICs — see PERF.md)
+        # (per-rank flatness needs per-host NICs)
         "gb_per_sec_aggregate": per_rank * np_,
     }
 

@@ -1,10 +1,10 @@
-"""The docs/PERF.md §56×56 experiment: Pallas residual-add kernel vs
+"""The 56×56-stage experiment: Pallas residual-add kernel vs
 XLA's elementwise fusion (VERDICT round-2 item 7 — "run the named
 experiment ... or demonstrate it loses and close the question with
 numbers").
 
 Two measurements on the real chip, interleaved in one process (the
-shared chip fluctuates ~2× between runs, docs/PERF.md:22):
+comparison that survives run-to-run drift):
 
   (a) standalone: relu(x + y) on the 56×56-stage activation shape
       [128, 56, 56, 256] bf16 — Pallas single pass vs jitted XLA;

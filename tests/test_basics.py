@@ -108,3 +108,69 @@ def test_mesh_sum_accumulates_half_precision_in_f32(hvd_init):
     iout = eager._sum_rows_fn(pmesh)(jnp.full((4, 8), 2**24 + 1, jnp.int32))
     assert iout.dtype == jnp.int32  # widening to f32 would lose exactness
     assert int(np.asarray(iout)[0]) == 4 * (2**24 + 1)
+
+
+# ---------------------------------------------------------------------------
+# the persistent compile cache is placed from outside, or at a fixed path
+# ---------------------------------------------------------------------------
+def _record_config_updates(monkeypatch):
+    from horovod_tpu import core
+
+    calls = []
+    monkeypatch.setattr(core.jax.config, "update",
+                        lambda k, v: calls.append((k, v)))
+    return core, calls
+
+
+_CACHE_EVERYTHING = ("jax_persistent_cache_min_compile_time_secs", 0.0)
+
+
+def test_compile_cache_env_placement_sets_no_directory(monkeypatch,
+                                                       tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: JAX reads it, the code sets no
+    directory of its own.  It still asks for every program to be cached,
+    unless the environment chose that threshold too."""
+    core, calls = _record_config_updates(monkeypatch)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.delenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
+                       raising=False)
+    core._place_compile_cache("tpu")
+    assert calls == [_CACHE_EVERYTHING]
+    assert core.compile_cache_dir() == str(tmp_path)
+    calls.clear()
+    monkeypatch.setenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
+    core._place_compile_cache("tpu")
+    assert calls == []
+
+
+def test_compile_cache_fixed_path_in_checkout(monkeypatch):
+    """Unset: <checkout>/.jax_cache — the path is part of the cache key,
+    so it must be the same in every process (never a temp dir, a pid or
+    a clock).  The CPU test mesh stays uncached."""
+    import os
+    import subprocess
+    import sys
+
+    core, calls = _record_config_updates(monkeypatch)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
+                       raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".jax_cache")
+    core._place_compile_cache("cpu")
+    assert calls == []
+    core._place_compile_cache("tpu")
+    assert calls == [_CACHE_EVERYTHING,
+                     ("jax_compilation_cache_dir", want)]
+    assert core.compile_cache_dir() == want
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = repo
+    code = ("from horovod_tpu import core; "
+            "print(core.compile_cache_dir())")
+    seen = {subprocess.run([sys.executable, "-c", code], cwd=cwd, env=env,
+                           capture_output=True, text=True, timeout=120,
+                           check=True).stdout.strip()
+            for cwd in (repo, os.path.join(repo, "tests"))}
+    assert seen == {want}
+

@@ -1,6 +1,6 @@
 """Pallas residual-join kernel vs the XLA oracle (fwd + grad) — the
-docs/PERF.md §56×56 experiment's correctness gate; perf verdict lives in
-scripts/pallas_residual_experiment.py / PERF.md."""
+56×56-stage experiment's correctness gate; perf verdict lives in
+scripts/pallas_residual_experiment.py / the root PERF.md."""
 
 import jax
 import jax.numpy as jnp

@@ -160,7 +160,7 @@ def test_requeue_preserves_order_and_respects_cap():
 
 
 def test_recorder_overhead_under_one_percent_of_1ms_step():
-    """The PERF.md pin: a record() append (dict build + deque push +
+    """The overhead pin: a record() append (dict build + deque push +
     counter inc) must average < 10 us — 1% of even a 1 ms step; real
     emitters fire at lifecycle cadence, not step cadence."""
     r = events_mod.Recorder(cap=8192)

@@ -187,3 +187,29 @@ def test_ulysses_pallas_matches_dense(hvd_init, rng, causal):
     out = np.asarray(step(q, k, v))
     np.testing.assert_allclose(out, _dense(q, k, v, causal),
                                rtol=2e-3, atol=2e-3)
+
+
+def test_on_tpu_propagates_a_backend_error(monkeypatch):
+    """A backend that cannot come up must not read as "not a TPU": that
+    would turn a broken chip into a silent interpreter-mode run."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    hvd.shutdown()
+
+    def broken(*a, **k):
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(fa.jax, "devices", broken)
+    with pytest.raises(RuntimeError, match="Unable to initialize backend"):
+        fa._on_tpu()
+    with pytest.raises(RuntimeError, match="Unable to initialize backend"):
+        fa._resolve_interpret(None)
+
+
+def test_on_tpu_reads_the_mesh_platform(hvd_init):
+    """The CPU test mesh — and only a CPU mesh — interprets."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    assert fa._on_tpu() is False
+    assert fa._resolve_interpret(None) is True
+

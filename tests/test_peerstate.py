@@ -293,7 +293,7 @@ def test_snapshot_copy_knob_detaches_in_place_array_mutation(rdv,
 def test_snapshot_enqueue_stall_under_one_percent_of_1ms_step(rdv,
                                                               monkeypatch):
     """The step path pays ONLY a slot write + thread wake.  Contract:
-    under 10 µs — 1% of even a 1 ms step (ISSUE acceptance; PERF.md).
+    under 10 µs — 1% of even a 1 ms step (ISSUE acceptance).
     The floor is asserted hard; the median gets a generous bound so a
     loaded CI box (GIL collisions with the background pickler) cannot
     flake the suite."""

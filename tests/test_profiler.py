@@ -443,14 +443,47 @@ def test_profiled_window_with_error_feedback_lazy_residual(
 # ---------------------------------------------------------------------------
 # peak-FLOPS single-sourcing (satellite 1) + bench mfu (satellite 2)
 # ---------------------------------------------------------------------------
-def test_peak_flops_env_override(monkeypatch):
+def test_peak_table_keyed_by_device_kind(monkeypatch, hvd_init):
+    """Known kind -> its published figures; unknown kind -> no default
+    (the CPU mesh included); HVD_PEAK_FLOPS names a peak explicitly."""
     from horovod_tpu.utils import flops
 
-    assert flops.peak_flops() == pytest.approx(197e12)
+    monkeypatch.delenv("HVD_PEAK_FLOPS", raising=False)
+    monkeypatch.delenv("HVD_PROFILE_HBM_GBPS", raising=False)
+    assert flops.peak_flops("TPU v5 lite") == pytest.approx(197e12)
+    assert flops.hbm_bytes_per_sec("TPU v5 lite") == pytest.approx(819e9)
+    assert flops.peak_flops("TPU v9 imaginary") is None
+    # the mesh here is 8 CPU devices: no peak, no MFU, and an error for
+    # the callers that publish one
+    assert flops.peak_flops() is None
+    assert flops.hbm_bytes_per_sec() is None
+    assert flops.image_model_mfu(2677.0) is None
+    assert flops.transformer_mfu(10.0, 124_000_000, 12, 768, 1024) is None
+    with pytest.raises(RuntimeError, match="no peak FLOP/s.*'cpu'"):
+        flops.require_peak_flops()
     monkeypatch.setenv("HVD_PEAK_FLOPS", "123e12")
     assert flops.peak_flops() == pytest.approx(123e12)
+    assert flops.require_peak_flops() == pytest.approx(123e12)
     monkeypatch.setenv("HVD_PROFILE_HBM_GBPS", "500")
     assert flops.hbm_bytes_per_sec() == pytest.approx(500e9)
+
+
+def test_anatomy_without_a_known_peak_has_no_mfu(monkeypatch):
+    """No peak for the device: the anatomy keeps times and achieved
+    rates, reports no MFU, and decides no roofline verdict that needs
+    the ridge."""
+    monkeypatch.delenv("HVD_PEAK_FLOPS", raising=False)
+    monkeypatch.delenv("HVD_PROFILE_HBM_GBPS", raising=False)
+    import horovod_tpu as hvd
+
+    hvd.shutdown()
+    an = reduce_trace_events(profile_fixture_events(0),
+                             gap_threshold_us=PROFILE_GAP_THRESHOLD_US)
+    assert an["peak_flops"] is None and an["mfu"] is None
+    fwd = an["segments"]["forward"]
+    assert fwd["verdict"] == "unknown" and "mfu" not in fwd
+    assert fwd["achieved_flops_per_sec"] > 0
+    assert an["host_gap"]["total_us"] == pytest.approx(200.0)
 
 
 def test_collective_report_peak_single_sourced(monkeypatch):
@@ -471,20 +504,24 @@ def _load_bench():
     return mod
 
 
-def test_bench_mfu_through_utils_flops(monkeypatch):
+def test_bench_mfu_through_utils_flops(monkeypatch, hvd_init):
     from horovod_tpu.utils import flops
 
     bench = _load_bench()
+    # the gauge and the bench number share one peak: an explicit peak
+    # moves both
+    monkeypatch.setenv("HVD_PEAK_FLOPS", "197e12")
     want = round(flops.image_model_mfu(2677.0), 4)
     assert bench._mfu(2677.0) == pytest.approx(want)
     assert want == pytest.approx(2677.0 * 12.27e9 / 197e12, abs=1e-4)
-    # the gauge and the bench number share one peak: override moves both
     monkeypatch.setenv("HVD_PEAK_FLOPS", "98.5e12")
     assert bench._mfu(2677.0) == pytest.approx(
         round(2677.0 * 12.27e9 / 98.5e12, 4))
-    # null-on-failure semantics, like the delta legs
-    assert bench._mfu("not a number") is None
-    assert bench._mfu(0.0) is None
+    # a device that is not in the table is an error in bench.py, never
+    # a null and never a v5e default (this mesh is CPU)
+    monkeypatch.delenv("HVD_PEAK_FLOPS")
+    with pytest.raises(RuntimeError, match="no peak FLOP/s"):
+        bench._mfu(2677.0)
 
 
 # ---------------------------------------------------------------------------
@@ -519,6 +556,10 @@ def test_profiled_train_step_end_to_end(cpu_devices, tmp_path,
     monkeypatch.setenv("HVD_PROFILE", "1")
     monkeypatch.setenv("HVD_PROFILE_START_STEP", "2")
     monkeypatch.setenv("HVD_PROFILE_END_STEP", "4")
+    # the CPU mesh has no peak of its own: name one, as a job on an
+    # unlisted device would
+    monkeypatch.setenv("HVD_PEAK_FLOPS", "197e12")
+    monkeypatch.setenv("HVD_PROFILE_HBM_GBPS", "819")
     monkeypatch.setenv("HVD_METRICS_KV_ADDR", "127.0.0.1")
     monkeypatch.setenv("HVD_METRICS_KV_PORT", str(server.port))
     hvd.shutdown()

@@ -700,7 +700,6 @@ def test_bench_projection_leg_merged_and_skippable(monkeypatch, capsys):
                                         "measured_step_us": 2915.0}))
         return FakeProc(json.dumps(payload))
 
-    monkeypatch.setattr(bench, "_probe", lambda: "ok")
     monkeypatch.setattr(bench, "_autotune_delta", lambda v: {})
     monkeypatch.setattr(bench, "_compression_delta", lambda v: {})
     monkeypatch.setattr(bench, "_serving_leg", lambda: {})

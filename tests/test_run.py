@@ -274,6 +274,31 @@ def test_function_mode_run():
     assert results == [30, 31]
 
 
+def test_function_mode_children_do_not_share_the_chip(monkeypatch):
+    """A chip belongs to one process and function mode assigns none:
+    with np > 1 every worker is pinned to the host platform, whatever
+    the parent ran on; a single worker inherits the parent's platform
+    (it owns all local chips); a caller that places the workers itself
+    is obeyed."""
+    import importlib
+
+    tpurun = importlib.import_module("horovod_tpu.run.run")
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    for pid in range(2):
+        env = tpurun.function_mode_env(pid, 2, 1234, b"s", {})
+        assert env["JAX_PLATFORMS"] == "cpu"
+        assert env["HVD_PROCESS_ID"] == str(pid)
+        assert env["HVD_NUM_PROCESSES"] == "2"
+    assert tpurun.function_mode_env(
+        0, 1, 1234, b"s", {})["JAX_PLATFORMS"] == "tpu"
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert "JAX_PLATFORMS" not in tpurun.function_mode_env(
+        0, 1, 1234, b"s", {})
+    placed = tpurun.function_mode_env(
+        1, 2, 1234, b"s", {"JAX_PLATFORMS": "tpu"})
+    assert placed["JAX_PLATFORMS"] == "tpu"
+
+
 def test_tpu_host_discovery_env_override(monkeypatch):
     """--tpu resolves hosts from HVD_TPU_HOSTS / TPU_WORKER_HOSTNAMES
     (SURVEY §7.1's replacement for the reference's ssh/NIC probing)."""

@@ -130,7 +130,7 @@ def test_bert_tiny_forward(hvd_init, rng):
 
 def test_in_graph_steps_matches_sequential(hvd_init, rng):
     """K scanned in-graph steps on one batch == K sequential step() calls
-    (the synthetic-benchmark mode, docs/PERF.md)."""
+    (the synthetic-benchmark mode)."""
     x, y = _make_problem(rng)
     model = MLP(features=(32, 10))
     opt = optax.sgd(0.1)
