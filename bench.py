@@ -17,10 +17,11 @@ into one program via lax.scan.  Both were picked on an earlier chip
 path (batch sweep, k ladder); root PERF.md lists them as hypotheses to
 re-measure in a cell.
 
-MFU accounting: ResNet-50 training ≈ 3 x 4.09 GFLOPs forward = 12.27
-GFLOPs/image of model math (the usual analytic count; XLA's own
-cost_analysis reports 23.9 GFLOPs/image because strided-conv gradients
-lower to dilated convs that multiply zeros).  The peak comes from
+MFU accounting: ResNet-50 training requires 24.30 GFLOPs/image (4.09 G
+multiply-adds forward at two operations each, three passes less the
+stem's input gradient: utils/flops.RESNET50_TRAIN_FLOPS_PER_IMG; until
+PR 24 this read 12.27, multiply-adds counted as operations, and the
+headline MFU read half).  The peak comes from
 utils/flops.DEVICE_PEAKS, keyed by the device kind; a device that is
 not in the table (and no HVD_PEAK_FLOPS) is an error.
 
@@ -100,7 +101,7 @@ def _measure() -> None:
         "unit": "images/sec/chip",
         "vs_baseline": round(per_chip / BASELINE_IMG_SEC_PER_DEVICE, 3),
         "mfu": _mfu(per_chip),
-        "mfu_note": "12.27 GF/img analytic / peak from utils/flops "
+        "mfu_note": "24.30 GF/img required / peak from utils/flops "
                     "DEVICE_PEAKS by device kind (HVD_PEAK_FLOPS "
                     "overrides)",
         **device,

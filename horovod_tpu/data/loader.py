@@ -11,6 +11,7 @@ analog of "rank r joined early" (reference controller.cc:253-264).
 
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 from typing import Iterable, Iterator, List, Optional, Tuple
@@ -19,6 +20,7 @@ import numpy as np
 
 
 from .. import core
+from ..timeline.timeline import host_span
 from ..training import shard_batch
 from ..utils import env as env_util
 
@@ -144,21 +146,48 @@ class ShardedLoader:
         self.drop_remainder = drop_remainder
         self.prefetch = prefetch
         self.n = n
+        #: passes over the data started so far: the ``epoch`` argument of
+        #: this loader's host spans
+        self.epoch = 0
 
     def __len__(self) -> int:
         g = self.batch_size * core.size()
         return self.n // g if self.drop_remainder else -(-self.n // g)
 
     def __iter__(self) -> Iterator[Tuple]:
-        def produce():
-            for cols, rows_per_rank in self._iterate_host():
-                yield (core._require_init().epoch, cols, rows_per_rank,
-                       tuple(shard_batch(a) for a in cols),
-                       shard_batch(rows_per_rank > 0))
+        # Host spans (docs/profiling.md), each with the pass and the
+        # batch's index in it: hvd_loader_host_batch (index, gather, pad)
+        # and hvd_loader_h2d (the device_put) on the hvd-prefetch thread,
+        # hvd_loader_wait (the q.get that blocks) on the consumer's.
+        self.epoch += 1
+        epoch = self.epoch
 
-        for epoch, cols, rpr, shards, active in prefetch_to_device(
-                produce(), self.prefetch):
-            if epoch != core._require_init().epoch:
+        def span(kind, index):
+            return host_span("loader_" + kind, cat="loader", epoch=epoch,
+                             batch=index)
+
+        def produce():
+            host = self._iterate_host()
+            for index in itertools.count():
+                with span("host_batch", index):
+                    item = next(host, None)
+                if item is None:
+                    return
+                cols, rows_per_rank = item
+                with span("h2d", index):
+                    shards = tuple(shard_batch(a) for a in cols)
+                    active = shard_batch(rows_per_rank > 0)
+                yield (core._require_init().epoch, cols, rows_per_rank,
+                       shards, active)
+
+        staged = prefetch_to_device(produce(), self.prefetch)
+        for index in itertools.count():
+            with span("wait", index):
+                item = next(staged, None)
+            if item is None:
+                return
+            mesh_epoch, cols, rpr, shards, active = item
+            if mesh_epoch != core._require_init().epoch:
                 # staged over a retired mesh: an elastic membership
                 # epoch landed while this batch sat in the prefetch
                 # queue, so its device placement names devices that may

@@ -181,6 +181,11 @@ STEPS_TOTAL = registry.counter(
     "hvd_steps_total", "Train steps dispatched.")
 SAMPLES_TOTAL = registry.counter(
     "hvd_samples_total", "Global samples dispatched into train steps.")
+STEP_COMPILES = registry.counter(
+    "hvd_step_compiles_total",
+    "Times the compiled train step traced and compiled: a (re)build's "
+    "first call, or a silent retrace on a new batch shape.  The flight "
+    "recorder's step.compile event names the step and the shapes.")
 TRAIN_LOSS = registry.gauge(
     "hvd_train_loss",
     "Most recently fetched training loss — fetched on the trailing "

@@ -70,7 +70,7 @@ KNOWN_KINDS = (
     "restart.attempt", "restart.resume",
     "autoscale.grow", "autoscale.shrink",
     "autotune.apply", "autotune.verify", "autotune.rollback",
-    "compression.fallback",
+    "compression.fallback", "step.compile",
     "checkpoint.save", "checkpoint.commit", "checkpoint.restore",
     "snapshot.begin", "snapshot.commit", "snapshot.reprotect",
     "restore.source", "spare.purged",

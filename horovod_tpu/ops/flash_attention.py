@@ -57,6 +57,13 @@ DEFAULT_BLOCK_K = 512
 
 _DIM_SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary")
 
+# The three kernels' names: each ``pallas_call``'s ``name=`` and the
+# ``jax.named_scope`` it runs under, so a device trace tells forward, dq and
+# dkv apart (docs/profiling.md; benchmarks/layer_metrics/flash_*_ms.py).
+FWD_KERNEL = "hvd_flash_fwd"
+DQ_KERNEL = "hvd_flash_dq"
+DKV_KERNEL = "hvd_flash_dkv"
+
 
 def _on_tpu() -> bool:
     """True if the devices the framework runs on are TPUs.
@@ -188,7 +195,7 @@ def _mha_fwd(q, k, v, offs, *, causal, scale, block_q, block_k,
         _fwd_kernel, causal=causal, scale=scale, normalize=normalize,
     )
     out_dtype = q.dtype if normalize else jnp.float32
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -222,7 +229,10 @@ def _mha_fwd(q, k, v, offs, *, causal, scale, block_q, block_k,
         ],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-    )(offs, q, k, v)
+        name=FWD_KERNEL,
+    )
+    with jax.named_scope(FWD_KERNEL):
+        return call(offs, q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -356,7 +366,7 @@ def _mha_bwd_dq(q, k, v, do, lse, delta, offs, *, causal, scale, block_q,
     interpret = _resolve_interpret(interpret)
     grid = (b, h, sq // block_q, sk // block_k)
     kernel = functools.partial(_bwd_dq_kernel, causal=causal, scale=scale)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -382,7 +392,10 @@ def _mha_bwd_dq(q, k, v, do, lse, delta, offs, *, causal, scale, block_q,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), jnp.float32),
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-    )(offs, q, k, v, do, lse, delta)
+        name=DQ_KERNEL,
+    )
+    with jax.named_scope(DQ_KERNEL):
+        return call(offs, q, k, v, do, lse, delta)
 
 
 def _mha_bwd_dkv(q, k, v, do, lse, delta, offs, *, causal, scale, block_q,
@@ -393,7 +406,7 @@ def _mha_bwd_dkv(q, k, v, do, lse, delta, offs, *, causal, scale, block_q,
     interpret = _resolve_interpret(interpret)
     grid = (b, h, sk // block_k, sq // block_q)
     kernel = functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -429,7 +442,10 @@ def _mha_bwd_dkv(q, k, v, do, lse, delta, offs, *, causal, scale, block_q,
         ],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
-    )(offs, q, k, v, do, lse, delta)
+        name=DKV_KERNEL,
+    )
+    with jax.named_scope(DKV_KERNEL):
+        return call(offs, q, k, v, do, lse, delta)
 
 
 # ---------------------------------------------------------------------------

@@ -182,6 +182,30 @@ class FusionPlan:
     def num_buckets(self) -> int:
         return len(self.buckets)
 
+    def describe(self, leaves: Sequence[Any],
+                 names: Sequence[str]) -> List[dict]:
+        """For each bucket, in dispatch order: its number (the ``<k>`` of
+        the ``hvd_bucket_<k>`` scope its ops carry), the names of its
+        leaves, its dtype and its bytes — what ties a collective in a
+        device trace to tensors."""
+        out = []
+        for k, bucket in enumerate(self.buckets):
+            dt = jnp.result_type(leaves[bucket[0]])
+            out.append({
+                "bucket": k, "scope": bucket_scope(k),
+                "leaves": [str(names[i]) for i in bucket],
+                "dtype": str(dt),
+                "bytes": int(sum(leaves[i].size for i in bucket)
+                             * dt.itemsize),
+            })
+        return out
+
+
+def bucket_scope(k: int) -> str:
+    """The ``jax.named_scope`` bucket ``k``'s pack, reduce and unpack run
+    under."""
+    return f"hvd_bucket_{k}"
+
 
 def tree_leaf_names(tree, *, is_leaf=None) -> List[str]:
     """Slash-joined key paths of a pytree's leaves (``params/dense/kernel``
@@ -305,25 +329,35 @@ def fused_allreduce(
             f"fusion plan covers {sum(len(b) for b in plan.buckets)} "
             f"tensors but the call passed {len(compressed)}")
     out: List[Any] = [None] * len(tensors)
-    for bucket in plan.buckets:
-        if len(bucket) == 1:
-            i = bucket[0]
-            red = _reduce_flat(compressed[i], op=op, axes=axes, groups=groups,
-                               group_size=group_size)
-            out[i] = comps[i].decompress(red, ctxs[i])
-            continue
-        flats = [compressed[i].reshape(-1) for i in bucket]
-        fused = jnp.concatenate(flats)
-        red = _reduce_flat(fused, op=op, axes=axes, groups=groups,
-                           group_size=group_size)
-        offset = 0
-        for i in bucket:
-            n = compressed[i].size
-            piece = lax.dynamic_slice_in_dim(red, offset, n).reshape(
-                compressed[i].shape
-            )
-            out[i] = comps[i].decompress(piece, ctxs[i])
-            offset += n
+
+    def reduce(flat):
+        with jax.named_scope("reduce"):
+            return _reduce_flat(flat, op=op, axes=axes, groups=groups,
+                                group_size=group_size)
+
+    # hvd_bucket_<k>/{pack,reduce,unpack}: the bucket's number on every
+    # op's metadata, so a device trace puts each collective and each copy
+    # down to a bucket, and FusionPlan.describe puts the bucket down to
+    # its tensors (docs/profiling.md)
+    for k, bucket in enumerate(plan.buckets):
+        with jax.named_scope(bucket_scope(k)):
+            if len(bucket) == 1:
+                i = bucket[0]
+                out[i] = comps[i].decompress(reduce(compressed[i]), ctxs[i])
+                continue
+            with jax.named_scope("pack"):
+                fused = jnp.concatenate(
+                    [compressed[i].reshape(-1) for i in bucket])
+            red = reduce(fused)
+            with jax.named_scope("unpack"):
+                offset = 0
+                for i in bucket:
+                    n = compressed[i].size
+                    piece = lax.dynamic_slice_in_dim(red, offset, n).reshape(
+                        compressed[i].shape
+                    )
+                    out[i] = comps[i].decompress(piece, ctxs[i])
+                    offset += n
     if new_res is not None:
         return out, new_res
     return out

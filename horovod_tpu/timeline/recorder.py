@@ -185,10 +185,22 @@ class Recorder:
         Also merges each gradient's shape into ``tensor_shapes.json`` and
         its dtype into ``tensor_dtypes.json``, keyed by manifest name —
         the byte counts the replay engine's what-if cost model
-        (timeline/replay/stitcher.py) joins comm events against."""
+        (timeline/replay/stitcher.py) joins comm events against.
+
+        And ``gradient_buckets.json``: the fused all-reduce's plan for
+        these gradients (``FusionPlan.describe``: bucket number, leaf
+        names, dtype, bytes), so ``hvd_bucket_<k>`` on an op of a device
+        trace can be put down to tensors (docs/profiling.md)."""
         if not self.enabled:
             return
+        from ..ops.fusion import FusionPlan, tree_leaf_names
+
         leaves = jax.tree_util.tree_flatten_with_path(grads)[0]
+        flat = [leaf for _, leaf in leaves]
+        if flat and all(hasattr(leaf, "shape") for leaf in flat):
+            with open(self._path("gradient_buckets.json"), "w") as f:
+                json.dump(FusionPlan(flat).describe(
+                    flat, tree_leaf_names(grads)), f, indent=1)
         paths = [
             "gradients/" + "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
                                     for k in path)

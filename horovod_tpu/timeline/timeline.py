@@ -35,6 +35,8 @@ import threading
 import time
 from typing import Optional
 
+import jax
+
 from .. import core
 from ..utils import env as env_util
 from ..utils.logging import get_logger
@@ -372,3 +374,31 @@ class Timeline:
 
 #: process-wide singleton, auto-enabled when HVD_TIMELINE is set at init
 timeline = Timeline()
+
+#: every host span's name in the profiler's trace starts with this, as every
+#: device scope's does (docs/profiling.md has the list of both)
+SPAN_PREFIX = "hvd_"
+
+
+@contextlib.contextmanager
+def host_span(name: str, *, cat: str = "train_step",
+              annotation=jax.profiler.TraceAnnotation, **args):
+    """THE host span of the step path (``training._invoke``,
+    ``data/loader``): one span, two sinks.
+
+    Always a ``jax.profiler.TraceAnnotation("hvd_" + name, **args)``:
+    written into the profiler's own trace, on the clock the device planes
+    share, whenever a profiler session is on (``HVD_PROFILE_XLA=1``,
+    ``TimelineHook(xla_profile=True)``, a benchmark's traced run); with no
+    session and no timeline the whole helper costs a few microseconds.  ``args`` (the step's number,
+    the batch's index) become the event's arguments, so the spans of one
+    step share an identifier.  ``annotation`` swaps in
+    ``jax.profiler.StepTraceAnnotation`` for the span that is the step.
+
+    And, while the Chrome-trace timeline is in its window, the complete
+    event :meth:`Timeline.span` emits: ``name.upper()`` as the activity
+    on the ``cat`` row of ``comm.json``.
+    """
+    with annotation(SPAN_PREFIX + name, **args), \
+            timeline.span(cat, name.upper()):
+        yield

@@ -70,7 +70,10 @@ class TrailingLossFetcher:
     def _fetch(self, n, loss) -> None:
         import numpy as np
 
-        self.value = float(np.asarray(jax.device_get(loss)))
+        from .timeline.timeline import host_span
+
+        with host_span("loss_fetch", step_num=self._n, fetched_step=n):
+            self.value = float(np.asarray(jax.device_get(loss)))
         self.step = n
         from . import metrics
 
@@ -279,9 +282,10 @@ def make_train_step(
                     logits, updates = apply_fn(
                         variables, x, train=True, mutable=["batch_stats"]
                     )
+                else:
+                    logits, updates = apply_fn(variables, x), {}
+                with jax.named_scope("hvd_loss"):
                     return loss_fn(logits, y), updates
-                logits = apply_fn(variables, x)
-                return loss_fn(logits, y), {}
 
         def _reduce_grads(grads, residual):
             with jax.named_scope("hvd_grad_allreduce"):
@@ -354,7 +358,8 @@ def make_train_step(
                 has_aux=True,
             )(state.params)
             grads, residual = _reduce_grads(grads, state.residual)
-            loss = collectives.allreduce(loss, op=Average)
+            with jax.named_scope("hvd_loss_allreduce"):
+                loss = collectives.allreduce(loss, op=Average)
             return (
                 _apply_update(state, grads, new_model_state, residual),
                 loss,
@@ -443,11 +448,19 @@ def make_train_step(
     if autotune is None:
         autotune = env_util.get_bool(env_util.HVD_AUTOTUNE)
 
+    from . import metrics
+    from .metrics import timeseries as _timeseries
+    from .timeline.timeline import host_span, timeline
+
     pm = None
     box = {"fused_base": fused_optimizer, "remat_base": remat_policy}
     fetcher_base_every = fetcher.every
+    #: host dispatches so far: the ``step_num`` every host span of one
+    #: step carries, and the step the cadence series and events name
+    step_count = [0]
 
-    def _rebuild(threshold_b, hier, plan=None, fused=None, remat=None):
+    def _rebuild(threshold_b, hier, plan=None, fused=None, remat=None,
+                 reason="first build"):
         """(Re)compile the SPMD step and remember the knobs + the core
         mesh epoch it was built against, so a later elastic membership
         change (core.reinit bumps the epoch and swaps the mesh) can
@@ -458,7 +471,9 @@ def make_train_step(
         knobs (optim/profile_guided.py; a compute-only plan has no
         buckets and leaves threshold bucketing untouched).  ``fused`` /
         ``remat`` move the base compute knobs (the GP tuner's
-        categorical dims); None leaves the base unchanged."""
+        categorical dims); None leaves the base unchanged.  ``reason``
+        (first build | epoch | plan | guard) is what the ``hvd_rebuild``
+        host span says of a rebuild that builds a new program."""
         if fused is not None:
             box["fused_base"] = fused
         if remat is not None:
@@ -510,10 +525,11 @@ def make_train_step(
         # while the tuner reports the plan applied.  box keeps the
         # original hier so rollback (plan=None) restores it.  A
         # compute-only plan (no buckets) leaves the comm layout alone.
-        fn, ef, profile_factory = _build(
-            threshold_b, hier and named is None, named,
-            comp, bucket_comp, two_level and named is None,
-            fused_eff, remat_eff)
+        with host_span("rebuild", reason=reason, step_num=step_count[0]):
+            fn, ef, profile_factory = _build(
+                threshold_b, hier and named is None, named,
+                comp, bucket_comp, two_level and named is None,
+                fused_eff, remat_eff)
         # any rebuild (new plan, elastic epoch, guard trip) invalidates
         # the profiler's cached decomposed segments — they must re-jit
         # against the same knobs as the fused program
@@ -523,6 +539,9 @@ def make_train_step(
             ef_active=ef, compression=comp, fused=fused_eff,
             remat=remat_eff, profile_factory=profile_factory,
             core_epoch=core._require_init().epoch, build_sig=sig,
+            # a new jitted function: its first call compiles, which
+            # _count_compiles sees as the cache growing from nothing
+            cache_size=0,
         )
 
     if autotune:
@@ -547,15 +566,12 @@ def make_train_step(
                                           p.hierarchical_allreduce,
                                           p.fusion_plan,
                                           fused=p.fused_optimizer,
-                                          remat=p.remat_policy)
+                                          remat=p.remat_policy,
+                                          reason="plan")
         _rebuild(initial.fusion_threshold_bytes,
                  initial.hierarchical_allreduce)
     else:
         _rebuild(threshold_bytes, hierarchical)
-
-    from . import metrics
-    from .metrics import timeseries as _timeseries
-    from .timeline.timeline import timeline
 
     import time as _time
 
@@ -674,11 +690,9 @@ def make_train_step(
     # the device queue, making dispatch-to-dispatch time the real step
     # time without a single synchronization.
     last_dispatch = [0.0]
-    step_count = [0]
 
     def _record_step_metrics(x):
         now = _time.perf_counter()
-        step_count[0] += 1
         if last_dispatch[0]:
             dt = now - last_dispatch[0]
             metrics.STEP_SECONDS.observe(dt)
@@ -747,7 +761,7 @@ def make_train_step(
         plan = box.get("plan")
         if plan is not None and getattr(plan, "compression", None):
             plan = dataclasses_replace_plan(plan)
-        _rebuild(box["threshold"], box["hier"], plan)
+        _rebuild(box["threshold"], box["hier"], plan, reason="guard")
 
     def dataclasses_replace_plan(plan):
         """The applied plan minus its compression decision — fusion
@@ -759,11 +773,30 @@ def make_train_step(
         except TypeError:
             return plan
 
+    def _count_compiles(n, x, y):
+        """``hvd_step_compiles_total``: the jitted step's cache grew
+        across the call — a rebuild's first call, or a silent retrace on
+        a new batch shape.  One integer compare a step; the event names
+        the step and the shapes, so "which step recompiled" has an
+        answer."""
+        size = box["fn"]._cache_size()
+        if size == box["cache_size"]:
+            return
+        box["cache_size"] = size
+        if metrics.on():
+            metrics.STEP_COMPILES.inc()
+        try:
+            from .observe import events as events_mod
+
+            events_mod.record_event(
+                "step.compile", payload={
+                    "step": n, "programs": size,
+                    "args": [f"{a.dtype}{list(a.shape)}" for a in
+                             jax.tree_util.tree_leaves((x, y))]})
+        except Exception:  # noqa: BLE001 — recording is best-effort
+            pass
+
     def _invoke(state, x, y, _under_trace=None):
-        # Host-side step record: advances the trace window (reference
-        # BYTEPS_TRACE_START/END_STEP semantics) and emits a STEP dispatch
-        # span.  On the compiled path collective timing lives inside XLA;
-        # this records the per-step cadence the tracer windows key on.
         # Skipped while under a jax trace (e.g. Recorder.record_step_function
         # running make_jaxpr) so abstract evaluation doesn't consume window
         # steps or emit phantom spans.  The autotuned wrapper passes its
@@ -772,46 +805,52 @@ def make_train_step(
             isinstance(leaf, jax.core.Tracer)
             for leaf in jax.tree_util.tree_leaves((state, x, y))
         )
-        if not under_trace:
-            # Failure-domain seam (docs/fault_tolerance.md): a coordinated
-            # abort raises HorovodAbortError here — before this rank
-            # dispatches a step its dead peer will never join — and the
-            # HVD_FAULT_SPEC harness injects its step-seam faults.
-            _heartbeat.maybe_raise_abort()
-            _faults.on_step()
-            # Elastic rebuild seam: after a membership epoch the mesh is
-            # new (core.reinit) and the compiled step — shard_map captured
-            # the old mesh at build — must re-trace over it.
-            if box["core_epoch"] != core._require_init().epoch:
-                _rebuild(box["threshold"], box["hier"], box.get("plan"))
-        if not under_trace and metrics.on():
-            _record_step_metrics(x)
-        if not under_trace:
-            box["profiled_last"] = False
-        if profiler is not None and not under_trace and profiler.on_step():
-            # capture window: the decomposed per-segment path, wrapped
-            # in the same timeline STEP span as a normal step so the
-            # comm.json window and compute.json envelopes stay aligned
-            box["profiled_last"] = True
-            if timeline.active:
-                timeline.record_step(owner="train_step")
-                timeline.mark_cycle_start()
-                with timeline.span("train_step", "STEP"):
-                    result = _profiled_step(state, x, y)
-            else:
-                result = _profiled_step(state, x, y)
-            _maybe_guard(result[0])
-            fetcher.push(result[1])
-            return result
-        if timeline.active and not under_trace:
+        if under_trace:
+            return box["fn"](state, x, y)
+        step_count[0] += 1
+        n = step_count[0]
+        if timeline.active:
+            # advances the trace window (reference
+            # BYTEPS_TRACE_START/END_STEP semantics) before the step's
+            # spans ask whether they are inside it
             timeline.record_step(owner="train_step")
             timeline.mark_cycle_start()
-            with timeline.span("train_step", "STEP"):
-                result = box["fn"](state, x, y)
-        else:
-            result = box["fn"](state, x, y)
-        if not under_trace:
-            _maybe_guard(result[0])
+        # Host-side step record (docs/profiling.md): hvd_step is the
+        # profiler's step (device ops are grouped under its number) and
+        # the timeline's STEP span; the spans beneath it carry the same
+        # step_num.  On the compiled path collective timing lives inside
+        # XLA; these record the per-step cadence the tracer windows key on.
+        with host_span("step", annotation=jax.profiler.StepTraceAnnotation,
+                       step_num=n):
+            with host_span("preflight", step_num=n):
+                # Failure-domain seam (docs/fault_tolerance.md): a
+                # coordinated abort raises HorovodAbortError here — before
+                # this rank dispatches a step its dead peer will never
+                # join — and the HVD_FAULT_SPEC harness injects its
+                # step-seam faults.
+                _heartbeat.maybe_raise_abort()
+                _faults.on_step()
+                # Elastic rebuild seam: after a membership epoch the mesh
+                # is new (core.reinit) and the compiled step — shard_map
+                # captured the old mesh at build — must re-trace over it.
+                if box["core_epoch"] != core._require_init().epoch:
+                    _rebuild(box["threshold"], box["hier"], box.get("plan"),
+                             reason="epoch")
+            if metrics.on():
+                _record_step_metrics(x)
+            box["profiled_last"] = profiler is not None \
+                and profiler.on_step()
+            if box["profiled_last"]:
+                # capture window: the decomposed per-segment path, inside
+                # the same STEP span as a normal step so the comm.json
+                # window and compute.json envelopes stay aligned
+                result = _profiled_step(state, x, y)
+            else:
+                with host_span("call", step_num=n):
+                    result = box["fn"](state, x, y)
+                _count_compiles(n, x, y)
+            with host_span("guard", step_num=n):
+                _maybe_guard(result[0])
             fetcher.push(result[1])
         return result
 
@@ -845,7 +884,7 @@ def make_train_step(
                 else:
                     pm.clear_plan()
             else:
-                _rebuild(box["threshold"], box["hier"], plan)
+                _rebuild(box["threshold"], box["hier"], plan, reason="plan")
 
         def _anatomy():
             """The compute tier's plan source: the in-job profiler's

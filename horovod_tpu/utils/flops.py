@@ -40,11 +40,15 @@ DEVICE_PEAKS = {
     "TPU v5 lite": DevicePeak(197e12, 819e9),
 }
 
-#: ResNet-50 training ≈ 3 × 4.09 GFLOPs forward of model math per image
-#: (the usual analytic count bench.py's headline MFU is built on; XLA's
-#: own cost_analysis reports ~23.9 GF/img because strided-conv gradients
-#: lower to dilated convs that multiply zeros)
-RESNET50_TRAIN_FLOPS_PER_IMG = 12.27e9
+#: Operations ResNet-50 v1.5 at 224x224 requires per image and training
+#: step, a multiply-add counted as two: 4.09 G multiply-adds forward, the
+#: gradient to the input and to the weights of every convolution and of the
+#: classifier, less the stem's input gradient, which nothing upstream
+#: wants.  Conv by conv in benchmarks/harness/flops.py
+#: (``resnet50_train_flops_per_image``), which a tier-1 test holds this
+#: constant to.  It read 12.27e9 until PR 24: multiply-adds counted as
+#: operations, so every MFU built on it read half.
+RESNET50_TRAIN_FLOPS_PER_IMG = 24.30e9
 
 
 def _mesh_device_kind() -> Optional[str]:

@@ -80,7 +80,7 @@ def test_result_line_names_the_device(monkeypatch, capsys):
     out = json.loads(line[len("RESULT "):])
     assert out["value"] == 2700.0
     assert {k: out[k] for k in device} == device
-    assert out["mfu"] == pytest.approx(2700.0 * 12.27e9 / 197e12, abs=1e-4)
+    assert out["mfu"] == pytest.approx(2700.0 * 24.30e9 / 197e12, abs=1e-4)
 
 
 def test_parent_imports_start_no_backend():

@@ -513,10 +513,10 @@ def test_bench_mfu_through_utils_flops(monkeypatch, hvd_init):
     monkeypatch.setenv("HVD_PEAK_FLOPS", "197e12")
     want = round(flops.image_model_mfu(2677.0), 4)
     assert bench._mfu(2677.0) == pytest.approx(want)
-    assert want == pytest.approx(2677.0 * 12.27e9 / 197e12, abs=1e-4)
+    assert want == pytest.approx(2677.0 * 24.30e9 / 197e12, abs=1e-4)
     monkeypatch.setenv("HVD_PEAK_FLOPS", "98.5e12")
     assert bench._mfu(2677.0) == pytest.approx(
-        round(2677.0 * 12.27e9 / 98.5e12, 4))
+        round(2677.0 * 24.30e9 / 98.5e12, 4))
     # a device that is not in the table is an error in bench.py, never
     # a null and never a v5e default (this mesh is CPU)
     monkeypatch.delenv("HVD_PEAK_FLOPS")
