@@ -13,7 +13,9 @@ batch of seeded random data:
   ``examples.synthetic_benchmark.run``, the function ``bench.py`` calls.
 
 It checks: the device is a TPU; ``flash_attention`` agrees with
-``softmax_attention`` on the chip (forward and gradients); the GPT step's
+``softmax_attention`` on the chip (forward and gradients, and with its
+offsets traced); on more than one chip, ring attention's Pallas variant
+agrees with it too (gradients over the whole ring); the GPT step's
 compiled module holds Mosaic custom calls; every loss is finite and the
 third is below the first; and, on more than one chip, that the batch is
 sharded one shard per device, the state replicated on all of them, the
@@ -102,8 +104,59 @@ def flash_phase() -> None:
         check(errs[label] <= FLASH_TOL,
               f"flash {label} differs from softmax_attention by "
               f"{errs[label]:.3g} of its largest element (> {FLASH_TOL})")
+    # the same call with its offsets traced, as ring attention makes it:
+    # the tile's kind is then decided on the chip, by the same kernels
+    traced = jax.jit(lambda q, k, v, at: flash_attention(
+        q, k, v, causal=True, q_offset=at, kv_offset=at))(
+            q, k, v, jnp.int32(0))
+    errs["traced_offsets"] = float(
+        np.abs(np.asarray(traced, np.float32) - got["flash"][0]).max())
+    check(errs["traced_offsets"] == 0.0,
+          "flash with traced offsets differs from the static call")
     report("flash_vs_reference", shape=[b, s, h, d], dtype="bfloat16",
            causal=True, tolerance=FLASH_TOL, rel_max_err=errs)
+
+
+def ring_phase(n: int) -> None:
+    """Ring attention's Pallas variant over all ``n`` chips against
+    softmax_attention on the whole sequence: the flash kernels with traced
+    offsets, forward and gradients."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    import horovod_tpu as hvd
+    from horovod_tpu.ops import collectives
+    from horovod_tpu.ops.flash_attention import softmax_attention
+    from horovod_tpu.parallel.ring_attention import ring_attention
+
+    b, s, h, d = 1, GPT_SEQ * n, 12, 64
+    keys = jax.random.split(jax.random.PRNGKey(1), 4)
+    q, k, v, w = (jax.random.normal(kk, (b, s, h, d), jnp.bfloat16)
+                  for kk in keys)
+
+    @hvd.spmd(in_specs=(P(None, hvd.AXIS),) * 4, out_specs=P())
+    def ring_loss(q, k, v, w):
+        out = ring_attention(q, k, v, causal=True, impl="pallas")
+        return collectives.allreduce(
+            jnp.sum((out * w).astype(jnp.float32)), op=hvd.Sum)
+
+    def ref_loss(q, k, v, w):
+        return jnp.sum((softmax_attention(q, k, v, causal=True)
+                        * w).astype(jnp.float32))
+
+    got = jax.grad(ring_loss, argnums=(0, 1, 2))(q, k, v, w)
+    ref = jax.jit(jax.grad(ref_loss, argnums=(0, 1, 2)))(q, k, v, w)
+    errs = {}
+    for label, a, b_ in zip(("dq", "dk", "dv"), got, ref):
+        a, b_ = np.asarray(a, np.float32), np.asarray(b_, np.float32)
+        check(np.isfinite(a).all(), f"ring {label} is not finite")
+        errs[label] = float(np.abs(a - b_).max() / np.abs(b_).max())
+        check(errs[label] <= FLASH_TOL,
+              f"ring {label} differs from softmax_attention by "
+              f"{errs[label]:.3g} of its largest element (> {FLASH_TOL})")
+    report("ring_vs_reference", shape=[b, s, h, d], chips=n,
+           tolerance=FLASH_TOL, rel_max_err=errs)
 
 
 def gpt_phase(n: int) -> None:
@@ -257,6 +310,8 @@ def main() -> int:
            peak_flops=flops.require_peak_flops())
 
     flash_phase()
+    if n > 1:
+        ring_phase(n)
     gpt_phase(n)
     resnet_phase()
 

@@ -21,6 +21,8 @@ hvd_host_collective_bytes_total counter    host-plane bytes, by ``op``/``transpo
 hvd_host_collective_seconds     histogram  host-plane wall time, by ``transport``
 hvd_collectives_traced_total    counter    collectives emitted at trace time
 hvd_collectives_traced_bytes_total counter traced payload bytes, by ``op``
+hvd_flash_tiles_traced_total    counter    flash score tiles per traced kernel
+                                           call, by ``kernel``/``kind``
 hvd_step_seconds                histogram  train-step cadence (dispatch-to-
                                            dispatch interval — honest under
                                            async dispatch, see training.py)
@@ -172,6 +174,12 @@ TRACED_GROUP_CALLS = registry.counter(
     "group (two-level local/cross stages, process sets) — the group-"
     "labelled inventory the schedule checker and sanitizer reason "
     "about.", ("op", "group"))
+FLASH_TILES = registry.counter(
+    "hvd_flash_tiles_traced_total",
+    "Score tiles of each traced flash-attention kernel call (per compile, "
+    "not per step): skipped (past the diagonal, not computed), full "
+    "(no key masked), crossed (the diagonal passes through); dynamic "
+    "(all of the grid's) when the offsets are traced.", ("kernel", "kind"))
 
 STEP_SECONDS = registry.histogram(
     "hvd_step_seconds",
@@ -493,6 +501,18 @@ def record_traced(op: str, tensor) -> None:
                            getattr(tensor, "dtype", "float32"))
         if nb:
             TRACED_BYTES.labels(op).inc(nb)
+    except Exception:  # noqa: BLE001 — tracing must never fail on metrics
+        pass
+
+
+def record_flash_tiles(kernel: str, counts) -> None:
+    """Score tiles by kind of one traced flash kernel call
+    (ops/flash_attention.py) — how often the unmasked body engages."""
+    if not registry.enabled:
+        return
+    try:
+        for kind, n in counts.items():
+            FLASH_TILES.labels(kernel, kind).inc(n)
     except Exception:  # noqa: BLE001 — tracing must never fail on metrics
         pass
 

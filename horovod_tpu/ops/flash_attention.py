@@ -6,11 +6,61 @@ of the TPU build, and this module is its compute core: a blockwise
 online-softmax ("flash") attention kernel family written in Pallas so the
 hot loop runs out of VMEM and the q·kᵀ / p·v contractions land on the MXU.
 
-Kernel structure: the kv loop is the innermost *grid* dimension (not a
-``fori_loop``) with the streaming accumulators in VMEM scratch that
-persists across grid steps — this lets the Mosaic pipeline overlap each
-kv-block DMA with the previous block's compute, which is ~2x over the
-loop-over-resident-kv formulation.
+Kernel structure.  A score tile is ``block_q x block_k``.  The streamed
+side of each kernel is the innermost *grid* dimension (not a
+``fori_loop``), with the accumulators in VMEM scratch that persists across
+grid steps, so the Mosaic pipeline overlaps each block's DMA with the
+previous block's compute; one grid step streams ``TILES_PER_STEP`` tiles'
+worth of it (keys for forward and dq, query rows for dkv).  What a step
+does with its block is decided from the offsets, at run time, by scalar
+arithmetic, so the local call and ring attention's traced offsets run the
+same code:
+
+* the resident rows see none of it (wholly past the diagonal): nothing is
+  computed, and the block's index map names the nearest block that is, so
+  nothing is fetched either;
+* forward and dq take the tiles their rows see at all as *one* block of
+  static width (a body for the whole block and one for half of it), so the
+  online softmax's statistics move once a grid step and not once a tile;
+  the forward has a third body without the mask for blocks its rows see
+  whole (the mask is a tenth of its time there; under 1% of dq's and
+  dkv's, which mask every causal tile);
+* dkv loops over the query tiles that are not skipped.
+
+The logit scale is multiplied into the ``[block, d]`` operand where that
+is exact (a power of two) and never onto the s^2 path of the backward
+(``ds`` goes unscaled into its product, ``dq``/``dk`` take the scale as
+they are written).  dkv computes its scores transposed, keys down the
+rows, so that ``p^T do`` and ``ds^T q`` need no transpose of a score tile
+and the row statistics arrive as lane-dense rows.  The forward's row sums
+stay partial sums a lane until the q block is done.  Every body is code
+that Mosaic unrolls and every layer's kernel carries, so the bodies are
+few, the resident rows are walked ``ROW_CHUNK`` at a time in a loop, and
+the launchers are jitted, which lets a model's layers share one trace and
+one lowering.
+
+Measured on one TPU v5e ("TPU v5 lite"), PR 25, each kernel alone on the
+device's clock at the two shapes the benchmark's GPT cells run, bf16,
+causal, in us per computed 512 x 512 tile (fwd / dq / dkv):
+
+====================================  ==================  ==================
+kernels                               [8,12,1024,64]      [1,12,16384,64]
+====================================  ==================  ==================
+before (one 512 x 512 tile a step)    2.28 / 1.89 / 2.51  2.13 / 1.76 / 2.89
+  its mask removed                    2.28 / 1.85 / 2.51  2.12 / 1.72 / 2.89
+  its statistics removed (forward)    1.46                1.34
+  1024 x 1024 tiles                   1.63 / 1.56 / 2.11  1.25 / 1.41 / 2.06
+these, 512 x 512, one tile a step     1.77 / 1.73 / 1.91  1.63 / 1.61 / 1.84
+these, 512 x 512, four tiles a step   1.43 / 1.27 / 1.65  1.11 / 1.30 / 1.51
+these as they are (1024 x 512, four)  1.43 / 1.27 / 1.68  1.03 / 1.24 / 1.53
+====================================  ==================  ==================
+
+(the matmuls alone need 0.68 / 1.02 / 1.36 at head_dim 64; the rows
+between were taken with every body unrolled, 110 MB of program for the
+16k step against 30 MB as they are).  The time was in the ``[block_q,
+1]`` statistics and their reductions across lanes, once a tile, and in
+dkv's two transposes; the mask cost nothing until those were gone.
+PERF.md section 6 has the whole table.
 
 Three public entry points:
 
@@ -36,6 +86,8 @@ interpreter mode, which keeps every test oracle-checkable on the
 from __future__ import annotations
 
 import functools
+import inspect
+import math
 from typing import Optional
 
 import jax
@@ -45,17 +97,36 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Finite stand-in for -inf: keeps exp()-of-differences NaN-free for fully
-# masked rows (exp(NEG_INF - NEG_INF) = 1, then zeroed by the mask select).
+# Finite stand-in for -inf, written over masked scores.
 NEG_INF = -1e30
+# Where a row's running maximum starts.  Above NEG_INF on purpose: a row that
+# is masked whole then computes exp(NEG_INF - M_INIT) = 0 and not
+# exp(NEG_INF - NEG_INF) = 1, so no second select has to zero ``p``, the row
+# sums stay 0 and the statistics handed on (m >= M_INIT) keep the backward
+# kernels' exp(s - lse) at 0 there too.
+M_INIT = NEG_INF / 2
 
-# 512x512 won a 128..1024 sweep at one shape (b4 h12 d64 s2048) on an
-# earlier chip path; root PERF.md lists it as a hypothesis to re-measure.
-# _fit_block shrinks automatically for shorter sequences
-DEFAULT_BLOCK_Q = 512
+# One score tile is block_q x block_k; a grid step streams TILES_PER_STEP
+# tiles' worth of the other operand (keys for forward and dq, query rows for
+# dkv).  From PR 25's sweep on a v5e at [8,12,1024,64] and [1,12,16384,64]
+# (the table in the module's docstring): 1024 query rows beat 512 by 6-7%
+# in forward and dq at 16k and lose at 1k, where _check_blocks fits them to
+# half the sequence; 1024 keys a tile change under 1%; a score block past
+# 8 MB (1024 x 4096, 2048 x 2048) runs four to eight times slower.
+DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 512
+TILES_PER_STEP = 4
+# Rows of the resident q tile that forward and dq work on at a time, in a
+# loop that is not unrolled: Mosaic unrolls everything else, and a kernel's
+# code grows with rows x columns of every body (36 kernels of 1024-row
+# bodies, nine of them a kernel, made a 110 MB program of the 16k cell's
+# step, 8 MB before, and its warm set-up 78 s for 52).  512 costs 2% of
+# forward and dq against no loop, 256 costs 8%, 128 costs 30%.
+ROW_CHUNK = 512
 
 _DIM_SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary")
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
 
 # The three kernels' names: each ``pallas_call``'s ``name=`` and the
 # ``jax.named_scope`` it runs under, so a device trace tells forward, dq and
@@ -85,16 +156,19 @@ def _resolve_interpret(interpret: Optional[bool]) -> bool:
     return (not _on_tpu()) if interpret is None else interpret
 
 
+def _static_offsets(q_offset, kv_offset):
+    """``(q_offset, kv_offset)`` when both are Python ints, else ``None``:
+    what the tile counter can count at trace time."""
+    if isinstance(q_offset, (int, np.integer)) and isinstance(
+            kv_offset, (int, np.integer)):
+        return int(q_offset), int(kv_offset)
+    return None
+
+
 def _offsets(q_offset, kv_offset):
     q_offset = jnp.asarray(q_offset, jnp.int32).reshape(())
     kv_offset = jnp.asarray(kv_offset, jnp.int32).reshape(())
     return jnp.stack([q_offset, kv_offset])
-
-
-def _compiler_params(interpret):
-    if interpret:
-        return None
-    return pltpu.CompilerParams(dimension_semantics=_DIM_SEMANTICS)
 
 
 def _fit_block(seq: int, cap: int) -> int:
@@ -109,12 +183,174 @@ def _fit_block(seq: int, cap: int) -> int:
     return aligned if aligned * 4 >= plain else plain
 
 
-def _check_blocks(sq, sk, block_q, block_k):
-    """Fit block sizes to the seq lengths: the grid must tile exactly (a
+def _check_blocks(sq, sk, block_q, block_k, causal=False):
+    """Fit the tile to the seq lengths: the grid must tile exactly (a
     non-dividing seq would silently truncate the grid and leave the tail
-    of the output uninitialized), so shrink each block to the largest
-    divisor of its seq length instead of erroring on shapes like 192/128."""
+    of the output uninitialized), so shrink each side to the largest
+    divisor of its seq length instead of erroring on shapes like 192/128.
+    A causal tile takes at most half of either length, so that a short
+    sequence still has a tile above the diagonal to skip (at 1024 tokens
+    a 1024-row tile computes four 512 x 512 quarters where three do)."""
+    if causal:
+        block_q = min(block_q, max(sq // 2, 1))
+        block_k = min(block_k, max(sk // 2, 1))
     return _fit_block(sq, block_q), _fit_block(sk, block_k)
+
+
+def _tiles_per_step(seq: int, tile: int) -> int:
+    """How many ``tile``-wide tiles of the streamed operand one grid step
+    takes: the most up to TILES_PER_STEP that tile ``seq`` exactly."""
+    n = seq // tile
+    return next(c for c in range(min(TILES_PER_STEP, n), 0, -1) if n % c == 0)
+
+
+def _scale_parts(scale: float, dtype):
+    """``(operand factor, score factor)``, one of them ``None``.  The logit
+    scale is multiplied into the ``[block, d]`` operand when that is exact
+    in its dtype (a power of two, as 1/8 for head_dim 64), and stays on the
+    float32 scores otherwise."""
+    exact = (math.frexp(scale)[0] == 0.5
+             and jnp.issubdtype(dtype, jnp.floating))
+    return (scale, None) if exact else (None, scale)
+
+
+def _clip(x, lo, hi):
+    if isinstance(x, int):
+        return max(lo, min(x, hi))
+    return jnp.clip(x, lo, hi)
+
+
+def _kv_tiles_seen(q_first, q_rows, k_first, n, width):
+    """Of ``n`` key tiles of ``width`` that start at position ``k_first``:
+    ``(full, live)``, how many the query rows ``[q_first, q_first +
+    q_rows)`` see whole (last key <= first row: nothing to mask) and how
+    many they see at all.  Causal order puts the full tiles first, the
+    crossed ones after them and the skipped ones last.  Python ints or
+    traced scalars."""
+    full = _clip(q_first - k_first + 1, 0, n * width) // width
+    live = _clip(q_first + q_rows - 1 - k_first + width, 0, n * width) // width
+    return full, live
+
+
+def tile_census(sq, sk, block_q, block_k, causal, q_offset=0, kv_offset=0):
+    """How many ``block_q x block_k`` score tiles of one head are
+    ``skipped`` (every key after every query row: not computed), ``full``
+    (every key visible to every row: nothing to mask) and ``crossed`` (the
+    diagonal passes through).  The blocks are fitted to the lengths as the
+    kernels fit them."""
+    block_q, block_k = _check_blocks(sq, sk, block_q, block_k, causal)
+    nq, nk = sq // block_q, sk // block_k
+    counts = {"skipped": 0, "full": 0, "crossed": 0}
+    for i in range(nq):
+        full, live = (_kv_tiles_seen(q_offset + i * block_q, block_q,
+                                     kv_offset, nk, block_k)
+                      if causal else (nk, nk))
+        counts["full"] += full
+        counts["crossed"] += live - full
+        counts["skipped"] += nk - live
+    return counts
+
+
+def _count_tiles(kernel, static_offs, q, k, block_q, block_k, causal):
+    """The trace-time counter: once for every kernel call that is traced."""
+    from .. import metrics
+
+    (b, h, sq, _), sk = q.shape, k.shape[2]
+    if static_offs is None and causal:
+        block_q, block_k = _check_blocks(sq, sk, block_q, block_k, causal)
+        counts = {"dynamic": (sq // block_q) * (sk // block_k)}
+    else:
+        counts = tile_census(sq, sk, block_q, block_k, causal,
+                             *(static_offs or (0, 0)))
+    metrics.record_flash_tiles(
+        kernel, {kind: n * b * h for kind, n in counts.items()})
+
+
+def _row_minus_col(rows, cols):
+    return (lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+            - lax.broadcasted_iota(jnp.int32, (rows, cols), 1))
+
+
+def _chunk(i, size):
+    """Rows ``[i * size, (i + 1) * size)`` of a block, ``i`` a loop's index."""
+    return pl.ds(pl.multiple_of(i * size, size), size)
+
+
+def _loop(lo, hi, body):
+    """``body(i)`` for ``i`` in ``[lo, hi)``, not unrolled."""
+    lax.fori_loop(lo, hi, lambda i, carry: (body(i), carry)[1], None)
+
+
+def _sum_lanes(width):
+    """Lanes of the forward kernel's running row sums: partial sums a lane
+    (one add a vreg, no reduction across lanes until the q block is done)
+    where the tile's width allows, else the sum itself."""
+    return 128 if width % 128 == 0 else 1
+
+
+def _fold_lanes(p, lanes):
+    if lanes == 1:
+        return jnp.sum(p, axis=-1, keepdims=True)
+    return sum(p[:, c:c + lanes] for c in range(0, p.shape[1], lanes))
+
+
+def _each_kind_of_block(block, n_tiles, full, live, unmasked):
+    """What a grid step of forward or dq does with its kv block of
+    ``n_tiles`` tiles, of which its query rows see ``full`` whole and
+    ``live`` at all.  Nothing where they see none.  Where the diagonal
+    crosses the block, ``block(width, masked=True)`` over the tiles they
+    see any of, rounded up to half a block: a body of static width each, so
+    the compiler schedules it whole, and two of them, because every body
+    is code (Mosaic unrolls it) that every layer's kernel carries.  With
+    ``unmasked``, ``block(n_tiles, masked=False)`` where they see all of
+    it whole; without, those blocks take the masked body too."""
+    half = max(1, n_tiles // 2)
+    crossed = live > 0
+    if unmasked:
+        pl.when(full == n_tiles)(lambda: block(n_tiles, False))
+        crossed = jnp.logical_and(crossed, full < n_tiles)
+    for width in range(half, n_tiles + 1, half):
+        pl.when(jnp.logical_and(crossed, (live + half - 1) // half * half
+                                == width))(
+            functools.partial(block, width, True))
+
+
+def _jit_kernel(fn):
+    """The three launchers are jitted with everything but the arrays
+    static, so that a model's layers share one trace of the kernel and one
+    lowering of it to Mosaic (the lowering runs in Python on every start,
+    cached program or not: 36 separate calls of these kernels put 24 s
+    onto the 16k cell's set-up)."""
+    return jax.jit(fn, static_argnames=tuple(
+        inspect.getfullargspec(fn).kwonlyargs))
+
+
+def _launch(name, kernel, offs, grid, ins, outs, scratch, interpret):
+    """One of the three ``pallas_call``s, under its name.  ``ins`` are
+    (array, block shape, index map), ``outs`` (shape, dtype, block shape,
+    index map), ``scratch`` float32 VMEM shapes.  The blocks as they are
+    fit Mosaic's 16 MiB of scoped VMEM up to head_dim 256 in float32."""
+    params = None if interpret else pltpu.CompilerParams(
+        dimension_semantics=_DIM_SEMANTICS)
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[pl.BlockSpec(block, index) for _, block, index in ins],
+            out_specs=[pl.BlockSpec(block, index)
+                       for _, _, block, index in outs],
+            scratch_shapes=[pltpu.VMEM(shape, jnp.float32)
+                            for shape in scratch],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(shape, dtype)
+                   for shape, dtype, _, _ in outs],
+        compiler_params=params,
+        interpret=interpret,
+        name=name,
+    )
+    with jax.named_scope(name):
+        return call(offs, *(x for x, _, _ in ins))
 
 
 # ---------------------------------------------------------------------------
@@ -124,115 +360,121 @@ def _check_blocks(sq, sk, block_q, block_k):
 
 def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                 acc_ref, mi_ref, li_ref, *,
-                causal, scale, normalize):
-    bq, d = q_ref.shape[2], q_ref.shape[3]
-    bk = k_ref.shape[2]
-    iq = pl.program_id(2)
+                causal, scale, normalize, tile_k):
+    bq = q_ref.shape[2]
+    chunk = _fit_block(bq, ROW_CHUNK)
+    n_tiles = k_ref.shape[2] // tile_k
     j = pl.program_id(3)
-    nk = pl.num_programs(3)
-    q_off = offs_ref[0]
-    kv_off = offs_ref[1]
+    q_first = offs_ref[0] + pl.program_id(2) * bq
+    k_first = offs_ref[1] + j * k_ref.shape[2]
+    q_scale, s_scale = _scale_parts(scale, q_ref.dtype)
 
     @pl.when(j == 0)
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
-        mi_ref[:] = jnp.full_like(mi_ref, NEG_INF)
+        mi_ref[:] = jnp.full_like(mi_ref, M_INIT)
         li_ref[:] = jnp.zeros_like(li_ref)
 
-    def compute():
-        q = q_ref[0, 0]
-        kb = k_ref[0, 0]
-        vb = v_ref[0, 0]
-        s = lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if causal:
-            q_pos = (q_off + iq * bq
-                     + lax.broadcasted_iota(jnp.int32, (bq, bk), 0))
-            k_pos = (kv_off + j * bk
-                     + lax.broadcasted_iota(jnp.int32, (bq, bk), 1))
-            mask = q_pos >= k_pos
-            s = jnp.where(mask, s, NEG_INF)
-        m_prev = mi_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        if causal:
-            p = jnp.where(mask, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        mi_ref[:] = m_new
-        li_ref[:] = li_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + lax.dot_general(
-            p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    def block(width, masked):
+        """One step of the online softmax over the first ``width`` tiles
+        of the kv block, taken as one: the statistics move once."""
+        cols = pl.ds(0, width * tile_k)
+
+        def some_rows(r):
+            rows = _chunk(r, chunk)
+            q = q_ref[0, 0, rows, :]
+            if q_scale is not None:
+                q = q * q_scale
+            s = lax.dot_general(q, k_ref[0, 0, cols, :], _NT,
+                                preferred_element_type=jnp.float32)
+            if s_scale is not None:
+                s = s * s_scale
+            if masked:
+                s = jnp.where(_row_minus_col(*s.shape)
+                              >= k_first - q_first - r * chunk, s, NEG_INF)
+            m_prev = mi_ref[rows, :]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            mi_ref[rows, :] = m_new
+            li_ref[rows, :] = (li_ref[rows, :] * alpha
+                               + _fold_lanes(p, li_ref.shape[1]))
+            vb = v_ref[0, 0, cols, :]
+            acc_ref[rows, :] = acc_ref[rows, :] * alpha + lax.dot_general(
+                p.astype(vb.dtype), vb, _NN,
+                preferred_element_type=jnp.float32)
+
+        _loop(0, bq // chunk, some_rows)
 
     if causal:
-        # Skip kv blocks strictly in the future of every row of this q block.
-        pl.when(kv_off + j * bk <= q_off + iq * bq + bq - 1)(compute)
+        # the mask is a tenth of this kernel's time where it is not needed
+        _each_kind_of_block(
+            block, n_tiles,
+            *_kv_tiles_seen(q_first, bq, k_first, n_tiles, tile_k),
+            unmasked=True)
     else:
-        compute()
+        block(n_tiles, False)
 
-    @pl.when(j == nk - 1)
+    @pl.when(j == pl.num_programs(3) - 1)
     def _():
         acc = acc_ref[:]
+        l = jnp.sum(li_ref[:], axis=-1, keepdims=True)
         if normalize:
-            acc = acc / jnp.maximum(li_ref[:], 1e-30)
+            acc = acc / jnp.maximum(l, 1e-30)
         o_ref[0, 0] = acc.astype(o_ref.dtype)
         m_ref[0, 0] = mi_ref[:]
-        l_ref[0, 0] = li_ref[:]
+        l_ref[0, 0] = l
 
 
-def _mha_fwd(q, k, v, offs, *, causal, scale, block_q, block_k,
-             normalize, interpret):
+def _kv_block_index(causal, block_q, block_k, sk):
+    """Index map of the streamed k / v blocks of forward and dq.  A grid
+    step wholly past the diagonal computes nothing, so it names the last
+    block its query rows do see: the index does not change and nothing is
+    fetched."""
+    def index(b_, h_, i, j, offs):
+        if causal:
+            last = _clip(offs[0] + i * block_q + block_q - 1 - offs[1],
+                         0, sk - 1) // block_k
+            j = jnp.minimum(j, last)
+        return (b_, h_, j, 0)
+
+    return index
+
+
+def _mha_fwd(q, k, v, offs, *, causal, block_q, block_k, interpret,
+             static_offs=None, **kw):
     """q/k/v ``[b,h,s,d]``; returns ``(o, m, l)`` with m/l ``[b,h,sq,1]``."""
+    _count_tiles("fwd", static_offs, q, k, block_q, block_k, causal)
+    return _fwd_call(q, k, v, offs, causal=causal, block_q=block_q,
+                     block_k=block_k,
+                     interpret=_resolve_interpret(interpret), **kw)
+
+
+@_jit_kernel
+def _fwd_call(q, k, v, offs, *, causal, scale, block_q, block_k, normalize,
+              interpret):
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    block_q, block_k = _check_blocks(sq, sk, block_q, block_k)
-    interpret = _resolve_interpret(interpret)
-    grid = (b, h, sq // block_q, sk // block_k)
+    block_q, tile_k = _check_blocks(sq, sk, block_q, block_k, causal)
+    block_k = tile_k * _tiles_per_step(sk, tile_k)
     kernel = functools.partial(
         _fwd_kernel, causal=causal, scale=scale, normalize=normalize,
+        tile_k=tile_k,
     )
     out_dtype = q.dtype if normalize else jnp.float32
-    call = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, block_q, d),
-                             lambda b_, h_, i, j, *_: (b_, h_, i, 0)),
-                pl.BlockSpec((1, 1, block_k, d),
-                             lambda b_, h_, i, j, *_: (b_, h_, j, 0)),
-                pl.BlockSpec((1, 1, block_k, d),
-                             lambda b_, h_, i, j, *_: (b_, h_, j, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1, block_q, d),
-                             lambda b_, h_, i, j, *_: (b_, h_, i, 0)),
-                pl.BlockSpec((1, 1, block_q, 1),
-                             lambda b_, h_, i, j, *_: (b_, h_, i, 0)),
-                pl.BlockSpec((1, 1, block_q, 1),
-                             lambda b_, h_, i, j, *_: (b_, h_, i, 0)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((block_q, d), jnp.float32),
-                pltpu.VMEM((block_q, 1), jnp.float32),
-                pltpu.VMEM((block_q, 1), jnp.float32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq, d), out_dtype),
-            jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
-        ],
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
-        name=FWD_KERNEL,
-    )
-    with jax.named_scope(FWD_KERNEL):
-        return call(offs, q, k, v)
+    q_index = lambda b_, h_, i, j, offs: (b_, h_, i, 0)  # noqa: E731
+    kv_index = _kv_block_index(causal, block_q, block_k, sk)
+    q_block, kv_block, row = ((1, 1, block_q, d), (1, 1, block_k, d),
+                              (1, 1, block_q, 1))
+    return _launch(
+        FWD_KERNEL, kernel, offs, (b, h, sq // block_q, sk // block_k),
+        ins=[(q, q_block, q_index), (k, kv_block, kv_index),
+             (v, kv_block, kv_index)],
+        outs=[((b, h, sq, d), out_dtype, q_block, q_index),
+              ((b, h, sq, 1), jnp.float32, row, q_index),
+              ((b, h, sq, 1), jnp.float32, row, q_index)],
+        scratch=[(block_q, d), (block_q, 1), (block_q, _sum_lanes(tile_k))],
+        interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -241,211 +483,198 @@ def _mha_fwd(q, k, v, offs, *, causal, scale, block_q, block_k,
 
 
 def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_acc_ref, *, causal, scale):
+                   dq_ref, dq_acc_ref, *, causal, scale, tile_k):
     bq = q_ref.shape[2]
-    bk = k_ref.shape[2]
-    iq = pl.program_id(2)
+    chunk = _fit_block(bq, ROW_CHUNK)
+    n_tiles = k_ref.shape[2] // tile_k
     j = pl.program_id(3)
-    nk = pl.num_programs(3)
-    q_off = offs_ref[0]
-    kv_off = offs_ref[1]
+    q_first = offs_ref[0] + pl.program_id(2) * bq
+    k_first = offs_ref[1] + j * k_ref.shape[2]
+    q_scale, s_scale = _scale_parts(scale, q_ref.dtype)
 
     @pl.when(j == 0)
     def _():
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
-    def compute():
-        q = q_ref[0, 0]
-        kb = k_ref[0, 0]
-        vb = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
-        s = lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if causal:
-            q_pos = (q_off + iq * bq
-                     + lax.broadcasted_iota(jnp.int32, (bq, bk), 0))
-            k_pos = (kv_off + j * bk
-                     + lax.broadcasted_iota(jnp.int32, (bq, bk), 1))
-            mask = q_pos >= k_pos
-            s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        if causal:
-            p = jnp.where(mask, p, 0.0)
-        dp = lax.dot_general(
-            do, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta) * scale
-        dq_acc_ref[:] += lax.dot_general(
-            ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    def block(width, masked):
+        """dq's share of the first ``width`` tiles of the kv block."""
+        cols = pl.ds(0, width * tile_k)
+
+        def some_rows(r):
+            rows = _chunk(r, chunk)
+            kb = k_ref[0, 0, cols, :]
+            q = q_ref[0, 0, rows, :]
+            if q_scale is not None:
+                q = q * q_scale
+            s = lax.dot_general(q, kb, _NT,
+                                preferred_element_type=jnp.float32)
+            if s_scale is not None:
+                s = s * s_scale
+            p = jnp.exp(s - lse_ref[0, 0, rows, :])
+            if masked:
+                # after the exp: a select drops what overflowed where masked
+                p = jnp.where(_row_minus_col(*s.shape)
+                              >= k_first - q_first - r * chunk, p, 0.0)
+            dp = lax.dot_general(do_ref[0, 0, rows, :], v_ref[0, 0, cols, :],
+                                 _NT, preferred_element_type=jnp.float32)
+            ds = p * (dp - delta_ref[0, 0, rows, :])
+            dq_acc_ref[rows, :] += lax.dot_general(
+                ds.astype(kb.dtype), kb, _NN,
+                preferred_element_type=jnp.float32)
+
+        _loop(0, bq // chunk, some_rows)
 
     if causal:
-        pl.when(kv_off + j * bk <= q_off + iq * bq + bq - 1)(compute)
+        # the mask costs this kernel under 1% (the VPU has the room beside
+        # three products), a second copy of the body costs code
+        _each_kind_of_block(
+            block, n_tiles,
+            *_kv_tiles_seen(q_first, bq, k_first, n_tiles, tile_k),
+            unmasked=False)
     else:
-        compute()
+        block(n_tiles, False)
 
-    @pl.when(j == nk - 1)
+    @pl.when(j == pl.num_programs(3) - 1)
     def _():
-        dq_ref[0, 0] = dq_acc_ref[:]
+        # ds was left without the logit scale: once here, on [block_q, d]
+        dq_ref[0, 0] = (dq_acc_ref[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *,
-                    causal, scale):
+                    causal, scale, tile_q):
+    """Scores transposed, keys down the rows and queries along the lanes:
+    every product is in a form the MXU takes as it is (k q^T, v do^T, p^T
+    do, ds^T q), and the row statistics come in as lane-dense rows."""
     bk = k_ref.shape[2]
-    bq = q_ref.shape[2]
-    ik = pl.program_id(2)
+    n_tiles = q_ref.shape[2] // tile_q
     i = pl.program_id(3)
-    nq = pl.num_programs(3)
-    q_off = offs_ref[0]
-    kv_off = offs_ref[1]
+    q_first = offs_ref[0] + i * q_ref.shape[2]
+    k_first = offs_ref[1] + pl.program_id(2) * bk
+    k_scale, s_scale = _scale_parts(scale, k_ref.dtype)
 
     @pl.when(i == 0)
     def _():
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
-    def compute():
+    def tile(c):
+        """dk's and dv's share of query tile ``c`` of the block."""
+        rows = _chunk(c, tile_q)
         kb = k_ref[0, 0]
-        vb = v_ref[0, 0]
-        qb = q_ref[0, 0]
-        dob = do_ref[0, 0]
-        lseb = lse_ref[0, 0]
-        deltab = delta_ref[0, 0]
-        s = lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
+        if k_scale is not None:
+            kb = kb * k_scale
+        qc = q_ref[0, 0, rows, :]
+        doc = do_ref[0, 0, rows, :]
+        st = lax.dot_general(kb, qc, _NT, preferred_element_type=jnp.float32)
+        if s_scale is not None:
+            st = st * s_scale
+        pt = jnp.exp(st - lse_ref[0, 0, c])
         if causal:
-            q_pos = (q_off + i * bq
-                     + lax.broadcasted_iota(jnp.int32, (bq, bk), 0))
-            k_pos = (kv_off + ik * bk
-                     + lax.broadcasted_iota(jnp.int32, (bq, bk), 1))
-            mask = q_pos >= k_pos
-            s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lseb)
-        if causal:
-            p = jnp.where(mask, p, 0.0)
+            # on every tile: the mask costs this kernel nothing measurable
+            # (0.2%), a second body is code.  After the exp: a select drops
+            # what overflowed where masked
+            pt = jnp.where(_row_minus_col(bk, tile_q)
+                           <= q_first + c * tile_q - k_first, pt, 0.0)
         dv_acc_ref[:] += lax.dot_general(
-            p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = lax.dot_general(
-            dob, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - deltab) * scale
+            pt.astype(doc.dtype), doc, _NN,
+            preferred_element_type=jnp.float32)
+        dpt = lax.dot_general(v_ref[0, 0], doc, _NT,
+                              preferred_element_type=jnp.float32)
+        dst = pt * (dpt - delta_ref[0, 0, c])
         dk_acc_ref[:] += lax.dot_general(
-            ds.astype(qb.dtype), qb, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+            dst.astype(qc.dtype), qc, _NN,
+            preferred_element_type=jnp.float32)
 
+    # query tiles wholly before the first key come first and are skipped
+    skipped = 0
     if causal:
-        # Skip q blocks entirely before this kv block.
-        pl.when(kv_off + ik * bk <= q_off + i * bq + bq - 1)(compute)
-    else:
-        compute()
+        skipped = _clip(k_first - q_first, 0, n_tiles * tile_q) // tile_q
+    _loop(skipped, n_tiles, tile)
 
-    @pl.when(i == nq - 1)
+    @pl.when(i == pl.num_programs(3) - 1)
     def _():
-        dk_ref[0, 0] = dk_acc_ref[:]
-        dv_ref[0, 0] = dv_acc_ref[:]
+        dk_ref[0, 0] = (dk_acc_ref[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc_ref[:].astype(dv_ref.dtype)
 
 
-def _mha_bwd_dq(q, k, v, do, lse, delta, offs, *, causal, scale, block_q,
-                block_k, interpret):
+def _mha_bwd_dq(q, k, v, do, lse, delta, offs, *, causal, block_q, block_k,
+                interpret, static_offs=None, **kw):
+    """lse/delta ``[b,h,sq,1]``."""
+    _count_tiles("dq", static_offs, q, k, block_q, block_k, causal)
+    return _dq_call(q, k, v, do, lse, delta, offs, causal=causal,
+                    block_q=block_q, block_k=block_k,
+                    interpret=_resolve_interpret(interpret), **kw)
+
+
+@_jit_kernel
+def _dq_call(q, k, v, do, lse, delta, offs, *, causal, scale, block_q,
+             block_k, interpret, out_dtype=jnp.float32):
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    block_q, block_k = _check_blocks(sq, sk, block_q, block_k)
-    interpret = _resolve_interpret(interpret)
-    grid = (b, h, sq // block_q, sk // block_k)
-    kernel = functools.partial(_bwd_dq_kernel, causal=causal, scale=scale)
-    call = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, block_q, d),
-                             lambda b_, h_, i, j, *_: (b_, h_, i, 0)),
-                pl.BlockSpec((1, 1, block_k, d),
-                             lambda b_, h_, i, j, *_: (b_, h_, j, 0)),
-                pl.BlockSpec((1, 1, block_k, d),
-                             lambda b_, h_, i, j, *_: (b_, h_, j, 0)),
-                pl.BlockSpec((1, 1, block_q, d),
-                             lambda b_, h_, i, j, *_: (b_, h_, i, 0)),
-                pl.BlockSpec((1, 1, block_q, 1),
-                             lambda b_, h_, i, j, *_: (b_, h_, i, 0)),
-                pl.BlockSpec((1, 1, block_q, 1),
-                             lambda b_, h_, i, j, *_: (b_, h_, i, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, block_q, d),
-                                   lambda b_, h_, i, j, *_: (b_, h_, i, 0)),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), jnp.float32),
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
-        name=DQ_KERNEL,
-    )
-    with jax.named_scope(DQ_KERNEL):
-        return call(offs, q, k, v, do, lse, delta)
+    block_q, tile_k = _check_blocks(sq, sk, block_q, block_k, causal)
+    block_k = tile_k * _tiles_per_step(sk, tile_k)
+    kernel = functools.partial(_bwd_dq_kernel, causal=causal, scale=scale,
+                               tile_k=tile_k)
+    q_index = lambda b_, h_, i, j, offs: (b_, h_, i, 0)  # noqa: E731
+    kv_index = _kv_block_index(causal, block_q, block_k, sk)
+    q_block, kv_block, row = ((1, 1, block_q, d), (1, 1, block_k, d),
+                              (1, 1, block_q, 1))
+    return _launch(
+        DQ_KERNEL, kernel, offs, (b, h, sq // block_q, sk // block_k),
+        ins=[(q, q_block, q_index), (k, kv_block, kv_index),
+             (v, kv_block, kv_index), (do, q_block, q_index),
+             (lse, row, q_index), (delta, row, q_index)],
+        outs=[((b, h, sq, d), out_dtype, q_block, q_index)],
+        scratch=[(block_q, d)], interpret=interpret)[0]
 
 
-def _mha_bwd_dkv(q, k, v, do, lse, delta, offs, *, causal, scale, block_q,
-                 block_k, interpret):
+def _mha_bwd_dkv(q, k, v, do, lse, delta, offs, *, causal, block_q, block_k,
+                 interpret, static_offs=None, **kw):
+    """lse/delta: one float32 a query row, in any shape; the kernel reads
+    them as rows of ``tile_q`` lanes."""
+    _count_tiles("dkv", static_offs, q, k, block_q, block_k, causal)
+    return _dkv_call(q, k, v, do, lse, delta, offs, causal=causal,
+                     block_q=block_q, block_k=block_k,
+                     interpret=_resolve_interpret(interpret), **kw)
+
+
+@_jit_kernel
+def _dkv_call(q, k, v, do, lse, delta, offs, *, causal, scale, block_q,
+              block_k, interpret, out_dtype=jnp.float32):
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    block_q, block_k = _check_blocks(sq, sk, block_q, block_k)
-    interpret = _resolve_interpret(interpret)
-    grid = (b, h, sk // block_k, sq // block_q)
-    kernel = functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale)
-    call = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, block_q, d),
-                             lambda b_, h_, jk, i, *_: (b_, h_, i, 0)),
-                pl.BlockSpec((1, 1, block_k, d),
-                             lambda b_, h_, jk, i, *_: (b_, h_, jk, 0)),
-                pl.BlockSpec((1, 1, block_k, d),
-                             lambda b_, h_, jk, i, *_: (b_, h_, jk, 0)),
-                pl.BlockSpec((1, 1, block_q, d),
-                             lambda b_, h_, jk, i, *_: (b_, h_, i, 0)),
-                pl.BlockSpec((1, 1, block_q, 1),
-                             lambda b_, h_, jk, i, *_: (b_, h_, i, 0)),
-                pl.BlockSpec((1, 1, block_q, 1),
-                             lambda b_, h_, jk, i, *_: (b_, h_, i, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1, block_k, d),
-                             lambda b_, h_, jk, i, *_: (b_, h_, jk, 0)),
-                pl.BlockSpec((1, 1, block_k, d),
-                             lambda b_, h_, jk, i, *_: (b_, h_, jk, 0)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((block_k, d), jnp.float32),
-                pltpu.VMEM((block_k, d), jnp.float32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, sk, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, sk, d), jnp.float32),
-        ],
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
-        name=DKV_KERNEL,
-    )
-    with jax.named_scope(DKV_KERNEL):
-        return call(offs, q, k, v, do, lse, delta)
+    tile_q, block_k = _check_blocks(sq, sk, block_q, block_k, causal)
+    n_tiles = _tiles_per_step(sq, tile_q)
+    block_q = tile_q * n_tiles
+    kernel = functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale,
+                               tile_q=tile_q)
+    lse, delta = (x.reshape(b, h, sq // tile_q, 1, tile_q)
+                  for x in (lse, delta))
+
+    def first_seen(b_, h_, jk, i, offs):
+        # a grid step whose query rows all lie before the kv block computes
+        # nothing: it names the first block that does, and nothing is
+        # fetched
+        if causal:
+            first = _clip(offs[1] + jk * block_k - offs[0],
+                          0, sq - 1) // block_q
+            i = jnp.maximum(i, first)
+        return i
+
+    q_index = lambda *g: (*g[:2], first_seen(*g), 0)  # noqa: E731
+    row_index = lambda *g: (*g[:2], first_seen(*g), 0, 0)  # noqa: E731
+    kv_index = lambda b_, h_, jk, i, offs: (b_, h_, jk, 0)  # noqa: E731
+    q_block, kv_block, rows = ((1, 1, block_q, d), (1, 1, block_k, d),
+                               (1, 1, n_tiles, 1, tile_q))
+    return _launch(
+        DKV_KERNEL, kernel, offs, (b, h, sk // block_k, sq // block_q),
+        ins=[(q, q_block, q_index), (k, kv_block, kv_index),
+             (v, kv_block, kv_index), (do, q_block, q_index),
+             (lse, rows, row_index), (delta, rows, row_index)],
+        outs=[((b, h, sk, d), out_dtype, kv_block, kv_index),
+              ((b, h, sk, d), out_dtype, kv_block, kv_index)],
+        scratch=[(block_k, d), (block_k, d)], interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -458,11 +687,13 @@ def mha_partial(q, k, v, q_offset, kv_offset, *, causal, scale,
                 interpret=None):
     """Unnormalized streaming triple ``(o[f32], m, l)`` for one q-shard ×
     kv-shard pair; offsets are *global positions* and may be traced.
-    m/l come back ``[b,h,sq,1]`` so they broadcast against ``o``."""
+    m/l come back ``[b,h,sq,1]`` so they broadcast against ``o``; a row
+    that sees no key has ``l`` 0 and ``m`` :data:`M_INIT`."""
     return _mha_fwd(
         q, k, v, _offsets(q_offset, kv_offset), causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, normalize=False,
         interpret=interpret,
+        static_offs=_static_offsets(q_offset, kv_offset),
     )
 
 
@@ -474,6 +705,7 @@ def mha_bwd_dq(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
         q, k, v, do, lse, delta, _offsets(q_offset, kv_offset),
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
         interpret=interpret,
+        static_offs=_static_offsets(q_offset, kv_offset),
     )
 
 
@@ -485,6 +717,7 @@ def mha_bwd_dkv(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
         q, k, v, do, lse, delta, _offsets(q_offset, kv_offset),
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
         interpret=interpret,
+        static_offs=_static_offsets(q_offset, kv_offset),
     )
 
 
@@ -494,9 +727,9 @@ def mha_bwd_dkv(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
 
 
 @functools.lru_cache(maxsize=None)
-def _flash_fn(causal, scale, block_q, block_k, interpret):
+def _flash_fn(causal, scale, block_q, block_k, interpret, static_offs):
     kw = dict(causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-              interpret=interpret)
+              interpret=interpret, static_offs=static_offs)
 
     @jax.custom_vjp
     def f(q, k, v, offs):
@@ -505,16 +738,20 @@ def _flash_fn(causal, scale, block_q, block_k, interpret):
 
     def fwd(q, k, v, offs):
         o, m, l = _mha_fwd(q, k, v, offs, normalize=True, **kw)
-        lse = m + jnp.log(jnp.maximum(l, 1e-30))  # [b,h,sq,1]
+        # kept [b,h,sq]: a [b,h,sq,1] float32 array pads every row to a
+        # tile of 128 lanes in HBM
+        lse = (m + jnp.log(jnp.maximum(l, 1e-30)))[..., 0]
         return o, (q, k, v, o, lse, offs)
 
     def bwd(res, do):
         q, k, v, o, lse, offs = res
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=-1, keepdims=True)
-        dq = _mha_bwd_dq(q, k, v, do, lse, delta, offs, **kw)
-        dk, dv = _mha_bwd_dkv(q, k, v, do, lse, delta, offs, **kw)
-        return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
+                        axis=-1)
+        dq = _mha_bwd_dq(q, k, v, do, lse[..., None], delta[..., None],
+                         offs, out_dtype=q.dtype, **kw)
+        dk, dv = _mha_bwd_dkv(q, k, v, do, lse, delta, offs,
+                              out_dtype=k.dtype, **kw)
+        return (dq, dk, dv.astype(v.dtype),
                 np.zeros(offs.shape, dtype=jax.dtypes.float0))
 
     f.defvjp(fwd, bwd)
@@ -537,13 +774,15 @@ def flash_attention(q, k, v, *, causal: bool = False,
       scale: logit scale, default ``1/sqrt(head_dim)``.
       q_offset, kv_offset: global position of element 0 of the q / kv
         shards (used by sequence-parallel callers).
+      block_q, block_k: rows and columns of one score tile.
 
     Returns attention output, same shape/dtype as ``q``.
     """
     if scale is None:
         scale = 1.0 / float(np.sqrt(q.shape[-1]))
     fn = _flash_fn(bool(causal), float(scale), int(block_q), int(block_k),
-                   _resolve_interpret(interpret))
+                   _resolve_interpret(interpret),
+                   _static_offsets(q_offset, kv_offset))
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)

@@ -1106,6 +1106,12 @@ class QuietThreadingHTTPServer(ThreadingHTTPServer):
     connection teardowns: a stopped server aborting its keep-alive
     connections (``rdv_dead``) and clients hanging up mid-request."""
 
+    # socketserver's default backlog of 5 drops SYNs when a world's ranks
+    # renew their leases at once while the accept thread is starved (16
+    # clients on a loaded host: one renewal in a hundred took the kernel's
+    # 1 s retransmit; scripts/control_plane_bench.py --check saw it)
+    request_queue_size = 128
+
     def handle_error(self, request, client_address):  # noqa: D102
         import sys as _sys
 
