@@ -14,7 +14,9 @@ batch of seeded random data:
 
 It checks: the device is a TPU; ``flash_attention`` agrees with
 ``softmax_attention`` on the chip (forward and gradients, and with its
-offsets traced); on more than one chip, ring attention's Pallas variant
+offsets traced), also at head size 256; the chunked gated delta rule
+agrees with its token-by-token recurrence at ``[1, 2048, 32, 128]``; on
+more than one chip, ring attention's Pallas variant
 agrees with it too (gradients over the whole ring); the GPT step's
 compiled module holds Mosaic custom calls; every loss is finite and the
 third is below the first; and, on more than one chip, that the batch is
@@ -48,6 +50,12 @@ RESNET_BATCH_PER_CHIP = 128
 #: probabilities at different points, so a few percent of the largest
 #: element is the agreement bf16 can give.
 FLASH_TOL = 3e-2
+#: chunked gated delta rule (bf16 operands, float32 state and decays) vs the
+#: float32 recurrence on the same bf16-rounded inputs: max |a - b| / max |b|.
+#: The chunked form rounds T, U_hat, W and the state it reads to bf16 once
+#: a chunk, each 2^-8; the errors of a chunk's products add up in the
+#: output like the flash kernels' do, so the same few percent.
+SCAN_TOL = 3e-2
 #: n-chip vs one-device first-step loss (absolute; the loss is ~11):
 #: the same rows through programs tiled for different batch shapes
 LOSS_TOL = 1e-2
@@ -73,16 +81,22 @@ def cache_entries(path: str) -> set:
     return set(os.listdir(path)) if os.path.isdir(path) else set()
 
 
-def flash_phase() -> None:
-    """flash_attention vs softmax_attention at one shape on the chip."""
+def flash_phase(b: int = 2, s: int = GPT_SEQ, h: int = 12,
+                d: int = 64) -> None:
+    """flash_attention vs softmax_attention at one shape on the chip, with
+    the tiles the models call it with at that head size."""
+    import functools
+
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu.ops.flash_attention import (
-        flash_attention, softmax_attention,
-    )
+    from horovod_tpu.models.qwen3_next import flash_blocks
+    from horovod_tpu.ops import flash_attention as fa
 
-    b, s, h, d = 2, GPT_SEQ, 12, 64
+    softmax_attention = fa.softmax_attention
+    flash_attention = functools.partial(fa.flash_attention,
+                                        **flash_blocks(d))
+
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
     q, k, v, w = (jax.random.normal(kk, (b, s, h, d), jnp.bfloat16)
                   for kk in keys)
@@ -115,6 +129,55 @@ def flash_phase() -> None:
           "flash with traced offsets differs from the static call")
     report("flash_vs_reference", shape=[b, s, h, d], dtype="bfloat16",
            causal=True, tolerance=FLASH_TOL, rel_max_err=errs)
+
+
+def scan_phase() -> None:
+    """The chunked gated delta rule vs the recurrence itself, forward and
+    gradients, at the head count and sizes of Qwen3-Next's DeltaNet
+    layers."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.gated_delta import (
+        gated_delta_recurrence, gated_delta_rule,
+    )
+
+    b, s, h, d = 1, 2048, 32, 128
+    keys = jax.random.split(jax.random.PRNGKey(1), 6)
+    q, k, v, w = (jax.random.normal(kk, (b, s, h, d), jnp.float32)
+                  for kk in keys[:4])
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * d ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    q, k, v, w = (x.astype(jnp.bfloat16) for x in (q, k, v, w))
+    # log decays from -0.02 to -12 a token, as A_log's uniform(0, 16) gives
+    g = -jnp.exp(jax.random.uniform(keys[4], (b, s, h), minval=-4.0,
+                                    maxval=2.5))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[5], (b, s, h)))
+
+    def loss_of(fn):
+        return lambda q, k, v, g, beta: jnp.sum(
+            fn(q, k, v, g, beta).astype(jnp.float32)
+            * w.astype(jnp.float32))
+
+    got = {}
+    for name, fn in (("chunked", gated_delta_rule),
+                     ("recurrence", gated_delta_recurrence)):
+        with jax.default_matmul_precision(
+                "highest" if name == "recurrence" else "default"):
+            out = jax.jit(fn)(q, k, v, g, beta)
+            grads = jax.jit(jax.grad(loss_of(fn), argnums=(0, 1, 2, 3, 4)))(
+                q, k, v, g, beta)
+        got[name] = [np.asarray(a, np.float32) for a in (out, *grads)]
+    errs = {}
+    for label, a, b_ in zip(("out", "dq", "dk", "dv", "dg", "dbeta"),
+                            got["chunked"], got["recurrence"]):
+        check(np.isfinite(a).all(), f"chunked scan {label} is not finite")
+        errs[label] = float(np.abs(a - b_).max() / np.abs(b_).max())
+        check(errs[label] <= SCAN_TOL,
+              f"chunked scan {label} differs from the recurrence by "
+              f"{errs[label]:.3g} of its largest element (> {SCAN_TOL})")
+    report("scan_vs_recurrence", shape=[b, s, h, d], dtype="bfloat16",
+           chunk=64, tolerance=SCAN_TOL, rel_max_err=errs)
 
 
 def ring_phase(n: int) -> None:
@@ -310,6 +373,10 @@ def main() -> int:
            peak_flops=flops.require_peak_flops())
 
     flash_phase()
+    # Qwen3-Next's attention layer: 16 heads of 256 (the kernels were swept
+    # at 64 only), and its DeltaNet layers' scan
+    flash_phase(1, 2048, 16, 256)
+    scan_phase()
     if n > 1:
         ring_phase(n)
     gpt_phase(n)

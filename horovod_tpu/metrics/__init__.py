@@ -23,6 +23,8 @@ hvd_collectives_traced_total    counter    collectives emitted at trace time
 hvd_collectives_traced_bytes_total counter traced payload bytes, by ``op``
 hvd_flash_tiles_traced_total    counter    flash score tiles per traced kernel
                                            call, by ``kernel``/``kind``
+hvd_moe_layers_traced_total     counter    routed expert layers traced, by
+                                           ``held``/``top_k``
 hvd_step_seconds                histogram  train-step cadence (dispatch-to-
                                            dispatch interval — honest under
                                            async dispatch, see training.py)
@@ -180,6 +182,11 @@ FLASH_TILES = registry.counter(
     "not per step): skipped (past the diagonal, not computed), full "
     "(no key masked), crossed (the diagonal passes through); dynamic "
     "(all of the grid's) when the offsets are traced.", ("kernel", "kind"))
+MOE_LAYERS = registry.counter(
+    "hvd_moe_layers_traced_total",
+    "Routed expert layers (parallel/moe.routed_experts) traced (per "
+    "compile, not per step), by how many experts the layer holds here and "
+    "how many a token picks.", ("held", "top_k"))
 
 STEP_SECONDS = registry.histogram(
     "hvd_step_seconds",
@@ -513,6 +520,16 @@ def record_flash_tiles(kernel: str, counts) -> None:
     try:
         for kind, n in counts.items():
             FLASH_TILES.labels(kernel, kind).inc(n)
+    except Exception:  # noqa: BLE001 — tracing must never fail on metrics
+        pass
+
+
+def record_moe_layer(held: int, top_k: int) -> None:
+    """One traced call of ``parallel/moe.routed_experts``."""
+    if not registry.enabled:
+        return
+    try:
+        MOE_LAYERS.labels(str(held), str(top_k)).inc()
     except Exception:  # noqa: BLE001 — tracing must never fail on metrics
         pass
 

@@ -21,15 +21,32 @@ HVD015's axis-shape check — a literal capacity reshape that contradicts
 a literal mesh declaration is flagged statically.  This module's
 dispatch tensors are shaped by the symbolic axis size, so the contract
 holds by construction.
+
+:func:`routed_experts` is the layer for models with many small experts
+and several a token (top-k of hundreds): it is told which experts it
+holds, routes over the router's full width, drops nothing, and computes
+its own experts' part of the result as a grouped matrix product over the
+assignments sorted by expert, a tile of rows at a time, as many tiles as
+the router sent rows.  :func:`load_census` is its model on the host: how
+many tokens each held expert gets, and how many tiles that makes.
 """
 
 from __future__ import annotations
 
-from typing import Callable
+import functools
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
+
+from .. import metrics
+
+# The expert layer's device scopes (docs/profiling.md): routing and data
+# movement, and the grouped products.
+ROUTE_SCOPE = "hvd_moe_route"
+EXPERTS_SCOPE = "hvd_moe_experts"
 
 
 def top1_dispatch(gates: jnp.ndarray, capacity: int):
@@ -109,3 +126,234 @@ def moe_apply(expert_fn: Callable, expert_params, x, router_kernel, *,
     out = out.reshape(e, capacity, d)
     # combine on the token side
     return jnp.einsum("ecd,nec->nd", out, combine.astype(out.dtype))
+
+
+# ---------------------------------------------------------------------------
+# top-k routing over held experts, nothing dropped
+# ---------------------------------------------------------------------------
+
+
+def route_top_k(x, router_kernel, top_k: int):
+    """``(weights [n, top_k] float32, experts [n, top_k] int32)``: softmax
+    over the router's full width in float32 (the product at ``highest``
+    precision, which on a TPU is otherwise one bfloat16 pass and flips
+    picks), the ``top_k`` largest, their weights divided by their sum."""
+    logits = jnp.dot(x.astype(jnp.float32), router_kernel.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    weights, experts = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return weights / jnp.sum(weights, axis=-1, keepdims=True), experts
+
+
+#: Rows of one grouped product.  A held expert's assignments are taken
+#: ``TILE`` at a time, so what the layer costs follows the rows the router
+#: sends here a tile at a time (an expert with 257 rows costs two tiles,
+#: with none it costs nothing).  On a v5e at 2048 x 512 experts a tile of
+#: 256 costs 0.26 ms forward and backward and one of 128 0.19 ms: 256 is
+#: the cheaper for every load over 128 rows an expert (PERF.md, PR 26).
+TILE = 256
+
+
+def _tile_schedule(sizes, tile: int):
+    """``(tiles [held], last [held], offsets [held])``: how many tiles each
+    held expert's rows fill, the index one past each expert's last tile
+    (so ``last[-1]`` is the number of tiles this step), and where each
+    expert's rows start among the sorted assignments."""
+    tiles = -(-sizes // tile)
+    return tiles, jnp.cumsum(tiles), jnp.cumsum(sizes) - sizes
+
+
+def _tile_rows(j, order, sizes, schedule, *, tile: int, top_k: int, n: int):
+    """Tile ``j``: ``(expert, picked [tile], token [tile])``.  ``picked``
+    indexes the flat assignments (``[n * top_k]``), ``token`` the rows of
+    ``x``.  A slot past the expert's last row gets indices out of bounds,
+    each its own, so a gather fills it with zeros and a scatter drops it
+    while the indices stay unique and ascending (the sort is stable: an
+    expert's rows are in token order)."""
+    tiles, last, offsets = schedule
+    e = jnp.sum(last <= j)
+    slot = jnp.arange(tile, dtype=jnp.int32)
+    row = (j - (last[e] - tiles[e])) * tile + slot
+    valid = row < sizes[e]
+    picked = order[jnp.where(valid, offsets[e] + row, 0)]
+    return (e, jnp.where(valid, picked, n * top_k + slot),
+            jnp.where(valid, picked // top_k, n + slot))
+
+
+def _dot(dtype):
+    """A product that accumulates in float32; exact for float32 operands
+    (a TPU's default there is one bfloat16 pass)."""
+    exact = lax.Precision.HIGHEST if dtype == jnp.float32 else None
+    return functools.partial(jnp.dot, precision=exact,
+                             preferred_element_type=jnp.float32)
+
+
+def _expert_forward(xs, params, e):
+    """Expert ``e`` on the rows ``xs``: its ``(gate, up, down)`` in
+    ``xs``'s dtype, and ``(a, b, hidden, y)`` with ``a = xs gate``, ``b =
+    xs up`` and ``y = hidden down`` in float32, ``hidden = silu(a) * b`` in
+    ``xs``'s dtype."""
+    dtype, dot = xs.dtype, _dot(xs.dtype)
+    gate, up, down = (
+        lax.dynamic_index_in_dim(params[k], e, keepdims=False).astype(dtype)
+        for k in ("gate_proj", "up_proj", "down_proj"))
+    a, b = dot(xs, gate), dot(xs, up)
+    hidden = (jax.nn.silu(a) * b).astype(dtype)
+    return (gate, up, down), (a, b, hidden, dot(hidden, down))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _held_part(x, weights, params, order, sizes, tile, top_k):
+    """The held experts' part of the layer: a loop over this step's tiles
+    (a number the device decides), each one expert's next ``tile`` rows —
+    gathered from ``x``, through the expert, weighted, added to their
+    tokens.  ``weights``: ``[n * top_k]`` float32, one an assignment;
+    ``order``: the assignments sorted by held expert, held ones first;
+    ``sizes``: rows of each held expert.  The loop's length is not known
+    when the step is traced, so XLA cannot transpose it: the backward pass
+    is the same loop written out (:func:`_held_part_bwd`)."""
+    n, d = x.shape
+    schedule = _tile_schedule(sizes, tile)
+
+    def one_tile(j, out):
+        with jax.named_scope(ROUTE_SCOPE):
+            e, picked, token = _tile_rows(j, order, sizes, schedule,
+                                          tile=tile, top_k=top_k, n=n)
+            xs = x.at[token].get(mode="fill", fill_value=0)
+            w = weights.at[picked].get(mode="fill", fill_value=0)
+        with jax.named_scope(EXPERTS_SCOPE):
+            y = _expert_forward(xs, params, e)[1][3]
+        with jax.named_scope(ROUTE_SCOPE):
+            return out.at[token].add(y * w[:, None], mode="drop",
+                                     unique_indices=True,
+                                     indices_are_sorted=True)
+
+    out = lax.fori_loop(0, schedule[1][-1], one_tile,
+                        jnp.zeros((n, d), jnp.float32))
+    return out.astype(x.dtype)
+
+
+def _held_part_fwd(x, weights, params, order, sizes, tile, top_k):
+    return (_held_part(x, weights, params, order, sizes, tile, top_k),
+            (x, weights, params, order, sizes))
+
+
+def _held_part_bwd(tile, top_k, kept, dout):
+    """Tile by tile again, each recomputed from the layer's inputs (nothing
+    a tile made is kept): the gradients to ``x`` and to the experts'
+    matrices accumulate in float32, an expert's in place in its slice."""
+    x, weights, params, order, sizes = kept
+    n, d = x.shape
+    dtype, dot = x.dtype, _dot(x.dtype)
+    schedule = _tile_schedule(sizes, tile)
+    scatter = dict(mode="drop", unique_indices=True, indices_are_sorted=True)
+
+    def one_tile(j, carry):
+        dx, dweights, dparams = carry
+        with jax.named_scope(ROUTE_SCOPE):
+            e, picked, token = _tile_rows(j, order, sizes, schedule,
+                                          tile=tile, top_k=top_k, n=n)
+            xs = x.at[token].get(mode="fill", fill_value=0)
+            w = weights.at[picked].get(mode="fill", fill_value=0)
+            dys = dout.at[token].get(mode="fill", fill_value=0).astype(
+                jnp.float32)
+        with jax.named_scope(EXPERTS_SCOPE):
+            (gate, up, down), (a, b, hidden, y) = _expert_forward(
+                xs, params, e)
+            dw = jnp.sum(dys * y, axis=-1)
+            dy = (dys * w[:, None]).astype(dtype)
+            dhidden = dot(dy, down.T)
+            sig = jax.nn.sigmoid(a)
+            da = (dhidden * b * sig * (1.0 + a * (1.0 - sig))).astype(dtype)
+            db = (dhidden * a * sig).astype(dtype)
+            dxs = dot(da, gate.T) + dot(db, up.T)
+            grads = {"gate_proj": dot(xs.T, da), "up_proj": dot(xs.T, db),
+                     "down_proj": dot(hidden.T, dy)}
+            dparams = {k: lax.dynamic_update_index_in_dim(
+                acc, lax.dynamic_index_in_dim(acc, e, keepdims=False)
+                + grads[k], e, 0) for k, acc in dparams.items()}
+        with jax.named_scope(ROUTE_SCOPE):
+            return (dx.at[token].add(dxs, **scatter),
+                    dweights.at[picked].set(dw, **scatter), dparams)
+
+    dx, dweights, dparams = lax.fori_loop(
+        0, schedule[1][-1], one_tile,
+        (jnp.zeros((n, d), jnp.float32), jnp.zeros_like(weights),
+         {k: jnp.zeros(params[k].shape, jnp.float32)
+          for k in ("gate_proj", "up_proj", "down_proj")}))
+    return (dx.astype(dtype), dweights,
+            {k: dparams[k].astype(params[k].dtype) for k in dparams},
+            None, None)
+
+
+_held_part.defvjp(_held_part_fwd, _held_part_bwd)
+
+
+def routed_experts(x, router_kernel, expert_params, *, top_k: int,
+                   first_expert: int = 0):
+    """The part of a top-k mixture-of-experts layer that the experts held
+    here give: ``sum_j w_j * down_j(silu(gate_j x) * up_j x)`` over those
+    of a token's ``top_k`` picks that fall on ``[first_expert,
+    first_expert + held)``.
+
+    The router keeps its full width and its ``top_k``, and the weights are
+    normalised over all the picks, not over the ones held: the parts of
+    all the shares add up to the whole layer.  No capacity, no dropped
+    token, static shapes: the assignments are sorted by expert into one
+    order (``n * top_k`` indices, the held experts' first), and a loop
+    whose length the device decides takes each held expert's rows ``TILE``
+    at a time through that expert's three matrices — a grouped matrix
+    product at the granularity of a tile.  Only an expert's last tile is
+    padded, so the layer's cost follows the number of rows the router
+    sends here, smoothly, from none to every token on one expert; there is
+    no tail to handle, because rows past the held assignments are never
+    visited.  One chip's layer: there is no exchange here and nothing in
+    its place; an ``ep`` exchange like :func:`moe_apply`'s goes round this
+    function (tokens in, their parts out), not inside it.
+
+    Args:
+      x: ``[n, d]`` tokens.
+      router_kernel: ``[d, E]``, all ``E`` experts of the layer.
+      expert_params: ``{"gate_proj": [held, d, f], "up_proj": [held, d, f],
+        "down_proj": [held, f, d]}``, the experts held here.
+      top_k: experts a token.
+      first_expert: index of the first held expert.
+
+    Returns ``[n, d]`` in ``x``'s dtype.
+    """
+    held = expert_params["gate_proj"].shape[0]
+    metrics.record_moe_layer(held, top_k)
+    with jax.named_scope(ROUTE_SCOPE):
+        weights, experts = route_top_k(x, router_kernel, top_k)
+        local = experts - first_expert
+        local = jnp.where((local >= 0) & (local < held), local,
+                          held).reshape(-1).astype(jnp.int32)
+        sizes = jnp.sum(local[:, None] == jnp.arange(held)[None, :],
+                        axis=0, dtype=jnp.int32)
+        # stable: the held assignments first, expert by expert, each
+        # expert's in token order
+        order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    params = {k: expert_params[k] for k in ("gate_proj", "up_proj",
+                                            "down_proj")}
+    return _held_part(x, weights.reshape(-1), params, order, sizes, TILE,
+                      top_k)
+
+
+def load_census(router_logits, first_expert: int, held: int, *,
+                top_k: int) -> dict:
+    """The expert layer's model on the host, beside the flash kernels'
+    ``tile_census``: from router logits ``[n, E]``, how many tokens each
+    of the experts ``[first_expert, first_expert + held)`` gets under
+    top-``top_k`` routing, how many assignments that is in all, the
+    largest load over the mean (1.0 is an even router), and how many tiles
+    of ``TILE`` rows :func:`routed_experts` runs for them: the layer's cost
+    is so many products."""
+    logits = np.asarray(router_logits, np.float64)
+    picks = np.argsort(-logits, axis=-1, kind="stable")[:, :top_k]
+    loads = np.bincount(picks.reshape(-1),
+                        minlength=logits.shape[-1])[first_expert:
+                                                    first_expert + held]
+    mean = loads.mean() if held else 0.0
+    return {"tokens_per_expert": [int(c) for c in loads],
+            "assignments": int(loads.sum()),
+            "largest_over_mean": float(loads.max() / mean) if mean else 0.0,
+            "tiles": int(sum(-(-int(c) // TILE) for c in loads))}
