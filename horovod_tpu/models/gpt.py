@@ -15,6 +15,7 @@ from __future__ import annotations
 
 from typing import Any, Callable, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
@@ -89,8 +90,20 @@ def gpt_tiny(**kw):
 
 
 def next_token_loss(logits, ids):
-    """Shifted cross-entropy: predict ids[t+1] from position t."""
-    logp = nn.log_softmax(logits[:, :-1])
-    tgt = ids[:, 1:]
-    ll = jnp.take_along_axis(logp, tgt[..., None], axis=-1)
-    return -jnp.mean(ll)
+    """Shifted cross-entropy: predict ids[t+1] from position t.
+
+    The mean over ``b * (s - 1)`` positions of ``logsumexp(logits[b, t]) -
+    logits[b, t, ids[b, t + 1]]``, written so that neither pass holds a
+    ``[b, s, V]`` array beside ``logits`` and its cotangent: every position
+    is computed and the last of each sequence weighs nothing (no slice, so
+    no pad on the way back), and the label's logit is picked by comparing a
+    vocabulary iota with the target inside the row's reduction (no gather,
+    so no scatter on the way back).
+    """
+    b, s, v = logits.shape
+    tgt = jnp.roll(ids, -1, axis=1)
+    hit = jnp.arange(v) == tgt[..., None]
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    picked = jnp.where(hit, logits, 0).sum(-1)
+    weight = (jnp.arange(s) < s - 1) / (b * (s - 1))
+    return jnp.sum((lse - picked) * weight)

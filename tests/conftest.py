@@ -56,14 +56,16 @@ def rng():
     return np.random.default_rng(1234)
 
 
-#: Tests of ``tests/benchmark`` that pin the benchmark to the four cells it
-#: had before ``qwen3next-8k``.  Those files are the benchmark's own
-#: (``BENCHMARK.json`` lists the directory under ``paths``) and a PR that
-#: changes the program may not edit them; the same three assertions with the
-#: fifth cell in are at the end of ``test_benchmark_qwen3_next.py``.  Strict,
-#: so that the `benchmark` PR which brings the pins up to date has to take
-#: this list out with them.
-PINNED_TO_FOUR_CELLS = {
+#: Tests of ``tests/benchmark`` that pin the benchmark to what it held when
+#: they were written: the four cells before ``qwen3next-8k`` (PR 26), and
+#: PR 26's eight metrics as the last of ``per_layer`` (PR 27 appends
+#: ``loss_ms``).  Those files are the benchmark's own (``BENCHMARK.json``
+#: lists the directory under ``paths``) and a PR that changes the program
+#: may not edit them; the same assertions brought up to date are at the end
+#: of ``test_benchmark_qwen3_next.py`` and in ``test_benchmark_loss.py``.
+#: Strict, so that the `benchmark` PR which brings the pins up to date has
+#: to take this list out with them.
+PINNED_TO_AN_EARLIER_BENCHMARK = {
     "test_benchmark_form.py::test_the_tiny_benchmark_keeps_the_form":
         "7 cells allowed one four-chip cell; with 8 the toy one is no fault",
     "test_benchmark_parts.py::test_new_metrics_are_entries_with_files":
@@ -71,12 +73,16 @@ PINNED_TO_FOUR_CELLS = {
     "test_benchmark_harness.py::"
     "test_every_cell_of_the_real_benchmark_finds_its_files":
         "the expected cells lack qwen3next-8k",
+    "test_benchmark_qwen3_next.py::"
+    "test_which_cells_list_the_flash_parts_the_pack_and_the_update":
+        "PR 26's eight metrics are no longer the last: loss_ms follows",
 }
 
 
 def pytest_collection_modifyitems(config, items):
     for item in items:
-        for name, why in PINNED_TO_FOUR_CELLS.items():
+        for name, why in PINNED_TO_AN_EARLIER_BENCHMARK.items():
             if item.nodeid.endswith("benchmark/" + name):
                 item.add_marker(pytest.mark.xfail(
-                    reason=f"pinned before PR 26: {why}", strict=True))
+                    reason=f"pinned to an earlier benchmark: {why}",
+                    strict=True))
