@@ -74,7 +74,6 @@ AUTOTUNE_TIMEOUT_S = 420   # autotuned comparison run (re-jits a few times)
 COMPRESSION_TIMEOUT_S = 420  # compressed comparison run (one compile)
 SERVE_TIMEOUT_S = 180      # serving fixture: a few MLP compiles + ~1.5 s trace
 PROJECTION_TIMEOUT_S = 240  # digital-twin leg: two traced MLP drives (1 + 8 dev)
-COMPUTE_OPT_TIMEOUT_S = 240  # compute-path A/B: two MLP drives + a profiler window
 CONTROL_TIMEOUT_S = 120    # control-plane churn: ~5k loopback HTTP requests
 WATCH_TIMEOUT_S = 90       # watchdog leg: pure host-side detector replay
 RESTORE_TIMEOUT_S = 120    # peer-restore leg: snapshot/restore fixture
@@ -201,30 +200,6 @@ def _measure_projection() -> None:
         "projection_err_pct": out["err_pct"],
         "projected_step_us": out["projected_step_us"],
         "measured_step_us": out["measured_step_us"],
-    }))
-
-
-def _measure_compute_opt() -> None:
-    """Child-process entry for the compute-path A/B leg: the same tiny
-    MLP job with the fused-update + async-pipeline path ON vs OFF on
-    the dev CPU mesh (optim/compute_knobs.py run_bench_fixture,
-    docs/profiling.md host-gap section).  Like the serving/projection legs this
-    benchmarks host-side machinery, not the chip — the delta isolates
-    what the per-leaf optimizer traversal, the per-step loss sync, and
-    the unprefetched loader cost, and the profiler window's
-    host_gap_pct is the async pipeline's proof."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8").strip()
-    from horovod_tpu.optim.compute_knobs import run_bench_fixture
-
-    out = run_bench_fixture()
-    print("RESULT " + json.dumps({
-        "compute_opt_delta_pct": out["compute_opt_delta_pct"],
-        "host_gap_pct": out["host_gap_pct"],
-        "compute_opt_loss_equal": out["loss_equal"],
     }))
 
 
@@ -562,37 +537,6 @@ def _control_leg() -> dict:
             "control_error": reason}
 
 
-def _compute_opt_leg() -> dict:
-    """The compute-path tail fields (compute_opt_delta_pct +
-    host_gap_pct), from a separately-timed child so a hung or failed
-    A/B can never cost the main number (HVD_BENCH_COMPUTE_OPT=0
-    skips).  Null-on-failure, same contract as every other leg."""
-    try:
-        from horovod_tpu.utils import env as env_util
-
-        enabled = env_util.get_bool(env_util.HVD_BENCH_COMPUTE_OPT, True)
-    except Exception:  # noqa: BLE001
-        enabled = True
-    if not enabled:
-        return {}
-    reason = None
-    try:
-        payload, reason = _run_child("--child-compute-opt",
-                                     COMPUTE_OPT_TIMEOUT_S)
-        if payload is not None:
-            return {
-                "compute_opt_delta_pct":
-                    payload.get("compute_opt_delta_pct"),
-                "host_gap_pct": payload.get("host_gap_pct"),
-                "compute_opt_loss_equal":
-                    payload.get("compute_opt_loss_equal"),
-            }
-    except Exception as e:  # noqa: BLE001 — the leg can never cost the main number
-        reason = f"{type(e).__name__}: {e}"
-    return {"compute_opt_delta_pct": None, "host_gap_pct": None,
-            "compute_opt_error": reason}
-
-
 def _projection_leg() -> dict:
     """The projection-accuracy tail field, from a separately-timed child
     so a hung or failed twin drive can never cost the main number
@@ -747,10 +691,6 @@ def main() -> int:
     # digital-twin tail (HVD_BENCH_PROJECTION=0 skips): the
     # projection engine's accuracy on the world being benched
     out.update(_projection_leg())
-    # compute-path tail (HVD_BENCH_COMPUTE_OPT=0 skips):
-    # fused-update + async-pipeline on-vs-off delta and the
-    # async pipeline's host_gap_pct, alongside mfu
-    out.update(_compute_opt_leg())
     # control-plane tail (HVD_BENCH_CONTROL=0 skips): churn-
     # harness p99 lease/epoch latencies + relay request
     # reduction — the control plane's own tracked numbers
@@ -780,8 +720,6 @@ if __name__ == "__main__":
         _measure_serving()
     elif "--child-projection" in sys.argv:
         _measure_projection()
-    elif "--child-compute-opt" in sys.argv:
-        _measure_compute_opt()
     elif "--child-control" in sys.argv:
         _measure_control()
     elif "--child-watch" in sys.argv:

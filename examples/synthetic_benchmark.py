@@ -73,11 +73,6 @@ def parse_args(argv=None):
     parser.add_argument("--dtype", type=str, default="bfloat16",
                         choices=["bfloat16", "float32"],
                         help="model compute dtype (params stay float32)")
-    parser.add_argument("--fused-optimizer", action="store_true",
-                        default=False,
-                        help="flat fused update kernel instead of the "
-                             "per-leaf optax traversal "
-                             "(optim/fused_update.py; bit-equal math)")
     parser.add_argument("--loss-fetch-steps", type=int, default=None,
                         help="trailing async loss-fetch cadence "
                              "(default: the HVD_LOSS_FETCH_STEPS knob)")
@@ -96,12 +91,7 @@ def run(args) -> dict:
     model = MODELS[args.model](
         num_classes=args.num_classes, dtype=jnp.dtype(args.dtype)
     )
-    if args.fused_optimizer:
-        from horovod_tpu.optim.fused_update import fused_sgd
-
-        opt = fused_sgd(0.01, momentum=0.9)
-    else:
-        opt = optax.sgd(0.01, momentum=0.9)
+    opt = optax.sgd(0.01, momentum=0.9)
 
     global_batch = args.batch_size * hvd.size()
     rng = np.random.default_rng(42)
@@ -141,7 +131,6 @@ def run(args) -> dict:
         autotune=args.autotune or None,
         autotune_log_file=args.autotune_log_file,
         in_graph_steps=args.num_in_graph_steps,
-        fused_optimizer=args.fused_optimizer or None,
         loss_fetch_steps=args.loss_fetch_steps,
     )
 

@@ -70,157 +70,19 @@ class SpaceToDepthConvInit(nn.Module):
         )
 
 
-class PallasConvBN3x3(nn.Module):
-    """Fused stride-1 3x3 conv + BatchNorm + ReLU over the Pallas kernels
-    (ops/conv_bn.py): train mode runs the conv+stats-epilogue kernel with
-    the full-BN-backward custom VJP; eval mode runs the folded-affine
-    kernel.  The round-4 conv+BN experiment module (root PERF.md) —
-    selected by ``ResNet(conv_bn="pallas")``; its parameter layout is its
-    own (kernel/scale/bias + batch_stats mean/var), so checkpoints do NOT
-    interchange with the (Conv, BatchNorm) pair it replaces."""
-
-    features: int
-    train: bool
-    dtype: Any = jnp.bfloat16
-    param_dtype: Any = jnp.float32
-    momentum: float = 0.9
-    epsilon: float = 1e-5
-
-    @nn.compact
-    def __call__(self, x):
-        from ..ops.conv_bn import conv3x3_bn_relu, conv3x3_bn_relu_train
-
-        cin = x.shape[-1]
-        kernel = self.param(
-            "kernel", nn.initializers.lecun_normal(),
-            (3, 3, cin, self.features), self.param_dtype,
-        )
-        gamma = self.param("scale", nn.initializers.ones,
-                           (self.features,), self.param_dtype)
-        beta = self.param("bias", nn.initializers.zeros,
-                          (self.features,), self.param_dtype)
-        ra_mean = self.variable(
-            "batch_stats", "mean",
-            lambda: jnp.zeros((self.features,), jnp.float32))
-        ra_var = self.variable(
-            "batch_stats", "var",
-            lambda: jnp.ones((self.features,), jnp.float32))
-        k = kernel.astype(self.dtype)
-        x = x.astype(self.dtype)
-        if self.train:
-            out, mean, var = conv3x3_bn_relu_train(
-                x, k, gamma.astype(jnp.float32), beta.astype(jnp.float32),
-                self.epsilon,
-            )
-            if not self.is_initializing():
-                m = self.momentum
-                ra_mean.value = m * ra_mean.value + (1 - m) * mean
-                ra_var.value = m * ra_var.value + (1 - m) * var
-        else:
-            scale = gamma * (lax.rsqrt(ra_var.value + self.epsilon))
-            bias = beta - ra_mean.value * scale
-            out = conv3x3_bn_relu(x, k, scale, bias)
-        return out
-
-
-class BatchNormReLU(nn.Module):
-    """BatchNorm + ReLU with the elementwise apply fused into one Pallas
-    pass (ops/elementwise.py ``scale_bias_relu``) — the compute tier's
-    norm+activation join, selected by ``ResNet(norm_act="pallas")``.
-
-    The per-channel statistics (a tiny reduction XLA handles well) and
-    the folded ``scale``/``bias`` stay in jnp; the [B,H,W,C]-sized
-    normalize+activate traffic — the HBM-bound part — runs as the single
-    fused kernel.  Gradients flow through batch mean/var exactly like
-    ``flax.linen.BatchNorm`` (the folded affine is a function of the
-    batch stats, so autodiff chains the kernel's dscale/dbias back
-    through them).  Parameter names inside the module mirror
-    ``BatchNorm``'s (params scale/bias, batch_stats mean/var), but the
-    module path differs — like ``conv_bn="pallas"``, checkpoints do NOT
-    interchange with the pair it replaces."""
-
-    use_running_average: bool
-    dtype: Any = jnp.bfloat16
-    param_dtype: Any = jnp.float32
-    momentum: float = 0.9
-    epsilon: float = 1e-5
-
-    @nn.compact
-    def __call__(self, x):
-        from ..ops.elementwise import scale_bias_relu
-
-        c = x.shape[-1]
-        gamma = self.param("scale", nn.initializers.ones, (c,),
-                           self.param_dtype)
-        beta = self.param("bias", nn.initializers.zeros, (c,),
-                          self.param_dtype)
-        ra_mean = self.variable("batch_stats", "mean",
-                                lambda: jnp.zeros((c,), jnp.float32))
-        ra_var = self.variable("batch_stats", "var",
-                               lambda: jnp.ones((c,), jnp.float32))
-        x = x.astype(self.dtype)
-        if self.use_running_average:
-            mean, var = ra_mean.value, ra_var.value
-        else:
-            xf = x.astype(jnp.float32)
-            axes = tuple(range(x.ndim - 1))
-            mean = xf.mean(axis=axes)
-            var = jnp.maximum(
-                (xf * xf).mean(axis=axes) - mean * mean, 0.0)
-            if not self.is_initializing():
-                m = self.momentum
-                ra_mean.value = m * ra_mean.value + (1 - m) * \
-                    lax.stop_gradient(mean)
-                ra_var.value = m * ra_var.value + (1 - m) * \
-                    lax.stop_gradient(var)
-        scale = gamma.astype(jnp.float32) * lax.rsqrt(var + self.epsilon)
-        bias = beta.astype(jnp.float32) - mean * scale
-        return scale_bias_relu(x, scale, bias)
-
-
-def _norm_relu(norm, norm_relu, y):
-    """Every ``norm()(y); relu(y)`` pair in the blocks goes through
-    here: XLA's own elementwise fusion by default, or the single-pass
-    Pallas norm+activation join when a ``BatchNormReLU`` partial is
-    wired in (``norm_act="pallas"``)."""
-    if norm_relu is not None:
-        return norm_relu()(y)
-    return nn.relu(norm()(y))
-
-
-def _residual_join(residual, y, kind: str):
-    """The block output ``relu(residual + y)``: XLA elementwise fusion by
-    default, or the Pallas single-pass kernel (the root PERF.md 56×56
-    experiment — measured by scripts/pallas_residual_experiment.py)."""
-    if kind == "pallas":
-        from ..ops.elementwise import residual_relu
-
-        return residual_relu(residual, y)
-    return nn.relu(residual + y)
-
-
 class BottleneckBlock(nn.Module):
     filters: int
     strides: int
     conv: ModuleDef
     norm: ModuleDef
-    join: str = "xla"  # "xla" | "pallas"
-    fused: ModuleDef = None  # PallasConvBN3x3 partial (conv_bn="pallas")
-    norm_relu: ModuleDef = None  # BatchNormReLU partial (norm_act="pallas")
 
     @nn.compact
     def __call__(self, x):
         residual = x
         y = self.conv(self.filters, (1, 1))(x)
-        y = _norm_relu(self.norm, self.norm_relu, y)
-        if self.fused is not None and self.strides == 1:
-            # the 3x3+BN+ReLU as one fused Pallas op (stride-1 blocks;
-            # stride-2 stage entries keep the XLA pair)
-            y = self.fused(features=self.filters)(y)
-        else:
-            y = self.conv(self.filters, (3, 3),
-                          strides=(self.strides,) * 2)(y)
-            y = _norm_relu(self.norm, self.norm_relu, y)
+        y = nn.relu(self.norm()(y))
+        y = self.conv(self.filters, (3, 3), strides=(self.strides,) * 2)(y)
+        y = nn.relu(self.norm()(y))
         y = self.conv(self.filters * 4, (1, 1))(y)
         y = self.norm(scale_init=nn.initializers.zeros)(y)
         if residual.shape != y.shape:
@@ -229,7 +91,7 @@ class BottleneckBlock(nn.Module):
                 name="conv_proj",
             )(residual)
             residual = self.norm(name="norm_proj")(residual)
-        return _residual_join(residual, y, self.join)
+        return nn.relu(residual + y)
 
 
 class BasicBlock(nn.Module):
@@ -237,21 +99,12 @@ class BasicBlock(nn.Module):
     strides: int
     conv: ModuleDef
     norm: ModuleDef
-    join: str = "xla"  # "xla" | "pallas"
-    fused: ModuleDef = None  # PallasConvBN3x3 partial (conv_bn="pallas")
-    norm_relu: ModuleDef = None  # BatchNormReLU partial (norm_act="pallas")
 
     @nn.compact
     def __call__(self, x):
         residual = x
-        if self.fused is not None and self.strides == 1:
-            # first 3x3+BN+ReLU fused; the second conv's BN has no ReLU
-            # before the join, so it stays on the XLA pair
-            y = self.fused(features=self.filters)(x)
-        else:
-            y = self.conv(self.filters, (3, 3),
-                          strides=(self.strides,) * 2)(x)
-            y = _norm_relu(self.norm, self.norm_relu, y)
+        y = self.conv(self.filters, (3, 3), strides=(self.strides,) * 2)(x)
+        y = nn.relu(self.norm()(y))
         y = self.conv(self.filters, (3, 3))(y)
         y = self.norm(scale_init=nn.initializers.zeros)(y)
         if residual.shape != y.shape:
@@ -260,7 +113,7 @@ class BasicBlock(nn.Module):
                 name="conv_proj",
             )(residual)
             residual = self.norm(name="norm_proj")(residual)
-        return _residual_join(residual, y, self.join)
+        return nn.relu(residual + y)
 
 
 class ResNet(nn.Module):
@@ -271,9 +124,6 @@ class ResNet(nn.Module):
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     stem: str = "conv"  # "conv" | "space_to_depth" (same params/output)
-    residual_join: str = "xla"  # "xla" | "pallas" (same math, see blocks)
-    conv_bn: str = "xla"  # "xla" | "pallas" (fused 3x3+BN+ReLU, see blocks)
-    norm_act: str = "xla"  # "xla" | "pallas" (fused BN-apply+ReLU join)
 
     @nn.compact
     def __call__(self, x, train: bool = True):
@@ -285,28 +135,6 @@ class ResNet(nn.Module):
             nn.BatchNorm, use_running_average=not train, momentum=0.9,
             epsilon=1e-5, dtype=self.dtype, param_dtype=self.param_dtype,
         )
-        fused = None
-        if self.conv_bn == "pallas":
-            fused = partial(
-                PallasConvBN3x3, train=train, dtype=self.dtype,
-                param_dtype=self.param_dtype,
-            )
-        elif self.conv_bn != "xla":
-            raise ValueError(
-                f"unknown conv_bn {self.conv_bn!r} (want 'xla' or "
-                "'pallas')"
-            )
-        norm_relu = None
-        if self.norm_act == "pallas":
-            norm_relu = partial(
-                BatchNormReLU, use_running_average=not train,
-                dtype=self.dtype, param_dtype=self.param_dtype,
-            )
-        elif self.norm_act != "xla":
-            raise ValueError(
-                f"unknown norm_act {self.norm_act!r} (want 'xla' or "
-                "'pallas')"
-            )
         x = x.astype(self.dtype)
         if self.stem == "space_to_depth":
             x = SpaceToDepthConvInit(
@@ -321,11 +149,7 @@ class ResNet(nn.Module):
                 f"unknown stem {self.stem!r} (want 'conv' or "
                 "'space_to_depth')"
             )
-        if norm_relu is not None:
-            x = norm_relu(name="bn_init")(x)
-        else:
-            x = norm(name="bn_init")(x)
-            x = nn.relu(x)
+        x = nn.relu(norm(name="bn_init")(x))
         x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1)))
         for i, block_count in enumerate(self.stage_sizes):
             for j in range(block_count):
@@ -333,8 +157,6 @@ class ResNet(nn.Module):
                 x = self.block_cls(
                     filters=self.num_filters * 2 ** i,
                     strides=strides, conv=conv, norm=norm,
-                    join=self.residual_join, fused=fused,
-                    norm_relu=norm_relu,
                 )(x)
         x = jnp.mean(x, axis=(1, 2))
         x = nn.Dense(self.num_classes, dtype=self.dtype,

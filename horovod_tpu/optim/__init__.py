@@ -11,12 +11,3 @@ ProfileGuidedTuner = profile_guided.ProfileGuidedTuner
 plan_from_summary = profile_guided.plan_from_summary
 plan_from_trace = profile_guided.plan_from_trace
 warm_start_manager = profile_guided.warm_start_manager
-from .fused_update import (  # noqa: E402,F401
-    FusedOptimizer,
-    fused_adam,
-    fused_sgd,
-)
-from .compute_knobs import (  # noqa: E402,F401
-    COMPUTE_AUTOTUNE_EXPECTED,
-    compute_plans_from_anatomy,
-)

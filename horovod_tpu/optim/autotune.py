@@ -191,42 +191,25 @@ class TunableParams:
       the fusion threshold).  Categorical flags are deliberately NOT
       encoded here: an RBF kernel over a {0,1} coordinate would smear
       observations across categories that share nothing.
-    * :meth:`category` — the categorical coordinates
-      (``hierarchical_allreduce`` plus the compute knobs below), which
-      select WHICH per-category GP an observation lands in (the
-      reference enumerates categorical combinations the same way).  A
-      flipped flag therefore always maps to a different GP; it can
-      never silently share one.
-
-    **Compute knobs** (the PR 9→14 compute tier, docs/autotune.md):
-    ``fused_optimizer`` selects the flat fused update kernel over the
-    per-leaf optax traversal (optim/fused_update.py) and
-    ``remat_policy`` rematerializes the loss closure
-    (none/full/dots).  Both default to ``None`` = *knob absent*: a job
-    whose optimizer isn't fusable (or that never opts into remat) keeps
-    exactly the legacy ``(hierarchical,)`` category key, so pre-compute
-    GP state and tests are untouched.  A non-None value appends a
-    ``(name, value)`` coordinate — distinct per value, so flipping
-    ``fused_optimizer`` can never share observations with any other
-    category's fusion-threshold GP.
+    * :meth:`category` — the categorical coordinate
+      (``hierarchical_allreduce``), which selects WHICH per-category GP
+      an observation lands in (the reference enumerates categorical
+      combinations the same way).  A flipped flag therefore always maps
+      to a different GP; it can never silently share one.
 
     ``fusion_plan`` pins an explicit profile-guided plan
     (optim/profile_guided.py FusionPlanSpec): while set, the plan's
-    bucket vector (and its ``compute`` knob dict) overrides the scalar
-    knobs in the training step's rebuild, and the GP loop is paused
-    (the planner owns the knobs).
+    bucket vector overrides the scalar knobs in the training step's
+    rebuild, and the GP loop is paused (the planner owns the knobs).
     """
 
     fusion_threshold_bytes: int = env_util.DEFAULT_FUSION_THRESHOLD_BYTES
     hierarchical_allreduce: bool = False
-    fused_optimizer: Optional[bool] = None
-    remat_policy: Optional[str] = None
     fusion_plan: Optional[object] = None
 
     #: dimension inventory backing the split (documentation + tests)
     CONTINUOUS_DIMS = ("fusion_threshold_bytes",)
-    CATEGORICAL_DIMS = ("hierarchical_allreduce", "fused_optimizer",
-                        "remat_policy")
+    CATEGORICAL_DIMS = ("hierarchical_allreduce",)
 
     def as_vector(self) -> np.ndarray:
         # log2 of threshold in MB-ish units for a smooth GP landscape;
@@ -235,15 +218,8 @@ class TunableParams:
                         np.float64)
 
     def category(self) -> Tuple:
-        """The per-category-GP key (one GP per value of this tuple).
-        Absent (None) compute knobs contribute no coordinate — the key
-        stays backward compatible with the comm-only era."""
-        cat: list = [bool(self.hierarchical_allreduce)]
-        if self.fused_optimizer is not None:
-            cat.append(("fused_optimizer", bool(self.fused_optimizer)))
-        if self.remat_policy is not None:
-            cat.append(("remat_policy", str(self.remat_policy)))
-        return tuple(cat)
+        """The per-category-GP key (one GP per value of this tuple)."""
+        return (bool(self.hierarchical_allreduce),)
 
 
 class ParameterManager:
@@ -269,8 +245,6 @@ class ParameterManager:
         log_file: Optional[str] = None,
         on_update: Optional[Callable[[TunableParams], None]] = None,
         tune_hierarchical: bool = True,
-        tune_fused_optimizer: bool = False,
-        tune_remat: bool = False,
         initial: Optional[TunableParams] = None,
     ):
         self.enabled = enabled if enabled is not None else \
@@ -280,8 +254,7 @@ class ParameterManager:
         self.steps_per_sample = steps_per_sample if steps_per_sample is not None \
             else env_util.get_int(env_util.HVD_AUTOTUNE_STEPS_PER_SAMPLE, 10)
         # resolved AFTER the category rotation is built (below): the
-        # default budget is per-category, so opting into the compute
-        # dims doesn't silently starve every GP
+        # default budget is per-category
         self._max_samples_arg = max_samples
         noise = env_util.get_float(
             env_util.HVD_AUTOTUNE_GAUSSIAN_PROCESS_NOISE, 0.8
@@ -294,48 +267,19 @@ class ParameterManager:
         # explicit split a flipped flag can't cross
         self._noise = noise
         self.current = initial if initial is not None else TunableParams()
-        # proposal rotation: the product of every TUNED dim's settings,
-        # untuned dims pinned at the initial value — an untuned flag
-        # must never be flipped by the rotation (tune_hierarchical=False
-        # with hierarchical=True would otherwise alternate the flag
-        # every sample, re-jitting and overriding the caller's pin).
-        # Compute knobs only enter the product when explicitly tuned
-        # (tune_fused_optimizer / tune_remat) — a knob a job can't
-        # apply (no FusedOptimizer) must stay pinned at None/absent.
-        import itertools
-
+        # proposal rotation: the tuned flag's settings, an untuned flag
+        # pinned at the initial value — it must never be flipped by the
+        # rotation (tune_hierarchical=False with hierarchical=True would
+        # otherwise alternate the flag every sample, re-jitting and
+        # overriding the caller's pin).
         hier_vals = [False, True] if tune_hierarchical \
             else [bool(self.current.hierarchical_allreduce)]
-        fused_vals = [False, True] if tune_fused_optimizer \
-            else [self.current.fused_optimizer]
-        # "none" (not None) when tuned: None means *knob absent* and
-        # would read as "leave unchanged" at the training rebuild seam.
-        # The current value always joins the rotation — a caller pinned
-        # to a custom policy must stay reachable, not be overridden by
-        # the first proposal and lost from every category.
-        remat_vals = list(dict.fromkeys(
-            ["none", "full", "dots", self.current.remat_policy or "none"])) \
-            if tune_remat else [self.current.remat_policy]
         self._category_knobs: List[dict] = [
-            {"hierarchical_allreduce": h, "fused_optimizer": f,
-             "remat_policy": r}
-            for h, f, r in itertools.product(hier_vals, fused_vals,
-                                             remat_vals)
+            {"hierarchical_allreduce": h} for h in hier_vals
         ]
         self._categories: List[Tuple] = [
             TunableParams(**k).category() for k in self._category_knobs
         ]
-        # normalize the INITIAL params onto the rotation's coordinates:
-        # with a compute dim tuned, an absent (None) knob would key an
-        # orphan category no proposal ever revisits — the first
-        # (default-config) observation must land in the rotation's
-        # matching category, not start that category cold
-        if tune_fused_optimizer and self.current.fused_optimizer is None:
-            self.current = dataclasses.replace(self.current,
-                                               fused_optimizer=False)
-        if tune_remat and self.current.remat_policy is None:
-            self.current = dataclasses.replace(self.current,
-                                               remat_policy="none")
         self._bo = {
             cat: BayesianOptimization([(20.0, 28.0)], noise=noise, seed=17 + i)
             for i, cat in enumerate(self._categories)
@@ -343,9 +287,8 @@ class ParameterManager:
         self._knobs_by_cat = dict(zip(self._categories,
                                       self._category_knobs))
         # the sample budget scales with the rotation (default 10 real
-        # observations per category — the 2-category comm-only default
-        # stays exactly the reference's 20): freezing 8+ categories on
-        # a fixed global 20 would leave ~2 noisy samples each
+        # observations per category — the 2-category default is exactly
+        # the reference's 20)
         if self._max_samples_arg is not None:
             self.max_samples = self._max_samples_arg
         else:
@@ -363,12 +306,9 @@ class ParameterManager:
         # Prefer the native state machine (csrc/autotune.cc — the analog of
         # the reference's C++ parameter_manager + optim/ GP); the NumPy
         # implementation above stays as the fallback and the test oracle.
-        # Compute-knob rotations stay on the python path: the native
-        # machine's category table predates them.
         self._native = None
         self._native_lib = None
-        if self.enabled and not env_util.get_bool("HVD_AUTOTUNE_PYTHON") \
-                and not (tune_fused_optimizer or tune_remat):
+        if self.enabled and not env_util.get_bool("HVD_AUTOTUNE_PYTHON"):
             try:
                 from ..runtime import native
 
@@ -535,8 +475,6 @@ class ParameterManager:
         changed = (
             p.fusion_threshold_bytes != self.current.fusion_threshold_bytes
             or p.hierarchical_allreduce != self.current.hierarchical_allreduce
-            or p.fused_optimizer != self.current.fused_optimizer
-            or p.remat_policy != self.current.remat_policy
             or p.fusion_plan is not self.current.fusion_plan
         )
         self.current = p

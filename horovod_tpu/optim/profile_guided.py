@@ -70,21 +70,11 @@ class FusionPlanSpec:
     steps a *verified* plan stays pinned before the tuner re-measures
     and re-plans from a fresh trace window (the compiled-world analog
     of the reference's cycle time; 0 pins the plan for the rest of the
-    job).
-
-    ``compute`` carries the compute-knob decisions of the compute tier
-    (optim/compute_knobs.py): ``{"fused_optimizer": bool,
-    "remat_policy": str, "loss_fetch_steps": int}`` entries override
-    the training step's defaults in the SAME rebuild
-    (training.py ``_rebuild``), so a compute decision is applied,
-    verified, and rolled back through the machinery fusion decisions
-    already use.  A compute-only plan has ``buckets == []`` — the
-    threshold bucketing stays untouched."""
+    job)."""
 
     buckets: List[List[str]]
     overlap: bool = True
     compression: Optional[List[Optional[str]]] = None
-    compute: Optional[dict] = None
     cycle_flush_steps: int = 0
     predicted_step_us: float = 0.0
     baseline_step_us: float = 0.0
@@ -101,6 +91,8 @@ class FusionPlanSpec:
 
     @classmethod
     def from_dict(cls, d: dict) -> "FusionPlanSpec":
+        # a record persisted by an earlier run (KV store, /autotune, the
+        # tuner's log) may carry keys this version has no field for
         fields = {f.name for f in dataclasses.fields(cls)}
         return cls(**{k: v for k, v in d.items() if k in fields})
 
@@ -247,9 +239,6 @@ class ProfileGuidedTuner:
 
     def __init__(self, *, analyze_fn: Callable[[], Optional[dict]],
                  apply_fn: Callable[[Optional[FusionPlanSpec]], None],
-                 anatomy_fn: Optional[Callable[[], Optional[dict]]] = None,
-                 fused_available: bool = True,
-                 active_compute: Optional[dict] = None,
                  window_steps: Optional[int] = None,
                  guard_band_pct: Optional[float] = None,
                  rollback: Optional[bool] = None,
@@ -263,14 +252,6 @@ class ProfileGuidedTuner:
                  push_target: Optional[tuple] = None):
         self.analyze_fn = analyze_fn
         self.apply_fn = apply_fn
-        self.anatomy_fn = anatomy_fn
-        self.fused_available = fused_available
-        #: compute knobs the job's BASE config already has on — a plan
-        #: proposing one of these would be a no-op that is guaranteed
-        #: to miss its prediction, get condemned, and waste two
-        #: measure windows plus a re-jit (training.py fills this from
-        #: the resolved fused/loss-fetch defaults)
-        self.active_compute = dict(active_compute or {})
         self.window_steps = window_steps if window_steps is not None else \
             env_util.get_int(env_util.HVD_AUTOTUNE_WINDOW_STEPS,
                              env_util.DEFAULT_AUTOTUNE_WINDOW_STEPS)
@@ -297,11 +278,7 @@ class ProfileGuidedTuner:
         self._plan_seq = 0
         self._plan_attempts = 0
         self._steady_left = 0
-        # compute-tier bookkeeping (optim/compute_knobs.py): knobs a
-        # verified plan pinned, knobs condemned by a rollback, and the
-        # last verified plan a compute regression falls back to
-        self._verified_compute: dict = {}
-        self._condemned_compute: set = set()
+        # the last verified plan: what a regression falls back to
         self._last_good_plan: Optional[FusionPlanSpec] = None
         # flight-recorder: the apply event roots the plan's causal
         # chain — verify/rollback chain onto it (observe/events.py)
@@ -364,26 +341,6 @@ class ProfileGuidedTuner:
                           "window", e)
                 summary = None
             plan = plan_from_summary(summary) if summary else None
-            # the compute tier: knob candidates priced from the
-            # profiler's per-block anatomy compete with the comm plan
-            # on the same predicted-speedup scale
-            cplan = self._best_compute_plan()
-            if cplan is not None and (
-                    plan is None
-                    or cplan.predicted_speedup_pct
-                    > plan.predicted_speedup_pct
-                    # a comm re-plan landing on the plan already
-                    # running would only be retained — spend the
-                    # window on the next compute knob instead
-                    or self._same_plan(plan, self.plan)):
-                plan = cplan
-            elif plan is not None and self._verified_compute:
-                # a comm plan must re-assert the compute knobs earlier
-                # windows verified: the training rebuild is whole-state,
-                # so a plan without them would silently revert verified
-                # optimizations (and they'd stay excluded forever)
-                plan.compute = {**self._verified_compute,
-                                **(plan.compute or {})}
         if self.plan_sync is not None:
             # unconditional (all ranks must join the broadcast): process
             # 0's plan-or-None wins, so a trace that flushed late on one
@@ -433,58 +390,19 @@ class ProfileGuidedTuner:
         self.apply_fn(plan)
         self._metrics_predicted(plan.predicted_speedup_pct)
         self._record(dict(plan.to_dict(), outcome="applied"))
-        log.info("profile-guided: applied plan %d (%d buckets, compute "
-                 "%s, predicted %+.2f%%)", plan.plan_id, plan.num_buckets,
-                 plan.compute or {}, plan.predicted_speedup_pct)
+        log.info("profile-guided: applied plan %d (%d buckets, predicted "
+                 "%+.2f%%)", plan.plan_id, plan.num_buckets,
+                 plan.predicted_speedup_pct)
         self.phase = self.PHASE_VERIFY
 
     @staticmethod
     def _same_plan(a: Optional[FusionPlanSpec],
                    b: Optional[FusionPlanSpec]) -> bool:
-        """Same applied decision (bucketing + wire formats + compute
-        knobs) — predicted numbers excluded, they drift per window."""
+        """Same applied decision (bucketing + wire formats) — predicted
+        numbers excluded, they drift per window."""
         return a is not None and b is not None \
             and a.buckets == b.buckets and a.overlap == b.overlap \
-            and a.compression == b.compression \
-            and (a.compute or None) == (b.compute or None)
-
-    # -- the compute tier ----------------------------------------------------
-    def _compute_candidates(self) -> List[FusionPlanSpec]:
-        """Ranked un-tried compute-knob plans from the profiler anatomy
-        ([] without an anatomy source or when every knob is applied or
-        condemned)."""
-        if self.anatomy_fn is None:
-            return []
-        try:
-            anatomy = self.anatomy_fn()
-        except Exception as e:  # noqa: BLE001
-            log.debug("profile-guided anatomy read failed: %s", e)
-            return []
-        if not anatomy:
-            return []
-        from .compute_knobs import compute_plans_from_anatomy
-
-        exclude = set(self._verified_compute) | self._condemned_compute \
-            | set(self.active_compute)
-        if self.plan is not None and self.plan.compute:
-            exclude |= set(self.plan.compute)
-        return compute_plans_from_anatomy(
-            anatomy, exclude=exclude, fused_available=self.fused_available)
-
-    def _best_compute_plan(self) -> Optional[FusionPlanSpec]:
-        cands = self._compute_candidates()
-        if not cands:
-            return None
-        best = cands[0]
-        # accumulate the knobs earlier windows verified: the rebuild is
-        # whole-state, so a new plan must re-assert them or lose them
-        best.compute = {**self._verified_compute, **(best.compute or {})}
-        if self.plan is not None and self.plan.buckets:
-            # keep a verified comm layout while trying a compute knob
-            best.buckets = [list(b) for b in self.plan.buckets]
-            best.compression = list(self.plan.compression) \
-                if self.plan.compression else None
-        return best
+            and a.compression == b.compression
 
     # -- verify --------------------------------------------------------------
     def _verify_window(self, realized_us: float) -> None:
@@ -512,14 +430,10 @@ class ProfileGuidedTuner:
                    shortfall_pct=round(shortfall, 2))
         if self.rollback_enabled and shortfall > self.guard_band_pct:
             # fall back to the LAST VERIFIED plan (None = threshold
-            # bucketing) and condemn any compute knob this plan newly
-            # introduced so the next window doesn't re-propose it
+            # bucketing)
             fallback = self._last_good_plan
             self.apply_fn(fallback)
             self.plan = fallback
-            prior = set((fallback.compute or {})) if fallback is not None \
-                else set()
-            self._condemned_compute |= set(plan.compute or {}) - prior
             rec["outcome"] = "rolled_back"
             self._metrics_rollback()
             log.warning(
@@ -530,30 +444,11 @@ class ProfileGuidedTuner:
         else:
             rec["outcome"] = "verified"
             self._last_good_plan = plan
-            if plan.compute:
-                self._verified_compute.update(plan.compute)
             log.info("profile-guided: plan %d verified (realized %+.2f%% "
                      "vs predicted %+.2f%%)", plan.plan_id, realized_pct,
                      plan.predicted_speedup_pct)
         self._record(rec)
-        # un-tried compute knobs remain?  The anatomy is PER-RANK data
-        # (host-gap share differs across ranks), so multi-process jobs
-        # take process 0's answer through the plan broadcast — every
-        # rank must keep (or stop) joining the window collectives in
-        # lockstep, same invariant as the plan decision itself.
-        more_compute = bool(self._compute_candidates()) if self.plan_root \
-            else False
-        if self.plan_sync is not None:
-            d = self.plan_sync({"more_compute": more_compute})
-            more_compute = bool((d or {}).get("more_compute"))
-        if more_compute:
-            # measure a fresh baseline (with everything verified so far
-            # still applied) and try the next compute knob through the
-            # same apply→verify machinery
-            self.phase = self.PHASE_BASELINE
-            self._window = []
-            self._plan_attempts = 0
-        elif rec["outcome"] == "verified" and plan.cycle_flush_steps > 0:
+        if rec["outcome"] == "verified" and plan.cycle_flush_steps > 0:
             self.phase = self.PHASE_STEADY
             self._steady_left = plan.cycle_flush_steps
         else:
@@ -599,7 +494,6 @@ class ProfileGuidedTuner:
                         rec.get("realized_speedup_pct"),
                     "shortfall_pct": rec.get("shortfall_pct"),
                     "num_buckets": len(rec.get("buckets") or []),
-                    "compute": rec.get("compute"),
                 },
                 cause_id=None if kind == "autotune.apply"
                 else self._apply_event_id)
@@ -637,9 +531,7 @@ class ProfileGuidedTuner:
             pass
 
 
-def tuner_from_env(analyze_fn, apply_fn, anatomy_fn=None,
-                   fused_available=True,
-                   active_compute=None) -> ProfileGuidedTuner:
+def tuner_from_env(analyze_fn, apply_fn) -> ProfileGuidedTuner:
     """A tuner wired to the job's rendezvous server (push target from the
     metrics-pusher env triple) — the training.py construction path.
 
@@ -674,8 +566,5 @@ def tuner_from_env(analyze_fn, apply_fn, anatomy_fn=None,
             push = None
             plan_root = False
     return ProfileGuidedTuner(analyze_fn=analyze_fn, apply_fn=apply_fn,
-                              anatomy_fn=anatomy_fn,
-                              fused_available=fused_available,
-                              active_compute=active_compute,
                               window_sync=window_sync, plan_sync=plan_sync,
                               plan_root=plan_root, push_target=push)

@@ -373,27 +373,6 @@ def load_compute_json(trace_dir: str) -> Dict[int, dict]:
     return dict(sorted(out.items()))
 
 
-def own_rank_anatomy(trace_dir: str,
-                     rank: Optional[int] = None) -> Optional[dict]:
-    """THIS rank's anatomy from an already-written ``compute.json``
-    (None when absent/undecodable) — the compute-knob tuner's offline
-    plan source (optim/compute_knobs.py): a job restarted over the same
-    trace dir can plan compute knobs from its previous incarnation's
-    window before its own profiler has run."""
-    if rank is None:
-        from .. import core
-
-        rank = core.process_rank() if core.is_initialized() else 0
-    p = os.path.join(trace_dir, str(rank), COMPUTE_JSON)
-    if not os.path.isfile(p):
-        return None
-    try:
-        with open(p) as f:
-            return json.load(f).get("anatomy") or None
-    except (ValueError, OSError):
-        return None
-
-
 def report_from_dir(trace_dir: str) -> Dict[str, Any]:
     """The step-anatomy report for a whole trace dir: every rank's
     anatomy plus the cross-rank aggregate — scripts/hvd_profile.py's

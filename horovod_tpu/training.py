@@ -5,7 +5,9 @@ BroadcastGlobalVariablesHook + the example boilerplate (reference
 examples/tensorflow2_synthetic_benchmark.py:72-97), packaged as one
 TPU-native entry: build a jitted SPMD train step where the global batch is
 sharded across ranks, parameters are replicated, and gradients flow through
-the fused allreduce.
+the fused allreduce.  What the backward pass recomputes is the model's
+decision, not the step's: a model wraps what it chooses to in ``nn.remat``
+(as ``models/qwen3_next.py`` does for each decoder layer).
 """
 
 from __future__ import annotations
@@ -124,8 +126,6 @@ def make_train_step(
     profile_guided: Optional[bool] = None,
     profile: Optional[bool] = None,
     in_graph_steps: int = 1,
-    fused_optimizer: Optional[bool] = None,
-    remat_policy: Optional[str] = None,
     loss_fetch_steps: Optional[int] = None,
 ):
     """Returns ``step(state, batch, labels) -> (state, loss)`` compiled SPMD
@@ -184,16 +184,6 @@ def make_train_step(
       reference's timed inner loop also re-feeds one synthetic batch,
       examples/tensorflow2_synthetic_benchmark.py:72-97).  Real data
       pipelines keep the default 1.
-    * ``fused_optimizer`` (default: ``HVD_FUSED_OPTIMIZER``, on when
-      ``optimizer`` is a :class:`~horovod_tpu.optim.fused_update.
-      FusedOptimizer`) routes the update through the flat fused
-      elementwise kernel instead of the per-leaf optax traversal —
-      same flat state either way, so the autotuner can flip the knob
-      through the re-jit seam without a state migration.
-    * ``remat_policy`` (default ``HVD_REMAT_POLICY``: none|full|dots)
-      rematerializes the loss closure under ``jax.checkpoint`` — a
-      compute knob the tuner can rotate when activations are the
-      HBM bottleneck.
     * ``loss_fetch_steps`` (default ``HVD_LOSS_FETCH_STEPS``, 16)
       fetches loss/metrics through a TRAILING async handle every N
       steps (``step.loss_fetcher.value``) instead of a per-step
@@ -218,43 +208,14 @@ def make_train_step(
     if two_level is None:
         two_level = use_two_level_default()
 
-    # -- compute tier defaults (docs/autotune.md "compute knobs") -----------
-    from .optim.fused_update import FusedOptimizer
-
-    fusable = isinstance(optimizer, FusedOptimizer)
-    if fused_optimizer is None:
-        fused_optimizer = env_util.get_bool(env_util.HVD_FUSED_OPTIMIZER,
-                                            fusable)
-    if fused_optimizer and not fusable:
-        log.info("HVD_FUSED_OPTIMIZER is on but the optimizer is not a "
-                 "FusedOptimizer — keeping the per-leaf optax path")
-        fused_optimizer = False
-    if remat_policy is None:
-        remat_policy = env_util.get_str(env_util.HVD_REMAT_POLICY)
-    if remat_policy in ("", "none"):
-        remat_policy = None
     if loss_fetch_steps is None:
         loss_fetch_steps = env_util.get_int(
             env_util.HVD_LOSS_FETCH_STEPS,
             env_util.DEFAULT_LOSS_FETCH_STEPS)
     fetcher = TrailingLossFetcher(loss_fetch_steps)
 
-    def _remat_wrap(fn, policy):
-        """The remat knob: checkpoint the loss closure so the backward
-        recomputes activations instead of holding them in HBM."""
-        if not policy or policy == "none":
-            return fn
-        if policy == "dots":
-            return jax.checkpoint(
-                fn, policy=jax.checkpoint_policies.checkpoint_dots)
-        if policy != "full":
-            raise ValueError(
-                f"unknown remat policy {policy!r} (none|full|dots)")
-        return jax.checkpoint(fn)
-
     def _build(threshold_b, hier, named_buckets=None, comp=None,
-               bucket_compression=None, tlvl=None, fused_opt=False,
-               remat=None):
+               bucket_compression=None, tlvl=None):
         comp = comp if comp is not None else compression
         tlvl = two_level if tlvl is None else tlvl
         # error feedback threads TrainState.residual — only on the fused
@@ -329,32 +290,20 @@ def make_train_step(
                     )
             return grads, residual
 
-        # fused knob: flat single-kernel update vs per-leaf traversal —
-        # both paths of a FusedOptimizer share one flat state layout, so
-        # the autotuner can flip this through a re-jit with no state
-        # migration (optim/fused_update.py)
-        fused_active = bool(fused_opt) and fusable
-
         def _apply_update(state, grads, new_model_state, residual):
             with jax.named_scope("hvd_optimizer_update"):
-                if fused_active:
-                    params, opt_state = optimizer.fused_update(
-                        grads, state.opt_state, state.params)
-                else:
-                    updates, opt_state = optimizer.update(
-                        grads, state.opt_state, state.params
-                    )
-                    import optax
+                updates, opt_state = optimizer.update(
+                    grads, state.opt_state, state.params
+                )
+                import optax
 
-                    params = optax.apply_updates(state.params, updates)
+                params = optax.apply_updates(state.params, updates)
             return TrainState(params, opt_state, new_model_state,
                               state.step + 1, residual)
 
         def per_rank_step(state: TrainState, x, y):
             (loss, new_model_state), grads = jax.value_and_grad(
-                _remat_wrap(
-                    lambda p: _compute_loss(p, state.model_state, x, y),
-                    remat),
+                lambda p: _compute_loss(p, state.model_state, x, y),
                 has_aux=True,
             )(state.params)
             grads, residual = _reduce_grads(grads, state.residual)
@@ -402,9 +351,7 @@ def make_train_step(
 
             def backward_seg(state, x, y):
                 (loss, new_ms), grads = jax.value_and_grad(
-                    _remat_wrap(
-                        lambda p: _compute_loss(p, state.model_state, x, y),
-                        remat),
+                    lambda p: _compute_loss(p, state.model_state, x, y),
                     has_aux=True,
                 )(state.params)
                 return loss[None], _stack(new_ms), _stack(grads)
@@ -453,39 +400,21 @@ def make_train_step(
     from .timeline.timeline import host_span, timeline
 
     pm = None
-    box = {"fused_base": fused_optimizer, "remat_base": remat_policy}
-    fetcher_base_every = fetcher.every
+    box = {}
     #: host dispatches so far: the ``step_num`` every host span of one
     #: step carries, and the step the cadence series and events name
     step_count = [0]
 
-    def _rebuild(threshold_b, hier, plan=None, fused=None, remat=None,
-                 reason="first build"):
+    def _rebuild(threshold_b, hier, plan=None, reason="first build"):
         """(Re)compile the SPMD step and remember the knobs + the core
         mesh epoch it was built against, so a later elastic membership
         change (core.reinit bumps the epoch and swaps the mesh) can
         rebuild with the same knobs.  ``plan`` is a profile-guided
         FusionPlanSpec: its explicit bucket vector overrides the scalar
-        threshold, its per-bucket ``compression`` names override the
-        wire format, and its ``compute`` dict overrides the compute
-        knobs (optim/profile_guided.py; a compute-only plan has no
-        buckets and leaves threshold bucketing untouched).  ``fused`` /
-        ``remat`` move the base compute knobs (the GP tuner's
-        categorical dims); None leaves the base unchanged.  ``reason``
-        (first build | epoch | plan | guard) is what the ``hvd_rebuild``
-        host span says of a rebuild that builds a new program."""
-        if fused is not None:
-            box["fused_base"] = fused
-        if remat is not None:
-            box["remat_base"] = None if remat == "none" else remat
-        pc = (getattr(plan, "compute", None) or {}) \
-            if plan is not None else {}
-        fused_eff = pc.get("fused_optimizer", box["fused_base"])
-        remat_eff = pc.get("remat_policy", box["remat_base"])
-        # the async-pipeline knob is host-side: the plan moves the
-        # fetch cadence without a re-jit, rollback restores the base
-        fetcher.every = max(int(pc.get("loss_fetch_steps",
-                                       fetcher_base_every)), 0)
+        threshold and its per-bucket ``compression`` names override the
+        wire format (optim/profile_guided.py).  ``reason`` (first build |
+        epoch | plan | guard) is what the ``hvd_rebuild`` host span says
+        of a rebuild that builds a new program."""
         named = plan.buckets if plan is not None and plan.buckets \
             else None
         bucket_comp = getattr(plan, "compression", None) \
@@ -506,39 +435,23 @@ def make_train_step(
                      "applying the fusion layout uncompressed")
             bucket_comp = None
         comp = box.get("compression", compression)
-        # Everything jit-relevant, hashed: a rebuild whose compiled
-        # program would be byte-identical (e.g. a plan moving ONLY the
-        # host-side loss-fetch cadence, or its rollback) skips the
-        # re-trace/recompile — on a big model that's multi-seconds per
-        # knob trial that would otherwise land inside the tuner's
-        # verify window.
-        sig = (threshold_b, hier and named is None,
-               tuple(tuple(b) for b in named) if named else None,
-               tuple(bucket_comp) if bucket_comp else None,
-               id(comp), two_level and named is None, fused_eff,
-               remat_eff, core._require_init().epoch)
-        if sig == box.get("build_sig"):
-            box["plan"] = plan
-            return
         # An explicit bucket plan owns the comm layout: the hierarchical
         # path reduces per leaf and would silently drop named_buckets
         # while the tuner reports the plan applied.  box keeps the
-        # original hier so rollback (plan=None) restores it.  A
-        # compute-only plan (no buckets) leaves the comm layout alone.
+        # original hier so rollback (plan=None) restores it.
         with host_span("rebuild", reason=reason, step_num=step_count[0]):
             fn, ef, profile_factory = _build(
                 threshold_b, hier and named is None, named,
-                comp, bucket_comp, two_level and named is None,
-                fused_eff, remat_eff)
+                comp, bucket_comp, two_level and named is None)
         # any rebuild (new plan, elastic epoch, guard trip) invalidates
         # the profiler's cached decomposed segments — they must re-jit
         # against the same knobs as the fused program
         box.pop("profile_fns", None)
         box.update(
             fn=fn, threshold=threshold_b, hier=hier, plan=plan,
-            ef_active=ef, compression=comp, fused=fused_eff,
-            remat=remat_eff, profile_factory=profile_factory,
-            core_epoch=core._require_init().epoch, build_sig=sig,
+            ef_active=ef, compression=comp,
+            profile_factory=profile_factory,
+            core_epoch=core._require_init().epoch,
             # a new jitted function: its first call compiles, which
             # _count_compiles sees as the cache growing from nothing
             cache_size=0,
@@ -551,22 +464,13 @@ def make_train_step(
             fusion_threshold_bytes=threshold_bytes
             or env_util.fusion_threshold_bytes(),
             hierarchical_allreduce=hierarchical,
-            fused_optimizer=fused_optimizer if fusable else None,
-            remat_policy=remat_policy,
         )
-        # HVD_AUTOTUNE_COMPUTE widens the GP rotation to the compute
-        # knobs — fused_optimizer only where the optimizer can fuse
-        tune_compute = env_util.get_bool(env_util.HVD_AUTOTUNE_COMPUTE)
         pm = ParameterManager(
             enabled=True, log_file=autotune_log_file, initial=initial,
-            tune_fused_optimizer=tune_compute and fusable,
-            tune_remat=tune_compute,
         )
         pm.on_update = lambda p: _rebuild(p.fusion_threshold_bytes,
                                           p.hierarchical_allreduce,
                                           p.fusion_plan,
-                                          fused=p.fused_optimizer,
-                                          remat=p.remat_policy,
                                           reason="plan")
         _rebuild(initial.fusion_threshold_bytes,
                  initial.hierarchical_allreduce)
@@ -886,34 +790,7 @@ def make_train_step(
             else:
                 _rebuild(box["threshold"], box["hier"], plan, reason="plan")
 
-        def _anatomy():
-            """The compute tier's plan source: the in-job profiler's
-            anatomy when a window has finalized, else this rank's
-            compute.json from an earlier run of the same trace dir."""
-            if profiler is not None and profiler.anatomy is not None:
-                return profiler.anatomy
-            if trace_dir:
-                from .timeline.profiler import own_rank_anatomy
-
-                return own_rank_anatomy(trace_dir)
-            return None
-
-        # knobs the base config already has on are not plan candidates:
-        # proposing them would be a no-op guaranteed to miss its
-        # prediction and get condemned.  loss_fetch_steps is ALWAYS
-        # excluded in-job: the tuner's baseline and verify windows both
-        # force a per-step result sync for honest timing — exactly the
-        # serialization the knob removes — so its realized delta inside
-        # a verify window is ~0 by construction and the guard band
-        # could only condemn (or falsely verify) it.  The knob stays
-        # reachable via HVD_LOSS_FETCH_STEPS, explicit plans, and the
-        # offline planner (scripts/compute_path_bench.py).
-        active = {"loss_fetch_steps": fetcher.every}
-        if fused_optimizer:
-            active["fused_optimizer"] = True
-        tuner = tuner_from_env(_analyze, _apply_plan, anatomy_fn=_anatomy,
-                               fused_available=fusable,
-                               active_compute=active)
+        tuner = tuner_from_env(_analyze, _apply_plan)
         if not trace_dir:
             from .utils.logging import get_logger
 

@@ -177,16 +177,10 @@ HVD_SERVE_MAX_REPLICAS = "HVD_SERVE_MAX_REPLICAS"      # grow ceiling (default 0
 HVD_SERVE_DRAIN_TIMEOUT_SECONDS = "HVD_SERVE_DRAIN_TIMEOUT_SECONDS"  # drain handshake budget (default elastic timeout)
 HVD_SERVE_WEIGHT_COMPRESSION = "HVD_SERVE_WEIGHT_COMPRESSION"  # none|bf16|int8|fp8 at-rest weight format
 HVD_BENCH_SERVE = "HVD_BENCH_SERVE"                    # 0 skips bench.py's serving leg
-# compute-path optimization tier (optim/fused_update.py, training.py,
-# data/loader.py, optim/compute_knobs.py; docs/autotune.md "Compute knobs"):
-# fused step kernels + async host pipeline + compute-knob autotuning
-HVD_FUSED_OPTIMIZER = "HVD_FUSED_OPTIMIZER"            # 0 forces the per-leaf optax path even for a FusedOptimizer
-HVD_FUSED_UPDATE_PALLAS = "HVD_FUSED_UPDATE_PALLAS"    # force the Pallas (1) / jnp (0) fused-update backend; default: Pallas on TPU only
+# async host pipeline (training.py TrailingLossFetcher, data/loader.py;
+# docs/profiling.md host-gap section)
 HVD_LOSS_FETCH_STEPS = "HVD_LOSS_FETCH_STEPS"          # trailing async loss fetch cadence (default 16; 0 never fetches)
 HVD_PREFETCH_DEPTH = "HVD_PREFETCH_DEPTH"              # device prefetch queue depth in data/loader.py (default 2; 0 disables)
-HVD_REMAT_POLICY = "HVD_REMAT_POLICY"                  # none|full|dots rematerialization of the loss closure
-HVD_AUTOTUNE_COMPUTE = "HVD_AUTOTUNE_COMPUTE"          # 1 lets the GP autotuner rotate the compute knobs too
-HVD_BENCH_COMPUTE_OPT = "HVD_BENCH_COMPUTE_OPT"        # 0 skips bench.py's compute-path A/B leg (host_gap_pct source)
 # hierarchical HA control plane (run/store.py, run/journal.py,
 # run/relay.py; docs/control_plane.md): sharded KV + per-host relay
 # aggregation + warm-standby failover

@@ -1,7 +1,7 @@
 """Async host pipeline: the trailing loss fetch (training.py
 TrailingLossFetcher + HVD_LOSS_FETCH_STEPS) and the device prefetch
 loader (data/loader.py prefetch_to_device) — the step-path honesty-sync
-fix and the loader-overlap satellite of the compute tier."""
+fix and the loader overlap."""
 
 import time
 
@@ -89,21 +89,6 @@ def test_fetcher_exports_train_loss_gauge(hvd_init, rng):
         state, _ = step(state, x, y)
     assert metrics.TRAIN_LOSS.get() == pytest.approx(
         step.loss_fetcher.value)
-
-
-def test_plan_moves_fetch_cadence_and_rollback_restores(hvd_init, rng):
-    """The loss_fetch_steps compute knob applies through the rebuild
-    seam without a re-jit and rolls back to the base cadence."""
-    from horovod_tpu.optim.profile_guided import FusionPlanSpec
-
-    step, state, x, y = _mlp_step(rng, loss_fetch_steps=16,
-                                  autotune=True)
-    state, _ = step(state, x, y)
-    step.parameter_manager.apply_plan(
-        FusionPlanSpec(buckets=[], compute={"loss_fetch_steps": 4}))
-    assert step.loss_fetcher.every == 4
-    step.parameter_manager.clear_plan()
-    assert step.loss_fetcher.every == 16
 
 
 # ---------------------------------------------------------------------------

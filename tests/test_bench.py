@@ -120,7 +120,6 @@ def test_successful_run_passes_result_through(monkeypatch, capsys):
     monkeypatch.setattr(bench, "_compression_delta", lambda v: {})
     monkeypatch.setattr(bench, "_serving_leg", lambda: {})
     monkeypatch.setattr(bench, "_projection_leg", lambda: {})
-    monkeypatch.setattr(bench, "_compute_opt_leg", lambda: {})
     monkeypatch.setattr(bench, "_control_leg", lambda: {})
     monkeypatch.setattr(bench, "_watch_leg", lambda: {})
     monkeypatch.setattr(bench, "_restore_leg", lambda: {})
@@ -332,70 +331,6 @@ def test_serving_leg_merged_and_skippable(monkeypatch, capsys):
     assert not any("--child-serve" in c for c in calls)
 
 
-def test_compute_opt_leg_merged_and_skippable(monkeypatch, capsys):
-    """The compute-path A/B leg (docs/profiling.md host-gap section) lands
-    compute_opt_delta_pct + host_gap_pct in the JSON tail alongside
-    mfu, and HVD_BENCH_COMPUTE_OPT=0 skips it — same null-on-failure
-    _run_child contract as every other leg."""
-    bench = _load_bench()
-    payload = {"metric": "resnet50_synthetic_img_sec_per_chip",
-               "value": 2700.0, "unit": "images/sec/chip",
-               "vs_baseline": 26.07}
-
-    class FakeProc:
-        def __init__(self, line):
-            self.returncode = 0
-            self.stdout = "RESULT " + line + "\n"
-            self.stderr = ""
-
-    calls = []
-
-    def fake_run(cmd, *a, **k):
-        calls.append(cmd)
-        if "--child-compute-opt" in cmd:
-            return FakeProc(json.dumps(
-                {"compute_opt_delta_pct": 21.4, "host_gap_pct": 3.1,
-                 "compute_opt_loss_equal": True}))
-        return FakeProc(json.dumps(payload))
-
-    monkeypatch.setattr(bench, "_autotune_delta", lambda v: {})
-    monkeypatch.setattr(bench, "_compression_delta", lambda v: {})
-    monkeypatch.setattr(bench, "_serving_leg", lambda: {})
-    monkeypatch.setattr(bench, "_projection_leg", lambda: {})
-    monkeypatch.setattr(bench.subprocess, "run", fake_run)
-    monkeypatch.delenv("HVD_BENCH_COMPUTE_OPT", raising=False)
-    bench.main()
-    out = json.loads(capsys.readouterr().out.strip())
-    assert out["value"] == 2700.0
-    assert out["compute_opt_delta_pct"] == 21.4
-    assert out["host_gap_pct"] == 3.1
-    assert out["compute_opt_loss_equal"] is True
-    assert any("--child-compute-opt" in c for c in calls)
-
-    # a hung A/B child degrades to nulls, never costs the main number
-    def raise_for_leg(cmd, *a, **k):
-        if "--child-compute-opt" in cmd:
-            raise bench.subprocess.TimeoutExpired(cmd="x", timeout=1)
-        return FakeProc(json.dumps(payload))
-
-    monkeypatch.setattr(bench.subprocess, "run", raise_for_leg)
-    bench.main()
-    out = json.loads(capsys.readouterr().out.strip())
-    assert out["value"] == 2700.0
-    assert out["compute_opt_delta_pct"] is None
-    assert out["host_gap_pct"] is None
-    assert "timeout" in out["compute_opt_error"]
-
-    # HVD_BENCH_COMPUTE_OPT=0: no child run, no tail fields
-    calls.clear()
-    monkeypatch.setattr(bench.subprocess, "run", fake_run)
-    monkeypatch.setenv("HVD_BENCH_COMPUTE_OPT", "0")
-    bench.main()
-    out = json.loads(capsys.readouterr().out.strip())
-    assert "compute_opt_delta_pct" not in out
-    assert not any("--child-compute-opt" in c for c in calls)
-
-
 def test_control_leg_merged_and_skippable(monkeypatch, capsys):
     """The control-plane churn leg (docs/control_plane.md) lands
     control_p99_lease_ms / control_p99_epoch_ms / control_abort_ms /
@@ -427,7 +362,6 @@ def test_control_leg_merged_and_skippable(monkeypatch, capsys):
     monkeypatch.setattr(bench, "_compression_delta", lambda v: {})
     monkeypatch.setattr(bench, "_serving_leg", lambda: {})
     monkeypatch.setattr(bench, "_projection_leg", lambda: {})
-    monkeypatch.setattr(bench, "_compute_opt_leg", lambda: {})
     monkeypatch.setattr(bench.subprocess, "run", fake_run)
     monkeypatch.delenv("HVD_BENCH_CONTROL", raising=False)
     bench.main()
@@ -491,8 +425,7 @@ def test_watch_leg_merged_and_skippable(monkeypatch, capsys):
 
     for leg in ("_autotune_delta", "_compression_delta"):
         monkeypatch.setattr(bench, leg, lambda v: {})
-    for leg in ("_serving_leg", "_projection_leg", "_compute_opt_leg",
-                "_control_leg"):
+    for leg in ("_serving_leg", "_projection_leg", "_control_leg"):
         monkeypatch.setattr(bench, leg, lambda: {})
     monkeypatch.setattr(bench.subprocess, "run", fake_run)
     monkeypatch.delenv("HVD_BENCH_WATCH", raising=False)
@@ -559,8 +492,8 @@ def test_restore_leg_merged_and_skippable(monkeypatch, capsys):
 
     for leg in ("_autotune_delta", "_compression_delta"):
         monkeypatch.setattr(bench, leg, lambda v: {})
-    for leg in ("_serving_leg", "_projection_leg", "_compute_opt_leg",
-                "_control_leg", "_watch_leg"):
+    for leg in ("_serving_leg", "_projection_leg", "_control_leg",
+                "_watch_leg"):
         monkeypatch.setattr(bench, leg, lambda: {})
     monkeypatch.setattr(bench.subprocess, "run", fake_run)
     monkeypatch.delenv("HVD_BENCH_RESTORE", raising=False)

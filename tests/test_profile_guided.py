@@ -596,6 +596,113 @@ def test_loop_freezes_after_planless_windows():
     assert tuner.history[-1]["windows_tried"] == 3
 
 
+def _one_plan_summary(buckets, predicted_us, baseline_us):
+    """An ``analyze()`` summary whose only plannable scenario is
+    ``buckets`` at ``predicted_us``."""
+    return {"steps": [{"step": 1, "what_if": {
+        "baseline_replay_us": baseline_us,
+        "scenarios": [{
+            "scenario": f"fuse_buckets_{len(buckets)}",
+            "predicted_step_us": predicted_us,
+            "speedup_pct": (baseline_us - predicted_us) / baseline_us * 100,
+            "plan": {"buckets": buckets}}]}}]}
+
+
+def test_loop_rolls_back_to_the_last_verified_plan():
+    """A re-plan that regresses falls back to the plan the loop had
+    verified before it, not to threshold bucketing: what an earlier window
+    proved is not thrown away with what a later one disproved."""
+    first = [["g0"], ["g1", "g2"]]
+    second = [["g0", "g1"], ["g2"]]
+    summaries = iter([_one_plan_summary(first, 700.0, 1000.0),
+                      _one_plan_summary(second, 560.0, 700.0)])
+    applied = []
+    tuner = ProfileGuidedTuner(
+        analyze_fn=lambda: next(summaries), apply_fn=applied.append,
+        window_steps=2, guard_band_pct=10.0, cycle_flush_steps=2)
+    for us in ([1000] * 2 + [700] * 2     # first plan: applied, verified
+               + [700] * 2                # pinned for its flush cadence
+               + [700] * 2                # fresh baseline: second plan
+               + [700] * 2):              # which buys nothing
+        tuner.on_step(us * 1e-6)
+    assert [r["outcome"] for r in tuner.history] == \
+        ["applied", "verified", "applied", "rolled_back"]
+    assert [p.buckets for p in applied] == [first, second, first]
+    assert tuner.plan.buckets == first and tuner.plan.plan_id == 1
+
+
+def test_ranks_leave_a_verify_window_together():
+    """What follows a verify window is decided from the synced window
+    alone: a rank whose own steps say "keep" and one whose own steps say
+    "roll back" take the same way out, or one of them would stop joining
+    the window's collectives."""
+    summary = _one_plan_summary([["g0"], ["g1", "g2"]], 700.0, 1000.0)
+    local_verify_us = {"fast": 700.0, "slow": 1000.0}
+    mean = sum(local_verify_us.values()) / 2
+    ways_out = {}
+    for rank, us in local_verify_us.items():
+        synced = iter([1000.0, mean])   # the baseline, then the verify
+        tuner = ProfileGuidedTuner(
+            analyze_fn=lambda: summary, apply_fn=lambda p: None,
+            window_steps=2, guard_band_pct=10.0, plan_root=rank == "fast",
+            window_sync=lambda w: next(synced),
+            plan_sync=lambda d: plan_from_summary(summary).to_dict())
+        for step_us in [1000.0] * 2 + [us] * 2:
+            tuner.on_step(step_us * 1e-6)
+        ways_out[rank] = (tuner.history[-1]["outcome"], tuner.phase,
+                          tuner.plan)
+    # realized 15% of an expected 30%: past the 10% band for both
+    assert ways_out["fast"] == ways_out["slow"] == \
+        ("rolled_back", ProfileGuidedTuner.PHASE_FROZEN, None)
+
+
+def test_a_persisted_plan_with_a_compute_key_is_read(server):
+    """Outside input: a plan record that a run before PR 28 left in the
+    KV store or the tuner's log carries a ``compute`` entry (PR 12's
+    knobs).  Reading it ignores the entry; the bucket plan still applies."""
+    record = {"buckets": [["g0"], ["g1", "g2"]], "overlap": True,
+              "compression": None,
+              "compute": {"fused_optimizer": True, "remat_policy": "dots",
+                          "loss_fetch_steps": 4},
+              "cycle_flush_steps": 0, "predicted_step_us": 300.0,
+              "baseline_step_us": 440.0, "predicted_speedup_pct": 31.82,
+              "source_step": 3, "plan_id": 1, "outcome": "verified"}
+    put_autotune_plan("127.0.0.1", server.port, 1, record)
+    stored = get_autotune("127.0.0.1", server.port)["current"]
+    plan = FusionPlanSpec.from_dict(stored)
+    assert plan.buckets == record["buckets"] and plan.plan_id == 1
+    assert not hasattr(plan, "compute") and "compute" not in plan.to_dict()
+    updates = []
+    pm = ParameterManager(enabled=True, on_update=updates.append)
+    pm.apply_plan(plan)
+    assert updates[-1].fusion_plan is plan
+
+
+def test_parameter_manager_rotates_threshold_and_hierarchical_only(
+        monkeypatch):
+    """The GP moves the two knobs of the gradient exchange and nothing
+    of the step's compute."""
+    import dataclasses
+
+    assert [f.name for f in dataclasses.fields(TunableParams)] == [
+        "fusion_threshold_bytes", "hierarchical_allreduce", "fusion_plan"]
+    assert TunableParams.CATEGORICAL_DIMS == ("hierarchical_allreduce",)
+    for gone in ("tune_fused_optimizer", "tune_remat"):
+        with pytest.raises(TypeError, match=gone):
+            ParameterManager(enabled=True, **{gone: True})
+    monkeypatch.setenv("HVD_AUTOTUNE_PYTHON", "1")
+    seen = []
+    pm = ParameterManager(enabled=True, warmup_samples=0,
+                          steps_per_sample=1, max_samples=8,
+                          on_update=seen.append)
+    while not pm.frozen:
+        pm.record_step(1e9, 1.0)
+    assert pm._category_knobs == [{"hierarchical_allreduce": False},
+                                  {"hierarchical_allreduce": True}]
+    assert {p.hierarchical_allreduce for p in seen} == {False, True}
+    assert len({p.fusion_threshold_bytes for p in seen}) > 1
+
+
 def test_parameter_manager_plan_pinning_fires_rejit_seam():
     updates = []
     pm = ParameterManager(enabled=True, on_update=updates.append)
