@@ -182,6 +182,12 @@ FLASH_TILES = registry.counter(
     "not per step): skipped (past the diagonal, not computed), full "
     "(no key masked), crossed (the diagonal passes through); dynamic "
     "(all of the grid's) when the offsets are traced.", ("kernel", "kind"))
+GDN_SCAN_CHUNKS = registry.counter(
+    "hvd_gdn_scan_chunks_traced_total",
+    "Chunks (of every value head) each traced gated-delta-rule kernel call "
+    "walks (per compile, not per step): kernel fwd or bwd, path mosaic "
+    "(compiled for the TPU) or interpret (Pallas interpreter mode).",
+    ("kernel", "path"))
 MOE_LAYERS = registry.counter(
     "hvd_moe_layers_traced_total",
     "Routed expert layers (parallel/moe.routed_experts) traced (per "
@@ -520,6 +526,17 @@ def record_flash_tiles(kernel: str, counts) -> None:
     try:
         for kind, n in counts.items():
             FLASH_TILES.labels(kernel, kind).inc(n)
+    except Exception:  # noqa: BLE001 — tracing must never fail on metrics
+        pass
+
+
+def record_gdn_scan_chunks(kernel: str, path: str, chunks: int) -> None:
+    """One traced call of a gated-delta-rule kernel (ops/gated_delta.py)
+    — that the kernels engaged, on which path, over how many chunks."""
+    if not registry.enabled:
+        return
+    try:
+        GDN_SCAN_CHUNKS.labels(kernel, path).inc(chunks)
     except Exception:  # noqa: BLE001 — tracing must never fail on metrics
         pass
 
