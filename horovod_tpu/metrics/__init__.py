@@ -22,9 +22,11 @@ hvd_host_collective_seconds     histogram  host-plane wall time, by ``transport`
 hvd_collectives_traced_total    counter    collectives emitted at trace time
 hvd_collectives_traced_bytes_total counter traced payload bytes, by ``op``
 hvd_flash_tiles_traced_total    counter    flash score tiles per traced kernel
-                                           call, by ``kernel``/``kind``
+                                           call, by ``kernel``/``kind``/``mask``
 hvd_moe_layers_traced_total     counter    routed expert layers traced, by
                                            ``held``/``top_k``
+hvd_bd_layers_traced_total      counter    block-diffusion attention layers
+                                           traced, by ``block``
 hvd_step_seconds                histogram  train-step cadence (dispatch-to-
                                            dispatch interval — honest under
                                            async dispatch, see training.py)
@@ -180,14 +182,19 @@ FLASH_TILES = registry.counter(
     "hvd_flash_tiles_traced_total",
     "Score tiles of each traced flash-attention kernel call (per compile, "
     "not per step): skipped (past the diagonal, not computed), full "
-    "(no key masked), crossed (the diagonal passes through); dynamic "
-    "(all of the grid's) when the offsets are traced.", ("kernel", "kind"))
+    "(no key masked), crossed (the mask's edge passes through); dynamic "
+    "(all of the grid's) when the offsets are traced; mask none, causal or "
+    "block_diffusion_b<block>.", ("kernel", "kind", "mask"))
 GDN_SCAN_CHUNKS = registry.counter(
     "hvd_gdn_scan_chunks_traced_total",
     "Chunks (of every value head) each traced gated-delta-rule kernel call "
     "walks (per compile, not per step): kernel fwd or bwd, path mosaic "
     "(compiled for the TPU) or interpret (Pallas interpreter mode).",
     ("kernel", "path"))
+BD_LAYERS = registry.counter(
+    "hvd_bd_layers_traced_total",
+    "Block-diffusion attention layers traced (models/sdar.py; per compile, "
+    "not per step), by the block length of their mask.", ("block",))
 MOE_LAYERS = registry.counter(
     "hvd_moe_layers_traced_total",
     "Routed expert layers (parallel/moe.routed_experts) traced (per "
@@ -518,14 +525,15 @@ def record_traced(op: str, tensor) -> None:
         pass
 
 
-def record_flash_tiles(kernel: str, counts) -> None:
+def record_flash_tiles(kernel: str, counts, mask: str) -> None:
     """Score tiles by kind of one traced flash kernel call
-    (ops/flash_attention.py) — how often the unmasked body engages."""
+    (ops/flash_attention.py) under the mask ``mask`` — how often the
+    unmasked body engages, how much of the grid is skipped."""
     if not registry.enabled:
         return
     try:
         for kind, n in counts.items():
-            FLASH_TILES.labels(kernel, kind).inc(n)
+            FLASH_TILES.labels(kernel, kind, mask).inc(n)
     except Exception:  # noqa: BLE001 — tracing must never fail on metrics
         pass
 
@@ -537,6 +545,16 @@ def record_gdn_scan_chunks(kernel: str, path: str, chunks: int) -> None:
         return
     try:
         GDN_SCAN_CHUNKS.labels(kernel, path).inc(chunks)
+    except Exception:  # noqa: BLE001 — tracing must never fail on metrics
+        pass
+
+
+def record_bd_layer(block: int) -> None:
+    """One traced block-diffusion attention layer (models/sdar.py)."""
+    if not registry.enabled:
+        return
+    try:
+        BD_LAYERS.labels(str(block)).inc()
     except Exception:  # noqa: BLE001 — tracing must never fail on metrics
         pass
 

@@ -107,3 +107,16 @@ def next_token_loss(logits, ids):
     picked = jnp.where(hit, logits, 0).sum(-1)
     weight = (jnp.arange(s) < s - 1) / (b * (s - 1))
     return jnp.sum((lse - picked) * weight)
+
+
+def weighted_token_loss(logits, targets, weight):
+    """``sum_i weight_i (logsumexp(logits_i) - logits_i[targets_i])`` over
+    all positions, with no shift: what a masked-token loss is, the weights
+    zero where nothing is predicted (``models/sdar.block_diffusion_loss``).
+    :func:`next_token_loss`'s form: no log-probability tensor, and the
+    target's logit is picked inside the row's reduction (no gather, so no
+    scatter on the way back)."""
+    hit = jnp.arange(logits.shape[-1]) == targets[..., None]
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    picked = jnp.where(hit, logits, 0).sum(-1)
+    return jnp.sum((lse - picked) * weight)

@@ -1,0 +1,303 @@
+"""SDAR-style block-diffusion decoder (flax/linen), TPU-first: a Qwen3-MoE
+decoder — plain RMSNorm, grouped-query attention with per-head q/k norms
+and full rotary embedding, a top-k mixture of many small experts with no
+shared expert, an untied head — *trained as a block-diffusion model*.
+
+A row of data is ``(ids [L], level [L / B], draw [L])``: the tokens, one
+noise level a block of ``B`` tokens and one draw a token, all integers.
+With ``t_b = level_b / 65536`` the mask probability of block ``b``, token
+``i`` is noised iff ``draw_i < level_{i // B}`` and then reads ``[MASK]``.
+One forward pass serves every block at once: the decoder runs over the
+noised copy and the clean copy side by side, ``2 L`` rows ``[noised ;
+clean]`` that both carry positions ``0 .. L - 1``, under the block-diffusion
+attention mask (``ops/flash_attention.block_diffusion_mask``): a clean row
+sees the clean blocks up to its own, a noised row the clean blocks before
+its own and the noised tokens of its own block, and nothing sees another
+block's noise.  The final norm and the head run on the noised half alone:
+logits ``[b, L, vocab]`` float32, one a data token, each predicting its own
+token (no shift).  :func:`block_diffusion_loss` is the masked-token loss,
+each masked position weighted by ``1 / t`` of its block.
+
+Every layer: ``x = x + attn(norm(x))``, ``x = x + moe(norm(x))``; RMSNorm is
+``x / rms(x) * w`` (``w`` starts at one, the heads' q / k norms at
+``qk_norm_init``).  No bias anywhere.
+
+* Attention: ``q_proj`` / ``k_proj`` / ``v_proj`` to ``num_heads`` /
+  ``num_kv_heads`` heads of ``head_dim``; RMSNorm over each head's dims on
+  q and on k (one weight of ``head_dim`` each, shared by the heads); rotary
+  embedding over all of a head's dims, halves paired (``rotate_half``),
+  **angles from the rows' position ids**; the Pallas flash kernels under
+  the mask above, with k and v repeated to the q heads outside them (the
+  kernels take equal head counts); ``o_proj``.
+* Expert layer: ``parallel/moe.routed_experts`` over the experts held here
+  (``num_experts`` of the router's ``router_experts``, from
+  ``first_expert``): softmax over all the router's outputs in float32, the
+  ``num_experts_per_tok`` largest, their weights divided by their sum.
+  With ``moe_capacity_factor`` the load is bounded as GShard bounds it: a
+  layer's rows are taken in groups of ``moe_group_rows`` and an expert
+  takes at most ``factor * group * top_k / router_experts`` of a group's
+  rows, the first in row order; what overflows is dropped.
+
+bf16 compute / float32 parameters like the other families.  ``remat``
+recomputes each decoder layer in the backward pass (``nn.remat``).  Device
+scopes (docs/profiling.md): ``hvd_bd_noise`` (the compare, the
+substitution, the concatenation, the position ids), ``hvd_bd_head_rows``
+(the slice before the head), ``hvd_flash_*``, ``hvd_moe`` (``hvd_moe_route``,
+``hvd_moe_experts``); counter ``hvd_bd_layers_traced_total{block}``.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Optional
+
+import math
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from .. import metrics
+from ..ops.flash_attention import block_diffusion_mask, flash_attention
+from ..parallel.moe import routed_experts
+from .gpt import weighted_token_loss
+from .qwen3_next import (_dense, _normal, apply_rotary, rms_normalise,
+                         rotary_tables)
+
+_F32 = jnp.float32
+#: a noise level is an integer in ``[0, LEVELS]``: mask probability
+#: ``level / LEVELS``; a token's draw is an integer in ``[0, LEVELS)``
+LEVELS = 65536
+
+
+def noised_tokens(ids, level, draw):
+    """``[b, L]`` booleans: token ``i`` of a row is noised iff its draw is
+    under its block's level (blocks of ``L / level.shape[1]`` tokens)."""
+    block = ids.shape[1] // level.shape[1]
+    return draw < jnp.repeat(level, block, axis=1)
+
+
+class RMSNorm(nn.Module):
+    """``x / rms(x) * w`` over the last dim, computed in float32; ``w``
+    starts at ``init``."""
+    eps: float = 1e-6
+    init: float = 1.0
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = _F32
+
+    @nn.compact
+    def __call__(self, x):
+        w = self.param("weight", nn.initializers.constant(self.init),
+                       (x.shape[-1],), self.param_dtype)
+        return (rms_normalise(x, self.eps) * w.astype(_F32)).astype(
+            self.dtype)
+
+
+class BlockDiffusionAttention(nn.Module):
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    block_length: int
+    eps: float
+    qk_norm_init: float = 1.0
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = _F32
+
+    @nn.compact
+    def __call__(self, x, cos, sin):
+        b, rows, _ = x.shape
+        h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        metrics.record_bd_layer(self.block_length)
+        q = _dense(h * hd, "q_proj", self)(x).reshape(b, rows, h, hd)
+        k = _dense(kv * hd, "k_proj", self)(x).reshape(b, rows, kv, hd)
+        v = _dense(kv * hd, "v_proj", self)(x).reshape(b, rows, kv, hd)
+        # the projections' scale cancels in these norms: their weights are
+        # the softmax's temperature (the scores' deviation is w_q * w_k)
+        norm = dict(eps=self.eps, init=self.qk_norm_init, dtype=self.dtype,
+                    param_dtype=self.param_dtype)
+        q = RMSNorm(name="q_norm", **norm)(q)
+        k = RMSNorm(name="k_norm", **norm)(k)
+        q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
+        # each kv head serves h // kv consecutive q heads; the kernels take
+        # equal head counts, so k and v are repeated outside them
+        k, v = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
+        o = flash_attention(q, k, v, mask=block_diffusion_mask(
+            self.block_length, rows // 2))
+        return _dense(x.shape[-1], "o_proj", self)(o.reshape(b, rows, h * hd))
+
+
+class RoutedMoe(nn.Module):
+    """The experts held here of ``router_experts``, ``top_k`` a token; no
+    shared expert.  With a ``capacity_factor`` the rows are taken in
+    groups of ``group_rows`` (all of them in one when fewer, or ``None``)
+    and an expert takes at most ``capacity_factor * group * top_k /
+    router_experts`` rows of a group."""
+    num_experts: int          # held here
+    router_experts: int       # the router's width: all the layer's experts
+    first_expert: int
+    top_k: int
+    expert_dim: int
+    group_rows: Optional[int] = None
+    capacity_factor: Optional[float] = None
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = _F32
+
+    @nn.compact
+    def __call__(self, x):
+        b, rows, d = x.shape
+        with jax.named_scope("hvd_moe"):
+            router = self.param("gate", _normal(), (d, self.router_experts),
+                                self.param_dtype)
+            shapes = {"gate_proj": (self.num_experts, d, self.expert_dim),
+                      "up_proj": (self.num_experts, d, self.expert_dim),
+                      "down_proj": (self.num_experts, self.expert_dim, d)}
+            experts = {name: self.param(f"experts_{name}", _normal(), shape,
+                                        self.param_dtype)
+                       for name, shape in shapes.items()}
+            n = b * rows
+            group = min(self.group_rows or n, n)
+            if n % group:
+                raise ValueError(
+                    f"{n} rows are not whole groups of {group}")
+            capacity = None if self.capacity_factor is None else math.ceil(
+                self.capacity_factor * group * self.top_k
+                / self.router_experts)
+
+            def one_group(xs):
+                return routed_experts(
+                    xs, router, experts, top_k=self.top_k,
+                    first_expert=self.first_expert, capacity=capacity)
+
+            return jax.lax.map(
+                one_group, x.reshape(n // group, group, d)).reshape(
+                    b, rows, d)
+
+
+class DecoderLayer(nn.Module):
+    attention: dict
+    moe: dict
+    eps: float
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = _F32
+
+    @nn.compact
+    def __call__(self, x, cos, sin):
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        h = RMSNorm(self.eps, name="input_layernorm", **kw)(x)
+        x = x + BlockDiffusionAttention(eps=self.eps, name="self_attn",
+                                        **self.attention, **kw)(h, cos, sin)
+        h = RMSNorm(self.eps, name="post_attention_layernorm", **kw)(x)
+        return x + RoutedMoe(name="mlp", **self.moe, **kw)(h)
+
+
+class SDAR(nn.Module):
+    """``(ids [b, L], level [b, L / block_length], draw [b, L])`` ->
+    logits ``[b, L, vocab_size]`` float32, of the noised copy's rows.
+
+    The defaults are the published widths of SDAR-30B-A3B-Chat; depth, the
+    experts held here and the vocabulary are what a caller sizes.  The
+    last id of the vocabulary is ``[MASK]`` unless ``mask_token_id`` names
+    another."""
+
+    vocab_size: int = 151936
+    hidden_size: int = 2048
+    num_layers: int = 48
+    num_heads: int = 32
+    num_kv_heads: int = 4
+    head_dim: int = 128
+    rope_theta: float = 1e6
+    num_experts: int = 128            # held here
+    router_experts: int = 128         # the router's width
+    first_expert: int = 0
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: int = 768
+    moe_group_rows: Optional[int] = None
+    moe_capacity_factor: Optional[float] = None
+    qk_norm_init: float = 1.0
+    rms_norm_eps: float = 1e-6
+    block_length: int = 4
+    mask_token_id: int = -1
+    remat: bool = True
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = _F32
+
+    def __call__(self, batch):
+        ids, level, draw = batch
+        length = ids.shape[1]
+        if length != level.shape[1] * self.block_length:
+            raise ValueError(
+                f"{level.shape[1]} noise levels a row do not cover "
+                f"{length} tokens in blocks of {self.block_length}")
+        with jax.named_scope("hvd_bd_noise"):
+            mask_id = self.mask_token_id % self.vocab_size
+            noised = jnp.where(noised_tokens(ids, level, draw), mask_id, ids)
+            rows = jnp.concatenate([noised, ids], axis=1)
+        return self.decode(rows)
+
+    @nn.compact
+    def decode(self, rows):
+        """``rows`` ``[b, 2 L]``: the noised copy's ids, then the clean
+        copy's -> logits ``[b, L, vocab_size]`` of the noised copy's rows.
+        What ``__call__`` runs once it has noised its batch; on its own it
+        takes the two copies as a caller made them."""
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        length = rows.shape[1] // 2
+        with jax.named_scope("hvd_bd_noise"):
+            # both copies carry the data's positions
+            positions = jnp.tile(jnp.arange(length), 2)
+            cos, sin = rotary_tables(positions, self.head_dim,
+                                     self.rope_theta)
+        x = nn.Embed(self.vocab_size, self.hidden_size,
+                     embedding_init=_normal(), name="embed_tokens",
+                     **kw)(rows)
+        layer_cls = nn.remat(DecoderLayer) if self.remat else DecoderLayer
+        attention = dict(
+            num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
+            head_dim=self.head_dim, block_length=self.block_length,
+            qk_norm_init=self.qk_norm_init)
+        moe = dict(
+            num_experts=self.num_experts,
+            router_experts=self.router_experts,
+            first_expert=self.first_expert, top_k=self.num_experts_per_tok,
+            expert_dim=self.moe_intermediate_size,
+            group_rows=self.moe_group_rows,
+            capacity_factor=self.moe_capacity_factor)
+        for i in range(self.num_layers):
+            x = layer_cls(attention=attention, moe=moe,
+                          eps=self.rms_norm_eps, name=f"layers_{i}",
+                          **kw)(x, cos, sin)
+        with jax.named_scope("hvd_bd_head_rows"):
+            # the clean copy's rows predict nothing
+            x = x[:, :length]
+        x = RMSNorm(self.rms_norm_eps, name="norm", **kw)(x)
+        head = self.param("lm_head", _normal(),
+                          (self.hidden_size, self.vocab_size),
+                          self.param_dtype)
+        return jnp.dot(x, head.astype(self.dtype),
+                       preferred_element_type=_F32)
+
+
+def block_diffusion_loss(logits, batch):
+    """The masked-token loss of block diffusion under the linear schedule:
+    ``(1 / (b L)) sum_i m_i (1 / t_{i // B}) (logsumexp(logits_i) -
+    logits_i[ids_i])`` with ``m`` the noised tokens and ``t = level /
+    LEVELS`` a block's mask probability.  No shift: a masked position
+    predicts its own token.  ``batch`` is the model's input, ``(ids, level,
+    draw)``."""
+    ids, level, draw = batch
+    b, length = ids.shape
+    block = length // level.shape[1]
+    weight = noised_tokens(ids, level, draw) * jnp.repeat(
+        LEVELS / level.astype(_F32), block, axis=1) / (b * length)
+    return weighted_token_loss(logits, ids, weight)
+
+
+def sdar_tiny(**kw):
+    """A toy of the same shape for tests and CPU dry-runs: two layers, four
+    of eight experts held."""
+    for key, value in dict(
+            vocab_size=256, hidden_size=64, num_layers=2, num_heads=4,
+            num_kv_heads=2, head_dim=16, rope_theta=1e4, num_experts=4,
+            router_experts=8, num_experts_per_tok=2,
+            moe_intermediate_size=32, block_length=4).items():
+        kw.setdefault(key, value)
+    return SDAR(**kw)

@@ -78,6 +78,22 @@ Three public entry points:
 All kernels take/return the ``[batch, heads, seq, head_dim]`` layout; the
 callers transpose from the model-facing ``[batch, seq, heads, head_dim]``.
 
+What a query row may see is one static description, a :class:`Mask`, taken
+by forward, dq, dkv and :func:`tile_census` alike: none, causal in global
+positions (above), or *block diffusion* (:func:`block_diffusion_mask`): the
+rows are a noised copy of ``noised`` tokens followed by their clean copy,
+cut into blocks of ``block``; a clean row sees the clean blocks up to its
+own, a noised row the clean blocks before its own and the noised tokens of
+its own block.  The kernels are the same ones: the mask says which tiles of
+a grid step's block are live and which of those are full (scalar
+arithmetic, :func:`_kv_tiles_seen` / :func:`_q_tiles_seen`), which block a
+skipped step names so that nothing is fetched (:func:`_nearest_live`), and
+which pairs of a crossed tile count (:func:`_seen`).  Tiles are fitted to
+the noised half so that none straddles the two copies: of the ``2L x 2L``
+scores one quadrant is empty, one block-diagonal and two block-lower-
+triangular, ``L^2 + L block`` pairs where a causal mask over ``2L`` rows
+allows ``2 L^2``.
+
 On the CPU test mesh, and only there, the kernels run in Pallas
 interpreter mode, which keeps every test oracle-checkable on the
 8-device virtual slice.
@@ -88,7 +104,7 @@ from __future__ import annotations
 import functools
 import inspect
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -116,6 +132,18 @@ M_INIT = NEG_INF / 2
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 512
 TILES_PER_STEP = 4
+
+
+def default_blocks(head_dim: int):
+    """``(block_q, block_k)`` a caller gets who names none: 1024 x 512,
+    swept at head size 64 (PR 25) and run, not swept, at 128 (PR 30).  dkv
+    streams four query tiles a grid step, and at head size 256 with
+    1024-row tiles that is 16 MiB of VMEM, the compiler's whole limit (the
+    step compiled or not by where XLA put the kernel's outputs: PR 26):
+    512-row tiles over head size 128."""
+    return (512 if head_dim > 128 else DEFAULT_BLOCK_Q), DEFAULT_BLOCK_K
+
+
 # Rows of the resident q tile that forward and dq work on at a time, in a
 # loop that is not unrolled: Mosaic unrolls everything else, and a kernel's
 # code grows with rows x columns of every body (36 kernels of 1024-row
@@ -134,6 +162,46 @@ _NN = (((1,), (0,)), ((), ()))  # a @ b
 FWD_KERNEL = "hvd_flash_fwd"
 DQ_KERNEL = "hvd_flash_dq"
 DKV_KERNEL = "hvd_flash_dkv"
+
+
+class Mask(NamedTuple):
+    """What a query row may see: static, hashable, one for all three
+    kernels and the census.  ``kind`` is ``"none"``, ``"causal"`` (global
+    positions, moved by the offsets) or ``"block_diffusion"`` (see
+    :func:`block_diffusion_mask`)."""
+    kind: str = "none"
+    block: int = 0    # block diffusion: tokens a block
+    noised: int = 0   # block diffusion: rows of the noised copy
+
+    @property
+    def label(self) -> str:
+        """The ``mask`` label of ``hvd_flash_tiles_traced_total``."""
+        return self.kind + (f"_b{self.block}" if self.block else "")
+
+
+NO_MASK = Mask()
+CAUSAL = Mask("causal")
+_BD = "block_diffusion"
+
+
+def block_diffusion_mask(block: int, noised: int) -> Mask:
+    """The training mask of block diffusion over ``[noised copy ; clean
+    copy]``, ``noised`` rows each, in blocks of ``block`` tokens.  With
+    ``c`` 0 for a noised and 1 for a clean row and ``g`` the block of a
+    row's position, query ``i`` sees key ``j`` iff ``(c_j = 1 and g_j < g_i
+    + c_i) or (c_i = 0 and c_j = 0 and g_j = g_i)``."""
+    block, noised = int(block), int(noised)
+    if block < 1 or noised % block:
+        raise ValueError(f"blocks of {block} do not tile {noised} rows")
+    return Mask(_BD, block, noised)
+
+
+def _as_mask(mask) -> Mask:
+    """A :class:`Mask` from one, or from the ``causal`` flag the callers
+    of old pass."""
+    if isinstance(mask, Mask):
+        return mask
+    return CAUSAL if mask else NO_MASK
 
 
 def _on_tpu() -> bool:
@@ -183,24 +251,36 @@ def _fit_block(seq: int, cap: int) -> int:
     return aligned if aligned * 4 >= plain else plain
 
 
-def _check_blocks(sq, sk, block_q, block_k, causal=False):
+def _check_blocks(sq, sk, block_q, block_k, mask=False):
     """Fit the tile to the seq lengths: the grid must tile exactly (a
     non-dividing seq would silently truncate the grid and leave the tail
     of the output uninitialized), so shrink each side to the largest
     divisor of its seq length instead of erroring on shapes like 192/128.
     A causal tile takes at most half of either length, so that a short
     sequence still has a tile above the diagonal to skip (at 1024 tokens
-    a 1024-row tile computes four 512 x 512 quarters where three do)."""
-    if causal:
+    a 1024-row tile computes four 512 x 512 quarters where three do).  A
+    block-diffusion tile divides the noised half, so that it lies in one
+    copy."""
+    mask = _as_mask(mask)
+    if mask.kind == _BD:
+        if sq != 2 * mask.noised or sk != sq:
+            raise ValueError(
+                f"a block-diffusion mask over {mask.noised} noised rows "
+                f"takes {2 * mask.noised} queries and keys, not {sq} and "
+                f"{sk}")
+        sq = sk = mask.noised
+    elif mask.kind == "causal":
         block_q = min(block_q, max(sq // 2, 1))
         block_k = min(block_k, max(sk // 2, 1))
     return _fit_block(sq, block_q), _fit_block(sk, block_k)
 
 
-def _tiles_per_step(seq: int, tile: int) -> int:
+def _tiles_per_step(seq: int, tile: int, mask: Mask = NO_MASK) -> int:
     """How many ``tile``-wide tiles of the streamed operand one grid step
-    takes: the most up to TILES_PER_STEP that tile ``seq`` exactly."""
-    n = seq // tile
+    takes: the most up to TILES_PER_STEP that tile ``seq`` exactly (under
+    block diffusion the noised half, so that a step's block lies in one
+    copy)."""
+    n = (mask.noised if mask.kind == _BD else seq) // tile
     return next(c for c in range(min(TILES_PER_STEP, n), 0, -1) if n % c == 0)
 
 
@@ -220,50 +300,158 @@ def _clip(x, lo, hi):
     return jnp.clip(x, lo, hi)
 
 
-def _kv_tiles_seen(q_first, q_rows, k_first, n, width):
-    """Of ``n`` key tiles of ``width`` that start at position ``k_first``:
-    ``(full, live)``, how many the query rows ``[q_first, q_first +
-    q_rows)`` see whole (last key <= first row: nothing to mask) and how
-    many they see at all.  Causal order puts the full tiles first, the
-    crossed ones after them and the skipped ones last.  Python ints or
-    traced scalars."""
-    full = _clip(q_first - k_first + 1, 0, n * width) // width
-    live = _clip(q_first + q_rows - 1 - k_first + width, 0, n * width) // width
-    return full, live
+def _where(cond, a, b):
+    if isinstance(cond, (bool, np.bool_)):
+        return a if cond else b
+    return jnp.where(cond, a, b)
+
+
+def _most(a, b):
+    if isinstance(a, int) and isinstance(b, int):
+        return max(a, b)
+    return jnp.maximum(a, b)
+
+
+def _kv_tiles_seen(mask, q_first, q_rows, k_first, n, width):
+    """Of ``n`` key tiles of ``width`` that start at key ``k_first``:
+    ``(first, full, live)``: the query rows ``[q_first, q_first + q_rows)``
+    see any of tiles ``[first, first + live)`` and no other, and ``full``
+    of them whole (nothing to mask).  Python ints or traced scalars.
+
+    Causal (positions): the full tiles come first (last key <= first row),
+    the crossed ones after them and the skipped ones last; ``first`` is 0.
+    Block diffusion (row indices; the rows lie in one copy and so do the
+    tiles): clean keys are seen like that up to a row's horizon, the end of
+    its own block from a clean row and of the block before from a noised
+    one; noised keys are seen by the noised rows of their block alone, so
+    the live tiles are the few that hold the rows' own blocks."""
+    if mask.kind == "causal":
+        full = _clip(q_first - k_first + 1, 0, n * width) // width
+        live = _clip(q_first + q_rows - 1 - k_first + width, 0,
+                     n * width) // width
+        return 0, full, live
+    if mask.kind != _BD:
+        return 0, n, n
+    size, half, span = mask.block, mask.noised, n * width
+    q_clean, k_clean = q_first >= half, k_first >= half
+    c = _where(q_clean, 1, 0)
+    qp, kp = q_first - c * half, k_first - _where(k_clean, half, 0)
+    g_lo, g_hi = qp // size, (qp + q_rows - 1) // size
+    # clean keys: every row's horizon lies between the first row's and the
+    # last row's
+    full_c = _clip((g_lo + c) * size - kp, 0, span) // width
+    live_c = _clip((g_hi + c) * size - kp + width - 1, 0, span) // width
+    # noised keys, noised rows: positions [lo, hi) of the rows' own blocks
+    lo, hi = g_lo * size - kp, (g_hi + 1) * size - kp
+    first_d = _clip(lo, 0, span) // width
+    live_d = _most(_clip(hi + width - 1, 0, span) // width - first_d, 0)
+    # whole only where the rows share one block and the tile lies inside it
+    full_d = _where(g_lo == g_hi, _most(
+        _clip(hi, 0, span) // width
+        - _clip(lo + width - 1, 0, span) // width, 0), 0)
+    return (_where(k_clean, 0, first_d),
+            _where(k_clean, full_c, _where(q_clean, 0, full_d)),
+            _where(k_clean, live_c, _where(q_clean, 0, live_d)))
+
+
+def _q_tiles_seen(mask, k_first, k_rows, q_first, n, width):
+    """dkv's side of the same: of ``n`` query tiles of ``width`` rows that
+    start at row ``q_first``, the half-open range ``(lo, hi)`` of those
+    that see any of the keys ``[k_first, k_first + k_rows)``."""
+    if mask.kind == "causal":
+        # query tiles wholly before the first key come first
+        return _clip(k_first - q_first, 0, n * width) // width, n
+    if mask.kind != _BD:
+        return 0, n
+    size, half, span = mask.block, mask.noised, n * width
+    q_clean, k_clean = q_first >= half, k_first >= half
+    c = _where(q_clean, 1, 0)
+    qp, kp = q_first - c * half, k_first - _where(k_clean, half, 0)
+    # clean keys: the rows whose horizon passes the first key
+    lo_c = _clip((kp // size + 1 - c) * size - qp, 0, span) // width
+    # noised keys: the noised rows of the keys' own blocks
+    lo_d = _clip(kp // size * size - qp, 0, span) // width
+    hi_d = _clip(((kp + k_rows - 1) // size + 1) * size - qp + width - 1,
+                 0, span) // width
+    return (_where(k_clean, lo_c, _where(q_clean, n, lo_d)),
+            _where(k_clean, n, _where(q_clean, n, _most(hi_d, lo_d))))
+
+
+def _nearest_live(i, ranges):
+    """The block a grid step names on the streamed side: block ``i`` where
+    it is live, else the next live one, else the last, so that a skipped
+    step changes no index and fetches nothing.  ``ranges``: the live blocks
+    ``[a0, a1)`` of the first copy and ``[b0, b1)`` of the second."""
+    (a0, a1), (b0, b1) = ranges
+    return jnp.where(
+        jnp.logical_and(a0 < a1, i < a1), jnp.maximum(i, a0),
+        jnp.where(b0 < b1, jnp.clip(i, b0, b1 - 1), jnp.maximum(a1 - 1, 0)))
+
+
+def _seen(mask, shape, q_first, k_first, r, rows, col=0, keys_down=False):
+    """Which pairs of a score tile the mask allows, boolean ``shape``: the
+    tile's first query is ``q_first + r * rows``, its first key ``k_first +
+    col``; ``keys_down`` for dkv's transposed scores (keys down the rows,
+    queries along the lanes)."""
+    if mask.kind == "causal":
+        if keys_down:
+            return _row_minus_col(*shape) <= q_first + r * rows - k_first
+        return _row_minus_col(*shape) >= k_first - q_first - r * rows
+    size, half, row = mask.block, mask.noised, r * rows
+    q_axis = 1 if keys_down else 0
+    q_shape = (1, shape[1]) if keys_down else (shape[0], 1)
+    k_shape = (shape[0], 1) if keys_down else (1, shape[1])
+    qi = q_first + row + lax.broadcasted_iota(jnp.int32, q_shape, q_axis)
+    kj = k_first + col + lax.broadcasted_iota(jnp.int32, k_shape,
+                                              1 - q_axis)
+    # the tile lies in one copy of the queries and one of the keys
+    q_clean, k_clean = q_first >= half, k_first >= half
+    c = jnp.where(q_clean, 1, 0)
+    start = (qi - c * half) // size * size      # of the row's own block
+    # keys [lo, hi) in the tile's copy: clean ones up to the row's horizon,
+    # noised ones of the row's own block (none for a clean row)
+    lo = jnp.where(k_clean, half, start)
+    hi = jnp.where(k_clean, half + start + c * size,
+                   jnp.where(q_clean, 0, start + size))
+    return jnp.logical_and(kj >= lo, kj < hi)
 
 
 def tile_census(sq, sk, block_q, block_k, causal, q_offset=0, kv_offset=0):
     """How many ``block_q x block_k`` score tiles of one head are
-    ``skipped`` (every key after every query row: not computed), ``full``
-    (every key visible to every row: nothing to mask) and ``crossed`` (the
-    diagonal passes through).  The blocks are fitted to the lengths as the
-    kernels fit them."""
-    block_q, block_k = _check_blocks(sq, sk, block_q, block_k, causal)
+    ``skipped`` (no row sees a key: not computed), ``full`` (every row sees
+    every key: nothing to mask) and ``crossed`` (the mask's edge passes
+    through).  ``causal`` is a :class:`Mask` or the flag.  The blocks are
+    fitted to the lengths as the kernels fit them."""
+    mask = _as_mask(causal)
+    block_q, block_k = _check_blocks(sq, sk, block_q, block_k, mask)
     nq, nk = sq // block_q, sk // block_k
+    # a copy's keys at a time: one range of live tiles in each
+    copies = 2 if mask.kind == _BD else 1
     counts = {"skipped": 0, "full": 0, "crossed": 0}
     for i in range(nq):
-        full, live = (_kv_tiles_seen(q_offset + i * block_q, block_q,
-                                     kv_offset, nk, block_k)
-                      if causal else (nk, nk))
-        counts["full"] += full
-        counts["crossed"] += live - full
-        counts["skipped"] += nk - live
+        for part in range(copies):
+            _, full, live = _kv_tiles_seen(
+                mask, q_offset + i * block_q, block_q,
+                kv_offset + part * mask.noised, nk // copies, block_k)
+            counts["full"] += full
+            counts["crossed"] += live - full
+            counts["skipped"] += nk // copies - live
     return counts
 
 
-def _count_tiles(kernel, static_offs, q, k, block_q, block_k, causal):
+def _count_tiles(kernel, static_offs, q, k, block_q, block_k, mask):
     """The trace-time counter: once for every kernel call that is traced."""
     from .. import metrics
 
     (b, h, sq, _), sk = q.shape, k.shape[2]
-    if static_offs is None and causal:
-        block_q, block_k = _check_blocks(sq, sk, block_q, block_k, causal)
+    if static_offs is None and mask.kind == "causal":
+        block_q, block_k = _check_blocks(sq, sk, block_q, block_k, mask)
         counts = {"dynamic": (sq // block_q) * (sk // block_k)}
     else:
-        counts = tile_census(sq, sk, block_q, block_k, causal,
+        counts = tile_census(sq, sk, block_q, block_k, mask,
                              *(static_offs or (0, 0)))
     metrics.record_flash_tiles(
-        kernel, {kind: n * b * h for kind, n in counts.items()})
+        kernel, {kind: n * b * h for kind, n in counts.items()}, mask.label)
 
 
 def _row_minus_col(rows, cols):
@@ -294,25 +482,36 @@ def _fold_lanes(p, lanes):
     return sum(p[:, c:c + lanes] for c in range(0, p.shape[1], lanes))
 
 
-def _each_kind_of_block(block, n_tiles, full, live, unmasked):
+def _tiles(start, width, tile):
+    """The rows of tiles ``[start, start + width)`` of a block; ``start``
+    is 0 or a traced scalar."""
+    if isinstance(start, int):
+        return pl.ds(start * tile, width * tile)
+    return pl.ds(pl.multiple_of(start * tile, tile), width * tile)
+
+
+def _each_kind_of_block(block, n_tiles, first, full, live, unmasked):
     """What a grid step of forward or dq does with its kv block of
-    ``n_tiles`` tiles, of which its query rows see ``full`` whole and
-    ``live`` at all.  Nothing where they see none.  Where the diagonal
-    crosses the block, ``block(width, masked=True)`` over the tiles they
-    see any of, rounded up to half a block: a body of static width each, so
-    the compiler schedules it whole, and two of them, because every body
-    is code (Mosaic unrolls it) that every layer's kernel carries.  With
-    ``unmasked``, ``block(n_tiles, masked=False)`` where they see all of
-    it whole; without, those blocks take the masked body too."""
+    ``n_tiles`` tiles, of which its query rows see tiles ``[first, first +
+    live)`` at all and ``full`` whole.  Nothing where they see none.  Where
+    the mask's edge crosses the block, ``block(width, True, start)`` over
+    the tiles they see any of, rounded up to half a block (``start`` moved
+    back where that would pass the block's end; a tile nobody sees is
+    masked whole): a body of static width each, so the compiler schedules
+    it whole, and two of them, because every body is code (Mosaic unrolls
+    it) that every layer's kernel carries.  With ``unmasked``,
+    ``block(n_tiles, False, 0)`` where they see all of it whole; without,
+    those blocks take the masked body too."""
     half = max(1, n_tiles // 2)
     crossed = live > 0
     if unmasked:
-        pl.when(full == n_tiles)(lambda: block(n_tiles, False))
+        pl.when(full == n_tiles)(lambda: block(n_tiles, False, 0))
         crossed = jnp.logical_and(crossed, full < n_tiles)
     for width in range(half, n_tiles + 1, half):
         pl.when(jnp.logical_and(crossed, (live + half - 1) // half * half
                                 == width))(
-            functools.partial(block, width, True))
+            functools.partial(block, width, True,
+                              _clip(first, 0, n_tiles - width)))
 
 
 def _jit_kernel(fn):
@@ -360,7 +559,7 @@ def _launch(name, kernel, offs, grid, ins, outs, scratch, interpret):
 
 def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                 acc_ref, mi_ref, li_ref, *,
-                causal, scale, normalize, tile_k):
+                mask, scale, normalize, tile_k):
     bq = q_ref.shape[2]
     chunk = _fit_block(bq, ROW_CHUNK)
     n_tiles = k_ref.shape[2] // tile_k
@@ -375,10 +574,11 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         mi_ref[:] = jnp.full_like(mi_ref, M_INIT)
         li_ref[:] = jnp.zeros_like(li_ref)
 
-    def block(width, masked):
-        """One step of the online softmax over the first ``width`` tiles
-        of the kv block, taken as one: the statistics move once."""
-        cols = pl.ds(0, width * tile_k)
+    def block(width, masked, start):
+        """One step of the online softmax over ``width`` tiles of the kv
+        block from tile ``start``, taken as one: the statistics move
+        once."""
+        cols = _tiles(start, width, tile_k)
 
         def some_rows(r):
             rows = _chunk(r, chunk)
@@ -390,8 +590,8 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
             if s_scale is not None:
                 s = s * s_scale
             if masked:
-                s = jnp.where(_row_minus_col(*s.shape)
-                              >= k_first - q_first - r * chunk, s, NEG_INF)
+                s = jnp.where(_seen(mask, s.shape, q_first, k_first, r,
+                                    chunk, start * tile_k), s, NEG_INF)
             m_prev = mi_ref[rows, :]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -406,14 +606,14 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
         _loop(0, bq // chunk, some_rows)
 
-    if causal:
+    if mask.kind == "none":
+        block(n_tiles, False, 0)
+    else:
         # the mask is a tenth of this kernel's time where it is not needed
         _each_kind_of_block(
             block, n_tiles,
-            *_kv_tiles_seen(q_first, bq, k_first, n_tiles, tile_k),
+            *_kv_tiles_seen(mask, q_first, bq, k_first, n_tiles, tile_k),
             unmasked=True)
-    else:
-        block(n_tiles, False)
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _():
@@ -426,44 +626,54 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         l_ref[0, 0] = l
 
 
-def _kv_block_index(causal, block_q, block_k, sk):
+def _kv_block_index(mask, block_q, block_k, sk):
     """Index map of the streamed k / v blocks of forward and dq.  A grid
     step wholly past the diagonal computes nothing, so it names the last
     block its query rows do see: the index does not change and nothing is
-    fetched."""
+    fetched.  Under block diffusion the live blocks are a range in each
+    copy of the keys."""
     def index(b_, h_, i, j, offs):
-        if causal:
+        if mask.kind == "causal":
             last = _clip(offs[0] + i * block_q + block_q - 1 - offs[1],
                          0, sk - 1) // block_k
             j = jnp.minimum(j, last)
+        elif mask.kind == _BD:
+            n = mask.noised // block_k
+            live = []
+            for part in range(2):
+                first, _, count = _kv_tiles_seen(
+                    mask, i * block_q, block_q, part * mask.noised, n,
+                    block_k)
+                live.append((part * n + first, part * n + first + count))
+            j = _nearest_live(j, live)
         return (b_, h_, j, 0)
 
     return index
 
 
-def _mha_fwd(q, k, v, offs, *, causal, block_q, block_k, interpret,
+def _mha_fwd(q, k, v, offs, *, mask, block_q, block_k, interpret,
              static_offs=None, **kw):
     """q/k/v ``[b,h,s,d]``; returns ``(o, m, l)`` with m/l ``[b,h,sq,1]``."""
-    _count_tiles("fwd", static_offs, q, k, block_q, block_k, causal)
-    return _fwd_call(q, k, v, offs, causal=causal, block_q=block_q,
+    _count_tiles("fwd", static_offs, q, k, block_q, block_k, mask)
+    return _fwd_call(q, k, v, offs, mask=mask, block_q=block_q,
                      block_k=block_k,
                      interpret=_resolve_interpret(interpret), **kw)
 
 
 @_jit_kernel
-def _fwd_call(q, k, v, offs, *, causal, scale, block_q, block_k, normalize,
+def _fwd_call(q, k, v, offs, *, mask, scale, block_q, block_k, normalize,
               interpret):
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    block_q, tile_k = _check_blocks(sq, sk, block_q, block_k, causal)
-    block_k = tile_k * _tiles_per_step(sk, tile_k)
+    block_q, tile_k = _check_blocks(sq, sk, block_q, block_k, mask)
+    block_k = tile_k * _tiles_per_step(sk, tile_k, mask)
     kernel = functools.partial(
-        _fwd_kernel, causal=causal, scale=scale, normalize=normalize,
+        _fwd_kernel, mask=mask, scale=scale, normalize=normalize,
         tile_k=tile_k,
     )
     out_dtype = q.dtype if normalize else jnp.float32
     q_index = lambda b_, h_, i, j, offs: (b_, h_, i, 0)  # noqa: E731
-    kv_index = _kv_block_index(causal, block_q, block_k, sk)
+    kv_index = _kv_block_index(mask, block_q, block_k, sk)
     q_block, kv_block, row = ((1, 1, block_q, d), (1, 1, block_k, d),
                               (1, 1, block_q, 1))
     return _launch(
@@ -483,7 +693,7 @@ def _fwd_call(q, k, v, offs, *, causal, scale, block_q, block_k, normalize,
 
 
 def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_acc_ref, *, causal, scale, tile_k):
+                   dq_ref, dq_acc_ref, *, mask, scale, tile_k):
     bq = q_ref.shape[2]
     chunk = _fit_block(bq, ROW_CHUNK)
     n_tiles = k_ref.shape[2] // tile_k
@@ -496,9 +706,10 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _():
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
-    def block(width, masked):
-        """dq's share of the first ``width`` tiles of the kv block."""
-        cols = pl.ds(0, width * tile_k)
+    def block(width, masked, start):
+        """dq's share of ``width`` tiles of the kv block from tile
+        ``start``."""
+        cols = _tiles(start, width, tile_k)
 
         def some_rows(r):
             rows = _chunk(r, chunk)
@@ -513,8 +724,8 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             p = jnp.exp(s - lse_ref[0, 0, rows, :])
             if masked:
                 # after the exp: a select drops what overflowed where masked
-                p = jnp.where(_row_minus_col(*s.shape)
-                              >= k_first - q_first - r * chunk, p, 0.0)
+                p = jnp.where(_seen(mask, s.shape, q_first, k_first, r,
+                                    chunk, start * tile_k), p, 0.0)
             dp = lax.dot_general(do_ref[0, 0, rows, :], v_ref[0, 0, cols, :],
                                  _NT, preferred_element_type=jnp.float32)
             ds = p * (dp - delta_ref[0, 0, rows, :])
@@ -524,15 +735,15 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
         _loop(0, bq // chunk, some_rows)
 
-    if causal:
+    if mask.kind == "none":
+        block(n_tiles, False, 0)
+    else:
         # the mask costs this kernel under 1% (the VPU has the room beside
         # three products), a second copy of the body costs code
         _each_kind_of_block(
             block, n_tiles,
-            *_kv_tiles_seen(q_first, bq, k_first, n_tiles, tile_k),
+            *_kv_tiles_seen(mask, q_first, bq, k_first, n_tiles, tile_k),
             unmasked=False)
-    else:
-        block(n_tiles, False)
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _():
@@ -542,7 +753,7 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *,
-                    causal, scale, tile_q):
+                    mask, scale, tile_q):
     """Scores transposed, keys down the rows and queries along the lanes:
     every product is in a form the MXU takes as it is (k q^T, v do^T, p^T
     do, ds^T q), and the row statistics come in as lane-dense rows."""
@@ -570,12 +781,12 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         if s_scale is not None:
             st = st * s_scale
         pt = jnp.exp(st - lse_ref[0, 0, c])
-        if causal:
+        if mask.kind != "none":
             # on every tile: the mask costs this kernel nothing measurable
             # (0.2%), a second body is code.  After the exp: a select drops
             # what overflowed where masked
-            pt = jnp.where(_row_minus_col(bk, tile_q)
-                           <= q_first + c * tile_q - k_first, pt, 0.0)
+            pt = jnp.where(_seen(mask, (bk, tile_q), q_first, k_first, c,
+                                 tile_q, keys_down=True), pt, 0.0)
         dv_acc_ref[:] += lax.dot_general(
             pt.astype(doc.dtype), doc, _NN,
             preferred_element_type=jnp.float32)
@@ -586,11 +797,8 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             dst.astype(qc.dtype), qc, _NN,
             preferred_element_type=jnp.float32)
 
-    # query tiles wholly before the first key come first and are skipped
-    skipped = 0
-    if causal:
-        skipped = _clip(k_first - q_first, 0, n_tiles * tile_q) // tile_q
-    _loop(skipped, n_tiles, tile)
+    # the query tiles that see no key of the block are skipped
+    _loop(*_q_tiles_seen(mask, k_first, bk, q_first, n_tiles, tile_q), tile)
 
     @pl.when(i == pl.num_programs(3) - 1)
     def _():
@@ -598,26 +806,26 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dv_ref[0, 0] = dv_acc_ref[:].astype(dv_ref.dtype)
 
 
-def _mha_bwd_dq(q, k, v, do, lse, delta, offs, *, causal, block_q, block_k,
+def _mha_bwd_dq(q, k, v, do, lse, delta, offs, *, mask, block_q, block_k,
                 interpret, static_offs=None, **kw):
     """lse/delta ``[b,h,sq,1]``."""
-    _count_tiles("dq", static_offs, q, k, block_q, block_k, causal)
-    return _dq_call(q, k, v, do, lse, delta, offs, causal=causal,
+    _count_tiles("dq", static_offs, q, k, block_q, block_k, mask)
+    return _dq_call(q, k, v, do, lse, delta, offs, mask=mask,
                     block_q=block_q, block_k=block_k,
                     interpret=_resolve_interpret(interpret), **kw)
 
 
 @_jit_kernel
-def _dq_call(q, k, v, do, lse, delta, offs, *, causal, scale, block_q,
+def _dq_call(q, k, v, do, lse, delta, offs, *, mask, scale, block_q,
              block_k, interpret, out_dtype=jnp.float32):
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    block_q, tile_k = _check_blocks(sq, sk, block_q, block_k, causal)
-    block_k = tile_k * _tiles_per_step(sk, tile_k)
-    kernel = functools.partial(_bwd_dq_kernel, causal=causal, scale=scale,
+    block_q, tile_k = _check_blocks(sq, sk, block_q, block_k, mask)
+    block_k = tile_k * _tiles_per_step(sk, tile_k, mask)
+    kernel = functools.partial(_bwd_dq_kernel, mask=mask, scale=scale,
                                tile_k=tile_k)
     q_index = lambda b_, h_, i, j, offs: (b_, h_, i, 0)  # noqa: E731
-    kv_index = _kv_block_index(causal, block_q, block_k, sk)
+    kv_index = _kv_block_index(mask, block_q, block_k, sk)
     q_block, kv_block, row = ((1, 1, block_q, d), (1, 1, block_k, d),
                               (1, 1, block_q, 1))
     return _launch(
@@ -629,25 +837,25 @@ def _dq_call(q, k, v, do, lse, delta, offs, *, causal, scale, block_q,
         scratch=[(block_q, d)], interpret=interpret)[0]
 
 
-def _mha_bwd_dkv(q, k, v, do, lse, delta, offs, *, causal, block_q, block_k,
+def _mha_bwd_dkv(q, k, v, do, lse, delta, offs, *, mask, block_q, block_k,
                  interpret, static_offs=None, **kw):
     """lse/delta: one float32 a query row, in any shape; the kernel reads
     them as rows of ``tile_q`` lanes."""
-    _count_tiles("dkv", static_offs, q, k, block_q, block_k, causal)
-    return _dkv_call(q, k, v, do, lse, delta, offs, causal=causal,
+    _count_tiles("dkv", static_offs, q, k, block_q, block_k, mask)
+    return _dkv_call(q, k, v, do, lse, delta, offs, mask=mask,
                      block_q=block_q, block_k=block_k,
                      interpret=_resolve_interpret(interpret), **kw)
 
 
 @_jit_kernel
-def _dkv_call(q, k, v, do, lse, delta, offs, *, causal, scale, block_q,
+def _dkv_call(q, k, v, do, lse, delta, offs, *, mask, scale, block_q,
               block_k, interpret, out_dtype=jnp.float32):
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    tile_q, block_k = _check_blocks(sq, sk, block_q, block_k, causal)
-    n_tiles = _tiles_per_step(sq, tile_q)
+    tile_q, block_k = _check_blocks(sq, sk, block_q, block_k, mask)
+    n_tiles = _tiles_per_step(sq, tile_q, mask)
     block_q = tile_q * n_tiles
-    kernel = functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale,
+    kernel = functools.partial(_bwd_dkv_kernel, mask=mask, scale=scale,
                                tile_q=tile_q)
     lse, delta = (x.reshape(b, h, sq // tile_q, 1, tile_q)
                   for x in (lse, delta))
@@ -656,10 +864,15 @@ def _dkv_call(q, k, v, do, lse, delta, offs, *, causal, scale, block_q,
         # a grid step whose query rows all lie before the kv block computes
         # nothing: it names the first block that does, and nothing is
         # fetched
-        if causal:
+        if mask.kind == "causal":
             first = _clip(offs[1] + jk * block_k - offs[0],
                           0, sq - 1) // block_q
             i = jnp.maximum(i, first)
+        elif mask.kind == _BD:
+            n = mask.noised // block_q
+            i = _nearest_live(i, [tuple(part * n + t for t in _q_tiles_seen(
+                mask, jk * block_k, block_k, part * mask.noised, n, block_q))
+                for part in range(2)])
         return i
 
     q_index = lambda *g: (*g[:2], first_seen(*g), 0)  # noqa: E731
@@ -690,7 +903,8 @@ def mha_partial(q, k, v, q_offset, kv_offset, *, causal, scale,
     m/l come back ``[b,h,sq,1]`` so they broadcast against ``o``; a row
     that sees no key has ``l`` 0 and ``m`` :data:`M_INIT`."""
     return _mha_fwd(
-        q, k, v, _offsets(q_offset, kv_offset), causal=causal, scale=scale,
+        q, k, v, _offsets(q_offset, kv_offset), mask=_as_mask(causal),
+        scale=scale,
         block_q=block_q, block_k=block_k, normalize=False,
         interpret=interpret,
         static_offs=_static_offsets(q_offset, kv_offset),
@@ -703,7 +917,7 @@ def mha_bwd_dq(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
     """dq (f32) contribution of one kv shard; lse/delta are ``[b,h,sq,1]``."""
     return _mha_bwd_dq(
         q, k, v, do, lse, delta, _offsets(q_offset, kv_offset),
-        causal=causal, scale=scale, block_q=block_q, block_k=block_k,
+        mask=_as_mask(causal), scale=scale, block_q=block_q, block_k=block_k,
         interpret=interpret,
         static_offs=_static_offsets(q_offset, kv_offset),
     )
@@ -715,7 +929,7 @@ def mha_bwd_dkv(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
     """(dk, dv) (f32) contributions of one q shard to one kv shard."""
     return _mha_bwd_dkv(
         q, k, v, do, lse, delta, _offsets(q_offset, kv_offset),
-        causal=causal, scale=scale, block_q=block_q, block_k=block_k,
+        mask=_as_mask(causal), scale=scale, block_q=block_q, block_k=block_k,
         interpret=interpret,
         static_offs=_static_offsets(q_offset, kv_offset),
     )
@@ -727,8 +941,8 @@ def mha_bwd_dkv(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
 
 
 @functools.lru_cache(maxsize=None)
-def _flash_fn(causal, scale, block_q, block_k, interpret, static_offs):
-    kw = dict(causal=causal, scale=scale, block_q=block_q, block_k=block_k,
+def _flash_fn(mask, scale, block_q, block_k, interpret, static_offs):
+    kw = dict(mask=mask, scale=scale, block_q=block_q, block_k=block_k,
               interpret=interpret, static_offs=static_offs)
 
     @jax.custom_vjp
@@ -759,10 +973,11 @@ def _flash_fn(causal, scale, block_q, block_k, interpret, static_offs):
 
 
 def flash_attention(q, k, v, *, causal: bool = False,
+                    mask: Optional[Mask] = None,
                     scale: Optional[float] = None,
                     q_offset=0, kv_offset=0,
-                    block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: Optional[bool] = None):
     """Flash attention over local shards, differentiable end to end.
 
@@ -771,18 +986,27 @@ def flash_attention(q, k, v, *, causal: bool = False,
         used throughout :mod:`horovod_tpu.parallel`).
       causal: apply causal masking in global positions
         (``q_offset + i >= kv_offset + j``).
+      mask: a :class:`Mask` in ``causal``'s place, e.g.
+        :func:`block_diffusion_mask` (which takes no offsets: the rows are
+        the whole doubled sequence).
       scale: logit scale, default ``1/sqrt(head_dim)``.
       q_offset, kv_offset: global position of element 0 of the q / kv
         shards (used by sequence-parallel callers).
-      block_q, block_k: rows and columns of one score tile.
+      block_q, block_k: rows and columns of one score tile; by default
+        :func:`default_blocks` of the head size.
 
     Returns attention output, same shape/dtype as ``q``.
     """
+    mask = _as_mask(causal) if mask is None else mask
+    static_offs = _static_offsets(q_offset, kv_offset)
+    if mask.kind == _BD and static_offs != (0, 0):
+        raise ValueError("a block-diffusion mask takes no offsets")
     if scale is None:
         scale = 1.0 / float(np.sqrt(q.shape[-1]))
-    fn = _flash_fn(bool(causal), float(scale), int(block_q), int(block_k),
-                   _resolve_interpret(interpret),
-                   _static_offsets(q_offset, kv_offset))
+    default_q, default_k = default_blocks(q.shape[-1])
+    fn = _flash_fn(mask, float(scale), int(block_q or default_q),
+                   int(block_k or default_k), _resolve_interpret(interpret),
+                   static_offs)
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)

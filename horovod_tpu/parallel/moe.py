@@ -24,8 +24,8 @@ holds by construction.
 
 :func:`routed_experts` is the layer for models with many small experts
 and several a token (top-k of hundreds): it is told which experts it
-holds, routes over the router's full width, drops nothing, and computes
-its own experts' part of the result as a grouped matrix product over the
+holds, routes over the router's full width, drops nothing unless the
+caller bounds an expert's load, and computes its own experts' part of the result as a grouped matrix product over the
 assignments sorted by expert, a tile of rows at a time, as many tiles as
 the router sent rows.  :func:`load_census` is its model on the host: how
 many tokens each held expert gets, and how many tiles that makes.
@@ -289,7 +289,7 @@ _held_part.defvjp(_held_part_fwd, _held_part_bwd)
 
 
 def routed_experts(x, router_kernel, expert_params, *, top_k: int,
-                   first_expert: int = 0):
+                   first_expert: int = 0, capacity: Optional[int] = None):
     """The part of a top-k mixture-of-experts layer that the experts held
     here give: ``sum_j w_j * down_j(silu(gate_j x) * up_j x)`` over those
     of a token's ``top_k`` picks that fall on ``[first_expert,
@@ -297,12 +297,12 @@ def routed_experts(x, router_kernel, expert_params, *, top_k: int,
 
     The router keeps its full width and its ``top_k``, and the weights are
     normalised over all the picks, not over the ones held: the parts of
-    all the shares add up to the whole layer.  No capacity, no dropped
-    token, static shapes: the assignments are sorted by expert into one
-    order (``n * top_k`` indices, the held experts' first), and a loop
-    whose length the device decides takes each held expert's rows ``TILE``
-    at a time through that expert's three matrices — a grouped matrix
-    product at the granularity of a tile.  Only an expert's last tile is
+    all the shares add up to the whole layer.  Static shapes and, unless
+    ``capacity`` bounds an expert, no dropped token: the assignments are
+    sorted by expert into one order (``n * top_k`` indices, the held
+    experts' first), and a loop whose length the device decides takes each
+    held expert's rows ``TILE`` at a time through that expert's three
+    matrices — a grouped matrix product at the granularity of a tile.  Only an expert's last tile is
     padded, so the layer's cost follows the number of rows the router
     sends here, smoothly, from none to every token on one expert; there is
     no tail to handle, because rows past the held assignments are never
@@ -317,6 +317,11 @@ def routed_experts(x, router_kernel, expert_params, *, top_k: int,
         "down_proj": [held, f, d]}``, the experts held here.
       top_k: experts a token.
       first_expert: index of the first held expert.
+      capacity: the most of these ``n`` tokens an expert takes, GShard's
+        bound on a group (a caller with several groups calls once a
+        group): an expert's assignments past its first ``capacity`` in
+        token order are dropped, their weights with them (a token's other
+        picks keep theirs).  ``None``: no bound.
 
     Returns ``[n, d]`` in ``x``'s dtype.
     """
@@ -327,6 +332,14 @@ def routed_experts(x, router_kernel, expert_params, *, top_k: int,
         local = experts - first_expert
         local = jnp.where((local >= 0) & (local < held), local,
                           held).reshape(-1).astype(jnp.int32)
+        if capacity is not None:
+            # an assignment's place among its expert's, in token order; one
+            # past the capacity counts as an expert's that lives elsewhere
+            mine = local[:, None] == jnp.arange(held)[None, :]
+            place = jnp.sum(jnp.where(mine, jnp.cumsum(mine, axis=0,
+                                                       dtype=jnp.int32), 0),
+                            axis=1)
+            local = jnp.where(place > capacity, held, local)
         sizes = jnp.sum(local[:, None] == jnp.arange(held)[None, :],
                         axis=0, dtype=jnp.int32)
         # stable: the held assignments first, expert by expert, each

@@ -129,7 +129,9 @@ def make_train_step(
     loss_fetch_steps: Optional[int] = None,
 ):
     """Returns ``step(state, batch, labels) -> (state, loss)`` compiled SPMD
-    over the global mesh.
+    over the global mesh.  ``batch`` and ``labels`` are arrays or pytrees of
+    arrays (a model that takes several arrays a row): every leaf is sharded
+    across ranks on dim 0.
 
     * ``apply_fn(variables, x, train=True, **mutable_kw)`` — flax-style.
     * ``loss_fn(logits, labels) -> scalar`` (per-rank mean).
@@ -608,9 +610,10 @@ def make_train_step(
         last_dispatch[0] = now
         metrics.STEPS_TOTAL.inc(max(in_graph_steps, 1))
         try:
-            metrics.SAMPLES_TOTAL.inc(
-                int(x.shape[0]) * max(in_graph_steps, 1)
-            )
+            # a batch of several arrays (a tuple, a dict) counts its rows
+            # once: every leaf is sharded on dim 0 alike
+            rows = jax.tree_util.tree_leaves(x)[0].shape[0]
+            metrics.SAMPLES_TOTAL.inc(int(rows) * max(in_graph_steps, 1))
         except (AttributeError, IndexError, TypeError):
             pass  # batch without a leading dim: samples stay uncounted
 

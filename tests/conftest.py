@@ -63,8 +63,11 @@ def rng():
 #: lists the directory under ``paths``) and a PR that changes the program
 #: may not edit them; the same assertions brought up to date are at the end
 #: of ``test_benchmark_qwen3_next.py`` and in ``test_benchmark_loss.py``.
-#: Strict, so that the `benchmark` PR which brings the pins up to date has
-#: to take this list out with them.
+#: PR 30 appends two cells (``sdar-bd4-8k``, ``gpt2s-4k``) and three metrics:
+#: three more tests pin the five cells and the lists of PR 27, and
+#: ``test_benchmark_sdar.py`` ends with the same assertions brought up to
+#: date.  Strict, so that the `benchmark` PR which brings the pins up to date
+#: has to take this list out with them.
 PINNED_TO_AN_EARLIER_BENCHMARK = {
     "test_benchmark_form.py::test_the_tiny_benchmark_keeps_the_form":
         "7 cells allowed one four-chip cell; with 8 the toy one is no fault",
@@ -76,6 +79,15 @@ PINNED_TO_AN_EARLIER_BENCHMARK = {
     "test_benchmark_qwen3_next.py::"
     "test_which_cells_list_the_flash_parts_the_pack_and_the_update":
         "PR 26's eight metrics are no longer the last: loss_ms follows",
+    "test_benchmark_loss.py::"
+    "test_loss_ms_is_the_last_entry_after_pr_26s_eight":
+        "loss_ms lists the two new cells and PR 30's three readers follow it",
+    "test_benchmark_qwen3_next.py::"
+    "test_qwen_metrics_are_entries_of_their_one_cell":
+        "moe_ms, moe_route_ms and moe_tiles now list sdar-bd4-8k too",
+    "test_benchmark_qwen3_next.py::"
+    "test_every_cell_of_the_benchmark_finds_its_files_the_fifth_too":
+        "the expected cells lack sdar-bd4-8k and gpt2s-4k",
 }
 
 
