@@ -191,6 +191,13 @@ GDN_SCAN_CHUNKS = registry.counter(
     "walks (per compile, not per step): kernel fwd or bwd, path mosaic "
     "(compiled for the TPU) or interpret (Pallas interpreter mode).",
     ("kernel", "path"))
+KERNEL_RESIDUAL_BYTES = registry.counter(
+    "hvd_kernel_residual_bytes_traced_total",
+    "Bytes each traced differentiated forward of a Pallas kernel hands its "
+    "backward beyond its own inputs (per compile, not per step): kernel "
+    "flash (the output and the rows' log-sum-exp) or gdn_scan (the output, "
+    "the chunks' start states and inverses) -- what a recomputed layer "
+    "that keeps them pays in memory.", ("kernel",))
 BD_LAYERS = registry.counter(
     "hvd_bd_layers_traced_total",
     "Block-diffusion attention layers traced (models/sdar.py; per compile, "
@@ -545,6 +552,18 @@ def record_gdn_scan_chunks(kernel: str, path: str, chunks: int) -> None:
         return
     try:
         GDN_SCAN_CHUNKS.labels(kernel, path).inc(chunks)
+    except Exception:  # noqa: BLE001 — tracing must never fail on metrics
+        pass
+
+
+def record_kernel_residual_bytes(kernel: str, nbytes: int) -> None:
+    """One traced differentiated forward of a Pallas kernel
+    (ops/flash_attention.py, ops/gated_delta.py): what it keeps for its
+    backward beyond its own inputs."""
+    if not registry.enabled:
+        return
+    try:
+        KERNEL_RESIDUAL_BYTES.labels(kernel).inc(nbytes)
     except Exception:  # noqa: BLE001 — tracing must never fail on metrics
         pass
 

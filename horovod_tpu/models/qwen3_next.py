@@ -29,10 +29,12 @@ on the DeltaNet output, which has a plain weight and is gated:
   ``first_expert``) plus ``sigmoid(x w_g) * shared_expert(x)``.
 
 bf16 compute / float32 parameters like the other families.  ``remat``
-recomputes each decoder layer in the backward pass (``nn.remat``), so only
-the layers' inputs are kept.  Device scopes (docs/profiling.md):
-``hvd_gdn`` (``hvd_gdn_conv``, ``hvd_gdn_scan``), ``hvd_moe``
-(``hvd_moe_route``, ``hvd_moe_experts``, ``hvd_moe_shared``).
+recomputes each decoder layer in the backward pass (:func:`recomputed`):
+the layers' inputs are kept and, of what is inside a layer, only what the
+flash and scan forward kernels wrote for their backward kernels, so a
+layer calls each forward kernel once a step.  Device scopes
+(docs/profiling.md): ``hvd_gdn`` (``hvd_gdn_conv``, ``hvd_gdn_scan``),
+``hvd_moe`` (``hvd_moe_route``, ``hvd_moe_experts``, ``hvd_moe_shared``).
 """
 
 from __future__ import annotations
@@ -43,8 +45,9 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from ..ops.flash_attention import flash_attention
-from ..ops.gated_delta import gated_delta_rule
+from ..ops.flash_attention import FLASH_LSE, FLASH_OUT, flash_attention
+from ..ops.gated_delta import (GDN_INVERSES, GDN_OUT, GDN_STATES,
+                               gated_delta_rule)
 from ..parallel.moe import routed_experts
 
 _F32 = jnp.float32
@@ -57,6 +60,21 @@ def flash_blocks(head_dim: int) -> dict:
     16 MiB of VMEM, the compiler's whole limit: the step compiled or not
     by where XLA put the kernel's outputs.  512-row tiles there."""
     return {"block_q": 512} if head_dim > 128 else {}
+
+
+def recomputed(layer_cls):
+    """``layer_cls`` recomputed in the backward pass (``nn.remat``) with
+    the one thing kept that costs a kernel call to make again and nothing
+    to keep but memory: what the Pallas forward kernels wrote for their
+    backward kernels (flash's output and row statistics; the scan's output,
+    chunk states and inverses).  Those kernels' other residuals (q, k, v,
+    g, beta) come out of projections the recompute runs anyway, so the
+    recomputed layer calls no forward kernel.  Projections, norms, rotary,
+    the k / v repeats, the convolution and the expert layer are recomputed
+    from the layer's input."""
+    return nn.remat(
+        layer_cls, policy=jax.checkpoint_policies.save_only_these_names(
+            FLASH_OUT, FLASH_LSE, GDN_OUT, GDN_STATES, GDN_INVERSES))
 
 
 def _normal(std: float = 0.02):
@@ -310,7 +328,7 @@ class Qwen3Next(nn.Module):
         x = nn.Embed(self.vocab_size, self.hidden_size,
                      embedding_init=_normal(), name="embed_tokens",
                      **kw)(ids)
-        layer_cls = nn.remat(DecoderLayer) if self.remat else DecoderLayer
+        layer_cls = recomputed(DecoderLayer) if self.remat else DecoderLayer
         attention = dict(
             num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
             head_dim=self.head_dim, rope_theta=self.rope_theta,

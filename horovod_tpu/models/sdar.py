@@ -39,7 +39,10 @@ Every layer: ``x = x + attn(norm(x))``, ``x = x + moe(norm(x))``; RMSNorm is
   rows, the first in row order; what overflows is dropped.
 
 bf16 compute / float32 parameters like the other families.  ``remat``
-recomputes each decoder layer in the backward pass (``nn.remat``).  Device
+recomputes each decoder layer in the backward pass
+(``qwen3_next.recomputed``: the layers' inputs are kept and the flash
+forward kernel's output and row statistics, which its backward kernels
+read, so a layer calls the forward kernel once a step).  Device
 scopes (docs/profiling.md): ``hvd_bd_noise`` (the compare, the
 substitution, the concatenation, the position ids), ``hvd_bd_head_rows``
 (the slice before the head), ``hvd_flash_*``, ``hvd_moe`` (``hvd_moe_route``,
@@ -60,8 +63,8 @@ from .. import metrics
 from ..ops.flash_attention import block_diffusion_mask, flash_attention
 from ..parallel.moe import routed_experts
 from .gpt import weighted_token_loss
-from .qwen3_next import (_dense, _normal, apply_rotary, rms_normalise,
-                         rotary_tables)
+from .qwen3_next import (_dense, _normal, apply_rotary, recomputed,
+                         rms_normalise, rotary_tables)
 
 _F32 = jnp.float32
 #: a noise level is an integer in ``[0, LEVELS]``: mask probability
@@ -249,7 +252,7 @@ class SDAR(nn.Module):
         x = nn.Embed(self.vocab_size, self.hidden_size,
                      embedding_init=_normal(), name="embed_tokens",
                      **kw)(rows)
-        layer_cls = nn.remat(DecoderLayer) if self.remat else DecoderLayer
+        layer_cls = recomputed(DecoderLayer) if self.remat else DecoderLayer
         attention = dict(
             num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
             head_dim=self.head_dim, block_length=self.block_length,

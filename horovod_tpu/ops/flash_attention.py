@@ -110,6 +110,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -162,6 +163,13 @@ _NN = (((1,), (0,)), ((), ()))  # a @ b
 FWD_KERNEL = "hvd_flash_fwd"
 DQ_KERNEL = "hvd_flash_dq"
 DKV_KERNEL = "hvd_flash_dkv"
+# What the differentiated forward hands the backward kernels beyond its own
+# inputs, by ``checkpoint_name``: the output and the rows' log-sum-exp.
+# Outside a checkpoint a name lowers to nothing; inside one whose policy
+# saves the names (``models/qwen3_next.recomputed``) the layer is recomputed
+# without a second forward kernel call.
+FLASH_OUT = "hvd_flash_out"
+FLASH_LSE = "hvd_flash_lse"
 
 
 class Mask(NamedTuple):
@@ -452,6 +460,16 @@ def _count_tiles(kernel, static_offs, q, k, block_q, block_k, mask):
                              *(static_offs or (0, 0)))
     metrics.record_flash_tiles(
         kernel, {kind: n * b * h for kind, n in counts.items()}, mask.label)
+
+
+def _count_residuals(kernel, *kept):
+    """The trace-time counter of a differentiated forward: the bytes it
+    hands its backward beyond its own inputs (ops/gated_delta.py shares
+    it)."""
+    from .. import metrics
+
+    metrics.record_kernel_residual_bytes(
+        kernel, sum(x.size * x.dtype.itemsize for x in kept))
 
 
 def _row_minus_col(rows, cols):
@@ -955,6 +973,11 @@ def _flash_fn(mask, scale, block_q, block_k, interpret, static_offs):
         # kept [b,h,sq]: a [b,h,sq,1] float32 array pads every row to a
         # tile of 128 lanes in HBM
         lse = (m + jnp.log(jnp.maximum(l, 1e-30)))[..., 0]
+        # what the forward kernel wrote and the backward kernels read: a
+        # checkpoint whose policy saves these names runs the kernel once
+        o = checkpoint_name(o, FLASH_OUT)
+        lse = checkpoint_name(lse, FLASH_LSE)
+        _count_residuals("flash", o, lse)
         return o, (q, k, v, o, lse, offs)
 
     def bwd(res, do):

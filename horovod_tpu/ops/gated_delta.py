@@ -78,10 +78,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _jit_kernel, _loop, _resolve_interpret
+from .flash_attention import (_count_residuals, _jit_kernel, _loop,
+                              _resolve_interpret)
 
 SCAN_SCOPE = "hvd_gdn_scan"
 # The two kernels' names: each ``pallas_call``'s ``name=`` and the
@@ -89,6 +91,12 @@ SCAN_SCOPE = "hvd_gdn_scan"
 # benchmarks/layer_metrics/gdn_scan_*.py read everything under SCAN_SCOPE).
 FWD_KERNEL = "hvd_gdn_scan_fwd"
 BWD_KERNEL = "hvd_gdn_scan_bwd"
+# What the differentiated forward hands the backward kernel beyond its own
+# inputs, by ``checkpoint_name`` (as ``flash_attention.FLASH_OUT`` /
+# ``FLASH_LSE``): the output, the chunks' start states and their inverses.
+GDN_OUT = "hvd_gdn_scan_out"
+GDN_STATES = "hvd_gdn_scan_states"
+GDN_INVERSES = "hvd_gdn_scan_inverses"
 # Chunks a grid step walks: one chunk a step would be 36 864 grid steps a
 # training step of the benchmark's cell; 8 chunks of 64 are 512 rows a
 # block, 0.5 / 1 MB of VMEM an operand.
@@ -582,6 +590,12 @@ def _scan_fn(per_step, interpret):
 
     def fwd(q, k, v, g, beta):
         o, states, inverses = forward(True, q, k, v, g, beta)
+        # what the forward kernel wrote and the backward kernel reads: a
+        # checkpoint whose policy saves these names runs the kernel once
+        o = checkpoint_name(o, GDN_OUT)
+        states = checkpoint_name(states, GDN_STATES)
+        inverses = checkpoint_name(inverses, GDN_INVERSES)
+        _count_residuals("gdn_scan", o, states, inverses)
         return o, (q, k, v, g, beta, states, inverses)
 
     def bwd(res, do):

@@ -7,7 +7,8 @@ TPU-native entry: build a jitted SPMD train step where the global batch is
 sharded across ranks, parameters are replicated, and gradients flow through
 the fused allreduce.  What the backward pass recomputes is the model's
 decision, not the step's: a model wraps what it chooses to in ``nn.remat``
-(as ``models/qwen3_next.py`` does for each decoder layer).
+(as ``models/qwen3_next.py`` does for each decoder layer, through
+``recomputed``, which keeps the Pallas kernels' residuals).
 """
 
 from __future__ import annotations

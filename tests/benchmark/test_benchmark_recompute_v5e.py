@@ -1,0 +1,126 @@
+"""The two cells that recompute their decoder layers (``sdar-bd4-8k``,
+``qwen3next-8k``) compiled for a described v5e at the cells' own sizes, as
+``run.py`` builds the step.  A recomputed layer keeps what the Pallas
+forward kernels wrote for their backward kernels
+(``models/qwen3_next.recomputed``), so the compiled step calls each forward
+kernel once a layer, not twice; what that costs is memory, held here
+beside the benchmark's weights.  No chip is attached and nothing runs.
+(A file of its own: ``test_benchmark_sdar_v5e.py`` holds the parent's eight
+forward calls and is pinned in ``tests/conftest.py``.)"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+import benchmark_tiny
+from test_benchmark_kernels_v5e import (  # noqa: F401 — fixtures
+    no_compile_cache, one_chip, topo)
+
+LAYERS = 4
+CHIP_BYTES = 16 * 2 ** 30
+#: cell -> (Mosaic calls of the step by kernel name, the band round the
+#: ``hbm_gb`` predicted before the chip (PERF.md section 6, PR 33: 9.665 and
+#: 6.582, the parent's 7.612 and 6.164), float32 parameters the benchmark
+#: keeps beside the state through the checked steps).  ``sdar-bd4-8k``
+#: holds 1.5 GB more than the 0.55 GB of ``o`` and ``lse`` it keeps: XLA
+#: makes ``lse`` from the forward kernel's ``m`` and ``l`` only just before
+#: the backward kernels, and those are ``[1, 32, 16384, 1]`` float32, padded
+#: to 128 lanes, 268 MB each, alive from the forward pass on (PERF.md
+#: section 7).
+CELLS = {
+    "sdar-bd4-8k": (
+        {"hvd_flash_fwd": LAYERS, "hvd_flash_dq": LAYERS,
+         "hvd_flash_dkv": LAYERS},
+        (9.5e9, 9.8e9), 456_346_624),
+    # three DeltaNet layers and one of full attention
+    "qwen3next-8k": (
+        {"hvd_flash_fwd": 1, "hvd_flash_dq": 1, "hvd_flash_dkv": 1,
+         "hvd_gdn_scan_fwd": 3, "hvd_gdn_scan_bwd": 3},
+        (6.5e9, 6.7e9), 424_340_544),
+}
+
+
+def compile_step(cell_name, topo):  # noqa: F811
+    """``cell_name``'s step compiled for one described chip."""
+    import horovod_tpu as hvd
+    from horovod_tpu import core
+    from horovod_tpu.training import init_train_state, make_train_step
+
+    from benchmarks.harness.spec import Spec
+
+    cell = Spec(benchmark_tiny.REPO).cell(cell_name)
+    cfg, mix, adapter = cell.cfg, cell.mix, cell.adapter
+    assert cfg["num_hidden_layers"] == LAYERS
+    assert cfg["remat"] == "decoder_layer"
+    hvd.shutdown()
+    try:
+        # the state's shapes from a world of host devices: a described chip
+        # holds no array
+        hvd.init(devices=jax.devices("cpu")[:1])
+        prog = adapter.program(cfg, mix)
+        state = jax.eval_shape(lambda: init_train_state(
+            prog["model"], prog["optimizer"], prog["sample"]))
+        hvd.shutdown()
+        hvd.init(devices=[topo.devices[0]])
+        whole = NamedSharding(core.mesh(), P())
+        rows = NamedSharding(core.mesh(), P(core.AXIS))
+        prog = adapter.program(cfg, mix)
+        step = make_train_step(
+            apply_fn=prog["apply_fn"], loss_fn=prog["loss_fn"],
+            optimizer=prog["optimizer"])
+        arrays = tuple(jax.ShapeDtypeStruct(
+            (mix["rows_per_chip"], *a["shape"]), jnp.dtype(a["dtype"]),
+            sharding=rows) for a in mix["arrays"])
+        return jax.jit(step).lower(
+            jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=whole), state),
+            *prog["xy"](arrays)).compile()
+    finally:
+        hvd.shutdown()
+
+
+@pytest.fixture(scope="module")
+def steps(topo, no_compile_cache):  # noqa: F811
+    return {name: compile_step(name, topo) for name in CELLS}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_a_layer_calls_each_forward_kernel_once(cell, steps):
+    """What the parent ran twice a layer (the forward pass, then
+    ``nn.remat``'s recompute) is in the compiled step once: as many forward
+    calls as backward ones, and no Mosaic call besides."""
+    calls = re.findall(
+        r"%(\S+?)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        steps[cell].as_text())
+    assert {k: calls.count(k) for k in set(calls)} == CELLS[cell][0]
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_the_layers_are_still_recomputed(cell, steps):
+    """Only the kernels' outputs are kept: the projections run again in
+    the backward pass (``rematted_computation`` on their path), as the
+    cell's configuration says (``remat``: ``decoder_layer``)."""
+    text = steps[cell].as_text()
+    again = [line for line in text.splitlines()
+             if "rematted_computation" in line and "dot_general" in line]
+    assert again, "no product is recomputed"
+    assert not [line for line in text.splitlines()
+                if "rematted_computation" in line
+                and 'custom_call_target="tpu_custom_call"' in line]
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_what_is_kept_fits_beside_the_benchmarks_weights(cell, steps):
+    """``hbm_gb`` as a traced run prints it (arguments + temporaries)
+    inside the band predicted for it before the chip (PERF.md section 6,
+    PR 33), and room for the benchmark's float32 weights through the
+    checked steps."""
+    _, (low, high), parameters = CELLS[cell]
+    mem = steps[cell].memory_analysis()
+    hbm = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert low < hbm < high, hbm
+    assert hbm + 4 * parameters < 0.75 * CHIP_BYTES

@@ -66,8 +66,12 @@ def rng():
 #: PR 30 appends two cells (``sdar-bd4-8k``, ``gpt2s-4k``) and three metrics:
 #: three more tests pin the five cells and the lists of PR 27, and
 #: ``test_benchmark_sdar.py`` ends with the same assertions brought up to
-#: date.  Strict, so that the `benchmark` PR which brings the pins up to date
-#: has to take this list out with them.
+#: date.  PR 33 keeps the flash forward kernel's output and row statistics
+#: across the layer recompute: ``sdar-bd4-8k``'s compiled step calls the
+#: forward kernel four times, not eight, and holds 9.66 GB, not under 8.0;
+#: ``test_benchmark_recompute_v5e.py`` holds both as they are now.  Strict,
+#: so that the `benchmark` PR which brings the pins up to date has to take
+#: this list out with them.
 PINNED_TO_AN_EARLIER_BENCHMARK = {
     "test_benchmark_form.py::test_the_tiny_benchmark_keeps_the_form":
         "7 cells allowed one four-chip cell; with 8 the toy one is no fault",
@@ -88,6 +92,12 @@ PINNED_TO_AN_EARLIER_BENCHMARK = {
     "test_benchmark_qwen3_next.py::"
     "test_every_cell_of_the_benchmark_finds_its_files_the_fifth_too":
         "the expected cells lack sdar-bd4-8k and gpt2s-4k",
+    "test_benchmark_sdar_v5e.py::"
+    "test_the_step_has_three_kernels_and_four_calls_a_layer":
+        "a recomputed layer keeps o and lse: four forward calls, not eight",
+    "test_benchmark_sdar_v5e.py::"
+    "test_the_step_fits_one_chip_beside_the_benchmarks_weights":
+        "what is kept is memory: hbm_gb 9.66, over the 8.0 asserted",
 }
 
 
