@@ -16,7 +16,9 @@ It checks: the device is a TPU; ``flash_attention`` agrees with
 ``softmax_attention`` on the chip (forward and gradients, and with its
 offsets traced), also at head size 256; under the block-diffusion mask
 the kernels agree with a dense-mask float32 softmax at ``[1, 16384, 32,
-128]``; the chunked gated delta rule
+128]``; at latent attention's head sizes (q and k ``[1, 8192, 32, 192]``, v
+``[1, 8192, 32, 128]``) with a dense float32 causal softmax; the chunked
+gated delta rule
 agrees with its token-by-token recurrence at ``[1, 2048, 32, 128]``; on
 more than one chip, ring attention's Pallas variant
 agrees with it too (gradients over the whole ring); the GPT step's
@@ -133,13 +135,52 @@ def flash_phase(b: int = 2, s: int = GPT_SEQ, h: int = 12,
            causal=True, tolerance=FLASH_TOL, rel_max_err=errs)
 
 
+def _flash_against_dense(what: str, flash, seen, q, k, v, w) -> dict:
+    """``flash(q, k, v)`` against the float32 softmax over the pairs ``seen``
+    allows (``[rows, rows]`` booleans), on the same bf16-rounded inputs, a
+    head at a time (one head's ``16384 x 16384`` scores are 1 GB): forward
+    and all three gradients of ``sum(out * w)``.  Returns the largest
+    errors, each relative to the reference's largest element."""
+    import jax
+    import jax.numpy as jnp
+
+    scale = q.shape[-1] ** -0.5
+
+    def dense(q, k, v):
+        def head(qkv):
+            qh, kh, vh = (x.astype(jnp.float32) for x in qkv)   # [rows, d]
+            s = jnp.dot(qh, kh.T, precision="highest") * scale
+            p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+            return jnp.dot(p, vh, precision="highest")
+        per_head = tuple(jnp.moveaxis(x[0], 1, 0) for x in (q, k, v))
+        out = jax.lax.map(jax.checkpoint(head), per_head)      # [h, rows, dv]
+        return jnp.moveaxis(out, 0, 1)[None]
+
+    got = {}
+    for name, fn in (("flash", flash), ("ref", dense)):
+        out, grads = jax.jit(lambda q, k, v: (fn(q, k, v), jax.grad(
+            lambda q, k, v: jnp.sum((fn(q, k, v) * w).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)))(q, k, v)
+        got[name] = [np.asarray(a, np.float32) for a in (out, *grads)]
+    errs = {}
+    for label, a, b_ in zip(("out", "dq", "dk", "dv"),
+                            got["flash"], got["ref"]):
+        check(a.shape == b_.shape, f"{what} flash {label} is {a.shape}")
+        check(np.isfinite(a).all(), f"{what} flash {label} is not finite")
+        errs[label] = float(np.abs(a - b_).max() / np.abs(b_).max())
+        check(errs[label] <= FLASH_TOL,
+              f"{what} flash {label} differs from the dense float32 "
+              f"softmax by {errs[label]:.3g} of its largest element "
+              f"(> {FLASH_TOL})")
+    return errs
+
+
 def block_diffusion_phase(block: int = 4, noised: int = 8192, h: int = 32,
                           d: int = 128) -> None:
     """The flash kernels under the block-diffusion mask at the shape the
     ``sdar-bd4-8k`` cell runs them, ``[1, 2 x 8192, 32, 128]`` bfloat16,
-    against the dense-mask float32 softmax on the same bf16-rounded inputs
-    (a head at a time: one head's ``16384 x 16384`` scores are 1 GB),
-    forward and all three gradients."""
+    against the dense-mask float32 softmax, forward and all three
+    gradients."""
     import jax
     import jax.numpy as jnp
 
@@ -154,40 +195,39 @@ def block_diffusion_phase(block: int = 4, noised: int = 8192, h: int = 32,
     c, g = i // noised, (i % noised) // block
     seen = ((c[None, :] == 1) & (g[None, :] < g[:, None] + c[:, None])) | (
         (c[:, None] == 0) & (c[None, :] == 0) & (g[None, :] == g[:, None]))
-
-    def dense(q, k, v):
-        def head(qkv):
-            qh, kh, vh = (x.astype(jnp.float32) for x in qkv)   # [rows, d]
-            s = jnp.dot(qh, kh.T, precision="highest") * d ** -0.5
-            p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
-            return jnp.dot(p, vh, precision="highest")
-        per_head = tuple(jnp.moveaxis(x[0], 1, 0) for x in (q, k, v))
-        out = jax.lax.map(jax.checkpoint(head), per_head)        # [h, rows, d]
-        return jnp.moveaxis(out, 0, 1)[None]
-
-    def flash(q, k, v):
-        return fa.flash_attention(q, k, v, mask=mask)
-
-    got = {}
-    for name, fn in (("flash", flash), ("ref", dense)):
-        out, grads = jax.jit(lambda q, k, v: (fn(q, k, v), jax.grad(
-            lambda q, k, v: jnp.sum((fn(q, k, v) * w).astype(jnp.float32)),
-            argnums=(0, 1, 2))(q, k, v)))(q, k, v)
-        got[name] = [np.asarray(a, np.float32) for a in (out, *grads)]
-    errs = {}
-    for label, a, b_ in zip(("out", "dq", "dk", "dv"),
-                            got["flash"], got["ref"]):
-        check(np.isfinite(a).all(), f"block-diffusion flash {label} is not "
-              "finite")
-        errs[label] = float(np.abs(a - b_).max() / np.abs(b_).max())
-        check(errs[label] <= FLASH_TOL,
-              f"block-diffusion flash {label} differs from the dense-mask "
-              f"softmax by {errs[label]:.3g} of its largest element "
-              f"(> {FLASH_TOL})")
+    errs = _flash_against_dense(
+        "block-diffusion",
+        lambda q, k, v: fa.flash_attention(q, k, v, mask=mask),
+        seen, q, k, v, w)
     report("flash_block_diffusion_vs_dense_mask", shape=[1, rows, h, d],
            dtype="bfloat16", block=block, tolerance=FLASH_TOL,
            tiles=fa.tile_census(rows, rows, *fa.default_blocks(d), mask),
            rel_max_err=errs)
+
+
+def latent_attention_phase(s: int = 8192, h: int = 32, dk: int = 192,
+                           dv: int = 128) -> None:
+    """The flash kernels where v's head size is not q.k's, at the shape
+    the ``kanana2-8k`` cell runs them (q and k ``[1, 8192, 32, 192]``, v
+    ``[1, 8192, 32, 128]``, bfloat16, causal), against the dense float32
+    causal softmax, forward and all three gradients."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash_attention as fa
+
+    keys = jax.random.split(jax.random.PRNGKey(2), 4)
+    q, k = (jax.random.normal(kk, (1, s, h, dk), jnp.bfloat16)
+            for kk in keys[:2])
+    v, w = (jax.random.normal(kk, (1, s, h, dv), jnp.bfloat16)
+            for kk in keys[2:])
+    errs = _flash_against_dense(
+        "latent",
+        lambda q, k, v: fa.flash_attention(q, k, v, causal=True),
+        jnp.arange(s)[:, None] >= jnp.arange(s)[None, :], q, k, v, w)
+    report("flash_latent_vs_dense", qk=[1, s, h, dk], v=[1, s, h, dv],
+           dtype="bfloat16", causal=True, tolerance=FLASH_TOL,
+           blocks=list(fa.default_blocks(dk)), rel_max_err=errs)
 
 
 def scan_phase() -> None:
@@ -442,6 +482,8 @@ def main() -> int:
     # SDAR's attention: 32 heads of 128 over a doubled sequence under the
     # block-diffusion mask
     block_diffusion_phase()
+    # Kanana-2's latent attention: q.k at 192 (128 + 64 rotary), v at 128
+    latent_attention_phase()
     scan_phase()
     if n > 1:
         ring_phase(n)

@@ -24,7 +24,9 @@ hvd_collectives_traced_bytes_total counter traced payload bytes, by ``op``
 hvd_flash_tiles_traced_total    counter    flash score tiles per traced kernel
                                            call, by ``kernel``/``kind``/``mask``
 hvd_moe_layers_traced_total     counter    routed expert layers traced, by
-                                           ``held``/``top_k``
+                                           ``held``/``top_k``/``rule``
+hvd_mla_layers_traced_total     counter    latent-attention layers traced, by
+                                           ``qk``/``v``/``latent``
 hvd_bd_layers_traced_total      counter    block-diffusion attention layers
                                            traced, by ``block``
 hvd_step_seconds                histogram  train-step cadence (dispatch-to-
@@ -205,8 +207,15 @@ BD_LAYERS = registry.counter(
 MOE_LAYERS = registry.counter(
     "hvd_moe_layers_traced_total",
     "Routed expert layers (parallel/moe.routed_experts) traced (per "
-    "compile, not per step), by how many experts the layer holds here and "
-    "how many a token picks.", ("held", "top_k"))
+    "compile, not per step), by how many experts the layer holds here, "
+    "how many a token picks and the routing rule's name (route_top_k: "
+    "softmax; route_sigmoid_top_k: sigmoid scores with a selection bias).",
+    ("held", "top_k", "rule"))
+MLA_LAYERS = registry.counter(
+    "hvd_mla_layers_traced_total",
+    "Latent-attention layers traced (models/kanana2.py; per compile, not "
+    "per step), by the q.k head size, the v head size and the width of the "
+    "compressed kv.", ("qk", "v", "latent"))
 
 STEP_SECONDS = registry.histogram(
     "hvd_step_seconds",
@@ -578,12 +587,22 @@ def record_bd_layer(block: int) -> None:
         pass
 
 
-def record_moe_layer(held: int, top_k: int) -> None:
+def record_moe_layer(held: int, top_k: int, rule: str) -> None:
     """One traced call of ``parallel/moe.routed_experts``."""
     if not registry.enabled:
         return
     try:
-        MOE_LAYERS.labels(str(held), str(top_k)).inc()
+        MOE_LAYERS.labels(str(held), str(top_k), rule).inc()
+    except Exception:  # noqa: BLE001 — tracing must never fail on metrics
+        pass
+
+
+def record_mla_layer(qk: int, v: int, latent: int) -> None:
+    """One traced latent-attention layer (models/kanana2.py)."""
+    if not registry.enabled:
+        return
+    try:
+        MLA_LAYERS.labels(str(qk), str(v), str(latent)).inc()
     except Exception:  # noqa: BLE001 — tracing must never fail on metrics
         pass
 

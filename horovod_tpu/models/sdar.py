@@ -53,15 +53,13 @@ from __future__ import annotations
 
 from typing import Any, Optional
 
-import math
-
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
 from .. import metrics
 from ..ops.flash_attention import block_diffusion_mask, flash_attention
-from ..parallel.moe import routed_experts
+from ..parallel.moe import grouped_routed_experts
 from .gpt import weighted_token_loss
 from .qwen3_next import (_dense, _normal, apply_rotary, recomputed,
                          rms_normalise, rotary_tables)
@@ -146,7 +144,7 @@ class RoutedMoe(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        b, rows, d = x.shape
+        d = x.shape[-1]
         with jax.named_scope("hvd_moe"):
             router = self.param("gate", _normal(), (d, self.router_experts),
                                 self.param_dtype)
@@ -156,23 +154,10 @@ class RoutedMoe(nn.Module):
             experts = {name: self.param(f"experts_{name}", _normal(), shape,
                                         self.param_dtype)
                        for name, shape in shapes.items()}
-            n = b * rows
-            group = min(self.group_rows or n, n)
-            if n % group:
-                raise ValueError(
-                    f"{n} rows are not whole groups of {group}")
-            capacity = None if self.capacity_factor is None else math.ceil(
-                self.capacity_factor * group * self.top_k
-                / self.router_experts)
-
-            def one_group(xs):
-                return routed_experts(
-                    xs, router, experts, top_k=self.top_k,
-                    first_expert=self.first_expert, capacity=capacity)
-
-            return jax.lax.map(
-                one_group, x.reshape(n // group, group, d)).reshape(
-                    b, rows, d)
+            return grouped_routed_experts(
+                x, router, experts, top_k=self.top_k,
+                first_expert=self.first_expert, group_rows=self.group_rows,
+                capacity_factor=self.capacity_factor)
 
 
 class DecoderLayer(nn.Module):

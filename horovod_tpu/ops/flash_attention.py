@@ -77,6 +77,10 @@ Three public entry points:
 
 All kernels take/return the ``[batch, heads, seq, head_dim]`` layout; the
 callers transpose from the model-facing ``[batch, seq, heads, head_dim]``.
+v's head size is its own (latent attention scores 192 columns and weighs
+128): ``o``, ``do`` and ``dv`` are as wide as v, ``dq`` and ``dk`` as q and
+k, and nothing is padded in HBM; where the two sizes are equal the kernels
+lower as they did when there was one.
 
 What a query row may see is one static description, a :class:`Mask`, taken
 by forward, dq, dkv and :func:`tile_census` alike: none, causal in global
@@ -136,13 +140,19 @@ TILES_PER_STEP = 4
 
 
 def default_blocks(head_dim: int):
-    """``(block_q, block_k)`` a caller gets who names none: 1024 x 512,
-    swept at head size 64 (PR 25) and run, not swept, at 128 (PR 30).  dkv
+    """``(block_q, block_k)`` a caller gets who names none, by q's head
+    size: 1024 x 512, swept at head size 64 (PR 25), run, not swept, at
+    128 (PR 30), and swept at latent attention's 192 with v at 128 (PR 34,
+    one v5e, ``[1, 32, 8192, 192 / 128]`` bf16 causal, us a 512 x 512 tile
+    fwd / dq / dkv: 256 x 512 2.32 / 2.91 / 2.78, 512 x 256 2.02 / 2.68 /
+    2.86, 512 x 512 2.03 / 2.70 / 2.59, 1024 x 256 1.91 / 2.67 / 2.81,
+    **1024 x 512 1.92 / 2.61 / 2.62**, 2048 x 256 1.96 / 2.81 / no room;
+    1024 keys a tile pass Mosaic's 16 MiB of VMEM in the forward).  dkv
     streams four query tiles a grid step, and at head size 256 with
     1024-row tiles that is 16 MiB of VMEM, the compiler's whole limit (the
     step compiled or not by where XLA put the kernel's outputs: PR 26):
-    512-row tiles over head size 128."""
-    return (512 if head_dim > 128 else DEFAULT_BLOCK_Q), DEFAULT_BLOCK_K
+    512-row tiles over head size 192."""
+    return (512 if head_dim > 192 else DEFAULT_BLOCK_Q), DEFAULT_BLOCK_K
 
 
 # Rows of the resident q tile that forward and dq work on at a time, in a
@@ -671,7 +681,8 @@ def _kv_block_index(mask, block_q, block_k, sk):
 
 def _mha_fwd(q, k, v, offs, *, mask, block_q, block_k, interpret,
              static_offs=None, **kw):
-    """q/k/v ``[b,h,s,d]``; returns ``(o, m, l)`` with m/l ``[b,h,sq,1]``."""
+    """q/k ``[b,h,s,d]``, v ``[b,h,sk,dv]``; returns ``(o [b,h,sq,dv], m,
+    l)`` with m/l ``[b,h,sq,1]``."""
     _count_tiles("fwd", static_offs, q, k, block_q, block_k, mask)
     return _fwd_call(q, k, v, offs, mask=mask, block_q=block_q,
                      block_k=block_k,
@@ -682,7 +693,7 @@ def _mha_fwd(q, k, v, offs, *, mask, block_q, block_k, interpret,
 def _fwd_call(q, k, v, offs, *, mask, scale, block_q, block_k, normalize,
               interpret):
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     block_q, tile_k = _check_blocks(sq, sk, block_q, block_k, mask)
     block_k = tile_k * _tiles_per_step(sk, tile_k, mask)
     kernel = functools.partial(
@@ -692,16 +703,18 @@ def _fwd_call(q, k, v, offs, *, mask, scale, block_q, block_k, normalize,
     out_dtype = q.dtype if normalize else jnp.float32
     q_index = lambda b_, h_, i, j, offs: (b_, h_, i, 0)  # noqa: E731
     kv_index = _kv_block_index(mask, block_q, block_k, sk)
-    q_block, kv_block, row = ((1, 1, block_q, d), (1, 1, block_k, d),
-                              (1, 1, block_q, 1))
+    q_block, k_block, row = ((1, 1, block_q, d), (1, 1, block_k, d),
+                             (1, 1, block_q, 1))
+    # o, like v, is dv wide (the scores come from d columns and weigh dv)
+    o_block, v_block = (1, 1, block_q, dv), (1, 1, block_k, dv)
     return _launch(
         FWD_KERNEL, kernel, offs, (b, h, sq // block_q, sk // block_k),
-        ins=[(q, q_block, q_index), (k, kv_block, kv_index),
-             (v, kv_block, kv_index)],
-        outs=[((b, h, sq, d), out_dtype, q_block, q_index),
+        ins=[(q, q_block, q_index), (k, k_block, kv_index),
+             (v, v_block, kv_index)],
+        outs=[((b, h, sq, dv), out_dtype, o_block, q_index),
               ((b, h, sq, 1), jnp.float32, row, q_index),
               ((b, h, sq, 1), jnp.float32, row, q_index)],
-        scratch=[(block_q, d), (block_q, 1), (block_q, _sum_lanes(tile_k))],
+        scratch=[(block_q, dv), (block_q, 1), (block_q, _sum_lanes(tile_k))],
         interpret=interpret)
 
 
@@ -837,19 +850,20 @@ def _mha_bwd_dq(q, k, v, do, lse, delta, offs, *, mask, block_q, block_k,
 def _dq_call(q, k, v, do, lse, delta, offs, *, mask, scale, block_q,
              block_k, interpret, out_dtype=jnp.float32):
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     block_q, tile_k = _check_blocks(sq, sk, block_q, block_k, mask)
     block_k = tile_k * _tiles_per_step(sk, tile_k, mask)
     kernel = functools.partial(_bwd_dq_kernel, mask=mask, scale=scale,
                                tile_k=tile_k)
     q_index = lambda b_, h_, i, j, offs: (b_, h_, i, 0)  # noqa: E731
     kv_index = _kv_block_index(mask, block_q, block_k, sk)
-    q_block, kv_block, row = ((1, 1, block_q, d), (1, 1, block_k, d),
-                              (1, 1, block_q, 1))
+    q_block, k_block, row = ((1, 1, block_q, d), (1, 1, block_k, d),
+                             (1, 1, block_q, 1))
+    do_block, v_block = (1, 1, block_q, dv), (1, 1, block_k, dv)
     return _launch(
         DQ_KERNEL, kernel, offs, (b, h, sq // block_q, sk // block_k),
-        ins=[(q, q_block, q_index), (k, kv_block, kv_index),
-             (v, kv_block, kv_index), (do, q_block, q_index),
+        ins=[(q, q_block, q_index), (k, k_block, kv_index),
+             (v, v_block, kv_index), (do, do_block, q_index),
              (lse, row, q_index), (delta, row, q_index)],
         outs=[((b, h, sq, d), out_dtype, q_block, q_index)],
         scratch=[(block_q, d)], interpret=interpret)[0]
@@ -869,7 +883,7 @@ def _mha_bwd_dkv(q, k, v, do, lse, delta, offs, *, mask, block_q, block_k,
 def _dkv_call(q, k, v, do, lse, delta, offs, *, mask, scale, block_q,
               block_k, interpret, out_dtype=jnp.float32):
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     tile_q, block_k = _check_blocks(sq, sk, block_q, block_k, mask)
     n_tiles = _tiles_per_step(sq, tile_q, mask)
     block_q = tile_q * n_tiles
@@ -896,16 +910,17 @@ def _dkv_call(q, k, v, do, lse, delta, offs, *, mask, scale, block_q,
     q_index = lambda *g: (*g[:2], first_seen(*g), 0)  # noqa: E731
     row_index = lambda *g: (*g[:2], first_seen(*g), 0, 0)  # noqa: E731
     kv_index = lambda b_, h_, jk, i, offs: (b_, h_, jk, 0)  # noqa: E731
-    q_block, kv_block, rows = ((1, 1, block_q, d), (1, 1, block_k, d),
-                               (1, 1, n_tiles, 1, tile_q))
+    q_block, k_block, rows = ((1, 1, block_q, d), (1, 1, block_k, d),
+                              (1, 1, n_tiles, 1, tile_q))
+    do_block, v_block = (1, 1, block_q, dv), (1, 1, block_k, dv)
     return _launch(
         DKV_KERNEL, kernel, offs, (b, h, sk // block_k, sq // block_q),
-        ins=[(q, q_block, q_index), (k, kv_block, kv_index),
-             (v, kv_block, kv_index), (do, q_block, q_index),
+        ins=[(q, q_block, q_index), (k, k_block, kv_index),
+             (v, v_block, kv_index), (do, do_block, q_index),
              (lse, rows, row_index), (delta, rows, row_index)],
-        outs=[((b, h, sk, d), out_dtype, kv_block, kv_index),
-              ((b, h, sk, d), out_dtype, kv_block, kv_index)],
-        scratch=[(block_k, d), (block_k, d)], interpret=interpret)
+        outs=[((b, h, sk, d), out_dtype, k_block, kv_index),
+              ((b, h, sk, dv), out_dtype, v_block, kv_index)],
+        scratch=[(block_k, d), (block_k, dv)], interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -1006,7 +1021,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
 
     Args:
       q, k, v: ``[batch, seq, heads, head_dim]`` (the model-facing layout
-        used throughout :mod:`horovod_tpu.parallel`).
+        used throughout :mod:`horovod_tpu.parallel`); v's head size may
+        differ from q's and k's.
       causal: apply causal masking in global positions
         (``q_offset + i >= kv_offset + j``).
       mask: a :class:`Mask` in ``causal``'s place, e.g.
@@ -1018,7 +1034,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
       block_q, block_k: rows and columns of one score tile; by default
         :func:`default_blocks` of the head size.
 
-    Returns attention output, same shape/dtype as ``q``.
+    Returns attention output in ``q``'s dtype, ``[batch, seq, heads, v's
+    head_dim]``.
     """
     mask = _as_mask(causal) if mask is None else mask
     static_offs = _static_offsets(q_offset, kv_offset)

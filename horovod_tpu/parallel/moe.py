@@ -24,7 +24,8 @@ holds by construction.
 
 :func:`routed_experts` is the layer for models with many small experts
 and several a token (top-k of hundreds): it is told which experts it
-holds, routes over the router's full width, drops nothing unless the
+holds, routes over the router's full width by the rule it is given
+(:func:`route_top_k`, softmax, or :func:`route_sigmoid_top_k`), drops nothing unless the
 caller bounds an expert's load, and computes its own experts' part of the result as a grouped matrix product over the
 assignments sorted by expert, a tile of rows at a time, as many tiles as
 the router sent rows.  :func:`load_census` is its model on the host: how
@@ -34,6 +35,7 @@ many tokens each held expert gets, and how many tiles that makes.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Callable, Optional
 
 import jax
@@ -142,6 +144,26 @@ def route_top_k(x, router_kernel, top_k: int):
                      precision=lax.Precision.HIGHEST)
     weights, experts = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
     return weights / jnp.sum(weights, axis=-1, keepdims=True), experts
+
+
+def route_sigmoid_top_k(x, router_kernel, top_k: int, *, bias,
+                        scale: float = 1.0):
+    """The same pair by the other published rule: each output's score is
+    its own sigmoid (float32, the product at ``highest``), the picks are
+    the ``top_k`` largest of ``score + bias``, and a pick's weight is its
+    score *without* the bias, divided by the picks' sum (plus 1e-20) and
+    multiplied by ``scale``.  ``bias`` (``[E]``) moves which experts a token
+    picks and never what it weighs them by: the selection bias an
+    auxiliary-loss-free balancer steers, a buffer and not a parameter (no
+    gradient reaches it).  A caller binds ``bias`` and ``scale``
+    (``functools.partial``) and passes the rule as ``route``."""
+    logits = jnp.dot(x.astype(jnp.float32), router_kernel.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, experts = lax.top_k(scores + jnp.asarray(bias, jnp.float32), top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    total = jnp.sum(weights, axis=-1, keepdims=True) + 1e-20
+    return weights / total * scale, experts
 
 
 #: Rows of one grouped product.  A held expert's assignments are taken
@@ -289,7 +311,8 @@ _held_part.defvjp(_held_part_fwd, _held_part_bwd)
 
 
 def routed_experts(x, router_kernel, expert_params, *, top_k: int,
-                   first_expert: int = 0, capacity: Optional[int] = None):
+                   first_expert: int = 0, capacity: Optional[int] = None,
+                   route: Callable = route_top_k):
     """The part of a top-k mixture-of-experts layer that the experts held
     here give: ``sum_j w_j * down_j(silu(gate_j x) * up_j x)`` over those
     of a token's ``top_k`` picks that fall on ``[first_expert,
@@ -322,13 +345,20 @@ def routed_experts(x, router_kernel, expert_params, *, top_k: int,
         group): an expert's assignments past its first ``capacity`` in
         token order are dropped, their weights with them (a token's other
         picks keep theirs).  ``None``: no bound.
+      route: how a token's picks and their weights are made, ``(x,
+        router_kernel, top_k) -> (weights [n, top_k] float32, experts [n,
+        top_k] int32)`` over the router's full width: :func:`route_top_k`
+        (softmax) or :func:`route_sigmoid_top_k` with its bias and scale
+        bound.  Held experts, the sort, the tiles and ``capacity`` do not
+        depend on it.
 
     Returns ``[n, d]`` in ``x``'s dtype.
     """
     held = expert_params["gate_proj"].shape[0]
-    metrics.record_moe_layer(held, top_k)
+    metrics.record_moe_layer(held, top_k,
+                             getattr(route, "func", route).__name__)
     with jax.named_scope(ROUTE_SCOPE):
-        weights, experts = route_top_k(x, router_kernel, top_k)
+        weights, experts = route(x, router_kernel, top_k)
         local = experts - first_expert
         local = jnp.where((local >= 0) & (local < held), local,
                           held).reshape(-1).astype(jnp.int32)
@@ -349,6 +379,35 @@ def routed_experts(x, router_kernel, expert_params, *, top_k: int,
                                             "down_proj")}
     return _held_part(x, weights.reshape(-1), params, order, sizes, TILE,
                       top_k)
+
+
+def grouped_routed_experts(x, router_kernel, expert_params, *, top_k: int,
+                           first_expert: int = 0,
+                           group_rows: Optional[int] = None,
+                           capacity_factor: Optional[float] = None,
+                           route: Callable = route_top_k):
+    """:func:`routed_experts` over the rows of ``x`` ``[b, rows, d]`` in
+    groups, GShard's way of bounding an expert's load: the ``b * rows``
+    rows, in order, form groups of ``group_rows`` (one group when ``None``
+    or when there are fewer rows), each routed on its own, and with a
+    ``capacity_factor`` an expert takes at most ``ceil(capacity_factor *
+    group * top_k / E)`` rows of a group, the first in row order.  Returns
+    ``[b, rows, d]``."""
+    b, rows, d = x.shape
+    n = b * rows
+    group = min(group_rows or n, n)
+    if n % group:
+        raise ValueError(f"{n} rows are not whole groups of {group}")
+    capacity = None if capacity_factor is None else math.ceil(
+        capacity_factor * group * top_k / router_kernel.shape[-1])
+
+    def one_group(xs):
+        return routed_experts(xs, router_kernel, expert_params, top_k=top_k,
+                              first_expert=first_expert, capacity=capacity,
+                              route=route)
+
+    return lax.map(one_group, x.reshape(n // group, group, d)).reshape(
+        b, rows, d)
 
 
 def load_census(router_logits, first_expert: int, held: int, *,

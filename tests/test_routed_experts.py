@@ -216,6 +216,7 @@ def test_traced_layers_are_counted(monkeypatch, rng):
 def _count(metrics):
     for s in metrics.registry.snapshot()["metrics"].get(
             "hvd_moe_layers_traced_total", {}).get("samples", []):
-        if s["labels"] == {"held": "4", "top_k": str(TOP_K)}:
+        if s["labels"] == {"held": "4", "top_k": str(TOP_K),
+                           "rule": "route_top_k"}:
             return s["value"]
     return 0
