@@ -24,7 +24,7 @@ hvd_collectives_traced_bytes_total counter traced payload bytes, by ``op``
 hvd_flash_tiles_traced_total    counter    flash score tiles per traced kernel
                                            call, by ``kernel``/``kind``/``mask``
 hvd_moe_layers_traced_total     counter    routed expert layers traced, by
-                                           ``held``/``top_k``/``rule``
+                                           ``held``/``top_k``/``rule``/``groups``
 hvd_mla_layers_traced_total     counter    latent-attention layers traced, by
                                            ``qk``/``v``/``latent``
 hvd_bd_layers_traced_total      counter    block-diffusion attention layers
@@ -208,9 +208,11 @@ MOE_LAYERS = registry.counter(
     "hvd_moe_layers_traced_total",
     "Routed expert layers (parallel/moe.routed_experts) traced (per "
     "compile, not per step), by how many experts the layer holds here, "
-    "how many a token picks and the routing rule's name (route_top_k: "
-    "softmax; route_sigmoid_top_k: sigmoid scores with a selection bias).",
-    ("held", "top_k", "rule"))
+    "how many a token picks, the routing rule's name (route_top_k: "
+    "softmax; route_sigmoid_top_k: sigmoid scores with a selection bias) "
+    "and the groups of rows the call carries one accumulator of the "
+    "experts' gradients through (1: nothing to carry).",
+    ("held", "top_k", "rule", "groups"))
 MLA_LAYERS = registry.counter(
     "hvd_mla_layers_traced_total",
     "Latent-attention layers traced (models/kanana2.py; per compile, not "
@@ -587,12 +589,13 @@ def record_bd_layer(block: int) -> None:
         pass
 
 
-def record_moe_layer(held: int, top_k: int, rule: str) -> None:
-    """One traced call of ``parallel/moe.routed_experts``."""
+def record_moe_layer(held: int, top_k: int, rule: str, groups: int) -> None:
+    """One traced call of ``parallel/moe.routed_experts`` over ``groups``
+    groups of rows."""
     if not registry.enabled:
         return
     try:
-        MOE_LAYERS.labels(str(held), str(top_k), rule).inc()
+        MOE_LAYERS.labels(str(held), str(top_k), rule, str(groups)).inc()
     except Exception:  # noqa: BLE001 — tracing must never fail on metrics
         pass
 

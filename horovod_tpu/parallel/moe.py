@@ -223,35 +223,54 @@ def _expert_forward(xs, params, e):
     return (gate, up, down), (a, b, hidden, dot(hidden, down))
 
 
+def _each_group(one_group, carry, rows):
+    """``lax.scan`` of ``one_group`` over the groups of ``rows``, arrays
+    whose first is ``x``: ``[groups, n, d]``, or ``[n, d]`` for a caller
+    with one group, which is a call and no loop, so that caller compiles
+    to what it would if there were no groups."""
+    if rows[0].ndim == 2:
+        return one_group(carry, rows)
+    return lax.scan(one_group, carry, rows)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _held_part(x, weights, params, order, sizes, tile, top_k):
-    """The held experts' part of the layer: a loop over this step's tiles
-    (a number the device decides), each one expert's next ``tile`` rows —
-    gathered from ``x``, through the expert, weighted, added to their
-    tokens.  ``weights``: ``[n * top_k]`` float32, one an assignment;
-    ``order``: the assignments sorted by held expert, held ones first;
-    ``sizes``: rows of each held expert.  The loop's length is not known
-    when the step is traced, so XLA cannot transpose it: the backward pass
-    is the same loop written out (:func:`_held_part_bwd`)."""
-    n, d = x.shape
-    schedule = _tile_schedule(sizes, tile)
+    """The held experts' part of the layer, group by group: a loop over a
+    group's tiles (a number the device decides), each one expert's next
+    ``tile`` rows — gathered from the group's ``x``, through the expert,
+    weighted, added to their tokens in the group's own float32 ``[n, d]``.
+    ``x``: ``[groups, n, d]``; ``weights``: ``[groups, n * top_k]``
+    float32, one an assignment; ``order``: a group's assignments sorted by
+    held expert, held ones first; ``sizes``: ``[groups, held]``, rows of
+    each held expert (one group: no leading axis on any of the four).  A
+    tile loop's length is not known when the step is traced, so XLA cannot
+    transpose it: the backward pass is the same loops written out
+    (:func:`_held_part_bwd`), and it carries one float32 accumulator of
+    each of the experts' matrices through all the groups."""
+    n, d = x.shape[-2:]
 
-    def one_tile(j, out):
-        with jax.named_scope(ROUTE_SCOPE):
-            e, picked, token = _tile_rows(j, order, sizes, schedule,
-                                          tile=tile, top_k=top_k, n=n)
-            xs = x.at[token].get(mode="fill", fill_value=0)
-            w = weights.at[picked].get(mode="fill", fill_value=0)
-        with jax.named_scope(EXPERTS_SCOPE):
-            y = _expert_forward(xs, params, e)[1][3]
-        with jax.named_scope(ROUTE_SCOPE):
-            return out.at[token].add(y * w[:, None], mode="drop",
-                                     unique_indices=True,
-                                     indices_are_sorted=True)
+    def one_group(_, group):
+        x, weights, order, sizes = group
+        schedule = _tile_schedule(sizes, tile)
 
-    out = lax.fori_loop(0, schedule[1][-1], one_tile,
-                        jnp.zeros((n, d), jnp.float32))
-    return out.astype(x.dtype)
+        def one_tile(j, out):
+            with jax.named_scope(ROUTE_SCOPE):
+                e, picked, token = _tile_rows(j, order, sizes, schedule,
+                                              tile=tile, top_k=top_k, n=n)
+                xs = x.at[token].get(mode="fill", fill_value=0)
+                w = weights.at[picked].get(mode="fill", fill_value=0)
+            with jax.named_scope(EXPERTS_SCOPE):
+                y = _expert_forward(xs, params, e)[1][3]
+            with jax.named_scope(ROUTE_SCOPE):
+                return out.at[token].add(y * w[:, None], mode="drop",
+                                         unique_indices=True,
+                                         indices_are_sorted=True)
+
+        out = lax.fori_loop(0, schedule[1][-1], one_tile,
+                            jnp.zeros((n, d), jnp.float32))
+        return None, out.astype(x.dtype)
+
+    return _each_group(one_group, None, (x, weights, order, sizes))[1]
 
 
 def _held_part_fwd(x, weights, params, order, sizes, tile, top_k):
@@ -260,49 +279,61 @@ def _held_part_fwd(x, weights, params, order, sizes, tile, top_k):
 
 
 def _held_part_bwd(tile, top_k, kept, dout):
-    """Tile by tile again, each recomputed from the layer's inputs (nothing
-    a tile made is kept): the gradients to ``x`` and to the experts'
-    matrices accumulate in float32, an expert's in place in its slice."""
+    """Group by group and tile by tile again, each tile recomputed from the
+    layer's inputs (nothing a tile made is kept).  The gradients to the
+    experts' matrices accumulate in float32 in three ``[held, ...]`` arrays
+    that are zeroed once a call and carried through every group's tile
+    loop, an expert's sum in place in its slice; a group's gradient to its
+    ``x`` accumulates in float32 in that group's own ``[n, d]``."""
     x, weights, params, order, sizes = kept
-    n, d = x.shape
+    n, d = x.shape[-2:]
     dtype, dot = x.dtype, _dot(x.dtype)
-    schedule = _tile_schedule(sizes, tile)
     scatter = dict(mode="drop", unique_indices=True, indices_are_sorted=True)
 
-    def one_tile(j, carry):
-        dx, dweights, dparams = carry
-        with jax.named_scope(ROUTE_SCOPE):
-            e, picked, token = _tile_rows(j, order, sizes, schedule,
-                                          tile=tile, top_k=top_k, n=n)
-            xs = x.at[token].get(mode="fill", fill_value=0)
-            w = weights.at[picked].get(mode="fill", fill_value=0)
-            dys = dout.at[token].get(mode="fill", fill_value=0).astype(
-                jnp.float32)
-        with jax.named_scope(EXPERTS_SCOPE):
-            (gate, up, down), (a, b, hidden, y) = _expert_forward(
-                xs, params, e)
-            dw = jnp.sum(dys * y, axis=-1)
-            dy = (dys * w[:, None]).astype(dtype)
-            dhidden = dot(dy, down.T)
-            sig = jax.nn.sigmoid(a)
-            da = (dhidden * b * sig * (1.0 + a * (1.0 - sig))).astype(dtype)
-            db = (dhidden * a * sig).astype(dtype)
-            dxs = dot(da, gate.T) + dot(db, up.T)
-            grads = {"gate_proj": dot(xs.T, da), "up_proj": dot(xs.T, db),
-                     "down_proj": dot(hidden.T, dy)}
-            dparams = {k: lax.dynamic_update_index_in_dim(
-                acc, lax.dynamic_index_in_dim(acc, e, keepdims=False)
-                + grads[k], e, 0) for k, acc in dparams.items()}
-        with jax.named_scope(ROUTE_SCOPE):
-            return (dx.at[token].add(dxs, **scatter),
-                    dweights.at[picked].set(dw, **scatter), dparams)
+    def one_group(dparams, group):
+        x, weights, order, sizes, dout = group
+        schedule = _tile_schedule(sizes, tile)
 
-    dx, dweights, dparams = lax.fori_loop(
-        0, schedule[1][-1], one_tile,
-        (jnp.zeros((n, d), jnp.float32), jnp.zeros_like(weights),
-         {k: jnp.zeros(params[k].shape, jnp.float32)
-          for k in ("gate_proj", "up_proj", "down_proj")}))
-    return (dx.astype(dtype), dweights,
+        def one_tile(j, carry):
+            dx, dweights, dparams = carry
+            with jax.named_scope(ROUTE_SCOPE):
+                e, picked, token = _tile_rows(j, order, sizes, schedule,
+                                              tile=tile, top_k=top_k, n=n)
+                xs = x.at[token].get(mode="fill", fill_value=0)
+                w = weights.at[picked].get(mode="fill", fill_value=0)
+                dys = dout.at[token].get(mode="fill", fill_value=0).astype(
+                    jnp.float32)
+            with jax.named_scope(EXPERTS_SCOPE):
+                (gate, up, down), (a, b, hidden, y) = _expert_forward(
+                    xs, params, e)
+                dw = jnp.sum(dys * y, axis=-1)
+                dy = (dys * w[:, None]).astype(dtype)
+                dhidden = dot(dy, down.T)
+                sig = jax.nn.sigmoid(a)
+                da = (dhidden * b * sig * (1.0 + a * (1.0 - sig))).astype(
+                    dtype)
+                db = (dhidden * a * sig).astype(dtype)
+                dxs = dot(da, gate.T) + dot(db, up.T)
+                grads = {"gate_proj": dot(xs.T, da), "up_proj": dot(xs.T, db),
+                         "down_proj": dot(hidden.T, dy)}
+                dparams = {k: lax.dynamic_update_index_in_dim(
+                    acc, lax.dynamic_index_in_dim(acc, e, keepdims=False)
+                    + grads[k], e, 0) for k, acc in dparams.items()}
+            with jax.named_scope(ROUTE_SCOPE):
+                return (dx.at[token].add(dxs, **scatter),
+                        dweights.at[picked].set(dw, **scatter), dparams)
+
+        dx, dweights, dparams = lax.fori_loop(
+            0, schedule[1][-1], one_tile,
+            (jnp.zeros((n, d), jnp.float32), jnp.zeros_like(weights),
+             dparams))
+        return dparams, (dx.astype(dtype), dweights)
+
+    dparams, (dx, dweights) = _each_group(
+        one_group, {k: jnp.zeros(params[k].shape, jnp.float32)
+                    for k in ("gate_proj", "up_proj", "down_proj")},
+        (x, weights, order, sizes, dout))
+    return (dx, dweights,
             {k: dparams[k].astype(params[k].dtype) for k in dparams},
             None, None)
 
@@ -333,18 +364,25 @@ def routed_experts(x, router_kernel, expert_params, *, top_k: int,
     its place; an ``ep`` exchange like :func:`moe_apply`'s goes round this
     function (tokens in, their parts out), not inside it.
 
+    ``x`` may hold several groups of rows (``[groups, n, d]``): each group
+    is routed, bounded, sorted and tiled on its own, as a call of its own
+    would be, and the backward pass carries one float32 accumulator of each
+    of the experts' matrices through all the groups (zeroed once and cast
+    once a call, a tile's products added in place), so what a group adds
+    to the experts' gradients is never summed with another group's as
+    whole arrays.  ``[n, d]`` is one group.
+
     Args:
-      x: ``[n, d]`` tokens.
+      x: ``[n, d]`` tokens, or ``[groups, n, d]``.
       router_kernel: ``[d, E]``, all ``E`` experts of the layer.
       expert_params: ``{"gate_proj": [held, d, f], "up_proj": [held, d, f],
         "down_proj": [held, f, d]}``, the experts held here.
       top_k: experts a token.
       first_expert: index of the first held expert.
-      capacity: the most of these ``n`` tokens an expert takes, GShard's
-        bound on a group (a caller with several groups calls once a
-        group): an expert's assignments past its first ``capacity`` in
-        token order are dropped, their weights with them (a token's other
-        picks keep theirs).  ``None``: no bound.
+      capacity: the most of a group's ``n`` tokens an expert takes,
+        GShard's bound on a group: an expert's assignments past its first
+        ``capacity`` in token order are dropped, their weights with them
+        (a token's other picks keep theirs).  ``None``: no bound.
       route: how a token's picks and their weights are made, ``(x,
         router_kernel, top_k) -> (weights [n, top_k] float32, experts [n,
         top_k] int32)`` over the router's full width: :func:`route_top_k`
@@ -352,33 +390,40 @@ def routed_experts(x, router_kernel, expert_params, *, top_k: int,
         bound.  Held experts, the sort, the tiles and ``capacity`` do not
         depend on it.
 
-    Returns ``[n, d]`` in ``x``'s dtype.
+    Returns ``x``'s shape in ``x``'s dtype.
     """
     held = expert_params["gate_proj"].shape[0]
     metrics.record_moe_layer(held, top_k,
-                             getattr(route, "func", route).__name__)
-    with jax.named_scope(ROUTE_SCOPE):
-        weights, experts = route(x, router_kernel, top_k)
-        local = experts - first_expert
-        local = jnp.where((local >= 0) & (local < held), local,
-                          held).reshape(-1).astype(jnp.int32)
-        if capacity is not None:
-            # an assignment's place among its expert's, in token order; one
-            # past the capacity counts as an expert's that lives elsewhere
-            mine = local[:, None] == jnp.arange(held)[None, :]
-            place = jnp.sum(jnp.where(mine, jnp.cumsum(mine, axis=0,
-                                                       dtype=jnp.int32), 0),
-                            axis=1)
-            local = jnp.where(place > capacity, held, local)
-        sizes = jnp.sum(local[:, None] == jnp.arange(held)[None, :],
-                        axis=0, dtype=jnp.int32)
-        # stable: the held assignments first, expert by expert, each
-        # expert's in token order
-        order = jnp.argsort(local, stable=True).astype(jnp.int32)
+                             getattr(route, "func", route).__name__,
+                             1 if x.ndim == 2 else x.shape[0])
+
+    def route_group(_, rows):
+        x, = rows
+        with jax.named_scope(ROUTE_SCOPE):
+            weights, experts = route(x, router_kernel, top_k)
+            local = experts - first_expert
+            local = jnp.where((local >= 0) & (local < held), local,
+                              held).reshape(-1).astype(jnp.int32)
+            if capacity is not None:
+                # an assignment's place among its expert's, in token order;
+                # one past the capacity counts as an expert's that lives
+                # elsewhere
+                mine = local[:, None] == jnp.arange(held)[None, :]
+                place = jnp.sum(
+                    jnp.where(mine, jnp.cumsum(mine, axis=0,
+                                               dtype=jnp.int32), 0), axis=1)
+                local = jnp.where(place > capacity, held, local)
+            sizes = jnp.sum(local[:, None] == jnp.arange(held)[None, :],
+                            axis=0, dtype=jnp.int32)
+            # stable: the held assignments first, expert by expert, each
+            # expert's in token order
+            order = jnp.argsort(local, stable=True).astype(jnp.int32)
+        return None, (weights.reshape(-1), order, sizes)
+
+    _, (weights, order, sizes) = _each_group(route_group, None, (x,))
     params = {k: expert_params[k] for k in ("gate_proj", "up_proj",
                                             "down_proj")}
-    return _held_part(x, weights.reshape(-1), params, order, sizes, TILE,
-                      top_k)
+    return _held_part(x, weights, params, order, sizes, TILE, top_k)
 
 
 def grouped_routed_experts(x, router_kernel, expert_params, *, top_k: int,
@@ -391,8 +436,10 @@ def grouped_routed_experts(x, router_kernel, expert_params, *, top_k: int,
     rows, in order, form groups of ``group_rows`` (one group when ``None``
     or when there are fewer rows), each routed on its own, and with a
     ``capacity_factor`` an expert takes at most ``ceil(capacity_factor *
-    group * top_k / E)`` rows of a group, the first in row order.  Returns
-    ``[b, rows, d]``."""
+    group * top_k / E)`` rows of a group, the first in row order.  One
+    call for all the groups, so the backward pass carries the experts'
+    float32 gradient accumulators from group to group.  Returns ``[b,
+    rows, d]``."""
     b, rows, d = x.shape
     n = b * rows
     group = min(group_rows or n, n)
@@ -400,14 +447,10 @@ def grouped_routed_experts(x, router_kernel, expert_params, *, top_k: int,
         raise ValueError(f"{n} rows are not whole groups of {group}")
     capacity = None if capacity_factor is None else math.ceil(
         capacity_factor * group * top_k / router_kernel.shape[-1])
-
-    def one_group(xs):
-        return routed_experts(xs, router_kernel, expert_params, top_k=top_k,
-                              first_expert=first_expert, capacity=capacity,
-                              route=route)
-
-    return lax.map(one_group, x.reshape(n // group, group, d)).reshape(
-        b, rows, d)
+    return routed_experts(
+        x.reshape(n // group, group, d), router_kernel, expert_params,
+        top_k=top_k, first_expert=first_expert, capacity=capacity,
+        route=route).reshape(b, rows, d)
 
 
 def load_census(router_logits, first_expert: int, held: int, *,

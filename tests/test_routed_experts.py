@@ -1,8 +1,14 @@
 """``parallel.moe.routed_experts``: top-k routing over the experts held
 here with nothing dropped, against a dense numpy oracle; the share test
 (the parts of all the shares add up to the uncut layer); a skewed router;
-tiles of several sizes; ``load_census`` against a hand count."""
+tiles of several sizes; ``load_census`` against a hand count; several
+groups of rows in one call against the dense oracle a group, and what the
+backward pass carries through them."""
 
+import functools
+import math
+
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -203,20 +209,192 @@ def test_load_census_against_a_hand_count(tile):
     assert moe.load_census(logits, 3, 1, top_k=2)["largest_over_mean"] == 0.0
 
 
+def _dense_groups(params, x, *, capacity):
+    """The held experts' part (experts 2 to 5 of 8) of every group of ``x``
+    ``[groups, n, d]`` as dense algebra that XLA differentiates itself: a
+    group is routed on its own, and with a ``capacity`` an expert keeps
+    its first ``capacity`` picks of a group in row order."""
+    def one_group(x):
+        p = jax.nn.softmax(x @ params["router"], axis=-1)
+        w, idx = jax.lax.top_k(p, TOP_K)
+        w = w / w.sum(-1, keepdims=True)
+        rows = jnp.arange(x.shape[0])[:, None]
+        gates = jnp.zeros_like(p).at[rows, idx].set(w)
+        picked = jnp.zeros(p.shape, bool).at[rows, idx].set(True)
+        if capacity is not None:
+            gates = jnp.where(jnp.cumsum(picked, axis=0) <= capacity, gates,
+                              0.0)
+        out = 0.0
+        for e in range(2, 6):
+            h = jax.nn.silu(x @ params["gate_proj"][e]) \
+                * (x @ params["up_proj"][e])
+            out = out + gates[:, e:e + 1] * (h @ params["down_proj"][e])
+        return out
+
+    return jnp.stack([one_group(g) for g in x])
+
+
+def _grouped(params, x, *, capacity_factor):
+    groups, n, _ = x.shape
+    share = {k: params[k][2:6] for k in ("gate_proj", "up_proj", "down_proj")}
+    return moe.grouped_routed_experts(
+        x.reshape(1, groups * n, D), params["router"], share, top_k=TOP_K,
+        first_expert=2, group_rows=n,
+        capacity_factor=capacity_factor).reshape(x.shape)
+
+
+class _Grouped(nn.Module):
+    """The grouped call as a model makes it, its matrices the module's."""
+    capacity_factor: float = None
+
+    @nn.compact
+    def __call__(self, x):
+        params = {k: self.get_variable("params", k)
+                  for k in ("router", "gate_proj", "up_proj", "down_proj")}
+        return _grouped(params, x, capacity_factor=self.capacity_factor)
+
+
+GROUP_ROWS, HELD_OF_8 = 24, 4
+
+
+@pytest.mark.parametrize("tile", [4], indirect=True)
+@pytest.mark.parametrize("capacity_factor", [None, 0.75])
+@pytest.mark.parametrize("groups", [1, 2, 4])
+@pytest.mark.parametrize("how", ["eager", "jit_remat"])
+def test_groups_in_one_call_match_the_dense_oracle_a_group(
+        rng, tile, groups, capacity_factor, how):
+    """One call over 1, 2 and 4 groups of rows: the output and the
+    gradients to ``x``, the router and the experts' three matrices are the
+    dense oracle's, whose gradients to the shared leaves are the sums over
+    the groups — with an expert's load unbounded and bounded (9 picks a
+    group, so the fullest experts drop rows), and as a model runs it:
+    compiled, the layer under ``nn.remat``."""
+    layer = _layer(rng, experts=8)
+    params = {k: jnp.asarray(v) for k, v in layer.items()}
+    x = jnp.asarray(rng.normal(size=(groups, GROUP_ROWS, D)).astype(
+        np.float32))
+    capacity = None if capacity_factor is None else math.ceil(
+        capacity_factor * GROUP_ROWS * TOP_K / 8)
+
+    def loss(part):
+        return lambda params, x: jnp.sum(jnp.sin(part(params, x)))
+
+    want_out = _dense_groups(params, x, capacity=capacity)
+    want = jax.grad(loss(functools.partial(
+        _dense_groups, capacity=capacity)), argnums=(0, 1))(params, x)
+    if how == "eager":
+        part = functools.partial(_grouped, capacity_factor=capacity_factor)
+        got_out = part(params, x)
+        got = jax.grad(loss(part), argnums=(0, 1))(params, x)
+    else:
+        module = nn.remat(_Grouped)(capacity_factor=capacity_factor)
+
+        def part(params, x):
+            return module.apply({"params": params}, x)
+
+        got_out = jax.jit(part)(params, x)
+        got = jax.jit(jax.grad(loss(part), argnums=(0, 1)))(params, x)
+    if capacity is not None:
+        # the bound binds: some held pick is dropped in some group
+        free = _dense_groups(params, x, capacity=None)
+        assert np.abs(np.asarray(free - want_out)).max() > 1e-3
+    np.testing.assert_allclose(got_out, want_out, rtol=1e-6, atol=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+
+
+def _eqns(jaxpr, inside=()):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it, each with
+    the primitives of the equations it sits in, outermost first."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub, inside + (eqn,))
+
+
+@pytest.mark.parametrize("tile", [4], indirect=True)
+def test_the_backward_pass_carries_one_accumulator_through_the_groups(
+        rng, tile):
+    """The gradient of a four-group call, as traced: the three float32
+    accumulators of the experts' shapes are made once, outside the loop
+    over the groups, and that loop carries them; nothing of those shapes
+    is added inside it (a tile adds one expert's slice, in place), where a
+    loop whose groups each made their own would sum them a group
+    (``add_any``)."""
+    groups, held = 4, HELD_OF_8
+    layer = _layer(rng, experts=8)
+    params = {k: jnp.asarray(v) for k, v in layer.items()}
+    x = jnp.asarray(rng.normal(size=(groups, GROUP_ROWS, D)).astype(
+        np.float32))
+    shapes = {(held, D, F), (held, F, D)}
+
+    def loss(params, x):
+        return jnp.sum(jnp.sin(_grouped(params, x, capacity_factor=1.25)))
+
+    def of_the_experts(eqn):
+        return [v for v in eqn.outvars if v.aval.shape in shapes
+                and v.aval.dtype == jnp.float32]
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x).jaxpr
+    carrying = [eqn for eqn, _ in _eqns(jaxpr)
+                if eqn.primitive.name == "scan"
+                and eqn.params["length"] == groups
+                and len(of_the_experts(eqn)) == 3]
+    assert len(carrying) == 1, "one loop over the groups carries the three"
+    zeroed, summed = [], []
+    for eqn, inside in _eqns(jaxpr):
+        if not of_the_experts(eqn):
+            continue
+        in_the_loop = any(outer is carrying[0] for outer in inside)
+        if eqn.primitive.name == "broadcast_in_dim":
+            zeroed.append(in_the_loop)
+        if eqn.primitive.name in ("add_any", "add"):
+            summed.append(in_the_loop)
+    assert zeroed == [False] * 3
+    assert not any(summed)
+
+
+def test_one_group_is_a_call_and_no_loop(rng):
+    """``[n, d]`` rows are one group: the traced gradient holds the two
+    tile loops and no loop over groups, the program the layer was before it
+    took groups (``models/qwen3_next.py`` calls it so)."""
+    layer = _layer(rng)
+    x = jnp.asarray(rng.normal(size=(N, D)).astype(np.float32))
+    share = _share(layer, 12, 4)
+
+    def loss(x, share):
+        return jnp.sum(jnp.sin(moe.routed_experts(
+            x, jnp.asarray(layer["router"]), share, top_k=TOP_K,
+            first_expert=12)))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, share).jaxpr
+    loops = [eqn.primitive.name for eqn, _ in _eqns(jaxpr)
+             if eqn.primitive.name in ("scan", "while")]
+    assert loops == ["while", "while"]
+
+
 def test_traced_layers_are_counted(monkeypatch, rng):
     from horovod_tpu import metrics
 
     monkeypatch.setattr(metrics.registry, "enabled", True)
     layer = _layer(rng)
-    before = _count(metrics)
-    _run(layer, rng.normal(size=(N, D)).astype(np.float32), 8, 4)
-    assert _count(metrics) == before + 1
+    x = rng.normal(size=(N, D)).astype(np.float32)
+    before = _count(metrics, 1), _count(metrics, 3)
+    _run(layer, x, 8, 4)
+    assert (_count(metrics, 1), _count(metrics, 3)) == (before[0] + 1,
+                                                        before[1])
+    moe.grouped_routed_experts(
+        jnp.asarray(x)[None], jnp.asarray(layer["router"]),
+        _share(layer, 8, 4), top_k=TOP_K, first_expert=8, group_rows=N // 3)
+    assert (_count(metrics, 1), _count(metrics, 3)) == (before[0] + 1,
+                                                        before[1] + 1)
 
 
-def _count(metrics):
+def _count(metrics, groups):
     for s in metrics.registry.snapshot()["metrics"].get(
             "hvd_moe_layers_traced_total", {}).get("samples", []):
         if s["labels"] == {"held": "4", "top_k": str(TOP_K),
-                           "rule": "route_top_k"}:
+                           "rule": "route_top_k", "groups": str(groups)}:
             return s["value"]
     return 0
