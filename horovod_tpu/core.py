@@ -24,6 +24,7 @@ ICI mesh), and XLA compiles collectives directly into the program.  So here:
 
 from __future__ import annotations
 
+import hashlib
 import os
 import threading
 from dataclasses import dataclass, field
@@ -141,6 +142,26 @@ def _place_compile_cache(platform: str) -> None:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", _COMPILE_CACHE_DIR)
+    _key_the_cache_by_scope_names()
+
+
+def _key_the_cache_by_scope_names() -> None:
+    """Fold the program's device-scope names (``models/scopes.py``) into
+    the persistent cache's key.  JAX keys a program with its debug info
+    stripped (``jax_compilation_cache_include_metadata_in_key`` is off, and
+    on it would key by source paths and line numbers too), so a program
+    that differs from a cached one only in its ``jax.named_scope``s loads
+    the cached executable with the names of whoever compiled first, and a
+    device trace reads scopes the running code does not emit
+    (docs/profiling.md; ``tests/test_part_scopes.py`` shows both).  JAX
+    0.9.0 hashes ``cache_key.custom_hook()`` into every key."""
+    from jax._src import cache_key
+
+    from .models import scopes
+
+    digest = hashlib.sha256(
+        "\n".join(scopes.documented()).encode()).hexdigest()[:16]
+    cache_key.custom_hook = lambda: f"hvd_scopes:{digest}"
 
 
 def init(

@@ -21,6 +21,7 @@ import numpy as np
 from flax import linen as nn
 
 from .bert import EncoderLayer
+from .scopes import HEAD
 
 
 def causal_flash_attention_fn(q, k, v, mask):
@@ -68,10 +69,12 @@ class GPT(nn.Module):
                 self.num_heads, self.mlp_dim, dtype=self.dtype,
                 param_dtype=self.param_dtype, attention_fn=attn,
             )(x)
-        x = nn.LayerNorm(dtype=self.dtype, param_dtype=self.param_dtype)(x)
-        # weight-tied LM head: logits = x @ wte^T, f32 for the softmax
-        logits = embed.attend(x.astype(self.param_dtype))
-        return logits.astype(jnp.float32)
+        with jax.named_scope(HEAD):
+            x = nn.LayerNorm(dtype=self.dtype,
+                             param_dtype=self.param_dtype)(x)
+            # weight-tied LM head: logits = x @ wte^T, f32 for the softmax
+            logits = embed.attend(x.astype(self.param_dtype))
+            return logits.astype(jnp.float32)
 
 
 def gpt2_small(**kw):

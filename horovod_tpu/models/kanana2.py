@@ -38,10 +38,10 @@ bf16 compute / float32 parameters like the other families.  ``remat``
 recomputes each decoder layer in the backward pass
 (``qwen3_next.recomputed``: the flash forward kernel's output and row
 statistics are kept, so a layer calls it once a step).  Device scopes
-(docs/profiling.md): ``hvd_mla`` (``hvd_mla_q``, ``hvd_mla_latent``, the
-kernels' own), ``hvd_dense_mlp``, ``hvd_moe`` (``hvd_moe_route``,
-``hvd_moe_experts``, ``hvd_moe_shared``); counter
-``hvd_mla_layers_traced_total{qk,v,latent}``.
+(``models/scopes.py``, docs/profiling.md): ``hvd_mla`` (``hvd_mla_q``,
+``hvd_mla_latent``, the kernels' own, ``hvd_mla_out``), ``hvd_dense_mlp``,
+``hvd_moe`` (``hvd_moe_route``, ``hvd_moe_experts``, ``hvd_moe_shared``),
+``hvd_head``; counter ``hvd_mla_layers_traced_total{qk,v,latent}``.
 """
 
 from __future__ import annotations
@@ -56,7 +56,8 @@ from flax import linen as nn
 from .. import metrics
 from ..ops.flash_attention import flash_attention
 from ..parallel.moe import grouped_routed_experts, route_sigmoid_top_k
-from .qwen3_next import _dense, _normal, recomputed
+from . import scopes
+from .qwen3_next import _dense, _normal, lm_head, recomputed
 from .sdar import RMSNorm
 
 _F32 = jnp.float32
@@ -96,8 +97,8 @@ class LatentAttention(nn.Module):
                              self.qk_rope_head_dim, self.v_head_dim)
         positions = jnp.arange(s)
         metrics.record_mla_layer(nope + rope, dv, self.kv_lora_rank)
-        with jax.named_scope("hvd_mla"):
-            with jax.named_scope("hvd_mla_q"):
+        with jax.named_scope(scopes.MLA):
+            with jax.named_scope(scopes.MLA_Q):
                 q = nn.Dense(
                     h * (nope + rope), use_bias=False, dtype=self.dtype,
                     param_dtype=self.param_dtype,
@@ -107,7 +108,7 @@ class LatentAttention(nn.Module):
                     q[..., nope:], positions, self.rope_theta).astype(
                         self.dtype)], axis=-1)
             # what latent attention costs beyond a plain k / v projection
-            with jax.named_scope("hvd_mla_latent"):
+            with jax.named_scope(scopes.MLA_LATENT):
                 ckr = _dense(self.kv_lora_rank + rope, "kv_a_proj_with_mqa",
                              self)(x)
                 c = RMSNorm(self.eps, name="kv_a_layernorm",
@@ -123,7 +124,8 @@ class LatentAttention(nn.Module):
                     k_rope, (b, s, h, rope))], axis=-1)
                 v = kv[..., nope:]
             o = flash_attention(q, k, v, causal=True)
-            return _dense(d, "o_proj", self)(o.reshape(b, s, h * dv))
+            with jax.named_scope(scopes.MLA_OUT):
+                return _dense(d, "o_proj", self)(o.reshape(b, s, h * dv))
 
 
 def _swiglu(module: nn.Module, x, width: int, prefix: str = ""):
@@ -139,7 +141,7 @@ class DenseMlp(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        with jax.named_scope("hvd_dense_mlp"):
+        with jax.named_scope(scopes.DENSE_MLP):
             return _swiglu(self, x, self.width)
 
 
@@ -166,7 +168,7 @@ class SharedRoutedMoe(nn.Module):
     @nn.compact
     def __call__(self, x):
         d = x.shape[-1]
-        with jax.named_scope("hvd_moe"):
+        with jax.named_scope(scopes.MOE):
             router = self.param("gate", _normal(), (d, self.router_experts),
                                 self.param_dtype)
             shapes = {"gate_proj": (self.num_experts, d, self.expert_dim),
@@ -184,7 +186,7 @@ class SharedRoutedMoe(nn.Module):
                 capacity_factor=self.capacity_factor,
                 route=functools.partial(route_sigmoid_top_k, bias=bias,
                                         scale=self.scale))
-            with jax.named_scope("hvd_moe_shared"):
+            with jax.named_scope(scopes.MOE_SHARED):
                 shared = _swiglu(self, x, self.shared_dim, "shared_experts_")
             return routed + shared
 
@@ -272,12 +274,7 @@ class Kanana2(nn.Module):
                 moe=None if i < self.first_dense_layers else moe,
                 dense_width=self.intermediate_size, eps=self.rms_norm_eps,
                 name=f"layers_{i}", **kw)(x)
-        x = RMSNorm(self.rms_norm_eps, name="norm", **kw)(x)
-        head = self.param("lm_head", _normal(),
-                          (self.hidden_size, self.vocab_size),
-                          self.param_dtype)
-        return jnp.dot(x, head.astype(self.dtype),
-                       preferred_element_type=_F32)
+        return lm_head(self, x, self.rms_norm_eps, RMSNorm)
 
 
 def kanana2_tiny(**kw):

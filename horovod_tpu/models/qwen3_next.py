@@ -33,8 +33,10 @@ recomputes each decoder layer in the backward pass (:func:`recomputed`):
 the layers' inputs are kept and, of what is inside a layer, only what the
 flash and scan forward kernels wrote for their backward kernels, so a
 layer calls each forward kernel once a step.  Device scopes
-(docs/profiling.md): ``hvd_gdn`` (``hvd_gdn_conv``, ``hvd_gdn_scan``),
-``hvd_moe`` (``hvd_moe_route``, ``hvd_moe_experts``, ``hvd_moe_shared``).
+(``models/scopes.py``, docs/profiling.md): ``hvd_attn`` (``hvd_attn_qkv``,
+the flash kernels' own, ``hvd_attn_out``), ``hvd_gdn`` (``hvd_gdn_in``,
+``hvd_gdn_conv``, ``hvd_gdn_scan``, ``hvd_gdn_out``), ``hvd_moe``
+(``hvd_moe_route``, ``hvd_moe_experts``, ``hvd_moe_shared``), ``hvd_head``.
 """
 
 from __future__ import annotations
@@ -49,8 +51,15 @@ from ..ops.flash_attention import FLASH_LSE, FLASH_OUT, flash_attention
 from ..ops.gated_delta import (GDN_INVERSES, GDN_OUT, GDN_STATES,
                                gated_delta_rule)
 from ..parallel.moe import routed_experts
+from . import scopes
 
 _F32 = jnp.float32
+#: what JAX (0.9.0) writes on the path of every op a ``jax.checkpoint``
+#: computes a second time: ``.../checkpoint/rematted_computation/<layer>/
+#: <scopes>/<primitive>``, the program's scopes after it.  The benchmark's
+#: ``recompute_ms`` reads it; ``tests/test_part_scopes.py`` fails by name
+#: when an upgrade renames it.
+REMAT_MARK = "rematted_computation"
 
 
 def flash_blocks(head_dim: int) -> dict:
@@ -141,23 +150,29 @@ class GatedAttention(nn.Module):
     def __call__(self, x):
         b, s, _ = x.shape
         h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        qg = _dense(h * hd * 2, "q_proj", self)(x).reshape(b, s, h, 2 * hd)
-        q, gate = qg[..., :hd], qg[..., hd:]
-        k = _dense(kv * hd, "k_proj", self)(x).reshape(b, s, kv, hd)
-        v = _dense(kv * hd, "v_proj", self)(x).reshape(b, s, kv, hd)
-        norm = dict(eps=self.eps, dtype=self.dtype,
-                    param_dtype=self.param_dtype)
-        q = RMSNorm(name="q_norm", **norm)(q)
-        k = RMSNorm(name="k_norm", **norm)(k)
-        cos, sin = rotary_tables(jnp.arange(s), self.rotary_dim,
-                                 self.rope_theta)
-        q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
-        # each kv head serves h // kv consecutive q heads; the kernels take
-        # equal head counts, so k and v are repeated outside them
-        k, v = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
-        o = flash_attention(q, k, v, causal=True, **flash_blocks(hd))
-        o = o * jax.nn.sigmoid(gate.astype(_F32)).astype(self.dtype)
-        return _dense(x.shape[-1], "o_proj", self)(o.reshape(b, s, h * hd))
+        with jax.named_scope(scopes.ATTN):
+            with jax.named_scope(scopes.ATTN_QKV):
+                qg = _dense(h * hd * 2, "q_proj", self)(x).reshape(
+                    b, s, h, 2 * hd)
+                q, gate = qg[..., :hd], qg[..., hd:]
+                k = _dense(kv * hd, "k_proj", self)(x).reshape(b, s, kv, hd)
+                v = _dense(kv * hd, "v_proj", self)(x).reshape(b, s, kv, hd)
+                norm = dict(eps=self.eps, dtype=self.dtype,
+                            param_dtype=self.param_dtype)
+                q = RMSNorm(name="q_norm", **norm)(q)
+                k = RMSNorm(name="k_norm", **norm)(k)
+                cos, sin = rotary_tables(jnp.arange(s), self.rotary_dim,
+                                         self.rope_theta)
+                q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
+                # each kv head serves h // kv consecutive q heads; the
+                # kernels take equal head counts, so k and v are repeated
+                # outside them
+                k, v = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
+            o = flash_attention(q, k, v, causal=True, **flash_blocks(hd))
+            with jax.named_scope(scopes.ATTN_OUT):
+                o = o * jax.nn.sigmoid(gate.astype(_F32)).astype(self.dtype)
+                return _dense(x.shape[-1], "o_proj", self)(
+                    o.reshape(b, s, h * hd))
 
 
 def causal_depthwise_conv(x, kernel):
@@ -186,46 +201,52 @@ class GatedDeltaNet(nn.Module):
         hk, hv, dk, dv = (self.num_k_heads, self.num_v_heads,
                           self.head_k_dim, self.head_v_dim)
         key_dim, value_dim = hk * dk, hv * dv
-        with jax.named_scope("hvd_gdn"):
-            qkvz = _dense(2 * key_dim + 2 * value_dim, "in_proj_qkvz",
-                          self)(x)
-            ba = _dense(2 * hv, "in_proj_ba", self)(x)
-            qkv, z = qkvz[..., :2 * key_dim + value_dim], \
-                qkvz[..., 2 * key_dim + value_dim:]
-            with jax.named_scope("hvd_gdn_conv"):
+        with jax.named_scope(scopes.GDN):
+            with jax.named_scope(scopes.GDN_IN):
+                qkvz = _dense(2 * key_dim + 2 * value_dim, "in_proj_qkvz",
+                              self)(x)
+                ba = _dense(2 * hv, "in_proj_ba", self)(x)
+                qkv, z = qkvz[..., :2 * key_dim + value_dim], \
+                    qkvz[..., 2 * key_dim + value_dim:]
+            with jax.named_scope(scopes.GDN_CONV):
                 kernel = self.param(
                     "conv1d", _normal(),
                     (self.conv_kernel, 2 * key_dim + value_dim),
                     self.param_dtype)
                 qkv = jax.nn.silu(causal_depthwise_conv(
                     qkv, kernel.astype(self.dtype)))
-            q = qkv[..., :key_dim].reshape(b, s, hk, dk)
-            k = qkv[..., key_dim:2 * key_dim].reshape(b, s, hk, dk)
-            v = qkv[..., 2 * key_dim:].reshape(b, s, hv, dv)
-            a_log = self.param(
-                "A_log", lambda key, shape, dtype: jnp.log(
-                    jax.random.uniform(key, shape, dtype, 1e-3, 16.0)),
-                (hv,), self.param_dtype)
-            dt_bias = self.param("dt_bias", nn.initializers.ones, (hv,),
-                                 self.param_dtype)
-            beta = jax.nn.sigmoid(ba[..., :hv].astype(_F32))
-            g = -jnp.exp(a_log.astype(_F32)) * jax.nn.softplus(
-                ba[..., hv:].astype(_F32) + dt_bias.astype(_F32))
+            # what prepares the scan's operands is the input side's too
+            with jax.named_scope(scopes.GDN_IN):
+                q = qkv[..., :key_dim].reshape(b, s, hk, dk)
+                k = qkv[..., key_dim:2 * key_dim].reshape(b, s, hk, dk)
+                v = qkv[..., 2 * key_dim:].reshape(b, s, hv, dv)
+                a_log = self.param(
+                    "A_log", lambda key, shape, dtype: jnp.log(
+                        jax.random.uniform(key, shape, dtype, 1e-3, 16.0)),
+                    (hv,), self.param_dtype)
+                dt_bias = self.param("dt_bias", nn.initializers.ones, (hv,),
+                                     self.param_dtype)
+                beta = jax.nn.sigmoid(ba[..., :hv].astype(_F32))
+                g = -jnp.exp(a_log.astype(_F32)) * jax.nn.softplus(
+                    ba[..., hv:].astype(_F32) + dt_bias.astype(_F32))
 
-            def l2(t):
-                t = t.astype(_F32)
-                return t * jax.lax.rsqrt(
-                    jnp.sum(jnp.square(t), axis=-1, keepdims=True) + self.eps)
+                def l2(t):
+                    t = t.astype(_F32)
+                    return t * jax.lax.rsqrt(
+                        jnp.sum(jnp.square(t), axis=-1, keepdims=True)
+                        + self.eps)
 
-            q = (l2(q) * dk ** -0.5).astype(self.dtype)
-            k = l2(k).astype(self.dtype)
+                q = (l2(q) * dk ** -0.5).astype(self.dtype)
+                k = l2(k).astype(self.dtype)
             o = gated_delta_rule(q, k, v, g, beta, chunk=self.chunk)
-            w = self.param("norm", nn.initializers.ones, (dv,),
-                           self.param_dtype)
-            z = z.reshape(b, s, hv, dv).astype(_F32)
-            o = (w.astype(_F32) * rms_normalise(o, self.eps)
-                 * jax.nn.silu(z)).astype(self.dtype)
-            return _dense(d, "out_proj", self)(o.reshape(b, s, value_dim))
+            with jax.named_scope(scopes.GDN_OUT):
+                w = self.param("norm", nn.initializers.ones, (dv,),
+                               self.param_dtype)
+                z = z.reshape(b, s, hv, dv).astype(_F32)
+                o = (w.astype(_F32) * rms_normalise(o, self.eps)
+                     * jax.nn.silu(z)).astype(self.dtype)
+                return _dense(d, "out_proj", self)(
+                    o.reshape(b, s, value_dim))
 
 
 class SparseMoe(nn.Module):
@@ -244,7 +265,7 @@ class SparseMoe(nn.Module):
     def __call__(self, x):
         b, s, d = x.shape
         flat = x.reshape(b * s, d)
-        with jax.named_scope("hvd_moe"):
+        with jax.named_scope(scopes.MOE):
             router = self.param("gate", _normal(), (d, self.router_experts),
                                 self.param_dtype)
             shapes = {"gate_proj": (self.num_experts, d, self.expert_dim),
@@ -255,7 +276,7 @@ class SparseMoe(nn.Module):
                        for name, shape in shapes.items()}
             routed = routed_experts(flat, router, experts, top_k=self.top_k,
                                     first_expert=self.first_expert)
-            with jax.named_scope("hvd_moe_shared"):
+            with jax.named_scope(scopes.MOE_SHARED):
                 hidden = jax.nn.silu(
                     _dense(self.shared_dim, "shared_gate_proj", self)(flat)) \
                     * _dense(self.shared_dim, "shared_up_proj", self)(flat)
@@ -288,6 +309,20 @@ class DecoderLayer(nn.Module):
         x = x + h
         h = RMSNorm(self.eps, name="post_attention_layernorm", **kw)(x)
         return x + SparseMoe(name="mlp", **self.moe, **kw)(h)
+
+
+def lm_head(model: nn.Module, x, eps: float, norm_cls=RMSNorm):
+    """The final norm and the untied head under ``hvd_head``: ``x`` ``[b, s,
+    d]`` -> logits ``[b, s, vocab_size]`` float32.  For a compact ``model``
+    with ``hidden_size``, ``vocab_size``, ``dtype`` and ``param_dtype``."""
+    with jax.named_scope(scopes.HEAD):
+        x = norm_cls(eps, name="norm", dtype=model.dtype,
+                     param_dtype=model.param_dtype)(x)
+        head = model.param("lm_head", _normal(),
+                           (model.hidden_size, model.vocab_size),
+                           model.param_dtype)
+        return jnp.dot(x, head.astype(model.dtype),
+                       preferred_element_type=_F32)
 
 
 class Qwen3Next(nn.Module):
@@ -350,12 +385,7 @@ class Qwen3Next(nn.Module):
                 full_attention=(i + 1) % self.full_attention_interval == 0,
                 attention=attention, linear_attention=linear_attention,
                 moe=moe, eps=self.rms_norm_eps, name=f"layers_{i}", **kw)(x)
-        x = RMSNorm(self.rms_norm_eps, name="norm", **kw)(x)
-        head = self.param("lm_head", _normal(),
-                          (self.hidden_size, self.vocab_size),
-                          self.param_dtype)
-        return jnp.dot(x, head.astype(self.dtype),
-                       preferred_element_type=_F32)
+        return lm_head(self, x, self.rms_norm_eps)
 
 
 def qwen3_next_tiny(**kw):

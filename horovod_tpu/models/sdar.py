@@ -43,10 +43,12 @@ recomputes each decoder layer in the backward pass
 (``qwen3_next.recomputed``: the layers' inputs are kept and the flash
 forward kernel's output and row statistics, which its backward kernels
 read, so a layer calls the forward kernel once a step).  Device
-scopes (docs/profiling.md): ``hvd_bd_noise`` (the compare, the
-substitution, the concatenation, the position ids), ``hvd_bd_head_rows``
-(the slice before the head), ``hvd_flash_*``, ``hvd_moe`` (``hvd_moe_route``,
-``hvd_moe_experts``); counter ``hvd_bd_layers_traced_total{block}``.
+scopes (``models/scopes.py``, docs/profiling.md): ``hvd_bd_noise`` (the
+compare, the substitution, the concatenation, the position ids),
+``hvd_attn`` (``hvd_attn_qkv``, ``hvd_flash_*``, ``hvd_attn_out``),
+``hvd_moe`` (``hvd_moe_route``, ``hvd_moe_experts``), ``hvd_bd_head_rows``
+(the slice before the head), ``hvd_head``; counter
+``hvd_bd_layers_traced_total{block}``.
 """
 
 from __future__ import annotations
@@ -60,8 +62,9 @@ from flax import linen as nn
 from .. import metrics
 from ..ops.flash_attention import block_diffusion_mask, flash_attention
 from ..parallel.moe import grouped_routed_experts
+from . import scopes
 from .gpt import weighted_token_loss
-from .qwen3_next import (_dense, _normal, apply_rotary, recomputed,
+from .qwen3_next import (_dense, _normal, apply_rotary, lm_head, recomputed,
                          rms_normalise, rotary_tables)
 
 _F32 = jnp.float32
@@ -108,22 +111,30 @@ class BlockDiffusionAttention(nn.Module):
         b, rows, _ = x.shape
         h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
         metrics.record_bd_layer(self.block_length)
-        q = _dense(h * hd, "q_proj", self)(x).reshape(b, rows, h, hd)
-        k = _dense(kv * hd, "k_proj", self)(x).reshape(b, rows, kv, hd)
-        v = _dense(kv * hd, "v_proj", self)(x).reshape(b, rows, kv, hd)
-        # the projections' scale cancels in these norms: their weights are
-        # the softmax's temperature (the scores' deviation is w_q * w_k)
-        norm = dict(eps=self.eps, init=self.qk_norm_init, dtype=self.dtype,
-                    param_dtype=self.param_dtype)
-        q = RMSNorm(name="q_norm", **norm)(q)
-        k = RMSNorm(name="k_norm", **norm)(k)
-        q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
-        # each kv head serves h // kv consecutive q heads; the kernels take
-        # equal head counts, so k and v are repeated outside them
-        k, v = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
-        o = flash_attention(q, k, v, mask=block_diffusion_mask(
-            self.block_length, rows // 2))
-        return _dense(x.shape[-1], "o_proj", self)(o.reshape(b, rows, h * hd))
+        with jax.named_scope(scopes.ATTN):
+            with jax.named_scope(scopes.ATTN_QKV):
+                q = _dense(h * hd, "q_proj", self)(x).reshape(b, rows, h, hd)
+                k = _dense(kv * hd, "k_proj", self)(x).reshape(
+                    b, rows, kv, hd)
+                v = _dense(kv * hd, "v_proj", self)(x).reshape(
+                    b, rows, kv, hd)
+                # the projections' scale cancels in these norms: their
+                # weights are the softmax's temperature (the scores'
+                # deviation is w_q * w_k)
+                norm = dict(eps=self.eps, init=self.qk_norm_init,
+                            dtype=self.dtype, param_dtype=self.param_dtype)
+                q = RMSNorm(name="q_norm", **norm)(q)
+                k = RMSNorm(name="k_norm", **norm)(k)
+                q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
+                # each kv head serves h // kv consecutive q heads; the
+                # kernels take equal head counts, so k and v are repeated
+                # outside them
+                k, v = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
+            o = flash_attention(q, k, v, mask=block_diffusion_mask(
+                self.block_length, rows // 2))
+            with jax.named_scope(scopes.ATTN_OUT):
+                return _dense(x.shape[-1], "o_proj", self)(
+                    o.reshape(b, rows, h * hd))
 
 
 class RoutedMoe(nn.Module):
@@ -145,7 +156,7 @@ class RoutedMoe(nn.Module):
     @nn.compact
     def __call__(self, x):
         d = x.shape[-1]
-        with jax.named_scope("hvd_moe"):
+        with jax.named_scope(scopes.MOE):
             router = self.param("gate", _normal(), (d, self.router_experts),
                                 self.param_dtype)
             shapes = {"gate_proj": (self.num_experts, d, self.expert_dim),
@@ -215,7 +226,7 @@ class SDAR(nn.Module):
             raise ValueError(
                 f"{level.shape[1]} noise levels a row do not cover "
                 f"{length} tokens in blocks of {self.block_length}")
-        with jax.named_scope("hvd_bd_noise"):
+        with jax.named_scope(scopes.BD_NOISE):
             mask_id = self.mask_token_id % self.vocab_size
             noised = jnp.where(noised_tokens(ids, level, draw), mask_id, ids)
             rows = jnp.concatenate([noised, ids], axis=1)
@@ -229,7 +240,7 @@ class SDAR(nn.Module):
         takes the two copies as a caller made them."""
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         length = rows.shape[1] // 2
-        with jax.named_scope("hvd_bd_noise"):
+        with jax.named_scope(scopes.BD_NOISE):
             # both copies carry the data's positions
             positions = jnp.tile(jnp.arange(length), 2)
             cos, sin = rotary_tables(positions, self.head_dim,
@@ -253,15 +264,10 @@ class SDAR(nn.Module):
             x = layer_cls(attention=attention, moe=moe,
                           eps=self.rms_norm_eps, name=f"layers_{i}",
                           **kw)(x, cos, sin)
-        with jax.named_scope("hvd_bd_head_rows"):
+        with jax.named_scope(scopes.BD_HEAD_ROWS):
             # the clean copy's rows predict nothing
             x = x[:, :length]
-        x = RMSNorm(self.rms_norm_eps, name="norm", **kw)(x)
-        head = self.param("lm_head", _normal(),
-                          (self.hidden_size, self.vocab_size),
-                          self.param_dtype)
-        return jnp.dot(x, head.astype(self.dtype),
-                       preferred_element_type=_F32)
+        return lm_head(self, x, self.rms_norm_eps, RMSNorm)
 
 
 def block_diffusion_loss(logits, batch):

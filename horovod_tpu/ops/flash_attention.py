@@ -180,6 +180,12 @@ DKV_KERNEL = "hvd_flash_dkv"
 # without a second forward kernel call.
 FLASH_OUT = "hvd_flash_out"
 FLASH_LSE = "hvd_flash_lse"
+# What :func:`flash_attention` does round its kernels, under one scope: the
+# swaps between the model's ``[b, s, h, d]`` and the kernels' ``[b, h, s,
+# d]``, the rows' log-sum-exp from the forward kernel's statistics, and
+# the backward pass's ``delta`` (the row sums of ``do * o``).  A scope is
+# metadata: the program lowers as without it.
+LAYOUT_SCOPE = "hvd_flash_layout"
 
 
 class Mask(NamedTuple):
@@ -987,7 +993,8 @@ def _flash_fn(mask, scale, block_q, block_k, interpret, static_offs):
         o, m, l = _mha_fwd(q, k, v, offs, normalize=True, **kw)
         # kept [b,h,sq]: a [b,h,sq,1] float32 array pads every row to a
         # tile of 128 lanes in HBM
-        lse = (m + jnp.log(jnp.maximum(l, 1e-30)))[..., 0]
+        with jax.named_scope(LAYOUT_SCOPE):
+            lse = (m + jnp.log(jnp.maximum(l, 1e-30)))[..., 0]
         # what the forward kernel wrote and the backward kernels read: a
         # checkpoint whose policy saves these names runs the kernel once
         o = checkpoint_name(o, FLASH_OUT)
@@ -997,10 +1004,12 @@ def _flash_fn(mask, scale, block_q, block_k, interpret, static_offs):
 
     def bwd(res, do):
         q, k, v, o, lse, offs = res
-        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=-1)
-        dq = _mha_bwd_dq(q, k, v, do, lse[..., None], delta[..., None],
-                         offs, out_dtype=q.dtype, **kw)
+        with jax.named_scope(LAYOUT_SCOPE):
+            delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                            axis=-1)
+            lse_column, delta_column = lse[..., None], delta[..., None]
+        dq = _mha_bwd_dq(q, k, v, do, lse_column, delta_column, offs,
+                         out_dtype=q.dtype, **kw)
         dk, dv = _mha_bwd_dkv(q, k, v, do, lse, delta, offs,
                               out_dtype=k.dtype, **kw)
         return (dq, dk, dv.astype(v.dtype),
@@ -1047,11 +1056,13 @@ def flash_attention(q, k, v, *, causal: bool = False,
     fn = _flash_fn(mask, float(scale), int(block_q or default_q),
                    int(block_k or default_k), _resolve_interpret(interpret),
                    static_offs)
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
+    with jax.named_scope(LAYOUT_SCOPE):
+        qt = jnp.swapaxes(q, 1, 2)
+        kt = jnp.swapaxes(k, 1, 2)
+        vt = jnp.swapaxes(v, 1, 2)
     o = fn(qt, kt, vt, _offsets(q_offset, kv_offset))
-    return jnp.swapaxes(o, 1, 2).astype(q.dtype)
+    with jax.named_scope(LAYOUT_SCOPE):
+        return jnp.swapaxes(o, 1, 2).astype(q.dtype)
 
 
 def softmax_attention(q, k, v, *, causal: bool = False,

@@ -78,7 +78,11 @@ def rng():
 #: carries one accumulator of the experts' gradients through a layer's groups:
 #: ``sdar-bd4-8k``'s compiled step holds 9.055 GB, two 302 MB temporaries
 #: under the band PR 33 predicted; ``test_benchmark_moe_groups_v5e.py`` holds
-#: the new value and what the loops carry.  Strict,
+#: the new value and what the loops carry.  PR 36 appends nine readers of the
+#: decoder layers' part scopes: three tests pin what ``sdar-bd4-8k`` and
+#: ``kanana2-8k`` list and PR 34's seven as the last entries
+#: (``test_benchmark_part_scopes.py`` ends with the same assertions brought up
+#: to date, and asserts its own entries by name).  Strict,
 #: so that the `benchmark` PR which brings the pins up to date has to take
 #: this list out with them.
 PINNED_TO_AN_EARLIER_BENCHMARK = {
@@ -121,6 +125,13 @@ PINNED_TO_AN_EARLIER_BENCHMARK = {
     "test_what_is_kept_fits_beside_the_benchmarks_weights[sdar-bd4-8k]":
         "one accumulator a layer, not one a group: hbm_gb 9.055, under the "
         "band round 9.665",
+    "test_benchmark_sdar.py::test_what_the_two_new_cells_report":
+        "sdar-bd4-8k lists five of PR 36's readers of the part scopes too",
+    "test_benchmark_kanana2.py::"
+    "test_which_cells_list_which_metrics_after_pr_34":
+        "PR 34's seven readers are no longer the last: PR 36's nine follow",
+    "test_benchmark_kanana2.py::test_what_the_new_cell_reports":
+        "kanana2-8k lists six of PR 36's readers of the part scopes too",
 }
 
 
