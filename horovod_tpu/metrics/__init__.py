@@ -200,6 +200,13 @@ KERNEL_RESIDUAL_BYTES = registry.counter(
     "flash (the output and the rows' log-sum-exp) or gdn_scan (the output, "
     "the chunks' start states and inverses) -- what a recomputed layer "
     "that keeps them pays in memory.", ("kernel",))
+RECOMPUTE_KEPT_BYTES = registry.counter(
+    "hvd_recompute_kept_bytes_traced_total",
+    "Bytes a traced model's recomputed decoder layers keep beyond the "
+    "Pallas kernels' residuals (models/recompute.py; per compile, not per "
+    "step, over all the model's layers), by the checkpoint name kept; "
+    "name=\"skipped\" is what the byte budget refused and the layers "
+    "still make a second time.", ("name",))
 BD_LAYERS = registry.counter(
     "hvd_bd_layers_traced_total",
     "Block-diffusion attention layers traced (models/sdar.py; per compile, "
@@ -575,6 +582,20 @@ def record_kernel_residual_bytes(kernel: str, nbytes: int) -> None:
         return
     try:
         KERNEL_RESIDUAL_BYTES.labels(kernel).inc(nbytes)
+    except Exception:  # noqa: BLE001 — tracing must never fail on metrics
+        pass
+
+
+def record_recompute_kept(kept: dict, skipped: int) -> None:
+    """One traced model with recomputed layers (models/recompute.py):
+    ``kept`` is ``{checkpoint name: bytes over all the layers}`` of what
+    the budget let the layers keep, ``skipped`` the bytes it refused."""
+    if not registry.enabled:
+        return
+    try:
+        for name, nbytes in kept.items():
+            RECOMPUTE_KEPT_BYTES.labels(name).inc(nbytes)
+        RECOMPUTE_KEPT_BYTES.labels("skipped").inc(skipped)
     except Exception:  # noqa: BLE001 — tracing must never fail on metrics
         pass
 

@@ -36,8 +36,18 @@ any projection.
 
 bf16 compute / float32 parameters like the other families.  ``remat``
 recomputes each decoder layer in the backward pass
-(``qwen3_next.recomputed``: the flash forward kernel's output and row
-statistics are kept, so a layer calls it once a step).  Device scopes
+(``models/recompute.recomputed``): the flash forward kernel's output and
+row statistics are always kept, so a layer calls it once a step, and of
+the other outputs a second run would make again what fits the byte budget
+``recompute`` reckons from the device's memory and the shapes
+(:meth:`Kanana2.recompute_parts`), in rank order: the router's logits,
+picks and order, ``o_proj``'s output, ``q_proj``'s, q as the kernels take
+it (after its rotary part and the swap), the SwiGLUs' gate and up,
+``kv_a_proj_with_mqa``'s output, k and v as the kernels take them (with
+those kept ``kv_b_proj``'s output is read by nothing in the backward pass
+and has no name).  A part kept has no op with ``rematted_computation`` on
+its path; counter ``hvd_recompute_kept_bytes_traced_total{name}``.  Device
+scopes
 (``models/scopes.py``, docs/profiling.md): ``hvd_mla`` (``hvd_mla_q``,
 ``hvd_mla_latent``, the kernels' own, ``hvd_mla_out``), ``hvd_dense_mlp``,
 ``hvd_moe`` (``hvd_moe_route``, ``hvd_moe_experts``, ``hvd_moe_shared``),
@@ -52,12 +62,16 @@ from typing import Any, Optional, Sequence
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.ad_checkpoint import checkpoint_name
 
 from .. import metrics
+from ..ops import flash_attention as flash
 from ..ops.flash_attention import flash_attention
+from ..parallel import moe
 from ..parallel.moe import grouped_routed_experts, route_sigmoid_top_k
 from . import scopes
-from .qwen3_next import _dense, _normal, lm_head, recomputed
+from .qwen3_next import _dense, _normal, lm_head
+from .recompute import recomputed
 from .sdar import RMSNorm
 
 _F32 = jnp.float32
@@ -99,18 +113,19 @@ class LatentAttention(nn.Module):
         metrics.record_mla_layer(nope + rope, dv, self.kv_lora_rank)
         with jax.named_scope(scopes.MLA):
             with jax.named_scope(scopes.MLA_Q):
-                q = nn.Dense(
+                q = checkpoint_name(nn.Dense(
                     h * (nope + rope), use_bias=False, dtype=self.dtype,
                     param_dtype=self.param_dtype,
-                    kernel_init=_normal(self.q_init_std), name="q_proj")(
-                        x).reshape(b, s, h, nope + rope)
+                    kernel_init=_normal(self.q_init_std), name="q_proj")(x),
+                    scopes.KEEP_Q_PROJ).reshape(b, s, h, nope + rope)
                 q = jnp.concatenate([q[..., :nope], interleaved_rotary(
                     q[..., nope:], positions, self.rope_theta).astype(
                         self.dtype)], axis=-1)
             # what latent attention costs beyond a plain k / v projection
             with jax.named_scope(scopes.MLA_LATENT):
-                ckr = _dense(self.kv_lora_rank + rope, "kv_a_proj_with_mqa",
-                             self)(x)
+                ckr = checkpoint_name(
+                    _dense(self.kv_lora_rank + rope, "kv_a_proj_with_mqa",
+                           self)(x), scopes.KEEP_KV_PROJ)
                 c = RMSNorm(self.eps, name="kv_a_layernorm",
                             dtype=self.dtype, param_dtype=self.param_dtype)(
                                 ckr[..., :self.kv_lora_rank])
@@ -125,13 +140,17 @@ class LatentAttention(nn.Module):
                 v = kv[..., nope:]
             o = flash_attention(q, k, v, causal=True)
             with jax.named_scope(scopes.MLA_OUT):
-                return _dense(d, "o_proj", self)(o.reshape(b, s, h * dv))
+                return checkpoint_name(
+                    _dense(d, "o_proj", self)(o.reshape(b, s, h * dv)),
+                    scopes.KEEP_OUT_PROJ)
 
 
 def _swiglu(module: nn.Module, x, width: int, prefix: str = ""):
-    hidden = jax.nn.silu(_dense(width, prefix + "gate_proj", module)(x)) \
-        * _dense(width, prefix + "up_proj", module)(x)
-    return _dense(x.shape[-1], prefix + "down_proj", module)(hidden)
+    gate, up = (checkpoint_name(_dense(width, prefix + name, module)(x),
+                                scopes.KEEP_MLP)
+                for name in ("gate_proj", "up_proj"))
+    return _dense(x.shape[-1], prefix + "down_proj", module)(
+        jax.nn.silu(gate) * up)
 
 
 class DenseMlp(nn.Module):
@@ -245,13 +264,46 @@ class Kanana2(nn.Module):
     dtype: Any = jnp.bfloat16
     param_dtype: Any = _F32
 
+    def recompute_parts(self, b: int, s: int):
+        """``(parts, held)`` for :func:`recompute.recomputed` over ``[b,
+        s]`` ids: the bytes each name would keep over the layers that have
+        it, and the activations the step holds whatever is kept (the
+        layers' inputs, the flash kernels' residuals, the logits)."""
+        rows, size = b * s, jnp.dtype(self.dtype).itemsize
+        layers = self.num_layers
+        dense = min(self.first_dense_layers, layers)
+        qk = self.num_heads * (self.qk_nope_head_dim + self.qk_rope_head_dim)
+        shared = self.num_shared_experts * self.moe_intermediate_size
+        parts = {
+            moe.ROUTING: (layers - dense) * moe.routing_bytes(
+                rows, self.router_experts, self.num_experts_per_tok),
+            scopes.KEEP_OUT_PROJ: layers * rows * self.hidden_size * size,
+            scopes.KEEP_Q_PROJ: layers * rows * qk * size,
+            flash.FLASH_Q: layers * rows * qk * size,
+            scopes.KEEP_MLP: rows * 2 * size * (
+                dense * self.intermediate_size + (layers - dense) * shared),
+            scopes.KEEP_KV_PROJ: layers * rows * size
+            * (self.kv_lora_rank + self.qk_rope_head_dim),
+            flash.FLASH_K: layers * rows * qk * size,
+            flash.FLASH_V: layers * rows * size
+            * self.num_heads * self.v_head_dim,
+        }
+        held = (layers * (rows * self.hidden_size * size
+                            + flash.residual_bytes(b, self.num_heads, s,
+                                                   self.v_head_dim, size))
+                + rows * self.vocab_size * 4)
+        return parts, held
+
     @nn.compact
     def __call__(self, ids):
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         x = nn.Embed(self.vocab_size, self.hidden_size,
                      embedding_init=_normal(), name="embed_tokens",
                      **kw)(ids)
-        layer_cls = recomputed(DecoderLayer) if self.remat else DecoderLayer
+        layer_cls = DecoderLayer
+        if self.remat:
+            layer_cls = recomputed(
+                DecoderLayer, self, *self.recompute_parts(*ids.shape))
         attention = dict(
             num_heads=self.num_heads,
             qk_nope_head_dim=self.qk_nope_head_dim,

@@ -29,10 +29,21 @@ on the DeltaNet output, which has a plain weight and is gated:
   ``first_expert``) plus ``sigmoid(x w_g) * shared_expert(x)``.
 
 bf16 compute / float32 parameters like the other families.  ``remat``
-recomputes each decoder layer in the backward pass (:func:`recomputed`):
-the layers' inputs are kept and, of what is inside a layer, only what the
-flash and scan forward kernels wrote for their backward kernels, so a
-layer calls each forward kernel once a step.  Device scopes
+recomputes each decoder layer in the backward pass
+(``models/recompute.recomputed``): the layers' inputs are kept, always
+what the flash and scan forward kernels wrote for their backward kernels
+(a layer calls each forward kernel once a step) and, of the other outputs
+a second run would make again, what fits the byte budget ``recompute``
+reckons from the device's memory and the shapes the model is applied to
+(:meth:`Qwen3Next.recompute_parts`), in rank order: the router's logits,
+picks and order (``parallel/moe.ROUTING``), the gated norm's output, the
+output projections', ``q_proj``'s, q as the flash kernels take it, the
+shared expert's gate and up, ``in_proj_qkvz`` / ``_ba``, ``k_proj`` /
+``v_proj``, the scan's operands, the convolution's output, k and v as the
+flash kernels take them (names: ``models/scopes.py`` ``KEEP_*`` and the
+kernels' own).  A part kept has no op with ``rematted_computation`` on its
+path; counter ``hvd_recompute_kept_bytes_traced_total{name}`` says what
+was kept and what was skipped.  Device scopes
 (``models/scopes.py``, docs/profiling.md): ``hvd_attn`` (``hvd_attn_qkv``,
 the flash kernels' own, ``hvd_attn_out``), ``hvd_gdn`` (``hvd_gdn_in``,
 ``hvd_gdn_conv``, ``hvd_gdn_scan``, ``hvd_gdn_out``), ``hvd_moe``
@@ -46,12 +57,16 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.ad_checkpoint import checkpoint_name
 
-from ..ops.flash_attention import FLASH_LSE, FLASH_OUT, flash_attention
-from ..ops.gated_delta import (GDN_INVERSES, GDN_OUT, GDN_STATES,
-                               gated_delta_rule)
+from ..ops import flash_attention as flash
+from ..ops import gated_delta as gdn
+from ..ops.flash_attention import flash_attention
+from ..ops.gated_delta import gated_delta_rule
+from ..parallel import moe
 from ..parallel.moe import routed_experts
 from . import scopes
+from .recompute import recomputed
 
 _F32 = jnp.float32
 #: what JAX (0.9.0) writes on the path of every op a ``jax.checkpoint``
@@ -69,21 +84,6 @@ def flash_blocks(head_dim: int) -> dict:
     16 MiB of VMEM, the compiler's whole limit: the step compiled or not
     by where XLA put the kernel's outputs.  512-row tiles there."""
     return {"block_q": 512} if head_dim > 128 else {}
-
-
-def recomputed(layer_cls):
-    """``layer_cls`` recomputed in the backward pass (``nn.remat``) with
-    the one thing kept that costs a kernel call to make again and nothing
-    to keep but memory: what the Pallas forward kernels wrote for their
-    backward kernels (flash's output and row statistics; the scan's output,
-    chunk states and inverses).  Those kernels' other residuals (q, k, v,
-    g, beta) come out of projections the recompute runs anyway, so the
-    recomputed layer calls no forward kernel.  Projections, norms, rotary,
-    the k / v repeats, the convolution and the expert layer are recomputed
-    from the layer's input."""
-    return nn.remat(
-        layer_cls, policy=jax.checkpoint_policies.save_only_these_names(
-            FLASH_OUT, FLASH_LSE, GDN_OUT, GDN_STATES, GDN_INVERSES))
 
 
 def _normal(std: float = 0.02):
@@ -152,11 +152,14 @@ class GatedAttention(nn.Module):
         h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
         with jax.named_scope(scopes.ATTN):
             with jax.named_scope(scopes.ATTN_QKV):
-                qg = _dense(h * hd * 2, "q_proj", self)(x).reshape(
-                    b, s, h, 2 * hd)
+                qg = checkpoint_name(
+                    _dense(h * hd * 2, "q_proj", self)(x),
+                    scopes.KEEP_Q_PROJ).reshape(b, s, h, 2 * hd)
                 q, gate = qg[..., :hd], qg[..., hd:]
-                k = _dense(kv * hd, "k_proj", self)(x).reshape(b, s, kv, hd)
-                v = _dense(kv * hd, "v_proj", self)(x).reshape(b, s, kv, hd)
+                k, v = (checkpoint_name(
+                    _dense(kv * hd, name, self)(x),
+                    scopes.KEEP_KV_PROJ).reshape(b, s, kv, hd)
+                    for name in ("k_proj", "v_proj"))
                 norm = dict(eps=self.eps, dtype=self.dtype,
                             param_dtype=self.param_dtype)
                 q = RMSNorm(name="q_norm", **norm)(q)
@@ -171,8 +174,9 @@ class GatedAttention(nn.Module):
             o = flash_attention(q, k, v, causal=True, **flash_blocks(hd))
             with jax.named_scope(scopes.ATTN_OUT):
                 o = o * jax.nn.sigmoid(gate.astype(_F32)).astype(self.dtype)
-                return _dense(x.shape[-1], "o_proj", self)(
-                    o.reshape(b, s, h * hd))
+                return checkpoint_name(
+                    _dense(x.shape[-1], "o_proj", self)(
+                        o.reshape(b, s, h * hd)), scopes.KEEP_OUT_PROJ)
 
 
 def causal_depthwise_conv(x, kernel):
@@ -203,9 +207,11 @@ class GatedDeltaNet(nn.Module):
         key_dim, value_dim = hk * dk, hv * dv
         with jax.named_scope(scopes.GDN):
             with jax.named_scope(scopes.GDN_IN):
-                qkvz = _dense(2 * key_dim + 2 * value_dim, "in_proj_qkvz",
-                              self)(x)
-                ba = _dense(2 * hv, "in_proj_ba", self)(x)
+                qkvz, ba = (checkpoint_name(
+                    _dense(width, name, self)(x), scopes.KEEP_GDN_IN_PROJ)
+                    for name, width in (
+                        ("in_proj_qkvz", 2 * key_dim + 2 * value_dim),
+                        ("in_proj_ba", 2 * hv)))
                 qkv, z = qkvz[..., :2 * key_dim + value_dim], \
                     qkvz[..., 2 * key_dim + value_dim:]
             with jax.named_scope(scopes.GDN_CONV):
@@ -213,8 +219,10 @@ class GatedDeltaNet(nn.Module):
                     "conv1d", _normal(),
                     (self.conv_kernel, 2 * key_dim + value_dim),
                     self.param_dtype)
-                qkv = jax.nn.silu(causal_depthwise_conv(
-                    qkv, kernel.astype(self.dtype)))
+                # SiLU's derivative reads the convolution's output, and its
+                # own output is elementwise in it
+                qkv = jax.nn.silu(checkpoint_name(causal_depthwise_conv(
+                    qkv, kernel.astype(self.dtype)), scopes.KEEP_GDN_CONV))
             # what prepares the scan's operands is the input side's too
             with jax.named_scope(scopes.GDN_IN):
                 q = qkv[..., :key_dim].reshape(b, s, hk, dk)
@@ -243,10 +251,13 @@ class GatedDeltaNet(nn.Module):
                 w = self.param("norm", nn.initializers.ones, (dv,),
                                self.param_dtype)
                 z = z.reshape(b, s, hv, dv).astype(_F32)
-                o = (w.astype(_F32) * rms_normalise(o, self.eps)
-                     * jax.nn.silu(z)).astype(self.dtype)
-                return _dense(d, "out_proj", self)(
-                    o.reshape(b, s, value_dim))
+                o = checkpoint_name(
+                    (w.astype(_F32) * rms_normalise(o, self.eps)
+                     * jax.nn.silu(z)).astype(self.dtype),
+                    scopes.KEEP_GDN_NORM)
+                return checkpoint_name(
+                    _dense(d, "out_proj", self)(o.reshape(b, s, value_dim)),
+                    scopes.KEEP_OUT_PROJ)
 
 
 class SparseMoe(nn.Module):
@@ -277,9 +288,11 @@ class SparseMoe(nn.Module):
             routed = routed_experts(flat, router, experts, top_k=self.top_k,
                                     first_expert=self.first_expert)
             with jax.named_scope(scopes.MOE_SHARED):
-                hidden = jax.nn.silu(
-                    _dense(self.shared_dim, "shared_gate_proj", self)(flat)) \
-                    * _dense(self.shared_dim, "shared_up_proj", self)(flat)
+                gate, up = (checkpoint_name(
+                    _dense(self.shared_dim, name, self)(flat),
+                    scopes.KEEP_MLP)
+                    for name in ("shared_gate_proj", "shared_up_proj"))
+                hidden = jax.nn.silu(gate) * up
                 shared = _dense(d, "shared_down_proj", self)(hidden)
                 gate = _dense(1, "shared_expert_gate", self)(flat)
                 shared = shared * jax.nn.sigmoid(
@@ -357,13 +370,56 @@ class Qwen3Next(nn.Module):
     dtype: Any = jnp.bfloat16
     param_dtype: Any = _F32
 
+    def recompute_parts(self, b: int, s: int):
+        """``(parts, held)`` for :func:`recompute.recomputed` over ``[b,
+        s]`` ids: the bytes each name would keep over the layers that have
+        it, and the activations the step holds whatever is kept (the
+        layers' inputs, the kernels' residuals, the logits)."""
+        rows, size = b * s, jnp.dtype(self.dtype).itemsize
+        full = self.num_layers // self.full_attention_interval
+        linear = self.num_layers - full
+        d, q = self.hidden_size, self.num_heads * self.head_dim
+        kv = self.num_kv_heads * self.head_dim
+        key = self.linear_num_key_heads * self.linear_key_head_dim
+        hv = self.linear_num_value_heads
+        value = hv * self.linear_value_head_dim
+        parts = {
+            moe.ROUTING: self.num_layers * moe.routing_bytes(
+                rows, self.router_experts, self.num_experts_per_tok),
+            scopes.KEEP_OUT_PROJ: self.num_layers * rows * d * size,
+            scopes.KEEP_MLP: self.num_layers * rows * size
+            * 2 * self.shared_expert_intermediate_size,
+            scopes.KEEP_Q_PROJ: full * rows * 2 * q * size,
+            scopes.KEEP_KV_PROJ: full * rows * 2 * kv * size,
+            flash.FLASH_Q: full * rows * q * size,
+            flash.FLASH_K: full * rows * q * size,
+            flash.FLASH_V: full * rows * q * size,
+            scopes.KEEP_GDN_IN_PROJ: linear * rows * size
+            * (2 * key + 2 * value + 2 * hv),
+            scopes.KEEP_GDN_CONV: linear * rows * (2 * key + value) * size,
+            gdn.GDN_IN: linear * rows
+            * ((2 * key + value) * size + 2 * hv * 4),
+            scopes.KEEP_GDN_NORM: linear * rows * value * size,
+        }
+        held = (self.num_layers * rows * d * size
+                + full * flash.residual_bytes(b, self.num_heads, s,
+                                              self.head_dim, size)
+                + linear * gdn.residual_bytes(
+                    b, s, hv, self.linear_key_head_dim,
+                    self.linear_value_head_dim, self.scan_chunk, size)
+                + rows * self.vocab_size * 4)
+        return parts, held
+
     @nn.compact
     def __call__(self, ids):
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         x = nn.Embed(self.vocab_size, self.hidden_size,
                      embedding_init=_normal(), name="embed_tokens",
                      **kw)(ids)
-        layer_cls = recomputed(DecoderLayer) if self.remat else DecoderLayer
+        layer_cls = DecoderLayer
+        if self.remat:
+            layer_cls = recomputed(
+                DecoderLayer, self, *self.recompute_parts(*ids.shape))
         attention = dict(
             num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
             head_dim=self.head_dim, rope_theta=self.rope_theta,

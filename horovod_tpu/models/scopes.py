@@ -45,6 +45,20 @@ HEAD = "hvd_head"
 BD_NOISE = "hvd_bd_noise"
 BD_HEAD_ROWS = "hvd_bd_head_rows"
 
+# What a recomputed decoder layer may keep besides the Pallas kernels'
+# residuals: ``jax.ad_checkpoint.checkpoint_name``s on the outputs the
+# second run would make again, at the place that makes each (an identity
+# outside a checkpoint).  ``models/recompute.py`` ranks them (with the
+# names the kernels and ``parallel/moe`` give their own residuals) and
+# keeps what fits its budget.
+KEEP_OUT_PROJ = "hvd_keep_out_proj"    # out_proj / o_proj's output
+KEEP_GDN_NORM = "hvd_keep_gdn_norm"    # the gated norm: out_proj's operand
+KEEP_Q_PROJ = "hvd_keep_q_proj"        # q_proj's output (with its gate)
+KEEP_KV_PROJ = "hvd_keep_kv_proj"      # k_proj, v_proj / kv_a_proj_with_mqa
+KEEP_GDN_IN_PROJ = "hvd_keep_gdn_in_proj"  # in_proj_qkvz, in_proj_ba
+KEEP_GDN_CONV = "hvd_keep_gdn_conv"    # the convolution's output, before SiLU
+KEEP_MLP = "hvd_keep_mlp"              # a SwiGLU's gate and up outputs
+
 #: ``ops/flash_attention.flash_attention``: its three kernels, and what it
 #: does round them (the layout swaps, the rows' log-sum-exp, ``delta``)
 FLASH_KERNELS = (_flash.FWD_KERNEL, _flash.DQ_KERNEL, _flash.DKV_KERNEL)

@@ -40,9 +40,17 @@ Every layer: ``x = x + attn(norm(x))``, ``x = x + moe(norm(x))``; RMSNorm is
 
 bf16 compute / float32 parameters like the other families.  ``remat``
 recomputes each decoder layer in the backward pass
-(``qwen3_next.recomputed``: the layers' inputs are kept and the flash
-forward kernel's output and row statistics, which its backward kernels
-read, so a layer calls the forward kernel once a step).  Device
+(``models/recompute.recomputed``): the layers' inputs are kept, always the
+flash forward kernel's output and row statistics, which its backward
+kernels read (a layer calls the forward kernel once a step), and of the
+other outputs a second run would make again what fits the byte budget
+``recompute`` reckons from the device's memory and the shapes
+(:meth:`SDAR.recompute_parts`), in rank order: the router's logits, picks
+and order, ``o_proj``'s output, ``q_proj``'s, q as the kernels take it,
+``k_proj`` / ``v_proj``'s, k and v as the kernels take them (at the
+benchmark's size the last two do not fit and their repeat and swap still
+run twice).  A part kept has no op with ``rematted_computation`` on its
+path; counter ``hvd_recompute_kept_bytes_traced_total{name}``.  Device
 scopes (``models/scopes.py``, docs/profiling.md): ``hvd_bd_noise`` (the
 compare, the substitution, the concatenation, the position ids),
 ``hvd_attn`` (``hvd_attn_qkv``, ``hvd_flash_*``, ``hvd_attn_out``),
@@ -58,14 +66,18 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.ad_checkpoint import checkpoint_name
 
 from .. import metrics
+from ..ops import flash_attention as flash
 from ..ops.flash_attention import block_diffusion_mask, flash_attention
+from ..parallel import moe
 from ..parallel.moe import grouped_routed_experts
 from . import scopes
 from .gpt import weighted_token_loss
-from .qwen3_next import (_dense, _normal, apply_rotary, lm_head, recomputed,
+from .qwen3_next import (_dense, _normal, apply_rotary, lm_head,
                          rms_normalise, rotary_tables)
+from .recompute import recomputed
 
 _F32 = jnp.float32
 #: a noise level is an integer in ``[0, LEVELS]``: mask probability
@@ -113,11 +125,13 @@ class BlockDiffusionAttention(nn.Module):
         metrics.record_bd_layer(self.block_length)
         with jax.named_scope(scopes.ATTN):
             with jax.named_scope(scopes.ATTN_QKV):
-                q = _dense(h * hd, "q_proj", self)(x).reshape(b, rows, h, hd)
-                k = _dense(kv * hd, "k_proj", self)(x).reshape(
-                    b, rows, kv, hd)
-                v = _dense(kv * hd, "v_proj", self)(x).reshape(
-                    b, rows, kv, hd)
+                q = checkpoint_name(
+                    _dense(h * hd, "q_proj", self)(x),
+                    scopes.KEEP_Q_PROJ).reshape(b, rows, h, hd)
+                k, v = (checkpoint_name(
+                    _dense(kv * hd, name, self)(x),
+                    scopes.KEEP_KV_PROJ).reshape(b, rows, kv, hd)
+                    for name in ("k_proj", "v_proj"))
                 # the projections' scale cancels in these norms: their
                 # weights are the softmax's temperature (the scores'
                 # deviation is w_q * w_k)
@@ -133,8 +147,9 @@ class BlockDiffusionAttention(nn.Module):
             o = flash_attention(q, k, v, mask=block_diffusion_mask(
                 self.block_length, rows // 2))
             with jax.named_scope(scopes.ATTN_OUT):
-                return _dense(x.shape[-1], "o_proj", self)(
-                    o.reshape(b, rows, h * hd))
+                return checkpoint_name(
+                    _dense(x.shape[-1], "o_proj", self)(
+                        o.reshape(b, rows, h * hd)), scopes.KEEP_OUT_PROJ)
 
 
 class RoutedMoe(nn.Module):
@@ -232,6 +247,30 @@ class SDAR(nn.Module):
             rows = jnp.concatenate([noised, ids], axis=1)
         return self.decode(rows)
 
+    def recompute_parts(self, b: int, s: int):
+        """``(parts, held)`` for :func:`recompute.recomputed` over ``[b,
+        s]`` rows (both copies): the bytes each name would keep over the
+        layers, and the activations the step holds whatever is kept (the
+        layers' inputs, the flash kernels' residuals, the noised half's
+        logits)."""
+        rows, size = b * s, jnp.dtype(self.dtype).itemsize
+        d, q = self.hidden_size, self.num_heads * self.head_dim
+        kv = self.num_kv_heads * self.head_dim
+        parts = {name: self.num_layers * n for name, n in {
+            moe.ROUTING: moe.routing_bytes(
+                rows, self.router_experts, self.num_experts_per_tok),
+            scopes.KEEP_OUT_PROJ: rows * d * size,
+            scopes.KEEP_Q_PROJ: rows * q * size,
+            scopes.KEEP_KV_PROJ: rows * 2 * kv * size,
+            flash.FLASH_Q: rows * q * size,
+            flash.FLASH_K: rows * q * size,
+            flash.FLASH_V: rows * q * size,
+        }.items()}
+        held = (self.num_layers * (rows * d * size + flash.residual_bytes(
+                    b, self.num_heads, s, self.head_dim, size))
+                + rows // 2 * self.vocab_size * 4)
+        return parts, held
+
     @nn.compact
     def decode(self, rows):
         """``rows`` ``[b, 2 L]``: the noised copy's ids, then the clean
@@ -248,7 +287,10 @@ class SDAR(nn.Module):
         x = nn.Embed(self.vocab_size, self.hidden_size,
                      embedding_init=_normal(), name="embed_tokens",
                      **kw)(rows)
-        layer_cls = recomputed(DecoderLayer) if self.remat else DecoderLayer
+        layer_cls = DecoderLayer
+        if self.remat:
+            layer_cls = recomputed(
+                DecoderLayer, self, *self.recompute_parts(*rows.shape))
         attention = dict(
             num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
             head_dim=self.head_dim, block_length=self.block_length,

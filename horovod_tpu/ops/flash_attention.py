@@ -1000,7 +1000,7 @@ def _flash_fn(mask, scale, block_q, block_k, interpret, static_offs):
         o = checkpoint_name(o, FLASH_OUT)
         lse = checkpoint_name(lse, FLASH_LSE)
         _count_residuals("flash", o, lse)
-        return o, (q, k, v, o, lse, offs)
+        return o, (*_named_inputs(q, k, v), o, lse, offs)
 
     def bwd(res, do):
         q, k, v, o, lse, offs = res
@@ -1081,3 +1081,36 @@ def softmax_attention(q, k, v, *, causal: bool = False,
                        -jnp.inf)
     p = jax.nn.softmax(sl, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+
+# ---------------------------------------------------------------------------
+# what a recomputed layer may keep besides ``FLASH_OUT`` and ``FLASH_LSE``
+# ---------------------------------------------------------------------------
+# Down here, and the ``fwd`` rule's use of it on the line that was there, so
+# that no line moves on the way from a caller to a kernel: a Mosaic body
+# carries the source lines of its call stack, and a model that keeps
+# nothing lowers to the bytes it had.
+
+# The backward kernels' other residuals: the forward's own inputs as the
+# kernels take them (``[b, h, s, d]``, after the caller's norms, rotary and
+# repeats and after the swap).  A checkpoint that saves these too does none
+# of that again (``models/recompute.py`` ranks them against its budget).
+FLASH_Q = "hvd_flash_q"
+FLASH_K = "hvd_flash_k"
+FLASH_V = "hvd_flash_v"
+
+
+def _named_inputs(q, k, v):
+    return (checkpoint_name(q, FLASH_Q), checkpoint_name(k, FLASH_K),
+            checkpoint_name(v, FLASH_V))
+
+
+def residual_bytes(b: int, h: int, s: int, dv: int, itemsize: int) -> int:
+    """Bytes a differentiated call at these sizes holds from the forward
+    pass to the backward beyond its inputs: ``o`` and ``lse`` (what
+    :func:`_count_residuals` counts) and the forward kernel's two row
+    statistics, ``[b, h, s, 1]`` float32 that XLA pads to 128 lanes and
+    makes ``lse`` from only just before the backward kernels (2.1 GB of
+    ``sdar-bd4-8k``'s step: PERF.md section 7)."""
+    return b * h * s * (dv * itemsize + 4 + 2 * 128 * 4)

@@ -97,6 +97,9 @@ BWD_KERNEL = "hvd_gdn_scan_bwd"
 GDN_OUT = "hvd_gdn_scan_out"
 GDN_STATES = "hvd_gdn_scan_states"
 GDN_INVERSES = "hvd_gdn_scan_inverses"
+# The backward kernel's other residuals: q, k, v, g and beta as the kernels
+# take them (``models/recompute.py`` ranks the name against its budget).
+GDN_IN = "hvd_gdn_scan_in"
 # Chunks a grid step walks: one chunk a step would be 36 864 grid steps a
 # training step of the benchmark's cell; 8 chunks of 64 are 512 rows a
 # block, 0.5 / 1 MB of VMEM an operand.
@@ -596,6 +599,8 @@ def _scan_fn(per_step, interpret):
         states = checkpoint_name(states, GDN_STATES)
         inverses = checkpoint_name(inverses, GDN_INVERSES)
         _count_residuals("gdn_scan", o, states, inverses)
+        q, k, v, g, beta = (checkpoint_name(x, GDN_IN)
+                            for x in (q, k, v, g, beta))
         return o, (q, k, v, g, beta, states, inverses)
 
     def bwd(res, do):
@@ -604,6 +609,16 @@ def _scan_fn(per_step, interpret):
 
     f.defvjp(fwd, bwd)
     return f
+
+
+def residual_bytes(b: int, s: int, hv: int, dk: int, dv: int, chunk: int,
+                   itemsize: int) -> int:
+    """Bytes a differentiated call at these sizes keeps for its backward
+    kernel beyond its inputs (what the ``fwd`` rule counts): ``o``, a
+    float32 ``dk x dv`` state a chunk and value head, and the chunks'
+    float32 inverses."""
+    return b * hv * (s * dv * itemsize + -(-s // chunk) * dk * dv * 4
+                     + s * chunk * 4)
 
 
 def _check_tiling(dtype, dk, dv, chunk):

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from .. import metrics
 
@@ -49,6 +50,14 @@ from .. import metrics
 # movement, and the grouped products.
 ROUTE_SCOPE = "hvd_moe_route"
 EXPERTS_SCOPE = "hvd_moe_experts"
+# What the routing's backward pass and the held part read, by
+# ``checkpoint_name`` (an identity outside a checkpoint): the router's
+# float32 logits over its full width, a token's picks and their scores, the
+# sorted order and the held experts' sizes.  A recomputed layer whose policy
+# saves the name (``models/recompute.py``) runs neither the router's
+# product, the top-k nor the sort again; under a loop over groups the kept
+# arrays are the loop's stacked outputs.
+ROUTING = "hvd_moe_routing"
 
 
 def top1_dispatch(gates: jnp.ndarray, capacity: int):
@@ -135,35 +144,84 @@ def moe_apply(expert_fn: Callable, expert_params, x, router_kernel, *,
 # ---------------------------------------------------------------------------
 
 
+def _logits(x, router_kernel):
+    """The router's outputs ``[n, E]`` float32, the product at ``highest``
+    precision (on a TPU it is otherwise one bfloat16 pass and flips picks);
+    named (:data:`ROUTING`): a rule's scores are elementwise in them."""
+    return checkpoint_name(
+        jnp.dot(x.astype(jnp.float32), router_kernel.astype(jnp.float32),
+                precision=lax.Precision.HIGHEST), ROUTING)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _top_k(scores, top_k: int):
+    """``lax.top_k`` over the last axis, ``(values, picks)``, whose backward
+    pass reads the *named* picks (:data:`ROUTING`, with the values).
+    ``lax.top_k``'s own derivative reads the picks of the very call it
+    differentiates, which no name reaches: a checkpoint that saved every
+    name would still run the top-k a second time to have them."""
+    return tuple(lax.top_k(scores, top_k))
+
+
+def _top_k_fwd(scores, top_k):
+    values, picks = (checkpoint_name(out, ROUTING)
+                     for out in lax.top_k(scores, top_k))
+    return (values, picks), (picks, scores)
+
+
+def _top_k_bwd(top_k, kept, cotangents):
+    """A pick's cotangent added into its place in a row of zeros: what
+    ``lax.top_k``'s own derivative transposes to (``[n, E]`` scores)."""
+    picks, scores = kept        # of the scores only the shape is read
+    return (lax.scatter_add(
+        jnp.zeros_like(scores), picks[..., None], cotangents[0],
+        lax.ScatterDimensionNumbers(
+            update_window_dims=(), inserted_window_dims=(1,),
+            scatter_dims_to_operand_dims=(1,), operand_batching_dims=(0,),
+            scatter_indices_batching_dims=(0,)),
+        mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS),)
+
+
+_top_k.defvjp(_top_k_fwd, _top_k_bwd)
+
+
 def route_top_k(x, router_kernel, top_k: int):
     """``(weights [n, top_k] float32, experts [n, top_k] int32)``: softmax
-    over the router's full width in float32 (the product at ``highest``
-    precision, which on a TPU is otherwise one bfloat16 pass and flips
-    picks), the ``top_k`` largest, their weights divided by their sum."""
-    logits = jnp.dot(x.astype(jnp.float32), router_kernel.astype(jnp.float32),
-                     precision=lax.Precision.HIGHEST)
-    weights, experts = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    over the router's full width in float32, the ``top_k`` largest, their
+    weights divided by their sum."""
+    weights, experts = _top_k(
+        jax.nn.softmax(_logits(x, router_kernel), axis=-1), top_k)
     return weights / jnp.sum(weights, axis=-1, keepdims=True), experts
 
 
 def route_sigmoid_top_k(x, router_kernel, top_k: int, *, bias,
                         scale: float = 1.0):
     """The same pair by the other published rule: each output's score is
-    its own sigmoid (float32, the product at ``highest``), the picks are
-    the ``top_k`` largest of ``score + bias``, and a pick's weight is its
-    score *without* the bias, divided by the picks' sum (plus 1e-20) and
-    multiplied by ``scale``.  ``bias`` (``[E]``) moves which experts a token
-    picks and never what it weighs them by: the selection bias an
-    auxiliary-loss-free balancer steers, a buffer and not a parameter (no
-    gradient reaches it).  A caller binds ``bias`` and ``scale``
-    (``functools.partial``) and passes the rule as ``route``."""
-    logits = jnp.dot(x.astype(jnp.float32), router_kernel.astype(jnp.float32),
-                     precision=lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
-    _, experts = lax.top_k(scores + jnp.asarray(bias, jnp.float32), top_k)
-    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    its own sigmoid (float32), the picks are the ``top_k`` largest of
+    ``score + bias``, and a pick's weight is its score *without* the bias,
+    divided by the picks' sum (plus 1e-20) and multiplied by ``scale``.
+    ``bias`` (``[E]``) moves which experts a token picks and never what it
+    weighs them by: the selection bias an auxiliary-loss-free balancer
+    steers, a buffer and not a parameter (no gradient reaches it).  A
+    caller binds ``bias`` and ``scale`` (``functools.partial``) and passes
+    the rule as ``route``."""
+    scores = jax.nn.sigmoid(_logits(x, router_kernel))
+    # no derivative is taken through this top-k: its values are not used
+    experts = checkpoint_name(
+        lax.top_k(scores + jnp.asarray(bias, jnp.float32), top_k)[1],
+        ROUTING)
+    weights = checkpoint_name(
+        jnp.take_along_axis(scores, experts, axis=-1), ROUTING)
     total = jnp.sum(weights, axis=-1, keepdims=True) + 1e-20
     return weights / total * scale, experts
+
+
+def routing_bytes(rows: int, experts: int, top_k: int) -> int:
+    """Bytes of what :data:`ROUTING` names over ``rows`` tokens: float32
+    logits over the router's ``experts`` outputs, and a pick, its score and
+    a place in the order for each of a token's ``top_k`` assignments (the
+    held experts' sizes, a few integers a group, are not counted)."""
+    return 4 * rows * (experts + 3 * top_k)
 
 
 #: Rows of one grouped product.  A held expert's assignments are taken
@@ -418,7 +476,8 @@ def routed_experts(x, router_kernel, expert_params, *, top_k: int,
             # stable: the held assignments first, expert by expert, each
             # expert's in token order
             order = jnp.argsort(local, stable=True).astype(jnp.int32)
-        return None, (weights.reshape(-1), order, sizes)
+        return None, (weights.reshape(-1), checkpoint_name(order, ROUTING),
+                      checkpoint_name(sizes, ROUTING))
 
     _, (weights, order, sizes) = _each_group(route_group, None, (x,))
     params = {k: expert_params[k] for k in ("gate_proj", "up_proj",

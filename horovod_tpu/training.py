@@ -7,8 +7,8 @@ TPU-native entry: build a jitted SPMD train step where the global batch is
 sharded across ranks, parameters are replicated, and gradients flow through
 the fused allreduce.  What the backward pass recomputes is the model's
 decision, not the step's: a model wraps what it chooses to in ``nn.remat``
-(as ``models/qwen3_next.py`` does for each decoder layer, through
-``recomputed``, which keeps the Pallas kernels' residuals).
+(as the decoders do for each layer through ``models/recompute.recomputed``,
+which keeps the kernels' residuals and what else fits the device).
 """
 
 from __future__ import annotations

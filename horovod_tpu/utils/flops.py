@@ -30,14 +30,15 @@ import numpy as np
 class DevicePeak(NamedTuple):
     flops: float              # bf16 FLOP/s, per chip
     hbm_bytes_per_sec: float  # HBM bandwidth, per chip
+    hbm_bytes: int            # HBM capacity, per chip
 
 
 #: Published per-chip peaks keyed by ``jax.Device.device_kind``.
 DEVICE_PEAKS = {
     # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s
-    # HBM.  The kind string is what jax 0.9.0 / libtpu 0.0.34 reports on
-    # the chip (chip_smoke.py output, PR 21).
-    "TPU v5 lite": DevicePeak(197e12, 819e9),
+    # HBM, 16 GB of it.  The kind string is what jax 0.9.0 / libtpu 0.0.34
+    # reports on the chip (chip_smoke.py output, PR 21).
+    "TPU v5 lite": DevicePeak(197e12, 819e9, 16 * 2 ** 30),
 }
 
 #: Operations ResNet-50 v1.5 at 224x224 requires per image and training
@@ -91,6 +92,15 @@ def hbm_bytes_per_sec(kind: Optional[str] = None) -> Optional[float]:
         return override * 1e9
     peak = _table_peak(kind)
     return peak.hbm_bytes_per_sec if peak else None
+
+
+def hbm_bytes(kind: Optional[str] = None) -> Optional[int]:
+    """Per-chip HBM capacity from :data:`DEVICE_PEAKS` for ``kind`` (default:
+    the mesh devices' kind), else None.  By kind and not from the
+    allocator's ``memory_stats``: a program compiled for a described device,
+    which has no allocator, must be the program the chip runs."""
+    peak = _table_peak(kind)
+    return peak.hbm_bytes if peak else None
 
 
 def require_peak_flops() -> float:
