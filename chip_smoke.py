@@ -230,6 +230,32 @@ def latent_attention_phase(s: int = 8192, h: int = 32, dk: int = 192,
            blocks=list(fa.default_blocks(dk)), rel_max_err=errs)
 
 
+def sliding_window_phase(window: int = 1024, s: int = 16384, h: int = 32,
+                         d: int = 128) -> None:
+    """The flash kernels under the sliding-window mask at the shape the
+    ``mellum2-16k`` cell's window layers run them, ``[1, 16384, 32, 128]``
+    bfloat16 with a window of 1024, against the dense-mask float32 softmax,
+    forward and all three gradients."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash_attention as fa
+
+    mask = fa.sliding_window_mask(window)
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+    q, k, v, w = (jax.random.normal(kk, (1, s, h, d), jnp.bfloat16)
+                  for kk in keys)
+    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    errs = _flash_against_dense(
+        "sliding-window",
+        lambda q, k, v: fa.flash_attention(q, k, v, mask=mask),
+        (j <= i) & (i - j < window), q, k, v, w)
+    report("flash_sliding_window_vs_dense_mask", shape=[1, s, h, d],
+           dtype="bfloat16", window=window, tolerance=FLASH_TOL,
+           tiles=fa.tile_census(s, s, *fa.default_blocks(d), mask),
+           rel_max_err=errs)
+
+
 def scan_phase() -> None:
     """The gated delta rule's Pallas kernels vs the recurrence itself,
     forward and gradients, at the head counts and sizes of Qwen3-Next's
@@ -484,6 +510,8 @@ def main() -> int:
     block_diffusion_phase()
     # Kanana-2's latent attention: q.k at 192 (128 + 64 rotary), v at 128
     latent_attention_phase()
+    # Mellum-2's window layers: 32 heads of 128, a row sees 1024 keys
+    sliding_window_phase()
     scan_phase()
     if n > 1:
         ring_phase(n)

@@ -225,6 +225,12 @@ MLA_LAYERS = registry.counter(
     "Latent-attention layers traced (models/kanana2.py; per compile, not "
     "per step), by the q.k head size, the v head size and the width of the "
     "compressed kv.", ("qk", "v", "latent"))
+ATTN_LAYERS = registry.counter(
+    "hvd_attn_layers_traced_total",
+    "Softmax-attention layers traced whose kind is the layer's own "
+    "(models/mellum2.py; per compile, not per step), by the kind, the keys a "
+    "row of a window layer sees (0: all before it) and the kind's rotary "
+    "rule.", ("kind", "window", "rope"))
 
 STEP_SECONDS = registry.histogram(
     "hvd_step_seconds",
@@ -627,6 +633,17 @@ def record_mla_layer(qk: int, v: int, latent: int) -> None:
         return
     try:
         MLA_LAYERS.labels(str(qk), str(v), str(latent)).inc()
+    except Exception:  # noqa: BLE001 — tracing must never fail on metrics
+        pass
+
+
+def record_attn_layer(kind: str, window: int, rope: str) -> None:
+    """One traced attention layer of a decoder whose layers differ by kind
+    (models/mellum2.py)."""
+    if not registry.enabled:
+        return
+    try:
+        ATTN_LAYERS.labels(kind, str(window), rope).inc()
     except Exception:  # noqa: BLE001 — tracing must never fail on metrics
         pass
 

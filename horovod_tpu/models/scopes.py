@@ -3,8 +3,9 @@
 A scope is a ``jax.named_scope``: it changes an op's metadata (``op_name`` in
 the compiled module, ``tf_op`` in a device trace) and nothing the compiler
 lowers, so a program with and without them is one program.  The decoders
-(``qwen3_next``, ``sdar``, ``kanana2``) put every matrix product, convolution
-and kernel call of a layer under exactly one *part*; ``gpt`` names its head.
+(``qwen3_next``, ``sdar``, ``kanana2``, ``mellum2``) put every matrix
+product, convolution and kernel call of a layer under exactly one *part*;
+``gpt`` names its head.
 A layer's two norms and its residual adds are elementwise and stay unnamed.
 ``docs/profiling.md`` has the table with each scope's reader, and
 ``tests/test_part_scopes.py`` holds the models to this list.
@@ -20,10 +21,15 @@ from ..ops import flash_attention as _flash
 from ..ops import gated_delta as _gdn
 from ..parallel import moe as _moe
 
-# softmax attention (qwen3_next.GatedAttention, sdar.BlockDiffusionAttention)
+# softmax attention (qwen3_next.GatedAttention, sdar.BlockDiffusionAttention,
+# mellum2.Attention)
 ATTN = "hvd_attn"
 ATTN_QKV = "hvd_attn_qkv"    # q / k / v projections, head norms, rotary, repeat
 ATTN_OUT = "hvd_attn_out"    # the output gate where there is one, o_proj
+# where a decoder's attention layers differ by kind (mellum2): the kind,
+# between ``hvd_attn`` and its parts, round the whole of a layer's attention
+ATTN_WINDOW = "hvd_attn_window"  # a sliding-window layer
+ATTN_FULL = "hvd_attn_full"      # a full (causal) layer beside window layers
 # gated DeltaNet (qwen3_next.GatedDeltaNet)
 GDN = "hvd_gdn"
 GDN_IN = "hvd_gdn_in"        # in_proj_qkvz / _ba, the split, l2 norms, beta, g
@@ -44,6 +50,8 @@ HEAD = "hvd_head"
 # block diffusion's input and the slice before the head (sdar.SDAR)
 BD_NOISE = "hvd_bd_noise"
 BD_HEAD_ROWS = "hvd_bd_head_rows"
+# the rotary tables of each kind of layer, made once a step (mellum2.Mellum2)
+ROTARY_TABLES = "hvd_rotary_tables"
 
 # What a recomputed decoder layer may keep besides the Pallas kernels'
 # residuals: ``jax.ad_checkpoint.checkpoint_name``s on the outputs the
@@ -77,8 +85,11 @@ PARTS = {
 }
 #: kernel names inside a part (each kernel's ``name=`` and scope)
 NESTED = {_gdn.SCAN_SCOPE: (_gdn.FWD_KERNEL, _gdn.BWD_KERNEL)}
+#: {a block: the kinds of its layers}, where a model's layers differ by
+#: kind: one of them between the block and its parts on every path
+KINDS = {ATTN: (ATTN_WINDOW, ATTN_FULL)}
 #: what a model emits outside its layers and its head
-OUTSIDE_LAYERS = (BD_NOISE, BD_HEAD_ROWS)
+OUTSIDE_LAYERS = (BD_NOISE, BD_HEAD_ROWS, ROTARY_TABLES)
 
 
 def documented() -> tuple:
@@ -92,4 +103,6 @@ def documented() -> tuple:
         names += [block, *parts]
     for part, kernels in NESTED.items():
         names += [part, *kernels]
+    for kinds in KINDS.values():
+        names += kinds
     return tuple(dict.fromkeys(names))

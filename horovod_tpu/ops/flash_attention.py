@@ -84,7 +84,11 @@ lower as they did when there was one.
 
 What a query row may see is one static description, a :class:`Mask`, taken
 by forward, dq, dkv and :func:`tile_census` alike: none, causal in global
-positions (above), or *block diffusion* (:func:`block_diffusion_mask`): the
+positions (above), a *sliding window* (:func:`sliding_window_mask`: row ``i``
+sees keys ``j`` with ``i - window < j <= i``, global positions too; the live
+key tiles of a query block are a range that starts where the window does,
+crossed at both ends, and every grid step outside it is skipped and fetches
+nothing), or *block diffusion* (:func:`block_diffusion_mask`): the
 rows are a noised copy of ``noised`` tokens followed by their clean copy,
 cut into blocks of ``block``; a clean row sees the clean blocks up to its
 own, a noised row the clean blocks before its own and the noised tokens of
@@ -191,21 +195,36 @@ LAYOUT_SCOPE = "hvd_flash_layout"
 class Mask(NamedTuple):
     """What a query row may see: static, hashable, one for all three
     kernels and the census.  ``kind`` is ``"none"``, ``"causal"`` (global
-    positions, moved by the offsets) or ``"block_diffusion"`` (see
-    :func:`block_diffusion_mask`)."""
+    positions, moved by the offsets), ``"sliding_window"`` (causal and no
+    further back than ``window``: :func:`sliding_window_mask`) or
+    ``"block_diffusion"`` (see :func:`block_diffusion_mask`)."""
     kind: str = "none"
     block: int = 0    # block diffusion: tokens a block
     noised: int = 0   # block diffusion: rows of the noised copy
+    window: int = 0   # sliding window: keys a row sees, itself among them
 
     @property
     def label(self) -> str:
         """The ``mask`` label of ``hvd_flash_tiles_traced_total``."""
-        return self.kind + (f"_b{self.block}" if self.block else "")
+        return (self.kind + (f"_b{self.block}" if self.block else "")
+                + (f"_w{self.window}" if self.window else ""))
 
 
 NO_MASK = Mask()
 CAUSAL = Mask("causal")
 _BD = "block_diffusion"
+_SW = "sliding_window"
+
+
+def sliding_window_mask(window: int) -> Mask:
+    """Causal attention no further back than ``window`` keys: query ``i``
+    sees key ``j`` iff ``i - window < j <= i`` (itself and the ``window -
+    1`` keys before it), in global positions moved by the offsets as the
+    causal case's are."""
+    window = int(window)
+    if window < 1:
+        raise ValueError(f"a window of {window} keys sees nothing")
+    return Mask(_SW, window=window)
 
 
 def block_diffusion_mask(block: int, noised: int) -> Mask:
@@ -293,7 +312,7 @@ def _check_blocks(sq, sk, block_q, block_k, mask=False):
                 f"takes {2 * mask.noised} queries and keys, not {sq} and "
                 f"{sk}")
         sq = sk = mask.noised
-    elif mask.kind == "causal":
+    elif mask.kind in ("causal", _SW):
         block_q = min(block_q, max(sq // 2, 1))
         block_k = min(block_k, max(sk // 2, 1))
     return _fit_block(sq, block_q), _fit_block(sk, block_k)
@@ -344,6 +363,10 @@ def _kv_tiles_seen(mask, q_first, q_rows, k_first, n, width):
 
     Causal (positions): the full tiles come first (last key <= first row),
     the crossed ones after them and the skipped ones last; ``first`` is 0.
+    Sliding window (positions): the live tiles start where the first row's
+    window does and end at the last row's diagonal; the full ones (no later
+    than the first row, inside the last row's window) lie between crossed
+    ones at both ends.
     Block diffusion (row indices; the rows lie in one copy and so do the
     tiles): clean keys are seen like that up to a row's horizon, the end of
     its own block from a clean row and of the block before from a noised
@@ -354,6 +377,14 @@ def _kv_tiles_seen(mask, q_first, q_rows, k_first, n, width):
         live = _clip(q_first + q_rows - 1 - k_first + width, 0,
                      n * width) // width
         return 0, full, live
+    if mask.kind == _SW:
+        span, back = n * width, q_first - k_first - mask.window + 1
+        first = _clip(back, 0, span) // width
+        end = _clip(q_first + q_rows - 1 - k_first + width, 0,
+                    span) // width
+        full = (_clip(q_first - k_first + 1, 0, span) // width
+                - _clip(back + q_rows - 1 + width - 1, 0, span) // width)
+        return first, _most(full, 0), _most(end - first, 0)
     if mask.kind != _BD:
         return 0, n, n
     size, half, span = mask.block, mask.noised, n * width
@@ -385,6 +416,12 @@ def _q_tiles_seen(mask, k_first, k_rows, q_first, n, width):
     if mask.kind == "causal":
         # query tiles wholly before the first key come first
         return _clip(k_first - q_first, 0, n * width) // width, n
+    if mask.kind == _SW:
+        # and the last one starts no later than the last key's window ends
+        lo = _clip(k_first - q_first, 0, n * width) // width
+        hi = _clip(k_first + k_rows + mask.window - 2 - q_first + width, 0,
+                   n * width) // width
+        return lo, _most(hi, lo)
     if mask.kind != _BD:
         return 0, n
     size, half, span = mask.block, mask.noised, n * width
@@ -405,7 +442,8 @@ def _nearest_live(i, ranges):
     """The block a grid step names on the streamed side: block ``i`` where
     it is live, else the next live one, else the last, so that a skipped
     step changes no index and fetches nothing.  ``ranges``: the live blocks
-    ``[a0, a1)`` of the first copy and ``[b0, b1)`` of the second."""
+    ``[a0, a1)`` of the first copy and ``[b0, b1)`` of the second (empty
+    where the mask has one range: a sliding window)."""
     (a0, a1), (b0, b1) = ranges
     return jnp.where(
         jnp.logical_and(a0 < a1, i < a1), jnp.maximum(i, a0),
@@ -421,6 +459,13 @@ def _seen(mask, shape, q_first, k_first, r, rows, col=0, keys_down=False):
         if keys_down:
             return _row_minus_col(*shape) <= q_first + r * rows - k_first
         return _row_minus_col(*shape) >= k_first - q_first - r * rows
+    if mask.kind == _SW:
+        # query minus key, in [0, window)
+        ahead = q_first + r * rows - k_first - col
+        diff = _row_minus_col(*shape)
+        if keys_down:
+            return jnp.logical_and(diff <= ahead, diff > ahead - mask.window)
+        return jnp.logical_and(diff >= -ahead, diff < mask.window - ahead)
     size, half, row = mask.block, mask.noised, r * rows
     q_axis = 1 if keys_down else 0
     q_shape = (1, shape[1]) if keys_down else (shape[0], 1)
@@ -468,7 +513,7 @@ def _count_tiles(kernel, static_offs, q, k, block_q, block_k, mask):
     from .. import metrics
 
     (b, h, sq, _), sk = q.shape, k.shape[2]
-    if static_offs is None and mask.kind == "causal":
+    if static_offs is None and mask.kind in ("causal", _SW):
         block_q, block_k = _check_blocks(sq, sk, block_q, block_k, mask)
         counts = {"dynamic": (sq // block_q) * (sk // block_k)}
     else:
@@ -664,13 +709,19 @@ def _kv_block_index(mask, block_q, block_k, sk):
     """Index map of the streamed k / v blocks of forward and dq.  A grid
     step wholly past the diagonal computes nothing, so it names the last
     block its query rows do see: the index does not change and nothing is
-    fetched.  Under block diffusion the live blocks are a range in each
-    copy of the keys."""
+    fetched.  Under a sliding window the live blocks are a range that starts
+    where the window does, under block diffusion a range in each copy of the
+    keys."""
     def index(b_, h_, i, j, offs):
         if mask.kind == "causal":
             last = _clip(offs[0] + i * block_q + block_q - 1 - offs[1],
                          0, sk - 1) // block_k
             j = jnp.minimum(j, last)
+        elif mask.kind == _SW:
+            first, _, count = _kv_tiles_seen(
+                mask, offs[0] + i * block_q, block_q, offs[1],
+                sk // block_k, block_k)
+            j = _nearest_live(j, ((first, first + count), (0, 0)))
         elif mask.kind == _BD:
             n = mask.noised // block_k
             live = []
@@ -906,6 +957,11 @@ def _dkv_call(q, k, v, do, lse, delta, offs, *, mask, scale, block_q,
             first = _clip(offs[1] + jk * block_k - offs[0],
                           0, sq - 1) // block_q
             i = jnp.maximum(i, first)
+        elif mask.kind == _SW:
+            # nor do the rows past the last key's window
+            i = _nearest_live(i, (_q_tiles_seen(
+                mask, offs[1] + jk * block_k, block_k, offs[0],
+                sq // block_q, block_q), (0, 0)))
         elif mask.kind == _BD:
             n = mask.noised // block_q
             i = _nearest_live(i, [tuple(part * n + t for t in _q_tiles_seen(
@@ -1034,9 +1090,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
         differ from q's and k's.
       causal: apply causal masking in global positions
         (``q_offset + i >= kv_offset + j``).
-      mask: a :class:`Mask` in ``causal``'s place, e.g.
-        :func:`block_diffusion_mask` (which takes no offsets: the rows are
-        the whole doubled sequence).
+      mask: a :class:`Mask` in ``causal``'s place:
+        :func:`sliding_window_mask` (positions moved by the offsets, as
+        the causal case's) or :func:`block_diffusion_mask` (which takes no
+        offsets: the rows are the whole doubled sequence).
       scale: logit scale, default ``1/sqrt(head_dim)``.
       q_offset, kv_offset: global position of element 0 of the q / kv
         shards (used by sequence-parallel callers).

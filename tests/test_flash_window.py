@@ -1,0 +1,279 @@
+"""The flash kernels under the sliding-window mask: forward and all three
+gradients in interpreter mode against materialised masked attention,
+``tile_census`` against a brute-force count over the mask and a count by
+hand, dkv's range of query tiles, the block a skipped step names, the traced
+tile counter.  Row ``i`` sees keys ``j`` with ``i - window < j <= i``: itself
+and the ``window - 1`` keys before it, by global position."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu.ops import flash_attention as fa
+
+
+@pytest.fixture(autouse=True)
+def _on_cpu():
+    """Exact f32 on the CPU whatever backends are present (as in
+    test_flash_attention.py)."""
+    with jax.default_device(jax.devices("cpu")[0]):
+        yield
+
+
+def dense_mask(window: int, sq: int, sk: int, q_offset: int = 0,
+               kv_offset: int = 0) -> np.ndarray:
+    """The definition, pair by pair: ``[sq, sk]`` booleans."""
+    i = q_offset + np.arange(sq)[:, None]
+    j = kv_offset + np.arange(sk)[None, :]
+    return (j <= i) & (i - j < window)
+
+
+def test_the_mask_is_hugging_faces_sliding_window():
+    """``kv_idx <= q_idx`` and ``kv_idx > q_idx - sliding_window``; over
+    ``s`` rows it allows ``s w - w (w - 1) / 2`` pairs (the first ``w`` rows
+    see fewer)."""
+    for window, s in ((1, 8), (4, 64), (16, 64), (64, 64), (100, 64)):
+        i, j = np.arange(s)[:, None], np.arange(s)[None, :]
+        assert (dense_mask(window, s, s) == ((j <= i) & (j > i - window))
+                ).all()
+        w = min(window, s)
+        assert dense_mask(window, s, s).sum() == s * w - w * (w - 1) // 2
+    # the benchmark's cell: 992.0 allowed pairs a row, 16 253 440 a head
+    assert 16384 * 1024 - 1024 * 1023 // 2 == 16_253_440
+
+
+def test_a_window_is_at_least_one_key():
+    with pytest.raises(ValueError, match="sees nothing"):
+        fa.sliding_window_mask(0)
+    assert fa.sliding_window_mask(1024).label == "sliding_window_w1024"
+    assert fa.CAUSAL.label == "causal" and fa.NO_MASK.label == "none"
+    assert fa.block_diffusion_mask(4, 64).label == "block_diffusion_b4"
+
+
+def _dense(q, k, v, seen):
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    p = jax.nn.softmax(jnp.where(seen[None, None], s, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+# (window, rows, tile rows, tile keys, q.k head size, v head size): windows
+# smaller than a tile, equal to one, larger than one, off the tiles' edges,
+# as long as the sequence and longer (the causal mask), one key (a row sees
+# itself alone), tiles wider than tall and taller than wide, and v's head
+# size apart from q.k's
+WINDOW_CASES = {
+    "w4_under_the_tile": (4, 64, 16, 16, 16, 16),
+    "w16_one_tile": (16, 64, 16, 16, 16, 16),
+    "w16_tall_tiles": (16, 128, 32, 16, 16, 16),
+    "w16_wide_tiles": (16, 128, 16, 32, 16, 16),
+    "w24_off_the_tiles": (24, 96, 16, 16, 16, 16),
+    "w40_over_the_tile": (40, 128, 16, 16, 16, 16),
+    "w64_the_sequence": (64, 64, 16, 16, 16, 16),
+    "w100_past_the_sequence": (100, 64, 16, 16, 16, 16),
+    "w1_itself_alone": (1, 32, 8, 8, 16, 16),
+    "w16_v_narrower": (16, 64, 16, 16, 24, 16),
+    "w24_v_wider": (24, 96, 32, 16, 16, 32),
+}
+
+
+def _out_and_grads(f, q, k, v, w):
+    return jax.jit(jax.value_and_grad(
+        lambda q, k, v: (jnp.sum(f(q, k, v) * w), f(q, k, v)),
+        argnums=(0, 1, 2), has_aux=True))(q, k, v)
+
+
+@pytest.mark.parametrize("case", sorted(WINDOW_CASES))
+def test_flash_window_matches_dense(rng, case):
+    window, s, bq, bk, d, dv = WINDOW_CASES[case]
+    mask = fa.sliding_window_mask(window)
+    seen = jnp.asarray(dense_mask(window, s, s))
+    mk = lambda width: jnp.asarray(  # noqa: E731
+        rng.normal(size=(1, s, 2, width)).astype(np.float32))
+    q, k, v, w = mk(d), mk(d), mk(dv), mk(dv)
+    (_, out), grads = _out_and_grads(lambda q, k, v: fa.flash_attention(
+        q, k, v, mask=mask, block_q=bq, block_k=bk, interpret=True),
+        q, k, v, w)
+    (_, want), want_grads = _out_and_grads(
+        lambda q, k, v: _dense(q, k, v, seen), q, k, v, w)
+    assert out.shape == (1, s, 2, dv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    for name, a, b in zip(("dq", "dk", "dv"), grads, want_grads):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
+                                   rtol=5e-5, err_msg=name)
+
+
+# (window, q rows, keys, q_offset, kv_offset): a later shard of the queries
+# against an earlier shard of the keys, as ring attention's hops pass them
+OFFSET_CASES = {
+    "the_diagonal_shard": (24, 64, 64, 64, 64),
+    "one_shard_back": (24, 64, 64, 64, 0),
+    "half_in_the_window": (40, 32, 64, 80, 16),
+    "out_of_the_window": (16, 32, 32, 96, 0),
+    "keys_ahead_of_the_rows": (16, 32, 32, 0, 64),
+}
+
+
+@pytest.mark.parametrize("traced", [False, True])
+@pytest.mark.parametrize("case", sorted(OFFSET_CASES))
+def test_the_window_moves_with_the_offsets(rng, case, traced):
+    """The partial triple of one q shard against one kv shard, with the
+    offsets static and traced, and both backward kernels, against the dense
+    mask at those positions; a row that sees no key of the shard comes back
+    with ``l`` 0."""
+    window, sq, sk, q_off, kv_off = OFFSET_CASES[case]
+    mask = fa.sliding_window_mask(window)
+    seen = dense_mask(window, sq, sk, q_off, kv_off)
+    mk = lambda s: jnp.asarray(  # noqa: E731
+        rng.normal(size=(1, 2, s, 16)).astype(np.float32))
+    q, k, v, do = mk(sq), mk(sk), mk(sk), mk(sq)
+    kw = dict(causal=mask, scale=0.25, block_q=16, block_k=16,
+              interpret=True)
+
+    def partial(q_off, kv_off):
+        return fa.mha_partial(q, k, v, q_off, kv_off, **kw)
+
+    o, m, l = (jax.jit(partial)(jnp.int32(q_off), jnp.int32(kv_off))
+               if traced else partial(q_off, kv_off))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * 0.25
+    s = jnp.where(seen[None, None], s, -jnp.inf)
+    some = seen.any(axis=1)
+    want_m = jnp.where(some[None, None, :, None],
+                       jnp.max(s, axis=-1, keepdims=True), fa.M_INIT)
+    p = jnp.where(seen[None, None], jnp.exp(s - want_m), 0.0)
+    np.testing.assert_allclose(np.asarray(l[..., 0]),
+                               np.asarray(p.sum(-1)), atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(o), np.asarray(jnp.einsum("bhqk,bhkd->bhqd", p, v)),
+        atol=2e-5, rtol=2e-5)
+    assert (np.asarray(l[0, 0, :, 0]) == 0).tolist() == (~some).tolist()
+    # the backward kernels at the same offsets, from any row statistics
+    lse = jnp.where(some[None, None, :, None],
+                    want_m + jnp.log(jnp.maximum(l, 1e-30)), 0.0)
+    delta = jnp.asarray(rng.normal(size=(1, 2, sq, 1)).astype(np.float32))
+    pn = jnp.where(seen[None, None], jnp.exp(s - lse), 0.0)
+    ds = pn * (jnp.einsum("bhqd,bhkd->bhqk", do, v) - delta)
+    offs = (jnp.int32(q_off), jnp.int32(kv_off)) if traced \
+        else (q_off, kv_off)
+    dq = fa.mha_bwd_dq(q, k, v, do, lse, delta, *offs, **kw)
+    dk, dv = fa.mha_bwd_dkv(q, k, v, do, lse, delta, *offs, **kw)
+    for name, got, want in (
+            ("dq", dq, 0.25 * jnp.einsum("bhqk,bhkd->bhqd", ds, k)),
+            ("dk", dk, 0.25 * jnp.einsum("bhqk,bhqd->bhkd", ds, q)),
+            ("dv", dv, jnp.einsum("bhqk,bhqd->bhkd", pn, do))):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=5e-5, rtol=5e-5, err_msg=name)
+
+
+CENSUS_CASES = [
+    # window, rows, block_q, block_k, q_offset, kv_offset
+    (1024, 16384, 1024, 512, 0, 0),
+    (1024, 16384, 512, 512, 0, 0),
+    (4096, 16384, 1024, 512, 0, 0),
+    (4, 64, 16, 16, 0, 0),
+    (16, 64, 16, 16, 0, 0),
+    (17, 64, 16, 16, 0, 0),
+    (24, 96, 32, 16, 0, 0),
+    (24, 96, 16, 32, 0, 0),
+    (40, 128, 16, 16, 0, 0),
+    (64, 64, 16, 16, 0, 0),
+    (100, 64, 16, 16, 0, 0),
+    (1, 32, 8, 8, 0, 0),
+    (24, 64, 16, 16, 64, 0),
+    (40, 64, 16, 16, 80, 16),
+    (16, 32, 16, 16, 0, 64),
+]
+
+
+@pytest.mark.parametrize("window,s,bq,bk,q_off,kv_off", CENSUS_CASES)
+def test_tile_census_matches_the_window(window, s, bq, bk, q_off, kv_off):
+    mask = fa.sliding_window_mask(window)
+    fq, fk = fa._check_blocks(s, s, bq, bk, mask)    # as the kernels fit
+    tiles = dense_mask(window, s, s, q_off, kv_off).reshape(
+        s // fq, fq, s // fk, fk)
+    every, some = tiles.all(axis=(1, 3)), tiles.any(axis=(1, 3))
+    assert fa.tile_census(s, s, bq, bk, mask, q_off, kv_off) == {
+        "skipped": int((~some).sum()), "full": int(every.sum()),
+        "crossed": int((some & ~every).sum())}
+
+
+def test_tile_census_of_the_window_cell_by_hand():
+    """16 384 rows under a window of 1024 at the tiles the kernels run by
+    default for head size 128 (1024 rows x 512 keys): a query block's rows
+    ``[q, q + 1024)`` see keys ``(q - 1024, q + 1024)``, four tiles (two
+    for the first block, which has nothing behind it), and none of them
+    whole: the window's low edge crosses the two behind the block, the
+    diagonal the two inside it.  62 of 512 tiles a head, where the causal
+    mask over the same rows visits 272."""
+    mask = fa.sliding_window_mask(1024)
+    assert fa.default_blocks(128) == (1024, 512)
+    assert fa.tile_census(16384, 16384, 1024, 512, mask) == {
+        "skipped": 512 - 62, "full": 0, "crossed": 2 + 15 * 4}
+    assert fa.tile_census(16384, 16384, 1024, 512, True) == {
+        "skipped": 240, "full": 240, "crossed": 32}
+    # a window of four tiles of keys: whole tiles between the two edges
+    assert fa.tile_census(16384, 16384, 1024, 512,
+                          fa.sliding_window_mask(4096)) == {
+        "skipped": 512 - (2 + 4 + 6 + 8 + 12 * 10),
+        "full": 0 + 2 + 4 + 6 + 12 * 6, "crossed": 2 + 2 + 2 + 2 + 12 * 4}
+
+
+@pytest.mark.parametrize("window,s,bq,bk,q_off,kv_off", CENSUS_CASES[3:])
+def test_what_dkv_skips_is_what_the_window_hides(window, s, bq, bk, q_off,
+                                                 kv_off):
+    """dkv's range of query tiles for a block of keys has a lower end (the
+    diagonal) and, new with this mask, an upper one (the window)."""
+    mask = fa.sliding_window_mask(window)
+    fq, fk = fa._check_blocks(s, s, bq, bk, mask)
+    some = dense_mask(window, s, s, q_off, kv_off).reshape(
+        s // fq, fq, s // fk, fk).any(axis=(1, 3))
+    for jk in range(s // fk):
+        lo, hi = fa._q_tiles_seen(mask, kv_off + jk * fk, fk, q_off,
+                                  s // fq, fq)
+        assert list(range(lo, hi)) == list(np.flatnonzero(some[:, jk])), jk
+    for i in range(s // fq):
+        first, _, live = fa._kv_tiles_seen(mask, q_off + i * fq, fq, kv_off,
+                                           s // fk, fk)
+        assert list(range(first, first + live)) \
+            == list(np.flatnonzero(some[i])), i
+
+
+def test_a_skipped_step_names_a_live_block_of_the_one_range():
+    """One range of live blocks (the second is empty): a step before it
+    names its first block, a step past it its last, and a step with no live
+    block at all a block that exists."""
+    got = [int(fa._nearest_live(jnp.int32(i), ((2, 4), (0, 0))))
+           for i in range(8)]
+    assert got == [2, 2, 2, 3, 3, 3, 3, 3]
+    assert [int(fa._nearest_live(jnp.int32(i), ((3, 3), (0, 0))))
+            for i in range(4)] == [2, 2, 2, 2]
+    assert [int(fa._nearest_live(jnp.int32(i), ((0, 0), (0, 0))))
+            for i in range(4)] == [0, 0, 0, 0]
+
+
+def test_flash_tiles_counter_names_the_window(monkeypatch, rng):
+    from horovod_tpu import metrics
+
+    monkeypatch.setattr(metrics.registry, "enabled", True)
+
+    def read():
+        return {(s["labels"]["kernel"], s["labels"]["kind"]): s["value"]
+                for s in metrics.registry.snapshot()["metrics"].get(
+                    "hvd_flash_tiles_traced_total", {}).get("samples", [])
+                if s["labels"]["mask"] == "sliding_window_w24"}
+
+    mask = fa.sliding_window_mask(24)
+    x = jnp.asarray(rng.normal(size=(2, 128, 3, 8)).astype(np.float32))
+    before = read()
+    jax.jit(jax.grad(lambda q: fa.flash_attention(
+        q, x, x, mask=mask, block_q=16, block_k=16,
+        interpret=True).sum()))(x)
+    delta = {k: v - before.get(k, 0) for k, v in read().items()}
+    census = fa.tile_census(128, 128, 16, 16, mask)
+    # a block's rows see three tiles (two for the second, one for the first)
+    assert census == {"skipped": 64 - 21, "full": 0, "crossed": 21}
+    for kernel in ("fwd", "dq", "dkv"):
+        assert {kind: delta[(kernel, kind)] for kind in census} == {
+            kind: 6 * n for kind, n in census.items()}

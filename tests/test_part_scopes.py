@@ -17,7 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import gpt, kanana2, qwen3_next, scopes, sdar
+from horovod_tpu.models import (gpt, kanana2, mellum2, qwen3_next, scopes,
+                                sdar)
 from horovod_tpu.models.gpt import next_token_loss
 from horovod_tpu.ops import flash_attention as flash
 from horovod_tpu.ops import gated_delta as gdn
@@ -85,6 +86,14 @@ MODELS = {
         {"self_attn": {scopes.MLA: scopes.PARTS[scopes.MLA]},
          "mlp": {scopes.DENSE_MLP: (), scopes.MOE: scopes.PARTS[scopes.MOE]},
          "": {scopes.HEAD: ()}}),
+    # one period: three window layers and a full one; the kind of a layer
+    # is a scope between ``hvd_attn`` and its parts (``scopes.KINDS``)
+    "mellum2_tiny": (
+        lambda: _causal(mellum2.mellum2_tiny()),
+        {"self_attn": {scopes.ATTN: (*scopes.KINDS[scopes.ATTN],
+                                     *scopes.PARTS[scopes.ATTN])},
+         "mlp": {scopes.MOE: _MOE},          # no shared expert
+         "": {scopes.HEAD: (), scopes.ROTARY_TABLES: ()}}),
     "gpt_tiny": (lambda: _causal(gpt.gpt_tiny(vocab_size=256)), {}),
 }
 DECODERS = sorted(set(MODELS) - {"gpt_tiny"})
@@ -151,7 +160,8 @@ def _all_leaves(name):
     its own."""
     return {part for blocks in MODELS[name][1].values()
             for block, parts in blocks.items() if block in scopes.PARTS
-            for part in (parts or (block,))}
+            for part in (parts or (block,))
+            if part not in scopes.KINDS.get(block, ())}
 
 
 @pytest.mark.parametrize("name", DECODERS)
@@ -187,6 +197,12 @@ def test_no_part_shows_outside_its_module_and_none_is_undocumented(
         # kernel's own name inside its part's)
         assert names[0] in blocks, path
         allowed = set(scopes.PARTS.get(names[0], ())) | set(NESTED)
+        kinds = scopes.KINDS.get(names[0], ())
+        if kinds and set(kinds) & set(blocks[names[0]]):
+            # a layer's kind comes next, once, and its parts inside it
+            assert names[1] in kinds and not set(names[2:]) & set(kinds), \
+                path
+            names = [names[0], *names[2:]]
         for inner in names[1:]:
             assert inner in allowed, path
             assert NESTED.get(inner, names[0]) in names, path
