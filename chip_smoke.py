@@ -36,6 +36,7 @@ The last line of a passing run is
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import sys
@@ -230,30 +231,147 @@ def latent_attention_phase(s: int = 8192, h: int = 32, dk: int = 192,
            blocks=list(fa.default_blocks(dk)), rel_max_err=errs)
 
 
+def _ms_a_call(fn, *args, calls: int = 10) -> float:
+    """Milliseconds a call of ``fn(*args)`` by the host's clock, over
+    ``calls`` calls after a warm one, ended by ``block_until_ready``."""
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    start = time.perf_counter()
+    for _ in range(calls):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - start) * 1e3 / calls
+
+
 def sliding_window_phase(window: int = 1024, s: int = 16384, h: int = 32,
                          d: int = 128) -> None:
     """The flash kernels under the sliding-window mask at the shape the
     ``mellum2-16k`` cell's window layers run them, ``[1, 16384, 32, 128]``
     bfloat16 with a window of 1024, against the dense-mask float32 softmax,
-    forward and all three gradients."""
+    forward and all three gradients; the tiles a caller gets who names
+    none, the grid steps the three kernels launch and how many of them are
+    live, and a layer's call with its layout swaps, forward and forward and
+    backward."""
     import jax
     import jax.numpy as jnp
 
     from horovod_tpu.ops import flash_attention as fa
 
     mask = fa.sliding_window_mask(window)
+    blocks = fa.default_blocks(d, mask)
     keys = jax.random.split(jax.random.PRNGKey(3), 4)
     q, k, v, w = (jax.random.normal(kk, (1, s, h, d), jnp.bfloat16)
                   for kk in keys)
     i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+
+    def flash(q, k, v):
+        return fa.flash_attention(q, k, v, mask=mask)
+
     errs = _flash_against_dense(
-        "sliding-window",
-        lambda q, k, v: fa.flash_attention(q, k, v, mask=mask),
-        (j <= i) & (i - j < window), q, k, v, w)
+        "sliding-window", flash, (j <= i) & (i - j < window), q, k, v, w)
+    both = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum((flash(q, k, v) * w).astype(jnp.float32)),
+        argnums=(0, 1, 2)))
     report("flash_sliding_window_vs_dense_mask", shape=[1, s, h, d],
            dtype="bfloat16", window=window, tolerance=FLASH_TOL,
-           tiles=fa.tile_census(s, s, *fa.default_blocks(d), mask),
+           blocks=list(blocks), tiles=fa.tile_census(s, s, *blocks, mask),
+           grid_steps_a_head=fa.grid_census(s, s, *blocks, mask),
+           layer_fwd_ms=_ms_a_call(jax.jit(flash), q, k, v),
+           layer_fwd_bwd_ms=_ms_a_call(both, q, k, v),
            rel_max_err=errs)
+
+
+def sliding_window_sweep(window: int = 1024, s: int = 16384, h: int = 32,
+                         d: int = 128, rows=(256, 512, 1024),
+                         keys=(256, 512), tiles=(1, 2, 4),
+                         row_tiles=(1,)) -> None:
+    """The sweep ``ops/flash_attention.default_blocks`` takes its rule under
+    a window from (not part of ``main``: ``python -c "import chip_smoke;
+    chip_smoke.sliding_window_sweep()"`` on the chip gives its docstring's
+    first table, ``sliding_window_sweep(rows=(512,), keys=(512,),
+    row_tiles=(1, 2, 4))`` the second): each of the three
+    kernels alone at ``[1, h, s, d]`` bfloat16 under a window of ``window``,
+    over rows a query tile x keys a tile x tiles a grid step x tiles of
+    rows forward's and dq's resident block holds, in us an
+    *allowed* 512 x 512 pair-tile (``s window - window (window - 1) / 2``
+    allowed pairs a head), with the grid steps each launches; and the whole
+    grid's steps at the causal tiles, whose difference from the fitted grid
+    is the cost of a step that is visited to do nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    import horovod_tpu as hvd
+    from horovod_tpu.ops import flash_attention as fa
+
+    if not hvd.is_initialized():
+        hvd.init(platform="tpu")
+    mask = fa.sliding_window_mask(window)
+    q, k, v, do = (jax.random.normal(kk, (1, h, s, d), jnp.bfloat16)
+                   for kk in jax.random.split(jax.random.PRNGKey(5), 4))
+    offs = fa._offsets(0, 0)
+    allowed_tiles = h * (s * window - window * (window - 1) // 2) / 512 ** 2
+    fitted = (fa._tiles_per_step, fa._window_steps, fa._row_tiles)
+
+    def kernels(block_q, block_k, static_offs=(0, 0)):
+        """ms a call of forward, dq and dkv, and what they returned."""
+        kw = dict(mask=mask, scale=d ** -0.5, block_q=block_q,
+                  block_k=block_k, interpret=None, static_offs=static_offs)
+        for launcher in (fa._fwd_call, fa._dq_call, fa._dkv_call):
+            launcher.clear_cache()
+        o, m, l = fa._mha_fwd(q, k, v, offs, normalize=True, **kw)
+        lse = m + jnp.log(jnp.maximum(l, 1e-30))
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                        axis=-1, keepdims=True)
+        back = (q, k, v, do, lse, delta, offs)
+        calls = {
+            "fwd": (lambda: fa._mha_fwd(q, k, v, offs, normalize=True,
+                                        **kw)[0]),
+            "dq": lambda: fa._mha_bwd_dq(*back, out_dtype=q.dtype, **kw),
+            "dkv": lambda: fa._mha_bwd_dkv(*back[:4], lse[..., 0],
+                                           delta[..., 0], offs,
+                                           out_dtype=q.dtype, **kw)}
+        ms = {name: _ms_a_call(call) for name, call in calls.items()}
+        return ms, [np.asarray(x, np.float32) for x in (
+            calls["fwd"](), calls["dq"](), *calls["dkv"]())]
+
+    reference = None
+    try:
+        for block_q, block_k, n, held in itertools.product(
+                rows, keys, tiles, row_tiles):
+            fa._tiles_per_step = lambda seq, tile, mask, n=n: n
+            fa._row_tiles = lambda sq, tile, held=held: held
+            ms, got = kernels(block_q, block_k)
+            reference = reference or got
+            report("sliding_window_sweep", rows=block_q, keys=block_k,
+                   tiles_a_step=n, row_tiles=held,
+                   us_an_allowed_tile={name: t * 1e3 / allowed_tiles
+                                       for name, t in ms.items()},
+                   ms=ms, grid_steps_a_head=fa.grid_census(
+                       s, s, block_q, block_k, mask),
+                   largest_difference_from_the_first=[
+                       float(np.abs(a - b).max())
+                       for a, b in zip(got, reference)])
+        # the causal tiles over every block there is, as before the grid was
+        # fitted, and over the fitted grid: the steps between cost the rest
+        fa._tiles_per_step = lambda seq, tile, mask: fa.TILES_PER_STEP
+        fa._row_tiles = lambda sq, tile: 1
+        fit, _ = kernels(fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K)
+        steps = fa.grid_census(s, s, fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K,
+                               mask)
+        fa._window_steps = lambda mask, resident, block, n: n
+        whole, _ = kernels(fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K, None)
+        every = fa.grid_census(s, s, fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K,
+                               mask, None, None)
+        report("sliding_window_idle_steps", whole_grid_ms=whole,
+               fitted_grid_ms=fit, us_an_idle_step={
+                   name: (whole[name] - fit[name]) * 1e3 / h
+                   / (every[name]["launched"] - steps[name]["launched"])
+                   for name in whole})
+    finally:
+        fa._tiles_per_step, fa._window_steps, fa._row_tiles = fitted
+        for launcher in (fa._fwd_call, fa._dq_call, fa._dkv_call):
+            launcher.clear_cache()
 
 
 def scan_phase() -> None:

@@ -187,6 +187,14 @@ FLASH_TILES = registry.counter(
     "(no key masked), crossed (the mask's edge passes through); dynamic "
     "(all of the grid's) when the offsets are traced; mask none, causal or "
     "block_diffusion_b<block>.", ("kernel", "kind", "mask"))
+FLASH_GRID_STEPS = registry.counter(
+    "hvd_flash_grid_steps_traced_total",
+    "Steps of the streamed grid axis of each traced flash-attention kernel "
+    "call (per compile, not per step): launched (the grid's extent) and "
+    "live (the step's block holds a tile some row sees; every other step "
+    "is visited to compute and fetch nothing; not counted when the offsets "
+    "are traced); mask as hvd_flash_tiles_traced_total's.",
+    ("kernel", "kind", "mask"))
 GDN_SCAN_CHUNKS = registry.counter(
     "hvd_gdn_scan_chunks_traced_total",
     "Chunks (of every value head) each traced gated-delta-rule kernel call "
@@ -556,17 +564,28 @@ def record_traced(op: str, tensor) -> None:
         pass
 
 
-def record_flash_tiles(kernel: str, counts, mask: str) -> None:
-    """Score tiles by kind of one traced flash kernel call
-    (ops/flash_attention.py) under the mask ``mask`` — how often the
-    unmasked body engages, how much of the grid is skipped."""
+def _count_by_kind(counter, kernel: str, counts, mask: str) -> None:
     if not registry.enabled:
         return
     try:
         for kind, n in counts.items():
-            FLASH_TILES.labels(kernel, kind, mask).inc(n)
+            counter.labels(kernel, kind, mask).inc(n)
     except Exception:  # noqa: BLE001 — tracing must never fail on metrics
         pass
+
+
+def record_flash_tiles(kernel: str, counts, mask: str) -> None:
+    """Score tiles by kind of one traced flash kernel call
+    (ops/flash_attention.py) under the mask ``mask`` — how often the
+    unmasked body engages, how much of the grid is skipped."""
+    _count_by_kind(FLASH_TILES, kernel, counts, mask)
+
+
+def record_flash_grid_steps(kernel: str, counts, mask: str) -> None:
+    """Launched and live steps of the streamed grid axis of one traced
+    flash kernel call (ops/flash_attention.py) under the mask ``mask`` —
+    how closely the grid fits the blocks the mask leaves live."""
+    _count_by_kind(FLASH_GRID_STEPS, kernel, counts, mask)
 
 
 def record_gdn_scan_chunks(kernel: str, path: str, chunks: int) -> None:

@@ -262,8 +262,12 @@ class Mellum2(nn.Module):
             original=self.yarn_original_positions,
             beta_fast=self.yarn_beta_fast, beta_slow=self.yarn_beta_slow),
             self.yarn_attention_factor)
+        # sorted: a set of strings iterates by the process's hash seed, and
+        # the order of the two tables is part of the step's program and of
+        # its compile-cache key
         return {kind: rotary_table(positions, *(
-            yarn if kind == FULL else plain)) for kind in set(self.kinds())}
+            yarn if kind == FULL else plain))
+            for kind in sorted(set(self.kinds()))}
 
     @nn.compact
     def __call__(self, ids):

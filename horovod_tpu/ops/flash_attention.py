@@ -87,16 +87,21 @@ by forward, dq, dkv and :func:`tile_census` alike: none, causal in global
 positions (above), a *sliding window* (:func:`sliding_window_mask`: row ``i``
 sees keys ``j`` with ``i - window < j <= i``, global positions too; the live
 key tiles of a query block are a range that starts where the window does,
-crossed at both ends, and every grid step outside it is skipped and fetches
-nothing), or *block diffusion* (:func:`block_diffusion_mask`): the
+crossed at both ends, and the launchers fit the grid and the tiles to it:
+the streamed axis has as many steps as a resident block's window reaches
+blocks, step ``j`` names the ``j``-th of them from the offsets, and the
+tiles a caller gets who names none are half the window long:
+:func:`_window_steps`, :func:`default_blocks`), or *block diffusion*
+(:func:`block_diffusion_mask`): the
 rows are a noised copy of ``noised`` tokens followed by their clean copy,
 cut into blocks of ``block``; a clean row sees the clean blocks up to its
 own, a noised row the clean blocks before its own and the noised tokens of
 its own block.  The kernels are the same ones: the mask says which tiles of
 a grid step's block are live and which of those are full (scalar
 arithmetic, :func:`_kv_tiles_seen` / :func:`_q_tiles_seen`), which block a
-skipped step names so that nothing is fetched (:func:`_nearest_live`), and
-which pairs of a crossed tile count (:func:`_seen`).  Tiles are fitted to
+skipped step names so that nothing is fetched (:func:`_nearest_live`;
+:func:`_window_block` on a fitted grid), and which pairs of a crossed tile
+count (:func:`_seen`).  Tiles are fitted to
 the noised half so that none straddles the two copies: of the ``2L x 2L``
 scores one quadrant is empty, one block-diagonal and two block-lower-
 triangular, ``L^2 + L block`` pairs where a causal mask over ``2L`` rows
@@ -143,7 +148,7 @@ DEFAULT_BLOCK_K = 512
 TILES_PER_STEP = 4
 
 
-def default_blocks(head_dim: int):
+def default_blocks(head_dim: int, mask=None):
     """``(block_q, block_k)`` a caller gets who names none, by q's head
     size: 1024 x 512, swept at head size 64 (PR 25), run, not swept, at
     128 (PR 30), and swept at latent attention's 192 with v at 128 (PR 34,
@@ -155,8 +160,60 @@ def default_blocks(head_dim: int):
     streams four query tiles a grid step, and at head size 256 with
     1024-row tiles that is 16 MiB of VMEM, the compiler's whole limit (the
     step compiled or not by where XLA put the kernel's outputs: PR 26):
-    512-row tiles over head size 192."""
-    return (512 if head_dim > 192 else DEFAULT_BLOCK_Q), DEFAULT_BLOCK_K
+    512-row tiles over head size 192.
+
+    Under a sliding window (``mask`` a :func:`sliding_window_mask`) the
+    rows of a tile are half the window's length (:func:`_window_rows`), a
+    grid step streams no more keys than the window is long
+    (:func:`_tiles_per_step`), and forward and dq hold up to 1024 rows
+    whose 512-row chunks each take the key tiles their own rows reach
+    (:func:`_row_tiles`).  Swept on one v5e (PR 39,
+    ``chip_smoke.sliding_window_sweep``) at ``[1, 32, 16384, 128]`` bf16
+    under a window of 1024, each kernel alone, us an *allowed* 512 x 512
+    pair-tile (1984 a call) fwd / dq / dkv, with one tile of rows a
+    resident block:
+
+    ============  ==================  ==================  ==================
+    rows x keys   one tile a step     two                 four
+    ============  ==================  ==================  ==================
+    256 x 256     5.59 / 4.51 / 4.87  3.97 / 3.69 / 4.44  3.43 / 3.50 / 4.37
+    256 x 512     3.97 / 3.73 / 4.09  3.44 / 3.49 / 3.76  3.80 / 4.47 / 3.66
+    512 x 256     5.09 / 3.91 / 3.70  2.98 / 2.98 / 3.57  2.68 / 2.93 / 4.01
+    512 x 512     2.97 / 2.96 / 3.15  2.68 / 2.91 / 3.04  3.08 / 3.69 / 3.31
+    1024 x 256    6.07 / 4.30 / 4.06  3.40 / 3.40 / 4.26  2.84 / 3.22 / 4.62
+    1024 x 512    3.41 / 3.39 / 3.72  2.81 / 3.20 / 3.91  2.84 / 3.53 / 4.09
+    ============  ==================  ==================  ==================
+
+    (1024-row tiles there took the tiles the whole block's rows reach: four
+    for three; the causal tiles over the grid of every block, as before
+    PR 39, 3.34 / 4.28 / 4.48) and at 512 x 512 over the tiles of rows
+    forward and dq hold (dkv holds one tile of keys: 3.04-3.29 throughout):
+
+    ==================  ===============  ===============  ===============
+    tiles of rows held  one key tile     two a step       four
+    ==================  ===============  ===============  ===============
+    one                 2.95 / 2.97      2.68 / 2.92      3.07 / 3.71
+    two                 2.96 / 3.20      **2.44 / 2.62**  2.87 / 3.55
+    four                3.06 / 3.26      2.51 / 2.71      2.82 / 3.18
+    ==================  ===============  ===============  ===============
+
+    (256 x 256 with four tiles of rows held 2.76 / 2.76 / 4.33, 256 x 512
+    2.77 / 2.76 / 3.77).  At the chip's peak the products of a *visited*
+    tile need 0.68 / 1.02 / 1.36 at head size 128; a chunk visits three
+    tiles for two allowed."""
+    block_q = 512 if head_dim > 192 else DEFAULT_BLOCK_Q
+    if mask is not None and mask.kind == _SW:
+        block_q = _window_rows(block_q, mask.window)
+    return block_q, DEFAULT_BLOCK_K
+
+
+def _window_rows(block_q: int, window: int) -> int:
+    """Rows of a tile under a sliding window: half the window's length in
+    powers of two (a tile of ``r`` rows visits ``r + window`` keys for
+    ``window`` allowed), no fewer than 256 (smaller tiles cost more a pair
+    than their masked pairs save) and no more than the causal tile's."""
+    return max(min(block_q, 256), min(block_q, 1 << max(
+        window // 2, 1).bit_length() - 1))
 
 
 # Rows of the resident q tile that forward and dq work on at a time, in a
@@ -324,7 +381,58 @@ def _tiles_per_step(seq: int, tile: int, mask: Mask = NO_MASK) -> int:
     block diffusion the noised half, so that a step's block lies in one
     copy)."""
     n = (mask.noised if mask.kind == _BD else seq) // tile
-    return next(c for c in range(min(TILES_PER_STEP, n), 0, -1) if n % c == 0)
+    most = TILES_PER_STEP
+    if mask.kind == _SW:
+        # a step's block no longer than the window: a block of four tiles
+        # under a window of two is fetched for the two its rows reach
+        most = max(1, min(most, mask.window // tile))
+    return next(c for c in range(min(most, n), 0, -1) if n % c == 0)
+
+
+def _kv_grid(sq, sk, block_q, block_k, mask, static_offs=None):
+    """Forward's and dq's ``(block_q, tile_k, block_k, steps, chunk)``: the
+    tile fitted to the lengths, the keys one grid step streams, how many
+    steps a query block takes over them (every block of keys there is, or
+    under a sliding window the few its rows can reach: :func:`_window_steps`;
+    with offsets that are Python ints, the most any query block does reach)
+    and the rows the kernels work on at a time.  Under a sliding window the
+    resident block is several tiles of rows (:func:`_row_tiles`), each of
+    which takes the key tiles its own rows reach."""
+    block_q, tile_k = _check_blocks(sq, sk, block_q, block_k, mask)
+    chunk = _fit_block(block_q, ROW_CHUNK)
+    block_k = tile_k * _tiles_per_step(sk, tile_k, mask)
+    steps = sk // block_k
+    if mask.kind == _SW:
+        block_q *= _row_tiles(sq, block_q)
+        steps = _window_steps(mask, block_q, block_k, steps)
+        if static_offs is not None:
+            steps = max(1, *_live_kv_blocks(mask, sq, sk, block_q, block_k,
+                                            static_offs))
+    return block_q, tile_k, block_k, steps, chunk
+
+
+def _row_tiles(sq: int, tile_q: int) -> int:
+    """Tiles of rows in forward's and dq's resident block under a sliding
+    window: up to DEFAULT_BLOCK_Q rows, so that a block of keys is fetched
+    once for them all; at most half the rows, as a causal tile."""
+    n = sq // tile_q
+    most = max(1, min(DEFAULT_BLOCK_Q // tile_q, n // 2))
+    return next(c for c in range(most, 0, -1) if n % c == 0)
+
+
+def _q_grid(sq, sk, block_q, block_k, mask, static_offs=None):
+    """dkv's ``(tile_q, block_q, block_k, steps)``, the roles swapped: the
+    query rows one grid step streams and the steps a block of keys takes
+    over them."""
+    tile_q, block_k = _check_blocks(sq, sk, block_q, block_k, mask)
+    block_q = tile_q * _tiles_per_step(sq, tile_q, mask)
+    steps = sq // block_q
+    if mask.kind == _SW:
+        steps = _window_steps(mask, block_k, block_q, steps)
+        if static_offs is not None:
+            steps = max(1, *_live_q_blocks(mask, sq, sk, block_q, block_k,
+                                           static_offs))
+    return tile_q, block_q, block_k, steps
 
 
 def _scale_parts(scale: float, dtype):
@@ -442,12 +550,71 @@ def _nearest_live(i, ranges):
     """The block a grid step names on the streamed side: block ``i`` where
     it is live, else the next live one, else the last, so that a skipped
     step changes no index and fetches nothing.  ``ranges``: the live blocks
-    ``[a0, a1)`` of the first copy and ``[b0, b1)`` of the second (empty
-    where the mask has one range: a sliding window)."""
+    ``[a0, a1)`` of the first copy and ``[b0, b1)`` of the second."""
     (a0, a1), (b0, b1) = ranges
     return jnp.where(
         jnp.logical_and(a0 < a1, i < a1), jnp.maximum(i, a0),
         jnp.where(b0 < b1, jnp.clip(i, b0, b1 - 1), jnp.maximum(a1 - 1, 0)))
+
+
+def _live_kv_blocks(mask, sq, sk, rows, keys, offs):
+    """How many blocks of ``keys`` keys each block of ``rows`` query rows
+    sees any of, at the Python-int offsets ``offs``: the live steps of
+    forward's and dq's grid, a query block at a time (both copies' under
+    block diffusion)."""
+    copies = 2 if mask.kind == _BD else 1
+    return [sum(_kv_tiles_seen(mask, offs[0] + i * rows, rows,
+                               offs[1] + part * mask.noised,
+                               sk // keys // copies, keys)[2]
+                for part in range(copies)) for i in range(sq // rows)]
+
+
+def _live_q_blocks(mask, sq, sk, rows, keys, offs):
+    """dkv's side of the same: how many blocks of ``rows`` query rows see
+    any of each block of ``keys`` keys."""
+    copies = 2 if mask.kind == _BD else 1
+    seen = [[_q_tiles_seen(mask, offs[1] + j * keys, keys,
+                           offs[0] + part * mask.noised,
+                           sq // rows // copies, rows)
+             for part in range(copies)] for j in range(sk // keys)]
+    return [sum(hi - lo for lo, hi in parts) for parts in seen]
+
+
+def _window_steps(mask, resident, block, n):
+    """Steps of the streamed axis of the grid under a sliding window: the
+    blocks of ``block`` that ``resident`` rows (dkv: keys) can reach, the
+    ``resident + window - 1`` positions of their windows wherever those
+    start in a block, and never more than the ``n`` there are (a window as
+    long as the sequence: the causal grid)."""
+    return min(n, -(-(resident + mask.window - 1) // block) + 1)
+
+
+def _window_block(step, first, count, n):
+    """``(block, live)`` of step ``step`` of a fitted range: the live
+    blocks are ``[first, first + count)`` of ``n`` and step ``j`` names
+    block ``first + j``; a step past the last live one names that one again
+    (nothing is fetched) and is not ``live`` (nothing is computed).  The
+    index map and the kernel's body both ask here, so a body masks against
+    the positions of the block it was handed and no other."""
+    return (_clip(first + jnp.minimum(step, count - 1), 0, n - 1),
+            step < count)
+
+
+def _window_kv_block(mask, q_first, q_rows, k_origin, n, width, step):
+    """Forward's and dq's: the block of ``width`` keys, of the ``n`` from
+    key ``k_origin``, that step ``step`` of the query rows ``[q_first,
+    q_first + q_rows)`` names."""
+    first, _, count = _kv_tiles_seen(mask, q_first, q_rows, k_origin, n,
+                                     width)
+    return _window_block(step, first, count, n)
+
+
+def _window_q_block(mask, k_first, k_rows, q_origin, n, width, step):
+    """dkv's: the block of ``width`` query rows, of the ``n`` from row
+    ``q_origin``, that step ``step`` of the keys ``[k_first, k_first +
+    k_rows)`` names."""
+    lo, hi = _q_tiles_seen(mask, k_first, k_rows, q_origin, n, width)
+    return _window_block(step, lo, hi - lo, n)
 
 
 def _seen(mask, shape, q_first, k_first, r, rows, col=0, keys_down=False):
@@ -508,11 +675,37 @@ def tile_census(sq, sk, block_q, block_k, causal, q_offset=0, kv_offset=0):
     return counts
 
 
+def grid_census(sq, sk, block_q, block_k, causal, q_offset=0, kv_offset=0):
+    """The grid steps a head's three kernels take on their streamed axis,
+    ``{"fwd": {"launched": ..., "live": ...}, "dq": ..., "dkv": ...}``: a
+    ``live`` step's block holds a tile somebody sees, every other step is
+    visited to compute nothing and fetch nothing (``live`` is ``None`` where
+    an offset is not a Python int: the launched extent is static, where the
+    live blocks start is data).  ``causal`` is a :class:`Mask` or the flag;
+    blocks and tiles a step are fitted as the launchers fit them."""
+    mask = _as_mask(causal)
+    offs = _static_offsets(q_offset, kv_offset)
+    rows, _, keys, steps, _ = _kv_grid(sq, sk, block_q, block_k, mask, offs)
+    kv_side = {"launched": sq // rows * steps,
+               "live": offs and sum(_live_kv_blocks(mask, sq, sk, rows, keys,
+                                                    offs))}
+    _, rows, keys, steps = _q_grid(sq, sk, block_q, block_k, mask, offs)
+    q_side = {"launched": sk // keys * steps,
+              "live": offs and sum(_live_q_blocks(mask, sq, sk, rows, keys,
+                                                  offs))}
+    return {"fwd": kv_side, "dq": dict(kv_side), "dkv": q_side}
+
+
 def _count_tiles(kernel, static_offs, q, k, block_q, block_k, mask):
-    """The trace-time counter: once for every kernel call that is traced."""
+    """The trace-time counters: once for every kernel call that is traced."""
     from .. import metrics
 
     (b, h, sq, _), sk = q.shape, k.shape[2]
+    steps = grid_census(sq, sk, block_q, block_k, mask,
+                        *(static_offs or (None, None)))[kernel]
+    metrics.record_flash_grid_steps(
+        kernel, {kind: n * b * h for kind, n in steps.items()
+                 if n is not None}, mask.label)
     if static_offs is None and mask.kind in ("causal", _SW):
         block_q, block_k = _check_blocks(sq, sk, block_q, block_k, mask)
         counts = {"dynamic": (sq // block_q) * (sk // block_k)}
@@ -567,6 +760,26 @@ def _tiles(start, width, tile):
     if isinstance(start, int):
         return pl.ds(start * tile, width * tile)
     return pl.ds(pl.multiple_of(start * tile, tile), width * tile)
+
+
+def _each_chunk_its_tiles(block, mask, live_step, q_first, bq, chunk,
+                          k_first, n_tiles, tile_k, unmasked):
+    """What a grid step of forward or dq does with its kv block under a
+    sliding window: every chunk of the resident rows takes the tiles its
+    own rows see (:func:`_each_kind_of_block` a chunk: a static width, a
+    traced start), so a resident block of two chunks multiplies the pairs
+    of one chunk's window twice and not those of both windows' span; no
+    tile where the step is past the last live block (it was handed that
+    block again)."""
+    def rows(r):
+        first, full, live = _kv_tiles_seen(
+            mask, q_first + r * chunk, chunk, k_first, n_tiles, tile_k)
+        _each_kind_of_block(
+            functools.partial(block, rows=r), n_tiles, first,
+            jnp.where(live_step, full, 0), jnp.where(live_step, live, 0),
+            unmasked)
+
+    _loop(0, bq // chunk, rows)
 
 
 def _each_kind_of_block(block, n_tiles, first, full, live, unmasked):
@@ -638,13 +851,17 @@ def _launch(name, kernel, offs, grid, ins, outs, scratch, interpret):
 
 def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                 acc_ref, mi_ref, li_ref, *,
-                mask, scale, normalize, tile_k):
+                mask, scale, normalize, tile_k, blocks, chunk):
     bq = q_ref.shape[2]
-    chunk = _fit_block(bq, ROW_CHUNK)
     n_tiles = k_ref.shape[2] // tile_k
     j = pl.program_id(3)
     q_first = offs_ref[0] + pl.program_id(2) * bq
-    k_first = offs_ref[1] + j * k_ref.shape[2]
+    if mask.kind == _SW:
+        named, live_step = _window_kv_block(
+            mask, q_first, bq, offs_ref[1], blocks, k_ref.shape[2], j)
+        k_first = offs_ref[1] + named * k_ref.shape[2]
+    else:
+        k_first = offs_ref[1] + j * k_ref.shape[2]
     q_scale, s_scale = _scale_parts(scale, q_ref.dtype)
 
     @pl.when(j == 0)
@@ -653,10 +870,11 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         mi_ref[:] = jnp.full_like(mi_ref, M_INIT)
         li_ref[:] = jnp.zeros_like(li_ref)
 
-    def block(width, masked, start):
+    def block(width, masked, start, rows=None):
         """One step of the online softmax over ``width`` tiles of the kv
         block from tile ``start``, taken as one: the statistics move
-        once."""
+        once.  For every chunk of the resident rows, or for chunk ``rows``
+        alone."""
         cols = _tiles(start, width, tile_k)
 
         def some_rows(r):
@@ -683,10 +901,16 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                 p.astype(vb.dtype), vb, _NN,
                 preferred_element_type=jnp.float32)
 
-        _loop(0, bq // chunk, some_rows)
+        if rows is None:
+            _loop(0, bq // chunk, some_rows)
+        else:
+            some_rows(rows)
 
     if mask.kind == "none":
         block(n_tiles, False, 0)
+    elif mask.kind == _SW:
+        _each_chunk_its_tiles(block, mask, live_step, q_first, bq, chunk,
+                              k_first, n_tiles, tile_k, unmasked=True)
     else:
         # the mask is a tenth of this kernel's time where it is not needed
         _each_kind_of_block(
@@ -709,19 +933,17 @@ def _kv_block_index(mask, block_q, block_k, sk):
     """Index map of the streamed k / v blocks of forward and dq.  A grid
     step wholly past the diagonal computes nothing, so it names the last
     block its query rows do see: the index does not change and nothing is
-    fetched.  Under a sliding window the live blocks are a range that starts
-    where the window does, under block diffusion a range in each copy of the
-    keys."""
+    fetched.  Under a sliding window the grid has a step for each block the
+    rows' windows reach and step ``j`` names the ``j``-th of them, under
+    block diffusion the live blocks are a range in each copy of the keys."""
     def index(b_, h_, i, j, offs):
         if mask.kind == "causal":
             last = _clip(offs[0] + i * block_q + block_q - 1 - offs[1],
                          0, sk - 1) // block_k
             j = jnp.minimum(j, last)
         elif mask.kind == _SW:
-            first, _, count = _kv_tiles_seen(
-                mask, offs[0] + i * block_q, block_q, offs[1],
-                sk // block_k, block_k)
-            j = _nearest_live(j, ((first, first + count), (0, 0)))
+            j, _ = _window_kv_block(mask, offs[0] + i * block_q, block_q,
+                                    offs[1], sk // block_k, block_k, j)
         elif mask.kind == _BD:
             n = mask.noised // block_k
             live = []
@@ -742,20 +964,20 @@ def _mha_fwd(q, k, v, offs, *, mask, block_q, block_k, interpret,
     l)`` with m/l ``[b,h,sq,1]``."""
     _count_tiles("fwd", static_offs, q, k, block_q, block_k, mask)
     return _fwd_call(q, k, v, offs, mask=mask, block_q=block_q,
-                     block_k=block_k,
+                     block_k=block_k, static_offs=static_offs,
                      interpret=_resolve_interpret(interpret), **kw)
 
 
 @_jit_kernel
 def _fwd_call(q, k, v, offs, *, mask, scale, block_q, block_k, normalize,
-              interpret):
+              interpret, static_offs=None):
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
-    block_q, tile_k = _check_blocks(sq, sk, block_q, block_k, mask)
-    block_k = tile_k * _tiles_per_step(sk, tile_k, mask)
+    block_q, tile_k, block_k, steps, chunk = _kv_grid(
+        sq, sk, block_q, block_k, mask, static_offs)
     kernel = functools.partial(
         _fwd_kernel, mask=mask, scale=scale, normalize=normalize,
-        tile_k=tile_k,
+        tile_k=tile_k, blocks=sk // block_k, chunk=chunk,
     )
     out_dtype = q.dtype if normalize else jnp.float32
     q_index = lambda b_, h_, i, j, offs: (b_, h_, i, 0)  # noqa: E731
@@ -765,7 +987,7 @@ def _fwd_call(q, k, v, offs, *, mask, scale, block_q, block_k, normalize,
     # o, like v, is dv wide (the scores come from d columns and weigh dv)
     o_block, v_block = (1, 1, block_q, dv), (1, 1, block_k, dv)
     return _launch(
-        FWD_KERNEL, kernel, offs, (b, h, sq // block_q, sk // block_k),
+        FWD_KERNEL, kernel, offs, (b, h, sq // block_q, steps),
         ins=[(q, q_block, q_index), (k, k_block, kv_index),
              (v, v_block, kv_index)],
         outs=[((b, h, sq, dv), out_dtype, o_block, q_index),
@@ -781,22 +1003,28 @@ def _fwd_call(q, k, v, offs, *, mask, scale, block_q, block_k, normalize,
 
 
 def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_acc_ref, *, mask, scale, tile_k):
+                   dq_ref, dq_acc_ref, *, mask, scale, tile_k, blocks,
+                   chunk):
     bq = q_ref.shape[2]
-    chunk = _fit_block(bq, ROW_CHUNK)
     n_tiles = k_ref.shape[2] // tile_k
     j = pl.program_id(3)
     q_first = offs_ref[0] + pl.program_id(2) * bq
-    k_first = offs_ref[1] + j * k_ref.shape[2]
+    if mask.kind == _SW:
+        named, live_step = _window_kv_block(
+            mask, q_first, bq, offs_ref[1], blocks, k_ref.shape[2], j)
+        k_first = offs_ref[1] + named * k_ref.shape[2]
+    else:
+        k_first = offs_ref[1] + j * k_ref.shape[2]
     q_scale, s_scale = _scale_parts(scale, q_ref.dtype)
 
     @pl.when(j == 0)
     def _():
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
-    def block(width, masked, start):
+    def block(width, masked, start, rows=None):
         """dq's share of ``width`` tiles of the kv block from tile
-        ``start``."""
+        ``start``, for every chunk of the resident rows or for chunk
+        ``rows`` alone."""
         cols = _tiles(start, width, tile_k)
 
         def some_rows(r):
@@ -821,10 +1049,16 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 ds.astype(kb.dtype), kb, _NN,
                 preferred_element_type=jnp.float32)
 
-        _loop(0, bq // chunk, some_rows)
+        if rows is None:
+            _loop(0, bq // chunk, some_rows)
+        else:
+            some_rows(rows)
 
     if mask.kind == "none":
         block(n_tiles, False, 0)
+    elif mask.kind == _SW:
+        _each_chunk_its_tiles(block, mask, live_step, q_first, bq, chunk,
+                              k_first, n_tiles, tile_k, unmasked=False)
     else:
         # the mask costs this kernel under 1% (the VPU has the room beside
         # three products), a second copy of the body costs code
@@ -841,14 +1075,20 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *,
-                    mask, scale, tile_q):
+                    mask, scale, tile_q, blocks):
     """Scores transposed, keys down the rows and queries along the lanes:
     every product is in a form the MXU takes as it is (k q^T, v do^T, p^T
     do, ds^T q), and the row statistics come in as lane-dense rows."""
     bk = k_ref.shape[2]
     n_tiles = q_ref.shape[2] // tile_q
     i = pl.program_id(3)
-    q_first = offs_ref[0] + i * q_ref.shape[2]
+    if mask.kind == _SW:
+        named, live_step = _window_q_block(
+            mask, offs_ref[1] + pl.program_id(2) * bk, bk, offs_ref[0],
+            blocks, q_ref.shape[2], i)
+        q_first = offs_ref[0] + named * q_ref.shape[2]
+    else:
+        q_first = offs_ref[0] + i * q_ref.shape[2]
     k_first = offs_ref[1] + pl.program_id(2) * bk
     k_scale, s_scale = _scale_parts(scale, k_ref.dtype)
 
@@ -886,7 +1126,11 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32)
 
     # the query tiles that see no key of the block are skipped
-    _loop(*_q_tiles_seen(mask, k_first, bk, q_first, n_tiles, tile_q), tile)
+    lo, hi = _q_tiles_seen(mask, k_first, bk, q_first, n_tiles, tile_q)
+    if mask.kind == _SW:
+        # a step past the last live block was handed that block again
+        hi = jnp.where(live_step, hi, lo)
+    _loop(lo, hi, tile)
 
     @pl.when(i == pl.num_programs(3) - 1)
     def _():
@@ -899,26 +1143,27 @@ def _mha_bwd_dq(q, k, v, do, lse, delta, offs, *, mask, block_q, block_k,
     """lse/delta ``[b,h,sq,1]``."""
     _count_tiles("dq", static_offs, q, k, block_q, block_k, mask)
     return _dq_call(q, k, v, do, lse, delta, offs, mask=mask,
-                    block_q=block_q, block_k=block_k,
+                    block_q=block_q, block_k=block_k, static_offs=static_offs,
                     interpret=_resolve_interpret(interpret), **kw)
 
 
 @_jit_kernel
 def _dq_call(q, k, v, do, lse, delta, offs, *, mask, scale, block_q,
-             block_k, interpret, out_dtype=jnp.float32):
+             block_k, interpret, out_dtype=jnp.float32, static_offs=None):
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
-    block_q, tile_k = _check_blocks(sq, sk, block_q, block_k, mask)
-    block_k = tile_k * _tiles_per_step(sk, tile_k, mask)
+    block_q, tile_k, block_k, steps, chunk = _kv_grid(
+        sq, sk, block_q, block_k, mask, static_offs)
     kernel = functools.partial(_bwd_dq_kernel, mask=mask, scale=scale,
-                               tile_k=tile_k)
+                               tile_k=tile_k, blocks=sk // block_k,
+                               chunk=chunk)
     q_index = lambda b_, h_, i, j, offs: (b_, h_, i, 0)  # noqa: E731
     kv_index = _kv_block_index(mask, block_q, block_k, sk)
     q_block, k_block, row = ((1, 1, block_q, d), (1, 1, block_k, d),
                              (1, 1, block_q, 1))
     do_block, v_block = (1, 1, block_q, dv), (1, 1, block_k, dv)
     return _launch(
-        DQ_KERNEL, kernel, offs, (b, h, sq // block_q, sk // block_k),
+        DQ_KERNEL, kernel, offs, (b, h, sq // block_q, steps),
         ins=[(q, q_block, q_index), (k, k_block, kv_index),
              (v, v_block, kv_index), (do, do_block, q_index),
              (lse, row, q_index), (delta, row, q_index)],
@@ -932,20 +1177,20 @@ def _mha_bwd_dkv(q, k, v, do, lse, delta, offs, *, mask, block_q, block_k,
     them as rows of ``tile_q`` lanes."""
     _count_tiles("dkv", static_offs, q, k, block_q, block_k, mask)
     return _dkv_call(q, k, v, do, lse, delta, offs, mask=mask,
-                     block_q=block_q, block_k=block_k,
+                     block_q=block_q, block_k=block_k, static_offs=static_offs,
                      interpret=_resolve_interpret(interpret), **kw)
 
 
 @_jit_kernel
 def _dkv_call(q, k, v, do, lse, delta, offs, *, mask, scale, block_q,
-              block_k, interpret, out_dtype=jnp.float32):
+              block_k, interpret, out_dtype=jnp.float32, static_offs=None):
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
-    tile_q, block_k = _check_blocks(sq, sk, block_q, block_k, mask)
-    n_tiles = _tiles_per_step(sq, tile_q, mask)
-    block_q = tile_q * n_tiles
+    tile_q, block_q, block_k, steps = _q_grid(sq, sk, block_q, block_k,
+                                              mask, static_offs)
+    n_tiles = block_q // tile_q
     kernel = functools.partial(_bwd_dkv_kernel, mask=mask, scale=scale,
-                               tile_q=tile_q)
+                               tile_q=tile_q, blocks=sq // block_q)
     lse, delta = (x.reshape(b, h, sq // tile_q, 1, tile_q)
                   for x in (lse, delta))
 
@@ -958,10 +1203,10 @@ def _dkv_call(q, k, v, do, lse, delta, offs, *, mask, scale, block_q,
                           0, sq - 1) // block_q
             i = jnp.maximum(i, first)
         elif mask.kind == _SW:
-            # nor do the rows past the last key's window
-            i = _nearest_live(i, (_q_tiles_seen(
-                mask, offs[1] + jk * block_k, block_k, offs[0],
-                sq // block_q, block_q), (0, 0)))
+            # nor do the rows past the last key's window: step i of the few
+            # a block of keys takes names the i-th block that sees it
+            i, _ = _window_q_block(mask, offs[1] + jk * block_k, block_k,
+                                   offs[0], sq // block_q, block_q, i)
         elif mask.kind == _BD:
             n = mask.noised // block_q
             i = _nearest_live(i, [tuple(part * n + t for t in _q_tiles_seen(
@@ -976,7 +1221,7 @@ def _dkv_call(q, k, v, do, lse, delta, offs, *, mask, scale, block_q,
                               (1, 1, n_tiles, 1, tile_q))
     do_block, v_block = (1, 1, block_q, dv), (1, 1, block_k, dv)
     return _launch(
-        DKV_KERNEL, kernel, offs, (b, h, sk // block_k, sq // block_q),
+        DKV_KERNEL, kernel, offs, (b, h, sk // block_k, steps),
         ins=[(q, q_block, q_index), (k, k_block, kv_index),
              (v, v_block, kv_index), (do, do_block, q_index),
              (lse, rows, row_index), (delta, rows, row_index)],
@@ -1109,7 +1354,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
         raise ValueError("a block-diffusion mask takes no offsets")
     if scale is None:
         scale = 1.0 / float(np.sqrt(q.shape[-1]))
-    default_q, default_k = default_blocks(q.shape[-1])
+    default_q, default_k = default_blocks(q.shape[-1], mask)
     fn = _flash_fn(mask, float(scale), int(block_q or default_q),
                    int(block_k or default_k), _resolve_interpret(interpret),
                    static_offs)
