@@ -28,7 +28,6 @@ def readings(spec, workload: str, seeds, control_seeds, *, devices=None,
     reference, control) are also written there as JSON, to try another
     number on without another chip call."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     import horovod_tpu as hvd
@@ -45,10 +44,6 @@ def readings(spec, workload: str, seeds, control_seeds, *, devices=None,
     hvd.init(devices=devices)
     prog = adapter.program(cfg, mix)
     ref = adapter.reference(cfg, mix)
-    template = init_train_state(prog["model"], prog["optimizer"],
-                                prog["sample"],
-                                has_batch_stats=prog["has_batch_stats"])
-    replicated = jax.tree_util.tree_leaves(template.params)[0].sharding
     step = make_train_step(
         apply_fn=prog["apply_fn"], loss_fn=prog["loss_fn"],
         optimizer=prog["optimizer"],
@@ -56,10 +51,13 @@ def readings(spec, workload: str, seeds, control_seeds, *, devices=None,
     out = {"program": {}, "control": {}}
     for seed in sorted(set(seeds) | set(control_seeds)):
         t = time.perf_counter()
-        weights = jax.device_put(ref["init"](seed), replicated)
-        state = jax.tree_util.tree_map(jnp.copy, template)._replace(
-            params=check.replace_leaves(
-                template.params, jax.tree_util.tree_map(jnp.copy, weights)))
+        # one state a seed, as ``run.py`` builds it: the step is given its
+        # state's buffers, and nothing of a seed is kept for the next
+        state, weights = bench_run.seeded_state(
+            init_train_state(prog["model"], prog["optimizer"],
+                             prog["sample"],
+                             has_batch_stats=prog["has_batch_stats"]),
+            ref, seed)
         arrays = traffic.dataset(mix, cfg, len(devices), seed)
         feed = traffic.batches(mix, arrays, seed, loop.annotate)
         run = bench_run.RunRecord(cell, len(devices),

@@ -115,8 +115,11 @@ def unflatten(flat: Dict[str, jnp.ndarray]) -> dict:
 # them ----------------------------------------------------------------------
 
 def adam_init(params):
-    zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
-    return {"m": zeros, "v": zeros, "t": jnp.zeros((), jnp.int32)}
+    # a tree of zeros each: ``train_steps`` gives both moments' buffers to
+    # the update, and a buffer can be given away once
+    return {"m": jax.tree_util.tree_map(jnp.zeros_like, params),
+            "v": jax.tree_util.tree_map(jnp.zeros_like, params),
+            "t": jnp.zeros((), jnp.int32)}
 
 
 def adam_update(params, grads, state, *, lr, b1=0.9, b2=0.999, eps=1e-8):
@@ -191,7 +194,7 @@ def leaf_sketches(flat):
     return out
 
 
-def train_steps(loss_fn: Callable, params: dict, batches: Sequence[tuple],
+def train_steps(loss_fn: Callable, params, batches: Sequence[tuple],
                 *, optimizer: str, lr: float, rows_per_block: int) -> dict:
     """Follow ``len(batches)`` training steps in float32 and return what
     ``correct`` compares: each step's loss, the first gradient's norm and
@@ -202,12 +205,35 @@ def train_steps(loss_fn: Callable, params: dict, batches: Sequence[tuple],
     ``rows_per_block`` at a time — one chip's share — and the blocks'
     losses and gradients averaged, which is what data-parallel chips with
     an averaging all-reduce compute.  ``loss_fn(params, *arrays)`` is the
-    per-block mean loss."""
+    per-block mean loss.
+
+    The walk holds no copy of the parameters that it does not read: under
+    Adam 16 bytes a parameter (parameters, two moments, one gradient), and
+    one block's gradient more while a batch of several blocks is summed.
+    An update gives away the moments and the gradient (the first) or the
+    parameters (the later ones), so what it returns takes their place, and
+    the blocks' running sum is added to and divided in place.  Only
+    buffers the walk made itself are given away: ``params`` is the
+    caller's to keep, and the first update reads it.  ``params`` may also
+    be a function that makes the tree (``follow``: from the seed).  The
+    walk then keeps no reference to what it made past the first update and
+    calls the function again after the last one, so the start weights are
+    not on the device through the steps."""
     init, update = OPTIMIZERS[optimizer]
     grad_fn = jax.jit(jax.value_and_grad(loss_fn))
-    step_fn = jax.jit(lambda p, g, s: update(p, g, s, lr=lr))
-    add = jax.jit(lambda a, b: jax.tree_util.tree_map(jnp.add, a, b))
-    start = params
+
+    def apply(p, g, s):
+        return update(p, g, s, lr=lr)
+
+    first_step = jax.jit(apply, donate_argnums=(1, 2))
+    later_step = jax.jit(apply, donate_argnums=(0, 2))
+    add = jax.jit(lambda a, b: jax.tree_util.tree_map(jnp.add, a, b),
+                  donate_argnums=0)
+    # the divisor is an argument, as it is to an eager ``g / blocks``
+    mean = jax.jit(lambda g, n: jax.tree_util.tree_map(
+        lambda x: x / n, g), donate_argnums=0)
+    make = params if callable(params) else (lambda tree=params: tree)
+    params = make()
     state = init(params)
     losses: List[float] = []
     first_grad_norms = None
@@ -217,27 +243,40 @@ def train_steps(loss_fn: Callable, params: dict, batches: Sequence[tuple],
             raise ValueError(f"{rows} rows do not split into blocks of "
                              f"{rows_per_block}")
         blocks = rows // rows_per_block
-        loss_sum, grad_sum = 0.0, None
+        loss_sum, grads = 0.0, None
         for i in range(blocks):
             part = tuple(jnp.asarray(a[i * rows_per_block:
                                        (i + 1) * rows_per_block])
                          for a in arrays)
-            loss, grads = grad_fn(params, *part)
+            loss, block_grads = grad_fn(params, *part)
             loss_sum = loss_sum + loss
-            grad_sum = grads if grad_sum is None else add(grad_sum, grads)
-        grads = jax.tree_util.tree_map(lambda g: g / blocks, grad_sum)
+            grads = block_grads if grads is None else add(grads,
+                                                          block_grads)
+            del block_grads
+            # A buffer is the allocator's until the program that reads it
+            # has run, whatever Python still names: wait, or the next
+            # dispatch allocates its outputs beside what was just consumed
+            # (the chip read 20 B a parameter for 16: PERF.md, PR 40).
+            jax.block_until_ready(grads)
+        if blocks > 1:  # x / 1 is x
+            grads = mean(grads, blocks)
         if first_grad_norms is None:
             first_grad_norms = leaf_norms(flatten(grads))
             first_grad_sketches = leaf_sketches(flatten(grads))
-        params, state = step_fn(params, grads, state)
+            params, state = first_step(params, grads, state)
+        else:
+            params, state = later_step(params, grads, state)
+        jax.block_until_ready(params)
+        del grads
         losses.append(float(loss_sum) / blocks)
+    del state
     return {
         "losses": losses,
         "grad_norms": _to_floats(first_grad_norms),
         "grad_sketches": {k: [float(x) for x in np.asarray(v)]
                           for k, v in first_grad_sketches.items()},
         "update_norms": _to_floats(
-            leaf_diff_norms(flatten(params), flatten(start))),
+            leaf_diff_norms(flatten(params), flatten(make()))),
     }
 
 
@@ -245,11 +284,14 @@ def follow(ref: dict, seed: int, batches: Sequence[tuple], rows: int,
            precision: str = "float32") -> dict:
     """``train_steps`` for a configuration's reference (its module's
     ``reference(cfg, mix)``), from the seed's weights, in full float32
-    matmul precision — or, with ``precision="fp8"``, its control."""
+    matmul precision — or, with ``precision="fp8"``, its control.  The
+    weights are made from the seed twice, before the first step and after
+    the last, and not kept between."""
     with full_precision():
         return train_steps(
-            ref["loss"](precision), unflatten(ref["init"](seed)), batches,
-            optimizer=ref["optimizer"], lr=ref["lr"], rows_per_block=rows)
+            ref["loss"](precision), lambda: unflatten(ref["init"](seed)),
+            batches, optimizer=ref["optimizer"], lr=ref["lr"],
+            rows_per_block=rows)
 
 
 def _to_floats(d) -> Dict[str, float]:

@@ -83,6 +83,27 @@ def _memory_peak(devices) -> int:
     return max(peaks)
 
 
+def seeded_state(state, ref: dict, seed: int):
+    """``state`` with the benchmark's weights of ``seed`` as its parameters
+    — not the program's: the reference starts from the same ones and takes
+    nothing the program made — and those weights, placed as the state is.
+    The state holds a copy (the step is given its state's buffers) and the
+    caller keeps the weights through the checked steps, for
+    ``first_steps`` to take each leaf's change against: 4 bytes a
+    parameter beside the state.  Making them again once the steps are done,
+    a few leaves at a time, frees that and cost ``setup_s`` 5 s a run on
+    the chip (PERF.md section 6, PR 40), so the copy stays."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.harness import check
+
+    replicated = jax.tree_util.tree_leaves(state.params)[0].sharding
+    weights = jax.device_put(ref["init"](seed), replicated)
+    return state._replace(params=check.replace_leaves(
+        state.params, jax.tree_util.tree_map(jnp.copy, weights))), weights
+
+
 def first_steps(step, state, feed, prog: dict, weights: dict, run,
                 chips: int):
     """Drive ``CHECK_STEPS`` steps through the window's own call and feed
@@ -94,7 +115,6 @@ def first_steps(step, state, feed, prog: dict, weights: dict, run,
     import jax
     import numpy as np
 
-    from benchmarks.harness import check
     from benchmarks.references import common
 
     placement, first, program = {}, [], {"losses": []}
@@ -136,7 +156,6 @@ def run_cell(spec, workload: str, seed: int, seconds: float, trace: bool,
     ``break_step`` wraps the compiled step (tests break the timed path
     with it).  Returns the result object."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     import horovod_tpu as hvd
@@ -164,15 +183,8 @@ def run_cell(spec, workload: str, seed: int, seconds: float, trace: bool,
     state = init_train_state(prog["model"], prog["optimizer"],
                              prog["sample"],
                              has_batch_stats=prog["has_batch_stats"])
-    # the benchmark's weights, not the program's: the reference starts from
-    # the same ones and takes nothing the program made
     ref = adapter.reference(cfg, mix)
-    weights = ref["init"](seed)
-    replicated = jax.tree_util.tree_leaves(state.params)[0].sharding
-    weights = jax.device_put(weights, replicated)
-    # a copy for the state: the step donates its state's buffers
-    state = state._replace(params=check.replace_leaves(
-        state.params, jax.tree_util.tree_map(jnp.copy, weights)))
+    state, weights = seeded_state(state, ref, seed)
     step = make_train_step(
         apply_fn=prog["apply_fn"], loss_fn=prog["loss_fn"],
         optimizer=prog["optimizer"],
@@ -260,12 +272,18 @@ def run_cell(spec, workload: str, seed: int, seconds: float, trace: bool,
 
     # -- correct, outside the window and outside set-up ---------------------
     host_batches = [tuple(np.asarray(a) for a in b) for b in first]
+    parameters = sum(x.size for x in jax.tree_util.tree_leaves(state.params))
     del state, step, feed, first, arrays
     hvd.shutdown()
     t = time.perf_counter()
     reference = common.follow(ref, seed, host_batches, rows)
     say(f"check: reference followed {len(host_batches)} steps in "
         f"{time.perf_counter() - t:.2f} s; losses {reference['losses']}")
+    # a line of the log, not a metric: what the process peaked at once the
+    # check has run too (``memory_peak_bytes`` was read before it)
+    after = _memory_peak(devices)
+    say(f"check: peak after the check {after} bytes on the fullest chip, "
+        f"{after / parameters:.2f} a parameter of {parameters}")
     numbers = check.first_steps_numbers(program, reference)
     numbers.update(check.window_numbers(w.losses))
     numbers.update(placement)

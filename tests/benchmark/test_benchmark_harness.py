@@ -218,20 +218,40 @@ def test_unknown_workload_lists_the_known_ones(tiny_root):
         Spec(tiny_root).cell("nope")
 
 
-def test_every_cell_of_the_real_benchmark_finds_its_files():
+def _real_cells():
+    with open(os.path.join(benchmark_tiny.REPO, "BENCHMARK.json")) as fh:
+        return json.load(fh)["workloads"]
+
+
+@pytest.mark.parametrize("entry", _real_cells(), ids=lambda w: w["name"])
+def test_a_cell_of_the_real_benchmark_finds_its_files(entry):
+    """The cells are ``BENCHMARK.json``'s own, whatever their number: a
+    later PR's cell is a case here without an edit."""
     spec = Spec(benchmark_tiny.REPO)
-    chips = {}
-    for entry in spec.data["workloads"]:
-        cell = spec.cell(entry["name"])
-        chips[cell.name] = cell.chips
-        assert "setup_s" in cell.end_to_end and "mfu" in cell.end_to_end
-        assert all(hasattr(m, "read") for m in cell.per_layer.values())
-        assert cell.adapter.flops_per_item(cell.cfg, cell.mix) > 0
-        limits = cell.adapter.limits(cell.cfg, cell.mix)
-        assert {"loss_gap", "grad_norm_gap", "update_norm_gap",
-                "final_loss"} <= set(limits)
-    assert chips == {"gpt2s-1k": 1, "resnet50-b256": 1, "gpt2s-16k": 1,
-                     "gpt2s-1k-dp4": 4}
+    cell = spec.cell(entry["name"])
+    assert (cell.config, cell.traffic, cell.chips) == (
+        entry["config"], entry["traffic"], entry["chips"])
+    assert "setup_s" in cell.end_to_end and "mfu" in cell.end_to_end
+    # the rate the cell reports is the one its traffic is counted in
+    rates = [m for m in cell.end_to_end if m.endswith("_per_s_chip")]
+    assert rates == [cell.mix["rate_metric"]]
+    assert cell.per_layer and all(
+        hasattr(m, "read") for m in cell.per_layer.values())
+    assert cell.adapter.flops_per_item(cell.cfg, cell.mix) > 0
+    limits = cell.adapter.limits(cell.cfg, cell.mix)
+    assert {"loss_gap", "grad_norm_gap", "grad_sketch_gap",
+            "update_norm_gap", "final_loss", "nonfinite_losses",
+            "batch_shards_missing",
+            "state_leaves_not_replicated"} <= set(limits)
+    assert len(entry["why"]) <= 200
+
+
+def test_a_traffic_file_is_one_cells_only():
+    """A pair of configuration and traffic is one cell's only (the driver
+    refused the file that named a traffic twice: PERF.md section 4)."""
+    cells = _real_cells()
+    assert len({w["traffic"] for w in cells}) == len(cells)
+    assert len({w["name"] for w in cells}) == len(cells)
 
 
 def test_without_a_tpu_the_command_exits_nonzero_and_prints_no_result():

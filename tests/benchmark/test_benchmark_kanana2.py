@@ -3,11 +3,10 @@ traffic ``seq8k-b1-k2``, the cell ``kanana2-8k`` and its seven readers.
 
 A file of its own because the other files of this directory are the
 benchmark's (``BENCHMARK.json`` lists ``tests/benchmark`` under ``paths``)
-and a PR that changes the program may only add beside them.  Three of
-``test_benchmark_sdar.py``'s tests pin the benchmark to the seven cells and
-the metric lists it had before this PR; ``tests/conftest.py`` marks them as
-expected failures by name, and the last section here holds the same
-assertions with the eighth cell in."""
+and a PR that changes the program may only add beside them.  Which cells
+list which metric, every cell's files and the toy benchmarks' form follow
+``BENCHMARK.json`` in ``test_benchmark_lists.py``, ``test_benchmark_harness.py``
+and ``test_benchmark_form.py`` (PR 40)."""
 
 import json
 import math
@@ -19,22 +18,18 @@ import pytest
 
 import benchmark_tiny
 import benchmark_tiny_kanana2
-import benchmark_tiny_qwen
-import benchmark_tiny_sdar
 from benchmarks.configs import kanana2_30b_a3b as adapter
 from benchmarks.harness import check, flops, peaks, trace
 from benchmarks.harness import kanana2_parts as parts
 from benchmarks.harness.spec import Spec
 from benchmarks.references import common, kanana2
 from benchmarks.run import RunRecord
-from test_benchmark_form import faults
 from test_benchmark_harness import _run as _run_cell, _well_formed
 from test_benchmark_harness import world  # noqa: F401 — a fixture
 from test_benchmark_parts import (CONV_STEP, GPT_STEP, MOSAIC, MS, PEAK,
                                    STEPS, _read, _run)
 
 CELL = "kanana2-8k"
-GPT_CELLS = ["gpt2s-1k", "gpt2s-16k", "gpt2s-1k-dp4"]
 KERNEL_SHARES = ["flash_mla_fwd_roofline", "flash_mla_dq_roofline",
                  "flash_mla_dkv_roofline"]
 NEW_READERS = ["mla_ms", "mla_latent_ms", "flash_mla_roofline",
@@ -448,104 +443,17 @@ def test_tiny_kanana2_adds_files_and_entries_and_edits_none(tiny_k2_root,
                      "traffic/seq64-b2-k2.json"}
 
 
-# -- the benchmark with its eighth cell ------------------------------------------
-# (the tests of ``test_benchmark_sdar.py`` that pin it to seven cells and to
-# the metric lists of PR 30, brought up to date)
+# -- the cell in ``BENCHMARK.json`` -----------------------------------------------
+# (which accepted readers list it is ``test_benchmark_lists.py``'s)
 
 
-def test_the_tiny_benchmarks_keep_the_form_with_eight_cells(tmp_path):
-    assert faults(benchmark_tiny.REPO) == []
-    assert faults(benchmark_tiny.make(str(tmp_path / "plain"))) == []
-    assert faults(benchmark_tiny_qwen.make(str(tmp_path / "qwen"))) == []
-    assert faults(benchmark_tiny_sdar.make(str(tmp_path / "sdar"))) == []
-    assert faults(benchmark_tiny_kanana2.make(str(tmp_path / "k2"))) == []
-
-
-def test_every_cell_of_the_benchmark_finds_its_files_all_eight():
-    spec = Spec(benchmark_tiny.REPO)
-    chips = {}
-    for entry in spec.data["workloads"]:
-        cell = spec.cell(entry["name"])
-        chips[cell.name] = cell.chips
-        assert "setup_s" in cell.end_to_end and "mfu" in cell.end_to_end
-        assert all(hasattr(m, "read") for m in cell.per_layer.values())
-        assert cell.adapter.flops_per_item(cell.cfg, cell.mix) > 0
-        limits = cell.adapter.limits(cell.cfg, cell.mix)
-        assert {"loss_gap", "grad_norm_gap", "grad_sketch_gap",
-                "update_norm_gap", "final_loss"} <= set(limits)
-        assert len(entry["why"]) <= 200
-    assert chips == {"gpt2s-1k": 1, "resnet50-b256": 1, "gpt2s-16k": 1,
-                     "gpt2s-1k-dp4": 4, "qwen3next-8k": 1,
-                     "sdar-bd4-8k": 1, "gpt2s-4k": 1, CELL: 1}
-    assert [w["name"] for w in spec.data["workloads"]][-1] == CELL
-    # a pair of configuration and traffic is one cell's only
-    pairs = [(w["config"], w["traffic"]) for w in spec.data["workloads"]]
-    assert len(set(pairs)) == len(pairs)
-    assert len({w["traffic"] for w in spec.data["workloads"]}) == len(pairs)
-
-
-def test_which_cells_list_which_metrics_after_pr_34():
-    """Readers that go by scope or kernel name find their ops in the new
-    cell too, so it is appended there; the accepted rooflines take one head
-    size, another router's key or GPT-2's keys and do not list it."""
-    spec = Spec(benchmark_tiny.REPO)
-    entries = {m["name"]: m for m in spec.data["per_layer"]}
-    qwen, pr30 = ["qwen3next-8k"], ["sdar-bd4-8k", "gpt2s-4k"]
-    for name in ("flash_ms", "flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms",
-                 "grad_pack_ms", "loss_ms"):
-        assert entries[name]["workloads"] \
-            == GPT_CELLS + qwen + pr30 + [CELL], name
-    for name in ("fwd_ms", "bwd_ms", "unscoped_ms"):
-        assert entries[name]["workloads"] == [
-            "gpt2s-1k", "resnet50-b256", "gpt2s-16k", "gpt2s-1k-dp4"] \
-            + qwen + pr30 + [CELL], name
-    for name in ("flash_roofline", "flash_fwd_roofline", "flash_dq_roofline",
-                 "flash_dkv_roofline", "optimizer_ms"):
-        assert entries[name]["workloads"] == GPT_CELLS + ["gpt2s-4k"], name
-    for name in ("moe_ms", "moe_route_ms", "moe_tiles"):
-        assert entries[name]["workloads"] == qwen + ["sdar-bd4-8k", CELL], \
-            name
-    for name in ("gdn_ms", "gdn_scan_ms", "gdn_scan_roofline",
-                 "moe_experts_roofline", "flash_gqa_roofline"):
-        assert entries[name]["workloads"] == qwen, name
-    for name in ("flash_bd_roofline", "bd_experts_roofline", "bd_noise_ms",
-                 "flash_bd_fwd_roofline", "flash_bd_dq_roofline",
-                 "flash_bd_dkv_roofline"):
-        assert entries[name]["workloads"] == ["sdar-bd4-8k"], name
-    # this PR's seven are the last
-    names = [m["name"] for m in spec.data["per_layer"]]
-    assert names[-13:] == [
-        "flash_bd_roofline", "bd_experts_roofline", "bd_noise_ms",
-        "flash_bd_fwd_roofline", "flash_bd_dq_roofline",
-        "flash_bd_dkv_roofline"] + NEW_READERS
-    for name in NEW_READERS:
-        assert entries[name]["workloads"] == [CELL]
-        assert entries[name]["source"] == "device_trace"
-        assert entries[name]["moves"] == "mfu"
-    for name in ["flash_mla_roofline"] + KERNEL_SHARES:
-        assert entries[name]["layer"] == entries["flash_ms"]["layer"]
-        assert entries[name]["unit"] == "%"
-    assert entries["mla_experts_roofline"]["layer"] \
-        == entries["moe_ms"]["layer"]
-    assert entries["mla_ms"]["layer"] == entries["mla_latent_ms"]["layer"] \
-        == "mixers: models/kanana2 latent attention"
-    rates = next(m for m in spec.data["end_to_end"]
-                 if m["name"] == "tokens_per_s_chip")
-    assert rates["workloads"] == GPT_CELLS + qwen + pr30 + [CELL]
-
-
-def test_what_the_new_cell_reports():
+def test_the_cell_reports_its_readers_and_builds_the_configurations_model():
     spec = Spec(benchmark_tiny.REPO)
     mine = spec.cell(CELL)
     assert (mine.config, mine.traffic, mine.chips) == (
         "kanana2_30b_a3b", "seq8k-b1-k2", 1)
     assert mine.end_to_end == ["tokens_per_s_chip", "mfu", "setup_s"]
-    assert set(mine.per_layer) == {
-        "init_s", "compile_s", "input_wait_ms", "dispatch_ms", "fwd_bwd_ms",
-        "device_idle_pct", "hbm_gb", "fwd_ms", "bwd_ms", "flash_ms",
-        "flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms", "grad_pack_ms",
-        "unscoped_ms", "moe_ms", "moe_route_ms", "moe_tiles", "loss_ms",
-        *NEW_READERS}
+    assert set(NEW_READERS) <= set(mine.per_layer)
     limits = mine.adapter.limits(mine.cfg, mine.mix)
     assert math.isclose(limits["final_loss"], math.log(16032) + 2.0)
     # the model the adapter builds is the configuration's

@@ -1,9 +1,8 @@
 """``loss_ms`` (PR 27): the reader on hand-built ``Reduced`` objects — the
 ops under ``hvd_loss`` forward and transposed, a loop counted once, not the
-loss's all-reduce, ``None`` where a program has no such scope — and its
-entry in ``BENCHMARK.json``, appended after PR 26's eight (the assertion of
-``test_benchmark_qwen3_next.py`` that pinned those as the last, brought up
-to date; ``tests/conftest.py`` marks the pinned one)."""
+loss's all-reduce, ``None`` where a program has no such scope — and which
+cells read it (its entry in ``BENCHMARK.json`` is held by name in
+``test_benchmark_lists.py``)."""
 
 import math
 
@@ -19,9 +18,6 @@ from test_benchmark_parts import (BWD, CFG, CONV_STEP, FWD, GPT_STEP, MIX,
 LOSS = FWD[:-4] + "hvd_loss/"
 LOSS_T = BWD[:-4] + "hvd_loss/"
 CELLS = ["gpt2s-1k", "gpt2s-16k", "gpt2s-1k-dp4", "qwen3next-8k"]
-PR_26 = ["gdn_ms", "gdn_scan_ms", "gdn_scan_roofline", "moe_ms",
-         "moe_route_ms", "moe_experts_roofline", "moe_tiles",
-         "flash_gqa_roofline"]
 
 
 def test_loss_ms_reads_the_scope_and_not_the_losss_allreduce():
@@ -65,23 +61,6 @@ def test_loss_ms_counts_a_loop_once():
                          ids=["no-loss-scope-conv", "no-loss-scope-gpt"])
 def test_loss_ms_is_none_where_the_program_names_no_loss(step):
     assert _read("loss_ms", _run(step)) is None
-
-
-def test_loss_ms_is_the_last_entry_after_pr_26s_eight():
-    spec = Spec(benchmark_tiny.REPO)
-    names = [m["name"] for m in spec.data["per_layer"]]
-    assert names[-len(PR_26) - 1:] == PR_26 + ["loss_ms"]
-    assert spec.data["per_layer"][-1] == {
-        "name": "loss_ms", "unit": "ms", "better": "lower",
-        "source": "device_trace", "layer": "model step on the device",
-        "moves": "mfu", "workloads": CELLS}
-    entries = {m["name"]: m for m in spec.data["per_layer"]}
-    for name in PR_26:
-        assert entries[name]["workloads"] == ["qwen3next-8k"]
-        assert entries[name]["source"] == "device_trace"
-        assert entries[name]["moves"] == "mfu"
-    # the layer's name, letter for letter, is the one its neighbours give
-    assert entries["unscoped_ms"]["layer"] == entries["loss_ms"]["layer"]
 
 
 @pytest.mark.parametrize("cell", CELLS + ["resnet50-b256"])

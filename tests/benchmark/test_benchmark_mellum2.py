@@ -3,10 +3,10 @@ traffic ``seq16k-b1-m2``, the cell ``mellum2-16k`` and its eight readers.
 
 A file of its own because the other files of this directory are the
 benchmark's (``BENCHMARK.json`` lists ``tests/benchmark`` under ``paths``)
-and a PR that changes the program may only add beside them.  The accepted
-tests that pin the benchmark to eight cells and to the metric lists of PR 36
-are marked as expected failures by name in ``tests/conftest.py``, and the
-last section here holds the same assertions with the ninth cell in."""
+and a PR that changes the program may only add beside them.  Which cells
+list which metric, every cell's files and the toy benchmarks' form follow
+``BENCHMARK.json`` in ``test_benchmark_lists.py``, ``test_benchmark_harness.py``
+and ``test_benchmark_form.py`` (PR 40)."""
 
 import json
 import math
@@ -17,30 +17,21 @@ import numpy as np
 import pytest
 
 import benchmark_tiny
-import benchmark_tiny_kanana2
 import benchmark_tiny_mellum2
-import benchmark_tiny_qwen
-import benchmark_tiny_sdar
 from benchmarks.configs import mellum2_12b_a2p5b as adapter
 from benchmarks.harness import check, flash_parts, flops, peaks, trace
 from benchmarks.harness import mellum2_parts as parts
 from benchmarks.harness.spec import Spec
 from benchmarks.references import common, mellum2
 from benchmarks.run import RunRecord
-from test_benchmark_form import faults
 from test_benchmark_harness import _run as _run_cell, _well_formed
 from test_benchmark_harness import world  # noqa: F401 — a fixture
-from test_benchmark_kanana2 import NEW_READERS as PR_34_READERS
-from test_benchmark_part_scopes import ACCEPTED as ACCEPTED_BEFORE_PR_36
-from test_benchmark_part_scopes import CELLS as PR_36_CELLS
 from test_benchmark_part_scopes import _fusion
 from test_benchmark_parts import (CONV_STEP, GPT_STEP, MOSAIC, MS, PEAK,
                                    STEPS, _read, _run)
-from test_benchmark_sdar import NEW_READERS as PR_30_READERS
 
 CELL = "mellum2-16k"
 SLIDING, FULL = parts.SLIDING, parts.FULL
-GPT_CELLS = ["gpt2s-1k", "gpt2s-16k", "gpt2s-1k-dp4"]
 KERNEL_SHARES = ["flash_swa_fwd_roofline", "flash_swa_dq_roofline",
                  "flash_swa_dkv_roofline"]
 NEW_READERS = ["attn_window_ms", "attn_full_ms", "flash_swa_roofline",
@@ -559,137 +550,8 @@ def test_tiny_mellum2_adds_files_and_entries_and_edits_none(tiny_m2_root,
                      "traffic/seq64-b2-m2.json"}
 
 
-# -- the benchmark with its ninth cell -------------------------------------------
-# (the accepted tests that pin it to eight cells and to the metric lists of
-# PR 36, brought up to date)
-
-
-def test_the_tiny_benchmarks_keep_the_form_with_nine_cells(tmp_path):
-    assert faults(benchmark_tiny.REPO) == []
-    assert faults(benchmark_tiny.make(str(tmp_path / "plain"))) == []
-    assert faults(benchmark_tiny_qwen.make(str(tmp_path / "qwen"))) == []
-    assert faults(benchmark_tiny_sdar.make(str(tmp_path / "sdar"))) == []
-    assert faults(benchmark_tiny_kanana2.make(str(tmp_path / "k2"))) == []
-    assert faults(benchmark_tiny_mellum2.make(str(tmp_path / "m2"))) == []
-
-
-def test_every_cell_of_the_benchmark_finds_its_files_all_nine():
-    spec = Spec(benchmark_tiny.REPO)
-    chips = {}
-    for entry in spec.data["workloads"]:
-        cell = spec.cell(entry["name"])
-        chips[cell.name] = cell.chips
-        assert "setup_s" in cell.end_to_end and "mfu" in cell.end_to_end
-        assert all(hasattr(m, "read") for m in cell.per_layer.values())
-        assert cell.adapter.flops_per_item(cell.cfg, cell.mix) > 0
-        limits = cell.adapter.limits(cell.cfg, cell.mix)
-        assert {"loss_gap", "grad_norm_gap", "grad_sketch_gap",
-                "update_norm_gap", "final_loss"} <= set(limits)
-        assert len(entry["why"]) <= 200
-    assert chips == {"gpt2s-1k": 1, "resnet50-b256": 1, "gpt2s-16k": 1,
-                     "gpt2s-1k-dp4": 4, "qwen3next-8k": 1,
-                     "sdar-bd4-8k": 1, "gpt2s-4k": 1, "kanana2-8k": 1,
-                     CELL: 1}
-    assert [w["name"] for w in spec.data["workloads"]][-1] == CELL
-    # a pair of configuration and traffic is one cell's only
-    pairs = [(w["config"], w["traffic"]) for w in spec.data["workloads"]]
-    assert len(set(pairs)) == len(pairs)
-    assert len({w["traffic"] for w in spec.data["workloads"]}) == len(pairs)
-
-
-def test_which_cells_list_which_metrics_after_pr_38():
-    """Every accepted list stands with the new cell appended where its
-    reader finds its ops here; this PR's eight follow PR 36's nine."""
-    spec = Spec(benchmark_tiny.REPO)
-    entries = {m["name"]: m for m in spec.data["per_layer"]}
-    names = [m["name"] for m in spec.data["per_layer"]]
-    assert names[-8:] == NEW_READERS
-    assert sorted(names[-17:-8]) == sorted(PR_36_CELLS)
-    assert names[-24:-17] == PR_34_READERS
-    assert len(names) == 47 + 9 + 8
-    eight = ["gpt2s-1k", "resnet50-b256", "gpt2s-16k", "gpt2s-1k-dp4",
-             "qwen3next-8k", "sdar-bd4-8k", "gpt2s-4k", "kanana2-8k"]
-    seven = [c for c in eight if c != "resnet50-b256"]
-    three = ["qwen3next-8k", "sdar-bd4-8k", "kanana2-8k"]
-    want = {
-        **{n: eight for n in ("fwd_ms", "bwd_ms", "unscoped_ms")},
-        **{n: seven for n in ("flash_ms", "flash_fwd_ms", "flash_dq_ms",
-                              "flash_dkv_ms", "grad_pack_ms", "loss_ms",
-                              "head_ms", "flash_layout_ms")},
-        **{n: three for n in ("moe_ms", "moe_route_ms", "moe_tiles",
-                              "recompute_ms", "recompute_mixer_ms",
-                              "recompute_moe_ms")},
-        "attn_proj_ms": ["qwen3next-8k", "sdar-bd4-8k"],
-    }
-    assert sorted(want) == sorted(LISTED)
-    for name, before in want.items():
-        assert entries[name]["workloads"] == before + [CELL], name
-    # every other accepted list is as it was: the new cell is in none
-    for name, entry in entries.items():
-        if name not in LISTED and name not in NEW_READERS:
-            assert CELL not in entry.get("workloads", ()), name
-    for name in ("flash_roofline", "flash_fwd_roofline", "flash_dq_roofline",
-                 "flash_dkv_roofline", "optimizer_ms"):
-        assert entries[name]["workloads"] == GPT_CELLS + ["gpt2s-4k"], name
-    for name in PR_34_READERS + ["mla_proj_ms"]:
-        assert entries[name]["workloads"] == ["kanana2-8k"], name
-    for name in PR_30_READERS:
-        assert entries[name]["workloads"] == ["sdar-bd4-8k"], name
-    for name in ("gdn_ms", "gdn_scan_ms", "gdn_scan_roofline",
-                 "moe_experts_roofline", "flash_gqa_roofline", "gdn_proj_ms",
-                 "gdn_conv_ms"):
-        assert entries[name]["workloads"] == ["qwen3next-8k"], name
-    # PR 36's nine as they were but for the cell appended to seven
-    for name, cells in PR_36_CELLS.items():
-        entry = entries[name]
-        assert entry["workloads"] == cells + [CELL] * (name in LISTED), name
-        assert (entry["unit"], entry["better"], entry["source"],
-                entry["moves"]) == ("ms", "lower", "device_trace", "mfu")
-    for name in ("recompute_ms", "recompute_mixer_ms", "recompute_moe_ms",
-                 "head_ms"):
-        assert entries[name]["layer"] == entries["bwd_ms"]["layer"]
-    assert entries["flash_layout_ms"]["layer"] == entries["flash_ms"]["layer"]
-    assert entries["attn_proj_ms"]["layer"] \
-        == "mixers: models/qwen3_next and models/sdar softmax attention"
-    for cell in (w["name"] for w in spec.data["workloads"]):
-        assert set(spec.cell(cell).per_layer) & set(PR_36_CELLS) == {
-            n for n, cells in PR_36_CELLS.items()
-            if cell in cells or (cell == CELL and n in LISTED)}
-    for name in NEW_READERS:
-        assert entries[name]["workloads"] == [CELL]
-        assert (entries[name]["source"], entries[name]["moves"]) \
-            == ("device_trace", "mfu")
-        assert set(entries[name]) == {"name", "unit", "better", "source",
-                                      "layer", "moves", "workloads"}
-    for name in ["flash_swa_roofline", "flash_full_roofline"] \
-            + KERNEL_SHARES:
-        assert entries[name]["layer"] == entries["flash_ms"]["layer"]
-        assert (entries[name]["unit"], entries[name]["better"]) \
-            == ("%", "higher")
-    assert entries["swa_experts_roofline"]["layer"] \
-        == entries["moe_ms"]["layer"]
-    assert entries["attn_window_ms"]["layer"] \
-        == entries["attn_full_ms"]["layer"] \
-        == "mixers: models/mellum2 attention by layer kind"
-    assert (entries["attn_window_ms"]["unit"],
-            entries["attn_window_ms"]["better"]) == ("ms", "lower")
-    rates = next(m for m in spec.data["end_to_end"]
-                 if m["name"] == "tokens_per_s_chip")
-    assert rates["workloads"] == seven + [CELL]
-    # no end-to-end entry changed but that list: bounds and sources stand
-    assert [(m["name"], m.get("bound")) for m in spec.data["end_to_end"]] \
-        == [("tokens_per_s_chip", 0.01), ("images_per_s_chip", 0.01),
-            ("mfu", 0.01), ("step_ms_p95", 0.01), ("setup_s", 0.1)]
-    assert spec.data["run_seconds"] == 20
-
-
-@pytest.mark.parametrize("cell,before", [
-    ("kanana2-8k", PR_34_READERS), ("sdar-bd4-8k", PR_30_READERS)])
-def test_the_accepted_cells_report_what_they_did(cell, before):
-    mine = Spec(benchmark_tiny.REPO).cell(cell)
-    assert mine.end_to_end == ["tokens_per_s_chip", "mfu", "setup_s"]
-    assert set(mine.per_layer) == ACCEPTED_BEFORE_PR_36 | set(before) | {
-        n for n, cells in PR_36_CELLS.items() if cell in cells}
+# -- the cell in ``BENCHMARK.json`` -----------------------------------------------
+# (which accepted readers list it is ``test_benchmark_lists.py``'s)
 
 
 def test_what_the_new_cell_reports():
@@ -698,9 +560,7 @@ def test_what_the_new_cell_reports():
     assert (mine.config, mine.traffic, mine.chips) == (
         "mellum2_12b_a2p5b", "seq16k-b1-m2", 1)
     assert mine.end_to_end == ["tokens_per_s_chip", "mfu", "setup_s"]
-    assert set(mine.per_layer) == {
-        "init_s", "compile_s", "input_wait_ms", "dispatch_ms", "fwd_bwd_ms",
-        "device_idle_pct", "hbm_gb", *LISTED, *NEW_READERS}
+    assert {*LISTED, *NEW_READERS} <= set(mine.per_layer)
     limits = mine.adapter.limits(mine.cfg, mine.mix)
     assert math.isclose(limits["final_loss"], math.log(12288) + 2.0)
     # the model the adapter builds is the configuration's

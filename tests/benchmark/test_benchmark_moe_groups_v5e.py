@@ -7,10 +7,8 @@ groups: in the compiled step the loop over the groups hands the three to
 the loop over a group's tiles and takes them back, and neither adds, copies
 nor zeroes an array of their shapes (the parent summed a group's
 ``[held, d, f]`` gradients into the running cotangent, 1.2 GB of traffic a
-group-layer).  No chip is attached and nothing runs.  (A file of its own:
-``test_benchmark_recompute_v5e.py`` holds ``sdar-bd4-8k``'s ``hbm_gb`` with
-the per-group accumulators in it and that case is pinned in
-``tests/conftest.py``.)"""
+group-layer).  No chip is attached and nothing runs.  (What the steps
+hold, ``hbm_gb``, is ``test_benchmark_keep_v5e.py``'s.)"""
 
 import collections
 import re
@@ -25,18 +23,14 @@ from test_benchmark_recompute_v5e import compile_step
 #: cell -> (float32 shapes of the held experts' matrices, expert layers,
 #: Mosaic calls of the step by kernel name as
 #: ``test_benchmark_recompute_v5e.py`` and ``test_benchmark_kanana2_v5e.py``
-#: hold them, the band round the ``hbm_gb`` compiled for PERF.md section 6,
-#: PR 35: 9.055 (the parent 9.665: two 302 MB temporaries fewer at the
-#: peak) and 7.497 (the parent 7.501))
+#: hold them)
 CELLS = {
     "sdar-bd4-8k": (
         r"f32\[16,(?:2048,768|768,2048)\]", 4,
-        {"hvd_flash_fwd": 4, "hvd_flash_dq": 4, "hvd_flash_dkv": 4},
-        (8.9e9, 9.2e9)),
+        {"hvd_flash_fwd": 4, "hvd_flash_dq": 4, "hvd_flash_dkv": 4}),
     "kanana2-8k": (
         r"f32\[8,(?:2048,768|768,2048)\]", 4,
-        {"hvd_flash_fwd": 5, "hvd_flash_dq": 5, "hvd_flash_dkv": 5},
-        (7.4e9, 7.6e9)),
+        {"hvd_flash_fwd": 5, "hvd_flash_dq": 5, "hvd_flash_dkv": 5}),
 }
 EXPERTS_SCOPE = "hvd_moe_experts"
 
@@ -88,7 +82,7 @@ def _produced(line, shape):
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_the_group_loop_hands_the_accumulators_on_and_touches_none(
         cell, steps):
-    shape, layers, _, _ = CELLS[cell]
+    shape, layers, _ = CELLS[cell]
     lines, called = _computations(steps[cell].as_text())
     carrying = {}    # body -> the computation its ``while`` sits in
     for name, body in lines.items():
@@ -141,12 +135,3 @@ def test_the_mosaic_calls_are_the_ones_the_step_had(cell, steps):
     assert {k: calls.count(k) for k in set(calls)} == CELLS[cell][2]
 
 
-@pytest.mark.parametrize("cell", sorted(CELLS))
-def test_the_step_holds_no_more_than_it_did(cell, steps):
-    """``hbm_gb`` as a traced run prints it (arguments + temporaries)
-    inside the band round the value compiled before the chip (PERF.md
-    section 6, PR 35), not over the parent's 9.665 / 7.501."""
-    low, high = CELLS[cell][3]
-    mem = steps[cell].memory_analysis()
-    hbm = mem.argument_size_in_bytes + mem.temp_size_in_bytes
-    assert low < hbm < high, hbm

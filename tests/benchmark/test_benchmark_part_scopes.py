@@ -2,44 +2,29 @@
 scopes and of the recompute's mark (``benchmarks/harness/part_scopes.py``),
 on a hand-built trace with, of each part, a first run, a marked recompute
 and a transposed op, a ``while`` envelope over marked body ops, and cells
-with nothing to read; the entries in ``BENCHMARK.json`` by name.
+with nothing to read.
 
 A file of its own because the other files of this directory are the
 benchmark's (``BENCHMARK.json`` lists ``tests/benchmark`` under ``paths``)
-and a PR that changes the program may only add beside them.  Three accepted
-tests pin the metric lists of ``kanana2-8k`` and ``sdar-bd4-8k`` to what
-they were before this PR; ``tests/conftest.py`` marks them as expected
-failures by name, and the last section here holds the same assertions with
-this PR's entries in."""
+and a PR that changes the program may only add beside them.  The nine
+entries by name, and which cells list them, follow ``BENCHMARK.json`` in
+``test_benchmark_lists.py`` (PR 40)."""
 
 import math
 
 import pytest
 
-import benchmark_tiny
 from benchmarks.harness import part_scopes as parts
 from benchmarks.harness import trace
-from benchmarks.harness.spec import Spec
 from benchmarks.run import RunRecord
-from test_benchmark_kanana2 import NEW_READERS as PR_34_READERS
 from test_benchmark_kanana2 import K2_STEP, _k2_run
 from test_benchmark_parts import (CONV_STEP, GPT_STEP, MOSAIC, MS, PEAK,
                                    STEPS, _read, _run)
-from test_benchmark_sdar import NEW_READERS as PR_30_READERS
 
-THREE = ["qwen3next-8k", "sdar-bd4-8k", "kanana2-8k"]
-SEVEN = ["gpt2s-1k", "gpt2s-16k", "gpt2s-1k-dp4", "qwen3next-8k",
-         "sdar-bd4-8k", "gpt2s-4k", "kanana2-8k"]
-#: {reader: the cells that list it}
-CELLS = {
-    "recompute_ms": THREE, "recompute_mixer_ms": THREE,
-    "recompute_moe_ms": THREE,
-    "attn_proj_ms": ["qwen3next-8k", "sdar-bd4-8k"],
-    "gdn_proj_ms": ["qwen3next-8k"], "gdn_conv_ms": ["qwen3next-8k"],
-    "mla_proj_ms": ["kanana2-8k"], "head_ms": SEVEN,
-    "flash_layout_ms": SEVEN,
-}
-READERS = sorted(CELLS)
+READERS = sorted([
+    "recompute_ms", "recompute_mixer_ms", "recompute_moe_ms", "attn_proj_ms",
+    "gdn_proj_ms", "gdn_conv_ms", "mla_proj_ms", "head_ms",
+    "flash_layout_ms"])
 
 # -- a hand-built step ----------------------------------------------------------
 
@@ -211,72 +196,3 @@ def test_the_mark_is_the_programs():
     assert mine <= set(scopes.documented())
 
 
-# -- the entries, by name -------------------------------------------------------
-
-
-def test_the_nine_readers_are_entries_with_files_by_name():
-    spec = Spec(benchmark_tiny.REPO)
-    entries = {m["name"]: m for m in spec.data["per_layer"]}
-    for name, cells in CELLS.items():
-        entry = entries[name]
-        assert entry["workloads"] == cells, name
-        assert (entry["unit"], entry["better"], entry["source"],
-                entry["moves"]) == ("ms", "lower", "device_trace", "mfu")
-        assert set(entry) == {"name", "unit", "better", "source", "layer",
-                              "moves", "workloads"}
-    for name in ("recompute_ms", "recompute_mixer_ms", "recompute_moe_ms",
-                 "head_ms"):
-        assert entries[name]["layer"] == entries["bwd_ms"]["layer"]
-    for name in ("gdn_proj_ms", "gdn_conv_ms"):
-        assert entries[name]["layer"] == entries["gdn_ms"]["layer"]
-    assert entries["mla_proj_ms"]["layer"] == entries["mla_ms"]["layer"]
-    assert entries["flash_layout_ms"]["layer"] == entries["flash_ms"]["layer"]
-    assert entries["attn_proj_ms"]["layer"] \
-        == "mixers: models/qwen3_next and models/sdar softmax attention"
-    # a cell lists a reader exactly where the table above says
-    for cell in (w["name"] for w in spec.data["workloads"]):
-        listed = set(spec.cell(cell).per_layer) & set(CELLS)
-        assert listed == {n for n, cells in CELLS.items() if cell in cells}
-    # nothing the benchmark had was edited: the entries before are the
-    # accepted ones, in their order, ending on PR 34's seven
-    before = [m["name"] for m in spec.data["per_layer"]
-              if m["name"] not in CELLS]
-    assert before[-7:] == PR_34_READERS
-    assert len(before) == 47 and "resnet50-b256" not in {
-        c for cells in CELLS.values() for c in cells}
-
-
-# -- the accepted tests that pin the cells' lists, brought up to date ------------
-
-ACCEPTED = {"init_s", "compile_s", "input_wait_ms", "dispatch_ms",
-            "fwd_bwd_ms", "device_idle_pct", "hbm_gb", "fwd_ms", "bwd_ms",
-            "flash_ms", "flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms",
-            "grad_pack_ms", "unscoped_ms", "moe_ms", "moe_route_ms",
-            "moe_tiles", "loss_ms"}
-
-
-@pytest.mark.parametrize("cell,before", [
-    ("kanana2-8k", PR_34_READERS), ("sdar-bd4-8k", PR_30_READERS)])
-def test_what_a_cell_reports_after_pr_36(cell, before):
-    mine = Spec(benchmark_tiny.REPO).cell(cell)
-    assert mine.end_to_end == ["tokens_per_s_chip", "mfu", "setup_s"]
-    assert set(mine.per_layer) == ACCEPTED | set(before) | {
-        n for n, cells in CELLS.items() if cell in cells}
-
-
-def test_which_cells_list_which_metrics_after_pr_36():
-    """PR 34's lists stand; this PR's nine follow them."""
-    spec = Spec(benchmark_tiny.REPO)
-    names = [m["name"] for m in spec.data["per_layer"]]
-    assert names[-16:-9] == PR_34_READERS
-    assert sorted(names[-9:]) == READERS
-    entries = {m["name"]: m for m in spec.data["per_layer"]}
-    for name in PR_34_READERS:
-        assert entries[name]["workloads"] == ["kanana2-8k"]
-    for name in ("fwd_ms", "bwd_ms", "unscoped_ms"):
-        assert entries[name]["workloads"] == [
-            "gpt2s-1k", "resnet50-b256", "gpt2s-16k", "gpt2s-1k-dp4",
-            "qwen3next-8k", "sdar-bd4-8k", "gpt2s-4k", "kanana2-8k"]
-    for name in ("flash_ms", "flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms",
-                 "grad_pack_ms", "loss_ms"):
-        assert entries[name]["workloads"] == SEVEN, name

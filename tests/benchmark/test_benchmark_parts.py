@@ -203,31 +203,6 @@ def test_unscoped_ms_counts_a_loop_once():
     assert math.isclose(_read("fwd_ms", run), 2.0)
 
 
-def test_new_metrics_are_entries_with_files():
-    """Every new reader is a ``per_layer`` entry of ``BENCHMARK.json`` that
-    moves ``mfu`` from the device trace, and the flash parts and the
-    update are read in the GPT cells only."""
-    import benchmark_tiny
-    from benchmarks.harness.spec import Spec
-
-    spec = Spec(benchmark_tiny.REPO)
-    entries = {m["name"]: m for m in spec.data["per_layer"]}
-    gpt = ["gpt2s-1k", "gpt2s-16k", "gpt2s-1k-dp4"]
-    for name in list(GPT_MS) + [f"flash_{k}_roofline"
-                                for k in flash_parts.KERNELS]:
-        entry = entries[name]
-        assert entry["source"] == "device_trace" and entry["moves"] == "mfu"
-        if name.startswith("flash_") or name in ("optimizer_ms",
-                                                 "grad_pack_ms"):
-            assert entry["workloads"] == gpt
-    # XLA folds SGD's update into the filter-gradient convolutions and
-    # cancels the whole pack of ResNet's buckets on one chip, so neither
-    # reader finds an op there and the cell lists neither metric
-    resnet = spec.cell("resnet50-b256").per_layer
-    assert "optimizer_ms" not in resnet and "grad_pack_ms" not in resnet
-    assert {"fwd_ms", "bwd_ms", "unscoped_ms"} <= set(resnet)
-
-
 def test_the_programs_resnet_constant_is_the_required_count():
     """``utils/flops.RESNET50_TRAIN_FLOPS_PER_IMG`` feeds the program's own
     MFU gauge and ``bench.py``; it counted multiply-adds as operations

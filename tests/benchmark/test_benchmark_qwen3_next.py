@@ -3,10 +3,10 @@ traffic ``seq8k-b1``, the cell ``qwen3next-8k`` and its per-layer metrics.
 
 They stand in a file of their own because the other files of this directory
 are the benchmark's (``BENCHMARK.json`` lists ``tests/benchmark`` under
-``paths``) and a PR that changes the program may only add beside them.  Three
-of those files' tests pin the benchmark to the four cells it had before this
-one; ``tests/conftest.py`` marks them as expected failures by name, and the
-last section here holds the same three with the fifth cell in."""
+``paths``) and a PR that changes the program may only add beside them.
+Which cells list which metric, every cell's files and the toy benchmarks'
+form follow ``BENCHMARK.json`` in ``test_benchmark_lists.py``,
+``test_benchmark_harness.py`` and ``test_benchmark_form.py`` (PR 40)."""
 
 import json
 import math
@@ -16,17 +16,13 @@ import pytest
 
 import benchmark_tiny
 import benchmark_tiny_qwen
-from benchmarks.harness import flash_parts, flops, peaks, trace
+from benchmarks.harness import flops, peaks, trace
 from benchmarks.harness import qwen3_next_parts as parts
-from benchmarks.harness.spec import Spec
 from benchmarks.run import RunRecord
-from test_benchmark_form import faults
 from test_benchmark_harness import _run as _run_cell, _well_formed
 from test_benchmark_harness import world  # noqa: F401 — a fixture
-from test_benchmark_parts import (CONV_STEP, GPT_MS, GPT_STEP, MOSAIC, MS,
-                                   PEAK, STEPS, _read, _run)
-
-GPT_CELLS = ["gpt2s-1k", "gpt2s-16k", "gpt2s-1k-dp4"]
+from test_benchmark_parts import (CONV_STEP, GPT_STEP, MOSAIC, MS, PEAK,
+                                   STEPS, _read, _run)
 
 
 def _qwen_cfg():
@@ -271,32 +267,6 @@ def test_a_qwen_part_reads_none_where_the_program_has_no_such_scope(
     assert _read(metric, run) is None
 
 
-def test_qwen_metrics_are_entries_of_their_one_cell():
-    import benchmark_tiny
-    from benchmarks.harness.spec import Spec
-
-    spec = Spec(benchmark_tiny.REPO)
-    entries = {m["name"]: m for m in spec.data["per_layer"]}
-    for name in list(QWEN_MS) + ["gdn_scan_roofline", "moe_tiles",
-                                 "moe_experts_roofline",
-                                 "flash_gqa_roofline"]:
-        entry = entries[name]
-        assert entry["source"] == "device_trace" and entry["moves"] == "mfu"
-        assert entry["workloads"] == ["qwen3next-8k"]
-    mine = spec.cell("qwen3next-8k")
-    assert mine.end_to_end == ["tokens_per_s_chip", "mfu", "setup_s"]
-    # the readers that take GPT-2's keys from the configuration are not
-    # listed
-    assert not {"flash_roofline", "flash_fwd_roofline",
-                "flash_dq_roofline", "flash_dkv_roofline",
-                "optimizer_ms"} & set(mine.per_layer)
-    # flash_ms takes every Mosaic call for a flash kernel: the scan and the
-    # expert layer are XLA ops (tests/benchmark/test_benchmark_kernels_v5e)
-    assert {"fwd_ms", "bwd_ms", "unscoped_ms", "grad_pack_ms", "flash_ms",
-            "flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms", "hbm_gb",
-            "device_idle_pct"} <= set(mine.per_layer)
-
-
 # -- the toy cell through the harness -----------------------------------------
 
 
@@ -344,60 +314,3 @@ def test_tiny_qwen_adds_files_and_entries_and_edits_none(tiny_qwen_root,
                 {"workloads": theirs["workloads"] + extra} if extra else {}))
 
 
-# -- the benchmark with its fifth cell ----------------------------------------
-# (the three tests of the accepted files that pin it to four)
-
-
-def test_the_tiny_benchmarks_keep_the_form(tmp_path):
-    # five real cells and three toys: a quarter of eight is two, so the
-    # toy four-chip cell beside the real one is no fault any more
-    assert faults(benchmark_tiny.make(str(tmp_path / "plain"))) == []
-    assert faults(benchmark_tiny_qwen.make(str(tmp_path / "qwen"))) == []
-
-
-def test_which_cells_list_the_flash_parts_the_pack_and_the_update():
-    """Readers that go by scope or kernel name find their ops in any cell
-    that has them, so ``qwen3next-8k`` is appended there; the accepted
-    rooflines and ``optimizer_ms`` take GPT-2's keys from the configuration
-    and stay with the GPT cells.  This PR's own readers list its cell."""
-    spec = Spec(benchmark_tiny.REPO)
-    entries = {m["name"]: m for m in spec.data["per_layer"]}
-    for name in list(GPT_MS) + [f"flash_{k}_roofline"
-                                for k in flash_parts.KERNELS]:
-        entry = entries[name]
-        assert entry["source"] == "device_trace" and entry["moves"] == "mfu"
-        if name.endswith("_roofline") or name == "optimizer_ms":
-            assert entry["workloads"] == GPT_CELLS
-        elif name.startswith("flash_") or name == "grad_pack_ms":
-            assert entry["workloads"] == GPT_CELLS + ["qwen3next-8k"]
-    new = ["gdn_ms", "gdn_scan_ms", "gdn_scan_roofline", "moe_ms",
-           "moe_route_ms", "moe_experts_roofline", "moe_tiles",
-           "flash_gqa_roofline"]
-    assert [m["name"] for m in spec.data["per_layer"]][-len(new):] == new
-    for name in new:
-        assert entries[name]["workloads"] == ["qwen3next-8k"]
-        assert entries[name]["source"] == "device_trace"
-        assert entries[name]["moves"] == "mfu"
-    cell = spec.cell("qwen3next-8k")
-    assert set(new) | {"fwd_ms", "bwd_ms", "unscoped_ms", "grad_pack_ms",
-                       "flash_ms", "flash_fwd_ms", "flash_dq_ms",
-                       "flash_dkv_ms"} <= set(cell.per_layer)
-    assert not {"optimizer_ms", "flash_roofline", "conv_roofline"} & set(
-        cell.per_layer)
-    assert set(cell.end_to_end) == {"tokens_per_s_chip", "mfu", "setup_s"}
-
-
-def test_every_cell_of_the_benchmark_finds_its_files_the_fifth_too():
-    spec = Spec(benchmark_tiny.REPO)
-    chips = {}
-    for entry in spec.data["workloads"]:
-        cell = spec.cell(entry["name"])
-        chips[cell.name] = cell.chips
-        assert "setup_s" in cell.end_to_end and "mfu" in cell.end_to_end
-        assert all(hasattr(m, "read") for m in cell.per_layer.values())
-        assert cell.adapter.flops_per_item(cell.cfg, cell.mix) > 0
-        limits = cell.adapter.limits(cell.cfg, cell.mix)
-        assert {"loss_gap", "grad_norm_gap", "update_norm_gap",
-                "final_loss"} <= set(limits)
-    assert chips == {"gpt2s-1k": 1, "resnet50-b256": 1, "gpt2s-16k": 1,
-                     "gpt2s-1k-dp4": 4, "qwen3next-8k": 1}

@@ -3,10 +3,10 @@
 ``run.py`` builds the step.  A recomputed layer keeps what the Pallas
 forward kernels wrote for their backward kernels
 (``models/qwen3_next.recomputed``), so the compiled step calls each forward
-kernel once a layer, not twice; what that costs is memory, held here
-beside the benchmark's weights.  No chip is attached and nothing runs.
-(A file of its own: ``test_benchmark_sdar_v5e.py`` holds the parent's eight
-forward calls and is pinned in ``tests/conftest.py``.)"""
+kernel once a layer, not twice.  No chip is attached and nothing runs.
+(What the steps hold since the layers keep more than the kernels' outputs,
+and which products still run a second time, is
+``test_benchmark_keep_v5e.py``'s.)"""
 
 import re
 
@@ -20,26 +20,14 @@ from test_benchmark_kernels_v5e import (  # noqa: F401 — fixtures
     no_compile_cache, one_chip, topo)
 
 LAYERS = 4
-CHIP_BYTES = 16 * 2 ** 30
-#: cell -> (Mosaic calls of the step by kernel name, the band round the
-#: ``hbm_gb`` predicted before the chip (PERF.md section 6, PR 33: 9.665 and
-#: 6.582, the parent's 7.612 and 6.164), float32 parameters the benchmark
-#: keeps beside the state through the checked steps).  ``sdar-bd4-8k``
-#: holds 1.5 GB more than the 0.55 GB of ``o`` and ``lse`` it keeps: XLA
-#: makes ``lse`` from the forward kernel's ``m`` and ``l`` only just before
-#: the backward kernels, and those are ``[1, 32, 16384, 1]`` float32, padded
-#: to 128 lanes, 268 MB each, alive from the forward pass on (PERF.md
-#: section 7).
+#: cell -> Mosaic calls of the step by kernel name
 CELLS = {
-    "sdar-bd4-8k": (
-        {"hvd_flash_fwd": LAYERS, "hvd_flash_dq": LAYERS,
-         "hvd_flash_dkv": LAYERS},
-        (9.5e9, 9.8e9), 456_346_624),
+    "sdar-bd4-8k": {"hvd_flash_fwd": LAYERS, "hvd_flash_dq": LAYERS,
+                    "hvd_flash_dkv": LAYERS},
     # three DeltaNet layers and one of full attention
-    "qwen3next-8k": (
-        {"hvd_flash_fwd": 1, "hvd_flash_dq": 1, "hvd_flash_dkv": 1,
-         "hvd_gdn_scan_fwd": 3, "hvd_gdn_scan_bwd": 3},
-        (6.5e9, 6.7e9), 424_340_544),
+    "qwen3next-8k": {"hvd_flash_fwd": 1, "hvd_flash_dq": 1,
+                     "hvd_flash_dkv": 1, "hvd_gdn_scan_fwd": 3,
+                     "hvd_gdn_scan_bwd": 3},
 }
 
 
@@ -96,14 +84,16 @@ def test_a_layer_calls_each_forward_kernel_once(cell, steps):
     calls = re.findall(
         r"%(\S+?)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
         steps[cell].as_text())
-    assert {k: calls.count(k) for k in set(calls)} == CELLS[cell][0]
+    assert {k: calls.count(k) for k in set(calls)} == CELLS[cell]
 
 
-@pytest.mark.parametrize("cell", sorted(CELLS))
+@pytest.mark.parametrize("cell", ["qwen3next-8k"])
 def test_the_layers_are_still_recomputed(cell, steps):
-    """Only the kernels' outputs are kept: the projections run again in
-    the backward pass (``rematted_computation`` on their path), as the
-    cell's configuration says (``remat``: ``decoder_layer``)."""
+    """A product still runs again in the backward pass
+    (``rematted_computation`` on its path), as the cell's configuration
+    says (``remat``: ``decoder_layer``); no kernel does.  In ``sdar-bd4-8k``
+    no product does any more (PR 37:
+    ``test_benchmark_keep_v5e.py::test_which_parts_still_run_a_second_time``)."""
     text = steps[cell].as_text()
     again = [line for line in text.splitlines()
              if "rematted_computation" in line and "dot_general" in line]
@@ -113,14 +103,3 @@ def test_the_layers_are_still_recomputed(cell, steps):
                 and 'custom_call_target="tpu_custom_call"' in line]
 
 
-@pytest.mark.parametrize("cell", sorted(CELLS))
-def test_what_is_kept_fits_beside_the_benchmarks_weights(cell, steps):
-    """``hbm_gb`` as a traced run prints it (arguments + temporaries)
-    inside the band predicted for it before the chip (PERF.md section 6,
-    PR 33), and room for the benchmark's float32 weights through the
-    checked steps."""
-    _, (low, high), parameters = CELLS[cell]
-    mem = steps[cell].memory_analysis()
-    hbm = mem.argument_size_in_bytes + mem.temp_size_in_bytes
-    assert low < hbm < high, hbm
-    assert hbm + 4 * parameters < 0.75 * CHIP_BYTES

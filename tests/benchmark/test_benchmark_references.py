@@ -233,3 +233,148 @@ def test_sketch_differences_estimate_the_norm_of_the_difference():
         < gap / 5
     assert check.sketch_gap(to_lists(sa), to_lists(sa), norms,
                             list(norms)) == 0.0
+
+
+# -- the walk holds no copy of the parameters that it does not read (PR 40) --
+
+def _mlp_params(seed=0, d=24, h=40):
+    rng = np.random.default_rng(seed)
+    draw = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
+    return {"a": {"w": 0.3 * draw(d, h), "b": jnp.zeros((h,), jnp.float32)},
+            "c": {"w": 0.3 * draw(h, 1)}}
+
+
+def _mlp_loss(params, x, y):
+    hidden = jnp.tanh(x @ params["a"]["w"] + params["a"]["b"])
+    return jnp.mean(jnp.square((hidden @ params["c"]["w"])[:, 0] - y))
+
+
+def _mlp_batches(steps=3, rows=4, d=24):
+    rng = np.random.default_rng(7)
+    return [(rng.normal(size=(rows, d)).astype(np.float32),
+             rng.normal(size=(rows,)).astype(np.float32))
+            for _ in range(steps)]
+
+
+def _plain_walk(loss_fn, params, batches, *, optimizer, lr, rows_per_block):
+    """``train_steps`` as it was before it gave anything away: every
+    update's inputs and outputs side by side, the blocks' sum and their
+    mean two trees, the start weights held through the steps."""
+    init, update = common.OPTIMIZERS[optimizer]
+    grad_fn = jax.jit(jax.value_and_grad(loss_fn))
+    step_fn = jax.jit(lambda p, g, s: update(p, g, s, lr=lr))
+    add = jax.jit(lambda a, b: jax.tree_util.tree_map(jnp.add, a, b))
+    start, state = params, init(params)
+    losses, first = [], None
+    for arrays in batches:
+        blocks = arrays[0].shape[0] // rows_per_block
+        loss_sum, grad_sum = 0.0, None
+        for i in range(blocks):
+            part = tuple(jnp.asarray(a[i * rows_per_block:
+                                       (i + 1) * rows_per_block])
+                         for a in arrays)
+            loss, grads = grad_fn(params, *part)
+            loss_sum = loss_sum + loss
+            grad_sum = grads if grad_sum is None else add(grad_sum, grads)
+        grads = jax.tree_util.tree_map(lambda g: g / blocks, grad_sum)
+        if first is None:
+            first = (common.leaf_norms(common.flatten(grads)),
+                     common.leaf_sketches(common.flatten(grads)))
+        params, state = step_fn(params, grads, state)
+        losses.append(float(loss_sum) / blocks)
+    moved = common.leaf_diff_norms(common.flatten(params),
+                                   common.flatten(start))
+    return {"losses": losses,
+            "grad_norms": {k: float(v) for k, v in first[0].items()},
+            "grad_sketches": {k: [float(x) for x in np.asarray(v)]
+                              for k, v in first[1].items()},
+            "update_norms": {k: float(v) for k, v in moved.items()}}
+
+
+@pytest.mark.parametrize("rows_per_block", [4, 2, 1])
+@pytest.mark.parametrize("optimizer", ["adam", "sgd_momentum"])
+def test_train_steps_returns_what_a_plain_walk_returns(optimizer,
+                                                       rows_per_block):
+    """Giving buffers away changes where a result is written, not what it
+    is: equality, no tolerance — from a tree and from a function that
+    makes the tree."""
+    kw = dict(optimizer=optimizer, lr=1e-2, rows_per_block=rows_per_block)
+    want = _plain_walk(_mlp_loss, _mlp_params(), _mlp_batches(), **kw)
+    assert common.train_steps(_mlp_loss, _mlp_params(), _mlp_batches(),
+                              **kw) == want
+    assert common.train_steps(_mlp_loss, _mlp_params, _mlp_batches(),
+                              **kw) == want
+    assert len(want["losses"]) == 3 and min(
+        want["update_norms"].values()) > 0
+
+
+@pytest.mark.parametrize("optimizer", ["adam", "sgd_momentum"])
+def test_the_callers_parameters_survive_the_walk(optimizer):
+    params = _mlp_params(3)
+    before = jax.tree_util.tree_map(np.array, params)
+    kw = dict(optimizer=optimizer, lr=1e-2, rows_per_block=2)
+    once = common.train_steps(_mlp_loss, params, _mlp_batches(), **kw)
+    again = common.train_steps(_mlp_loss, params, _mlp_batches(), **kw)
+    assert once == again
+    # ``follow`` is handed the same arrays twice, as the controls' tests
+    # hand them (``ref["init"]`` returns one set to the reference and to
+    # its control)
+    ref = {"init": lambda seed: common.flatten(params),
+           "loss": lambda precision: _mlp_loss,
+           "optimizer": optimizer, "lr": 1e-2}
+    assert common.follow(ref, 0, _mlp_batches(), 2) == once
+    assert common.follow(ref, 0, _mlp_batches(), 2) == once
+    for got, want in zip(jax.tree_util.tree_leaves(params),
+                         jax.tree_util.tree_leaves(before)):
+        assert not got.is_deleted()
+        assert np.array_equal(np.asarray(got), want)
+
+
+def _live_bytes():
+    return sum(a.nbytes for a in jax.live_arrays())
+
+
+@pytest.mark.parametrize("rows_per_block", [4, 1])
+@pytest.mark.parametrize("made_by", ["the caller", "the walk"])
+def test_the_walk_holds_sixteen_bytes_a_parameter_at_an_update(
+        monkeypatch, made_by, rows_per_block):
+    """What is alive when an update is called, beyond what was alive before
+    the walk, read through the walk's own seam: the ``OPTIMIZERS`` entry,
+    patched to record and then call the real one.  Without ``jit`` every
+    step calls it (under ``jit`` only a trace would), and what is alive
+    then is what the walk holds by name: its own parameters (from the
+    second step on; in the first it reads the caller's), Adam's two
+    moments and one gradient, 16 bytes a float32 parameter, with the
+    batch's last block and a few scalars.  The walk as it was held 20
+    beyond the caller's from the second step on: the new parameters, the
+    moments, the blocks' sum and their mean."""
+    init, update = common.OPTIMIZERS["adam"]
+    seen = []
+
+    def recording(params, grads, state, **kw):
+        seen.append(_live_bytes())
+        return update(params, grads, state, **kw)
+
+    monkeypatch.setitem(common.OPTIMIZERS, "adam", (init, recording))
+    params, batches = _mlp_params(5, d=64, h=96), _mlp_batches(3, d=64)
+    size = sum(x.nbytes for x in jax.tree_util.tree_leaves(params))
+    batch = sum(a.nbytes for a in batches[0])
+    slack = batch + 4096
+    if made_by == "the walk":
+        host = jax.tree_util.tree_map(np.asarray, params)
+        del params
+        params = lambda: jax.tree_util.tree_map(jnp.asarray, host)  # noqa: E731
+    before = _live_bytes()
+    with jax.disable_jit():
+        common.train_steps(_mlp_loss, params, batches, optimizer="adam",
+                           lr=1e-2, rows_per_block=rows_per_block)
+    held = [s - before for s in seen]
+    assert len(held) == 3
+    # first update: the walk's own are the moments and the gradient, and,
+    # where it made them itself, the parameters
+    first = 4 * size if made_by == "the walk" else 3 * size
+    assert 3 * size <= held[0] <= first + slack
+    # later updates: parameters, moments, gradient; the start weights the
+    # walk made itself are gone
+    assert all(4 * size <= h <= 4 * size + slack for h in held[1:]), (
+        held, size)

@@ -4,11 +4,10 @@ and the second cell ``gpt2s-4k`` (traffic ``seq4k-b2``).
 
 A file of its own because the other files of this directory are the
 benchmark's (``BENCHMARK.json`` lists ``tests/benchmark`` under ``paths``)
-and a PR that changes the program may only add beside them.  Three more of
-their tests pin the benchmark to the five cells and the metric lists it had
-before this PR; ``tests/conftest.py`` marks them as expected failures by
-name, and the last section here holds the same assertions with the two new
-cells in."""
+and a PR that changes the program may only add beside them.  Which cells
+list which metric, every cell's files and the toy benchmarks' form follow
+``BENCHMARK.json`` in ``test_benchmark_lists.py``, ``test_benchmark_harness.py``
+and ``test_benchmark_form.py`` (PR 40)."""
 
 import json
 import math
@@ -20,7 +19,6 @@ import numpy as np
 import pytest
 
 import benchmark_tiny
-import benchmark_tiny_qwen
 import benchmark_tiny_sdar
 from benchmarks.configs import sdar_30b_a3b_chat as adapter
 from benchmarks.harness import check, flops, peaks, trace
@@ -28,13 +26,11 @@ from benchmarks.harness import sdar_parts as parts
 from benchmarks.harness.spec import Spec
 from benchmarks.references import common, sdar
 from benchmarks.run import RunRecord
-from test_benchmark_form import faults
 from test_benchmark_harness import _run as _run_cell, _well_formed
 from test_benchmark_harness import world  # noqa: F401 — a fixture
 from test_benchmark_parts import (CONV_STEP, GPT_STEP, MOSAIC, MS, PEAK,
                                    STEPS, _read, _run)
 
-GPT_CELLS = ["gpt2s-1k", "gpt2s-16k", "gpt2s-1k-dp4"]
 KERNEL_SHARES = ["flash_bd_fwd_roofline", "flash_bd_dq_roofline",
                  "flash_bd_dkv_roofline"]
 NEW_READERS = ["flash_bd_roofline", "bd_experts_roofline", "bd_noise_ms",
@@ -346,7 +342,7 @@ def test_flash_bd_roofline_is_least_time_over_the_three_kernels(capsys):
     least, bound = flops.least_seconds(*need, PEAK)
     assert bound == "compute"
     got = _read("flash_bd_roofline", _sdar_run())
-    # forward twice (remat) 4 + 4, dq 3, dkv 4
+    # the hand-built trace's kernels: forward 4 + 4, dq 3, dkv 4 ms a step
     assert math.isclose(got, 100.0 * least / (15.0 * MS))
     assert "flash_bd_roofline:" in capsys.readouterr().out
     # the accepted readers find the same kernels by name
@@ -467,94 +463,15 @@ def test_tiny_sdar_adds_files_and_entries_and_edits_none(tiny_sdar_root,
                      "traffic/seq64-b2-bd4.json"}
 
 
-# -- the benchmark with its sixth and seventh cell -------------------------------
-# (the tests of the accepted files that pin it to five cells and to the
-# metric lists of PR 27, brought up to date)
+# -- the two cells in ``BENCHMARK.json`` -------------------------------------------
+# (which accepted readers list them is ``test_benchmark_lists.py``'s)
 
 
-def test_the_tiny_benchmarks_keep_the_form_with_seven_cells(tmp_path):
-    assert faults(benchmark_tiny.REPO) == []
-    assert faults(benchmark_tiny.make(str(tmp_path / "plain"))) == []
-    assert faults(benchmark_tiny_qwen.make(str(tmp_path / "qwen"))) == []
-    assert faults(benchmark_tiny_sdar.make(str(tmp_path / "sdar"))) == []
-
-
-def test_every_cell_of_the_benchmark_finds_its_files_all_seven():
-    spec = Spec(benchmark_tiny.REPO)
-    chips = {}
-    for entry in spec.data["workloads"]:
-        cell = spec.cell(entry["name"])
-        chips[cell.name] = cell.chips
-        assert "setup_s" in cell.end_to_end and "mfu" in cell.end_to_end
-        assert all(hasattr(m, "read") for m in cell.per_layer.values())
-        assert cell.adapter.flops_per_item(cell.cfg, cell.mix) > 0
-        limits = cell.adapter.limits(cell.cfg, cell.mix)
-        assert {"loss_gap", "grad_norm_gap", "grad_sketch_gap",
-                "update_norm_gap", "final_loss"} <= set(limits)
-        assert len(entry["why"]) <= 200
-    assert chips == {"gpt2s-1k": 1, "resnet50-b256": 1, "gpt2s-16k": 1,
-                     "gpt2s-1k-dp4": 4, "qwen3next-8k": 1,
-                     "sdar-bd4-8k": 1, "gpt2s-4k": 1}
-    assert [w["name"] for w in spec.data["workloads"]][-2:] == [
-        "sdar-bd4-8k", "gpt2s-4k"]
-
-
-def test_which_cells_list_which_metrics_after_pr_30():
-    """Readers that go by scope or kernel name find their ops in any cell
-    that has them, so the two new cells are appended there; the accepted
-    rooflines and ``optimizer_ms`` take GPT-2's keys from the configuration
-    and go to ``gpt2s-4k`` alone; ``moe_experts_roofline`` and
-    ``flash_gqa_roofline`` take the rows from the traffic's first array and
-    a causal pair count and stay with ``qwen3next-8k`` (this PR's own two
-    rooflines count both copies' rows and the allowed pairs)."""
-    spec = Spec(benchmark_tiny.REPO)
-    entries = {m["name"]: m for m in spec.data["per_layer"]}
-    qwen, new = ["qwen3next-8k"], ["sdar-bd4-8k", "gpt2s-4k"]
-    for name in ("flash_ms", "flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms",
-                 "grad_pack_ms", "loss_ms"):
-        assert entries[name]["workloads"] == GPT_CELLS + qwen + new, name
-    for name in ("fwd_ms", "bwd_ms", "unscoped_ms"):
-        assert entries[name]["workloads"] == [
-            "gpt2s-1k", "resnet50-b256", "gpt2s-16k", "gpt2s-1k-dp4"] \
-            + qwen + new, name
-    for name in ("flash_roofline", "flash_fwd_roofline", "flash_dq_roofline",
-                 "flash_dkv_roofline", "optimizer_ms"):
-        assert entries[name]["workloads"] == GPT_CELLS + ["gpt2s-4k"], name
-    for name in ("moe_ms", "moe_route_ms", "moe_tiles"):
-        assert entries[name]["workloads"] == qwen + ["sdar-bd4-8k"], name
-    for name in ("gdn_ms", "gdn_scan_ms", "gdn_scan_roofline",
-                 "moe_experts_roofline", "flash_gqa_roofline"):
-        assert entries[name]["workloads"] == qwen, name
-    # PR 26's eight, PR 27's loss_ms, then this PR's six are the last
-    names = [m["name"] for m in spec.data["per_layer"]]
-    assert names[-15:] == [
-        "gdn_ms", "gdn_scan_ms", "gdn_scan_roofline", "moe_ms",
-        "moe_route_ms", "moe_experts_roofline", "moe_tiles",
-        "flash_gqa_roofline", "loss_ms"] + NEW_READERS
-    for name in NEW_READERS:
-        assert entries[name]["workloads"] == ["sdar-bd4-8k"]
-        assert entries[name]["source"] == "device_trace"
-        assert entries[name]["moves"] == "mfu"
-    for name in ["flash_bd_roofline"] + KERNEL_SHARES:
-        assert entries[name]["layer"] == entries["flash_ms"]["layer"]
-    assert entries["bd_experts_roofline"]["layer"] \
-        == entries["moe_ms"]["layer"]
-    assert entries["bd_noise_ms"]["layer"] == entries["loss_ms"]["layer"]
-    rates = next(m for m in spec.data["end_to_end"]
-                 if m["name"] == "tokens_per_s_chip")
-    assert rates["workloads"] == GPT_CELLS + qwen + new
-
-
-def test_what_the_two_new_cells_report():
+def test_the_two_cells_report_their_readers_and_gpt2s_4k_is_gpt2s_16ks_kind():
     spec = Spec(benchmark_tiny.REPO)
     mine = spec.cell("sdar-bd4-8k")
     assert mine.end_to_end == ["tokens_per_s_chip", "mfu", "setup_s"]
-    assert set(mine.per_layer) == {
-        "init_s", "compile_s", "input_wait_ms", "dispatch_ms", "fwd_bwd_ms",
-        "device_idle_pct", "hbm_gb", "fwd_ms", "bwd_ms", "flash_ms",
-        "flash_fwd_ms", "flash_dq_ms", "flash_dkv_ms", "grad_pack_ms",
-        "unscoped_ms", "moe_ms", "moe_route_ms", "moe_tiles", "loss_ms",
-        *NEW_READERS}
+    assert set(NEW_READERS) <= set(mine.per_layer)
     mid, long = spec.cell("gpt2s-4k"), spec.cell("gpt2s-16k")
     assert mid.end_to_end == long.end_to_end == [
         "tokens_per_s_chip", "mfu", "setup_s"]      # no tail: 200 steps

@@ -61,18 +61,21 @@ def sdar_step(topo, no_compile_cache):  # noqa: F811
         hvd.shutdown()
 
 
-def test_the_step_has_three_kernels_and_four_calls_a_layer(sdar_step):
-    """A layer calls the forward kernel twice (``nn.remat``), dq and dkv
-    once, and nothing else of the step is a Mosaic call: what ``flash_ms``
-    finds by call target and ``flash_bd_roofline`` by name are the flash
-    kernels alone."""
+def test_the_steps_mosaic_calls_are_the_flash_kernels_under_their_scopes(
+        sdar_step):
+    """Nothing of the step but the three flash kernels is a Mosaic call:
+    what ``flash_ms`` finds by call target and ``flash_bd_roofline`` by name
+    are the flash kernels alone, and the scopes the cell's readers go by
+    are in the compiled text.  (How often a layer calls each kernel, once
+    since PR 33 kept the forward's output across the recompute, is
+    ``test_benchmark_recompute_v5e.py``'s.)"""
     text = sdar_step.as_text()
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 4 * LAYERS
     by_kernel = {k: sum(f"hvd_flash_{k}" in line for line in calls)
                  for k in ("fwd", "dq", "dkv")}
-    assert by_kernel == {"fwd": 2 * LAYERS, "dq": LAYERS, "dkv": LAYERS}
+    assert sum(by_kernel.values()) == len(calls) and min(
+        by_kernel.values()) >= LAYERS
     for scope in ("hvd_bd_noise", "hvd_bd_head_rows", "hvd_moe_route",
                   "hvd_moe_experts", "hvd_loss/"):
         assert scope in text, scope
