@@ -19,7 +19,9 @@ the kernels agree with a dense-mask float32 softmax at ``[1, 16384, 32,
 128]``; at latent attention's head sizes (q and k ``[1, 8192, 32, 192]``, v
 ``[1, 8192, 32, 128]``) with a dense float32 causal softmax; the chunked
 gated delta rule
-agrees with its token-by-token recurrence at ``[1, 2048, 32, 128]``; on
+agrees with its token-by-token recurrence at ``[1, 2048, 32, 128]``, and
+the chunked state-space scan with its own at ``[1, 8192, 64, 64]`` (a state
+of 128 in 8 groups), with the scan's time and share of its roofline; on
 more than one chip, ring attention's Pallas variant
 agrees with it too (gradients over the whole ring); the GPT step's
 compiled module holds Mosaic custom calls; every loss is finite and the
@@ -36,6 +38,7 @@ The last line of a passing run is
 
 from __future__ import annotations
 
+import functools
 import itertools
 import json
 import os
@@ -427,6 +430,77 @@ def scan_phase() -> None:
            chunk=64, tolerance=SCAN_TOL, rel_max_err=errs)
 
 
+def ssd_phase(s: int = 8192, h: int = 64, p: int = 64, g: int = 8,
+              n: int = 128, chunk: int = 128) -> None:
+    """The chunked state-space scan (``ops/ssd.py``, XLA) alone at the shape
+    ``nemotron3-8k``'s Mamba-2 blocks run it, ``[1, 8192, 64, 64]`` bf16
+    with B and C in 8 groups of 128: against the recurrence itself,
+    forward and gradients, and its time forward and forward + backward
+    with the share of its roofline that is (the recurrence's three ``P x
+    N`` products a head a token and x, dt, B, C, y and their gradients once
+    each: what ``benchmarks/harness/nemotron_h_parts.scan_train_required``
+    charges a block), for the ``perf_opt`` that follows."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.ssd import ssd, ssd_recurrence
+    from horovod_tpu.utils import flops
+
+    keys = jax.random.split(jax.random.PRNGKey(2), 7)
+    x, w = (jax.random.normal(kk, (1, s, h, p), jnp.float32).astype(
+        jnp.bfloat16) for kk in keys[:2])
+    bm, cm = (jax.random.normal(kk, (1, s, g, n), jnp.float32).astype(
+        jnp.bfloat16) for kk in keys[2:4])
+    # steps log-uniform on [1e-3, 0.1] as the seeded dt_bias gives, rates
+    # -1 .. -64 as A_log = log(1..64) gives
+    dt = jnp.exp(jax.random.uniform(keys[4], (1, s, h), minval=np.log(1e-3),
+                                    maxval=np.log(0.1)))
+    rate = -jnp.arange(1, h + 1, dtype=jnp.float32)
+    skip = jnp.ones((h,), jnp.float32)
+    args = (x, dt, rate, bm, cm, skip)
+
+    def loss_of(fn):
+        return lambda *a: jnp.sum(fn(*a).astype(jnp.float32)
+                                  * w.astype(jnp.float32))
+
+    chunked = functools.partial(ssd, chunk=chunk)
+    # against the recurrence over the first ``checked`` tokens: its
+    # gradient keeps a float32 state a token (2 MB: 17 GB at 8192)
+    checked = 1024
+    short = tuple(a[:, :checked] if a.ndim > 1 else a for a in args)
+    w_full, w = w, w[:, :checked]
+    got = {}
+    for name, fn in (("chunked", chunked), ("recurrence", ssd_recurrence)):
+        with jax.default_matmul_precision(
+                "highest" if name == "recurrence" else "default"):
+            out = jax.jit(fn)(*short)
+            grads = jax.jit(jax.grad(loss_of(fn), argnums=range(6)))(*short)
+        got[name] = [np.asarray(a, np.float32) for a in (out, *grads)]
+    w = w_full
+    errs = {}
+    for label, a, b_ in zip(("y", "dx", "ddt", "dA", "dB", "dC", "dD"),
+                            got["chunked"], got["recurrence"]):
+        check(np.isfinite(a).all(), f"chunked ssd {label} is not finite")
+        errs[label] = float(np.abs(a - b_).max() / np.abs(b_).max())
+        check(errs[label] <= SCAN_TOL,
+              f"chunked ssd {label} differs from the recurrence by "
+              f"{errs[label]:.3g} of its largest element (> {SCAN_TOL})")
+    forward = jax.jit(chunked)
+    both = jax.jit(jax.grad(loss_of(chunked), argnums=range(6)))
+    fwd_ms, both_ms = _ms_a_call(forward, *args), _ms_a_call(both, *args)
+    products = 2.0 * 3 * s * h * p * n
+    tensors = s * ((h * p * 2 + 2 * g * n) * 2 + h * 4)
+    peak, hbm = flops.require_peak_flops(), flops.hbm_bytes_per_sec()
+    least = {"fwd": max(products / peak, tensors / hbm),
+             "fwd_bwd": max(3 * products / peak, 3 * tensors / hbm)}
+    report("ssd_vs_recurrence", shape=[1, s, h, p], groups=g, state=n,
+           chunk=chunk, dtype="bfloat16", checked_tokens=checked,
+           tolerance=SCAN_TOL,
+           rel_max_err=errs, fwd_ms=fwd_ms, fwd_bwd_ms=both_ms,
+           fwd_roofline_pct=100 * least["fwd"] * 1e3 / fwd_ms,
+           fwd_bwd_roofline_pct=100 * least["fwd_bwd"] * 1e3 / both_ms)
+
+
 def ring_phase(n: int) -> None:
     """Ring attention's Pallas variant over all ``n`` chips against
     softmax_attention on the whole sequence: the flash kernels with traced
@@ -619,6 +693,16 @@ def main() -> int:
            compile_cache_entries=len(entries_before),
            peak_flops=flops.require_peak_flops())
 
+    only = [globals()[name] for name in sys.argv[1:]]
+    if only:
+        # the named phases alone (none of them takes an argument it lacks a
+        # default for): ``python chip_smoke.py ssd_phase``
+        for phase in only:
+            phase()
+        hvd.shutdown()
+        print(json.dumps({"ok": True, "device": device,
+                          "phases": sys.argv[1:]}), flush=True)
+        return 0
     flash_phase()
     # Qwen3-Next's attention layer: 16 heads of 256 (the kernels were swept
     # at 64 only), and its DeltaNet layers' scan
@@ -631,6 +715,8 @@ def main() -> int:
     # Mellum-2's window layers: 32 heads of 128, a row sees 1024 keys
     sliding_window_phase()
     scan_phase()
+    # Nemotron-3-Nano's Mamba-2 blocks: 64 heads of 64 over a state of 128
+    ssd_phase()
     if n > 1:
         ring_phase(n)
     gpt_phase(n)

@@ -27,6 +27,9 @@ hvd_moe_layers_traced_total     counter    routed expert layers traced, by
                                            ``held``/``top_k``/``rule``/``groups``
 hvd_mla_layers_traced_total     counter    latent-attention layers traced, by
                                            ``qk``/``v``/``latent``
+hvd_ssm_layers_traced_total     counter    Mamba-2 state-space mixers traced, by
+                                           ``heads``/``head_dim``/``state``/
+                                           ``groups``/``chunk``
 hvd_bd_layers_traced_total      counter    block-diffusion attention layers
                                            traced, by ``block``
 hvd_step_seconds                histogram  train-step cadence (dispatch-to-
@@ -224,8 +227,9 @@ MOE_LAYERS = registry.counter(
     "Routed expert layers (parallel/moe.routed_experts) traced (per "
     "compile, not per step), by how many experts the layer holds here, "
     "how many a token picks, the routing rule's name (route_top_k: "
-    "softmax; route_sigmoid_top_k: sigmoid scores with a selection bias) "
-    "and the groups of rows the call carries one accumulator of the "
+    "softmax; route_sigmoid_top_k: sigmoid scores with a selection bias; "
+    "with '+relu2' after it where the experts are down(relu(up x)^2) and "
+    "not the gated SiLU pair) and the groups of rows the call carries one accumulator of the "
     "experts' gradients through (1: nothing to carry).",
     ("held", "top_k", "rule", "groups"))
 MLA_LAYERS = registry.counter(
@@ -239,6 +243,12 @@ ATTN_LAYERS = registry.counter(
     "(models/mellum2.py; per compile, not per step), by the kind, the keys a "
     "row of a window layer sees (0: all before it) and the kind's rotary "
     "rule.", ("kind", "window", "rope"))
+SSM_LAYERS = registry.counter(
+    "hvd_ssm_layers_traced_total",
+    "Mamba-2 state-space mixers traced (models/nemotron_h.py; per compile, "
+    "not per step), by the heads, a head's channels, the state's size, the "
+    "groups B and C come in and the scan's chunk (ops/ssd.py).",
+    ("heads", "head_dim", "state", "groups", "chunk"))
 
 STEP_SECONDS = registry.histogram(
     "hvd_step_seconds",
@@ -663,6 +673,18 @@ def record_attn_layer(kind: str, window: int, rope: str) -> None:
         return
     try:
         ATTN_LAYERS.labels(kind, str(window), rope).inc()
+    except Exception:  # noqa: BLE001 — tracing must never fail on metrics
+        pass
+
+
+def record_ssm_layer(heads: int, head_dim: int, state: int, groups: int,
+                     chunk: int) -> None:
+    """One traced Mamba-2 mixer (models/nemotron_h.py)."""
+    if not registry.enabled:
+        return
+    try:
+        SSM_LAYERS.labels(str(heads), str(head_dim), str(state), str(groups),
+                          str(chunk)).inc()
     except Exception:  # noqa: BLE001 — tracing must never fail on metrics
         pass
 

@@ -1,13 +1,15 @@
 """What a recomputed decoder layer keeps: ranked names inside a byte budget.
 
-``remat`` on the decoders (``qwen3_next``, ``sdar``, ``kanana2``) recomputes
-each layer in the backward pass from the layer's input.  What a layer always
-keeps is what the Pallas forward kernels wrote for their backward kernels
-(:data:`KERNEL_RESIDUALS`), so a layer calls each forward kernel once.
+``remat`` on the decoders (``qwen3_next``, ``sdar``, ``kanana2``,
+``mellum2``, ``nemotron_h``) recomputes each layer in the backward pass from
+the layer's input.  What a layer always keeps is what the Pallas forward
+kernels, and the state-space scan, wrote for their backward rules
+(:data:`KERNEL_RESIDUALS`), so a layer calls each of them once.
 Everything else the second run makes again costs time and buys memory, and a
 chip that has the memory need not pay: the models name those outputs
 (``checkpoint_name``; ``models/scopes.py``, ``ops/flash_attention.py``,
-``ops/gated_delta.py``, ``parallel/moe.py``), :data:`RANK` orders the names
+``ops/gated_delta.py``, ``ops/ssd.py``, ``parallel/moe.py``), :data:`RANK`
+orders the names
 by the recompute time a kept byte saves (measured part by part on a v5e:
 PERF.md section 5), and :func:`recomputed` keeps, in that order, what fits
 the bytes :func:`keep_budget` finds free — the device's memory less what the
@@ -30,13 +32,16 @@ from .. import metrics
 from ..ops.flash_attention import (FLASH_K, FLASH_LSE, FLASH_OUT, FLASH_Q,
                                    FLASH_V)
 from ..ops.gated_delta import GDN_IN, GDN_INVERSES, GDN_OUT, GDN_STATES
+from ..ops.ssd import SSD_IN, SSD_OUT
 from ..parallel.moe import ROUTING
 from ..utils import flops
 from . import scopes
 
-#: what the forward kernels wrote and the backward kernels read: kept
-#: whatever the budget (a kernel call to make again, PR 33)
-KERNEL_RESIDUALS = (FLASH_OUT, FLASH_LSE, GDN_OUT, GDN_STATES, GDN_INVERSES)
+#: what the forward kernels wrote and the backward kernels read, and the
+#: state-space scan's output: kept whatever the budget (a kernel call, or
+#: the scan, to make again, PR 33)
+KERNEL_RESIDUALS = (FLASH_OUT, FLASH_LSE, GDN_OUT, GDN_STATES, GDN_INVERSES,
+                    SSD_OUT)
 
 #: The names a layer may keep besides, by the recompute time a kept byte
 #: saves, most first (ms a GB on a v5e, PERF.md section 5, PR 36's table by
@@ -48,10 +53,14 @@ KERNEL_RESIDUALS = (FLASH_OUT, FLASH_LSE, GDN_OUT, GDN_STATES, GDN_INVERSES)
 #: up (11-12); ``in_proj_qkvz`` (11); the k / v side's projections (11);
 #: the scan's operands (11); the convolution's output (10); k and v as the
 #: flash kernels take them (4-6: the repeat or the assembly, the swap).
-RANK = (ROUTING, scopes.KEEP_GDN_NORM, scopes.KEEP_OUT_PROJ,
-        scopes.KEEP_Q_PROJ, FLASH_Q, scopes.KEEP_MLP,
-        scopes.KEEP_GDN_IN_PROJ, scopes.KEEP_KV_PROJ, GDN_IN,
-        scopes.KEEP_GDN_CONV, FLASH_K, FLASH_V)
+#: The state-space mixer's names (``nemotron_h``) stand beside the gated
+#: DeltaNet's, part for part (the same work on the same bytes: its norm, its
+#: ``in_proj``, the scan's operands, its convolution; not measured apart).
+RANK = (ROUTING, scopes.KEEP_GDN_NORM, scopes.KEEP_SSM_NORM,
+        scopes.KEEP_OUT_PROJ, scopes.KEEP_Q_PROJ, FLASH_Q, scopes.KEEP_MLP,
+        scopes.KEEP_GDN_IN_PROJ, scopes.KEEP_SSM_IN_PROJ,
+        scopes.KEEP_KV_PROJ, GDN_IN, SSD_IN, scopes.KEEP_GDN_CONV,
+        scopes.KEEP_SSM_CONV, FLASH_K, FLASH_V)
 
 #: The share of a device's memory a step may fill with its arguments, its
 #: temporaries and one more float32 copy of the parameters beside it (a

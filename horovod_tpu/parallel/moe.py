@@ -267,18 +267,53 @@ def _dot(dtype):
                              preferred_element_type=jnp.float32)
 
 
-def _expert_forward(xs, params, e):
-    """Expert ``e`` on the rows ``xs``: its ``(gate, up, down)`` in
-    ``xs``'s dtype, and ``(a, b, hidden, y)`` with ``a = xs gate``, ``b =
-    xs up`` and ``y = hidden down`` in float32, ``hidden = silu(a) * b`` in
-    ``xs``'s dtype."""
+#: An expert's form: ``{name: its matrices, in the order the tile loops
+#: take them}``, the last one back to the model's width.  ``swiglu``:
+#: ``down(silu(gate x) * up x)``; ``relu2``: ``down(relu(up x) ** 2)``, no
+#: gate.  A caller names the form (``routed_experts(form=...)``); routing,
+#: the sort, the tiles, capacity and groups do not depend on it.
+FORMS = {"swiglu": ("gate_proj", "up_proj", "down_proj"),
+         "relu2": ("up_proj", "down_proj")}
+
+
+def _expert_forward(xs, params, e, form):
+    """Expert ``e`` on the rows ``xs``: its matrices (``FORMS[form]``) in
+    ``xs``'s dtype, and ``(pre, hidden, y)``: what the rows give under the
+    matrices before ``down`` (``swiglu``: ``(xs gate, xs up)``; ``relu2``:
+    ``(xs up,)``) and ``y = hidden down`` in float32, ``hidden`` (``silu(a)
+    * b``; ``relu(a) ** 2``) in ``xs``'s dtype."""
     dtype, dot = xs.dtype, _dot(xs.dtype)
-    gate, up, down = (
+    mats = tuple(
         lax.dynamic_index_in_dim(params[k], e, keepdims=False).astype(dtype)
-        for k in ("gate_proj", "up_proj", "down_proj"))
-    a, b = dot(xs, gate), dot(xs, up)
-    hidden = (jax.nn.silu(a) * b).astype(dtype)
-    return (gate, up, down), (a, b, hidden, dot(hidden, down))
+        for k in FORMS[form])
+    pre = tuple(dot(xs, m) for m in mats[:-1])
+    if form == "relu2":
+        hidden = jnp.square(jax.nn.relu(pre[0])).astype(dtype)
+    else:
+        hidden = (jax.nn.silu(pre[0]) * pre[1]).astype(dtype)
+    return mats, (pre, hidden, dot(hidden, mats[-1]))
+
+
+def _expert_backward(xs, mats, pre, hidden, dy, form):
+    """``(dxs, {name: gradient})`` in float32 from ``dy``, the weighted
+    gradient to the expert's output in ``xs``'s dtype: through ``down``,
+    the form's elementwise part and the matrices before it."""
+    dtype, dot = xs.dtype, _dot(xs.dtype)
+    names = FORMS[form]
+    dhidden = dot(dy, mats[-1].T)
+    if form == "relu2":
+        dpre = ((dhidden * 2.0 * jax.nn.relu(pre[0])).astype(dtype),)
+    else:
+        a, b = pre
+        sig = jax.nn.sigmoid(a)
+        dpre = ((dhidden * b * sig * (1.0 + a * (1.0 - sig))).astype(dtype),
+                (dhidden * a * sig).astype(dtype))
+    dxs = dot(dpre[0], mats[0].T)
+    for d, m in zip(dpre[1:], mats[1:]):
+        dxs = dxs + dot(d, m.T)
+    grads = {k: dot(xs.T, d) for k, d in zip(names, dpre)}
+    grads[names[-1]] = dot(hidden.T, dy)
+    return dxs, grads
 
 
 def _each_group(one_group, carry, rows):
@@ -291,12 +326,13 @@ def _each_group(one_group, carry, rows):
     return lax.scan(one_group, carry, rows)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _held_part(x, weights, params, order, sizes, tile, top_k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _held_part(x, weights, params, order, sizes, tile, top_k, form):
     """The held experts' part of the layer, group by group: a loop over a
     group's tiles (a number the device decides), each one expert's next
-    ``tile`` rows — gathered from the group's ``x``, through the expert,
-    weighted, added to their tokens in the group's own float32 ``[n, d]``.
+    ``tile`` rows — gathered from the group's ``x``, through the expert
+    (of ``form``, :data:`FORMS`), weighted, added to their tokens in the
+    group's own float32 ``[n, d]``.
     ``x``: ``[groups, n, d]``; ``weights``: ``[groups, n * top_k]``
     float32, one an assignment; ``order``: a group's assignments sorted by
     held expert, held ones first; ``sizes``: ``[groups, held]``, rows of
@@ -318,7 +354,7 @@ def _held_part(x, weights, params, order, sizes, tile, top_k):
                 xs = x.at[token].get(mode="fill", fill_value=0)
                 w = weights.at[picked].get(mode="fill", fill_value=0)
             with jax.named_scope(EXPERTS_SCOPE):
-                y = _expert_forward(xs, params, e)[1][3]
+                y = _expert_forward(xs, params, e, form)[1][2]
             with jax.named_scope(ROUTE_SCOPE):
                 return out.at[token].add(y * w[:, None], mode="drop",
                                          unique_indices=True,
@@ -331,21 +367,21 @@ def _held_part(x, weights, params, order, sizes, tile, top_k):
     return _each_group(one_group, None, (x, weights, order, sizes))[1]
 
 
-def _held_part_fwd(x, weights, params, order, sizes, tile, top_k):
-    return (_held_part(x, weights, params, order, sizes, tile, top_k),
+def _held_part_fwd(x, weights, params, order, sizes, tile, top_k, form):
+    return (_held_part(x, weights, params, order, sizes, tile, top_k, form),
             (x, weights, params, order, sizes))
 
 
-def _held_part_bwd(tile, top_k, kept, dout):
+def _held_part_bwd(tile, top_k, form, kept, dout):
     """Group by group and tile by tile again, each tile recomputed from the
     layer's inputs (nothing a tile made is kept).  The gradients to the
-    experts' matrices accumulate in float32 in three ``[held, ...]`` arrays
-    that are zeroed once a call and carried through every group's tile
+    experts' matrices accumulate in float32 in one ``[held, ...]`` array a
+    matrix of the form (three, or ``relu2``'s two) that are zeroed once a call and carried through every group's tile
     loop, an expert's sum in place in its slice; a group's gradient to its
     ``x`` accumulates in float32 in that group's own ``[n, d]``."""
     x, weights, params, order, sizes = kept
     n, d = x.shape[-2:]
-    dtype, dot = x.dtype, _dot(x.dtype)
+    dtype = x.dtype
     scatter = dict(mode="drop", unique_indices=True, indices_are_sorted=True)
 
     def one_group(dparams, group):
@@ -362,18 +398,11 @@ def _held_part_bwd(tile, top_k, kept, dout):
                 dys = dout.at[token].get(mode="fill", fill_value=0).astype(
                     jnp.float32)
             with jax.named_scope(EXPERTS_SCOPE):
-                (gate, up, down), (a, b, hidden, y) = _expert_forward(
-                    xs, params, e)
+                mats, (pre, hidden, y) = _expert_forward(xs, params, e, form)
                 dw = jnp.sum(dys * y, axis=-1)
                 dy = (dys * w[:, None]).astype(dtype)
-                dhidden = dot(dy, down.T)
-                sig = jax.nn.sigmoid(a)
-                da = (dhidden * b * sig * (1.0 + a * (1.0 - sig))).astype(
-                    dtype)
-                db = (dhidden * a * sig).astype(dtype)
-                dxs = dot(da, gate.T) + dot(db, up.T)
-                grads = {"gate_proj": dot(xs.T, da), "up_proj": dot(xs.T, db),
-                         "down_proj": dot(hidden.T, dy)}
+                dxs, grads = _expert_backward(xs, mats, pre, hidden, dy,
+                                              form)
                 dparams = {k: lax.dynamic_update_index_in_dim(
                     acc, lax.dynamic_index_in_dim(acc, e, keepdims=False)
                     + grads[k], e, 0) for k, acc in dparams.items()}
@@ -389,7 +418,7 @@ def _held_part_bwd(tile, top_k, kept, dout):
 
     dparams, (dx, dweights) = _each_group(
         one_group, {k: jnp.zeros(params[k].shape, jnp.float32)
-                    for k in ("gate_proj", "up_proj", "down_proj")},
+                    for k in FORMS[form]},
         (x, weights, order, sizes, dout))
     return (dx, dweights,
             {k: dparams[k].astype(params[k].dtype) for k in dparams},
@@ -401,9 +430,10 @@ _held_part.defvjp(_held_part_fwd, _held_part_bwd)
 
 def routed_experts(x, router_kernel, expert_params, *, top_k: int,
                    first_expert: int = 0, capacity: Optional[int] = None,
-                   route: Callable = route_top_k):
+                   route: Callable = route_top_k, form: str = "swiglu"):
     """The part of a top-k mixture-of-experts layer that the experts held
-    here give: ``sum_j w_j * down_j(silu(gate_j x) * up_j x)`` over those
+    here give: ``sum_j w_j * down_j(silu(gate_j x) * up_j x)`` (``form``
+    ``"swiglu"``; ``"relu2"``: ``down_j(relu(up_j x) ** 2)``) over those
     of a token's ``top_k`` picks that fall on ``[first_expert,
     first_expert + held)``.
 
@@ -413,7 +443,7 @@ def routed_experts(x, router_kernel, expert_params, *, top_k: int,
     ``capacity`` bounds an expert, no dropped token: the assignments are
     sorted by expert into one order (``n * top_k`` indices, the held
     experts' first), and a loop whose length the device decides takes each
-    held expert's rows ``TILE`` at a time through that expert's three
+    held expert's rows ``TILE`` at a time through that expert's
     matrices — a grouped matrix product at the granularity of a tile.  Only an expert's last tile is
     padded, so the layer's cost follows the number of rows the router
     sends here, smoothly, from none to every token on one expert; there is
@@ -434,7 +464,8 @@ def routed_experts(x, router_kernel, expert_params, *, top_k: int,
       x: ``[n, d]`` tokens, or ``[groups, n, d]``.
       router_kernel: ``[d, E]``, all ``E`` experts of the layer.
       expert_params: ``{"gate_proj": [held, d, f], "up_proj": [held, d, f],
-        "down_proj": [held, f, d]}``, the experts held here.
+        "down_proj": [held, f, d]}``, the experts held here (``relu2``: no
+        ``gate_proj``).
       top_k: experts a token.
       first_expert: index of the first held expert.
       capacity: the most of a group's ``n`` tokens an expert takes,
@@ -447,13 +478,22 @@ def routed_experts(x, router_kernel, expert_params, *, top_k: int,
         (softmax) or :func:`route_sigmoid_top_k` with its bias and scale
         bound.  Held experts, the sort, the tiles and ``capacity`` do not
         depend on it.
+      form: an expert's form, a key of :data:`FORMS`: ``"swiglu"`` (the
+        gated SiLU pair, three matrices) or ``"relu2"`` (two matrices,
+        ``hidden = relu(up x) ** 2``, no gate).  Schedule, gathers,
+        scatter, capacity, groups and the float32 accumulators (one a
+        matrix) are the same loops for both.
 
     Returns ``x``'s shape in ``x``'s dtype.
     """
-    held = expert_params["gate_proj"].shape[0]
-    metrics.record_moe_layer(held, top_k,
-                             getattr(route, "func", route).__name__,
-                             1 if x.ndim == 2 else x.shape[0])
+    if form not in FORMS:
+        raise ValueError(f"an expert's form is one of {sorted(FORMS)}, "
+                         f"not {form!r}")
+    held = expert_params[FORMS[form][-1]].shape[0]
+    rule = getattr(route, "func", route).__name__
+    metrics.record_moe_layer(
+        held, top_k, rule if form == "swiglu" else f"{rule}+{form}",
+        1 if x.ndim == 2 else x.shape[0])
 
     def route_group(_, rows):
         x, = rows
@@ -480,16 +520,16 @@ def routed_experts(x, router_kernel, expert_params, *, top_k: int,
                       checkpoint_name(sizes, ROUTING))
 
     _, (weights, order, sizes) = _each_group(route_group, None, (x,))
-    params = {k: expert_params[k] for k in ("gate_proj", "up_proj",
-                                            "down_proj")}
-    return _held_part(x, weights, params, order, sizes, TILE, top_k)
+    params = {k: expert_params[k] for k in FORMS[form]}
+    return _held_part(x, weights, params, order, sizes, TILE, top_k, form)
 
 
 def grouped_routed_experts(x, router_kernel, expert_params, *, top_k: int,
                            first_expert: int = 0,
                            group_rows: Optional[int] = None,
                            capacity_factor: Optional[float] = None,
-                           route: Callable = route_top_k):
+                           route: Callable = route_top_k,
+                           form: str = "swiglu"):
     """:func:`routed_experts` over the rows of ``x`` ``[b, rows, d]`` in
     groups, GShard's way of bounding an expert's load: the ``b * rows``
     rows, in order, form groups of ``group_rows`` (one group when ``None``
@@ -509,7 +549,7 @@ def grouped_routed_experts(x, router_kernel, expert_params, *, top_k: int,
     return routed_experts(
         x.reshape(n // group, group, d), router_kernel, expert_params,
         top_k=top_k, first_expert=first_expert, capacity=capacity,
-        route=route).reshape(b, rows, d)
+        route=route, form=form).reshape(b, rows, d)
 
 
 def load_census(router_logits, first_expert: int, held: int, *,

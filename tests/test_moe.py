@@ -166,3 +166,132 @@ def test_moe_gradients_flow(rng):
     assert np.isfinite(gw).all() and np.isfinite(grt).all()
     assert np.abs(gw).max() > 0
     assert np.abs(grt).max() > 0
+
+
+# -- the expert's form is a seam of routed_experts ----------------------------
+
+
+def _dense_sum(x, router, params, form, *, top_k, first, capacity, route):
+    """The held experts' part as a dense sum over experts, every expert on
+    every row under a ``[rows, held]`` matrix of weights (an expert's picks
+    past its first ``capacity`` in row order zeroed): plain ``jnp`` that
+    JAX differentiates itself."""
+    from horovod_tpu.parallel import moe
+
+    held = params["down_proj"].shape[0]
+    weights, experts = route(x, router, top_k)
+    dense = jnp.zeros((x.shape[0], router.shape[1]), jnp.float32).at[
+        jnp.arange(x.shape[0])[:, None], experts].add(weights)
+    dense = dense[:, first:first + held]
+    if capacity is not None:
+        dense = dense * (jnp.cumsum(dense > 0, axis=0) <= capacity)
+    up = jnp.einsum("nd,edf->enf", x, params["up_proj"],
+                    precision=jax.lax.Precision.HIGHEST)
+    if form == "relu2":
+        hidden = jnp.square(jax.nn.relu(up))
+    else:
+        hidden = jax.nn.silu(jnp.einsum(
+            "nd,edf->enf", x, params["gate_proj"],
+            precision=jax.lax.Precision.HIGHEST)) * up
+    assert sorted(params) == sorted(moe.FORMS[form])
+    return jnp.einsum("ne,enf,efd->nd", dense, hidden, params["down_proj"],
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+@pytest.mark.parametrize("how", ["eager", "jit_remat"])
+@pytest.mark.parametrize("capacity", [None, 7])
+@pytest.mark.parametrize("rule", ["softmax", "sigmoid"])
+@pytest.mark.parametrize("form", ["relu2", "swiglu"])
+def test_an_experts_form_against_a_dense_sum_forward_and_backward(
+        rng, monkeypatch, form, rule, capacity, how):
+    """``form="relu2"`` is ``down(relu(up x) ** 2)`` through the loops the
+    gated pair takes: the same schedule, gathers, scatter and capacity,
+    two float32 accumulators where the pair has three.  Values and every
+    gradient (x, the router, each of the form's matrices) against the
+    dense sum, under either routing rule, with and without a capacity,
+    eagerly and under ``jit`` + ``remat``."""
+    import functools
+
+    from horovod_tpu.parallel import moe
+
+    monkeypatch.setattr(moe, "TILE", 8)
+    n, d, f, experts, held, top_k, first = 96, 16, 8, 8, 4, 3, 2
+    mk = lambda *s: jnp.asarray(0.4 * rng.normal(size=s), jnp.float32)  # noqa: E731
+    x, router = mk(n, d), mk(d, experts)
+    params = {k: mk(held, *((f, d) if k == "down_proj" else (d, f)))
+              for k in moe.FORMS[form]}
+    route = moe.route_top_k if rule == "softmax" else functools.partial(
+        moe.route_sigmoid_top_k, bias=jnp.zeros(experts), scale=2.5)
+    weight = mk(n, d)
+    kw = dict(top_k=top_k, capacity=capacity, route=route)
+
+    def program(x, router, params):
+        return jnp.sum(weight * moe.routed_experts(
+            x, router, params, first_expert=first, form=form, **kw))
+
+    def oracle(x, router, params):
+        return jnp.sum(weight * _dense_sum(x, router, params, form,
+                                           first=first, **kw))
+
+    if how == "jit_remat":
+        program = jax.jit(jax.checkpoint(program))
+    np.testing.assert_allclose(
+        np.asarray(moe.routed_experts(x, router, params, first_expert=first,
+                                      form=form, **kw)),
+        np.asarray(_dense_sum(x, router, params, form, first=first, **kw)),
+        atol=2e-6, rtol=1e-5)
+    got = jax.grad(program, argnums=(0, 1, 2))(x, router, params)
+    want = jax.grad(oracle, argnums=(0, 1, 2))(x, router, params)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert float(jnp.max(jnp.abs(w))) > 0
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=3e-6,
+                                   rtol=1e-4)
+    if capacity is not None:
+        # the bound bites at this size: the dropless layer gives another
+        free = moe.routed_experts(x, router, params, first_expert=first,
+                                  form=form, top_k=top_k, route=route)
+        assert float(jnp.max(jnp.abs(free - moe.routed_experts(
+            x, router, params, first_expert=first, form=form, **kw)))) > 1e-4
+
+
+def test_the_form_is_named_and_counted(rng, monkeypatch):
+    """An unknown form is refused by name; a ``relu2`` call takes no gate
+    and adds ``+relu2`` to the counter's ``rule``, a gated call's labels
+    read as they did; groups go through ``grouped_routed_experts``
+    alike."""
+    from horovod_tpu import metrics
+    from horovod_tpu.parallel import moe
+
+    monkeypatch.setattr(metrics.registry, "enabled", True)
+    monkeypatch.setattr(moe, "TILE", 8)
+    n, d, f, experts, held = 32, 16, 8, 8, 4
+    mk = lambda *s: jnp.asarray(0.4 * rng.normal(size=s), jnp.float32)  # noqa: E731
+    x, router = mk(2, n, d), mk(d, experts)
+    gated = {"gate_proj": mk(held, d, f), "up_proj": mk(held, d, f),
+             "down_proj": mk(held, f, d)}
+    plain = {k: gated[k] for k in ("up_proj", "down_proj")}
+
+    def count(rule, groups):
+        return sum(s["value"] for s in metrics.registry.snapshot()[
+            "metrics"].get("hvd_moe_layers_traced_total", {}).get(
+                "samples", [])
+            if s["labels"] == {"held": str(held), "top_k": "2",
+                               "rule": rule, "groups": str(groups)})
+
+    before = count("route_top_k", 2), count("route_top_k+relu2", 2)
+    with pytest.raises(ValueError, match="form"):
+        moe.routed_experts(x[0], router, gated, top_k=2, form="gelu")
+    got = moe.grouped_routed_experts(x, router, plain, top_k=2,
+                                     group_rows=n, form="relu2")
+    assert got.shape == x.shape
+    assert (count("route_top_k", 2), count("route_top_k+relu2", 2)) \
+        == (before[0], before[1] + 1)
+    # the gate is not read: with one beside them the result is the same
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(moe.grouped_routed_experts(
+            x, router, gated, top_k=2, group_rows=n, form="relu2")))
+    moe.grouped_routed_experts(x, router, gated, top_k=2, group_rows=n)
+    assert count("route_top_k", 2) == before[0] + 1
+    assert moe.FORMS == {"swiglu": ("gate_proj", "up_proj", "down_proj"),
+                         "relu2": ("up_proj", "down_proj")}

@@ -17,11 +17,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import (gpt, kanana2, mellum2, qwen3_next, scopes,
-                                sdar)
+from horovod_tpu.models import (gpt, kanana2, mellum2, nemotron_h,
+                                qwen3_next, scopes, sdar)
 from horovod_tpu.models.gpt import next_token_loss
 from horovod_tpu.ops import flash_attention as flash
 from horovod_tpu.ops import gated_delta as gdn
+from horovod_tpu.ops import ssd
 from horovod_tpu.parallel import moe
 
 TOKENS = 64
@@ -35,11 +36,14 @@ FORWARD, LOSS = "hvd_forward", "hvd_loss"
 #: time: the forward kernels, whose residuals it keeps (of the scan's scope
 #: the recompute holds the transposes that lay ``g`` and ``beta`` out, not
 #: the kernel); the backward kernels, which only the backward pass calls;
-#: and the experts' tile products, which ``parallel/moe``'s own backward rule
+#: the experts' tile products, which ``parallel/moe``'s own backward rule
 #: runs again inside its loops (the recompute needs the routing, not the
-#: layer's output)
+#: layer's output); and the state-space scan, whose output a block keeps
+#: and whose backward rule runs the chunk algebra again itself, from
+#: operands that are named as they arrive (nothing under the scan's scope
+#: makes them)
 NOT_RECOMPUTED = {*scopes.FLASH_KERNELS, gdn.FWD_KERNEL, gdn.BWD_KERNEL,
-                  moe.EXPERTS_SCOPE}
+                  moe.EXPERTS_SCOPE, ssd.SCAN_SCOPE}
 #: {a kernel name nested in a documented part: the part}
 NESTED = {kernel: part for part, kernels in scopes.NESTED.items()
           for kernel in kernels}
@@ -94,9 +98,24 @@ MODELS = {
                                      *scopes.PARTS[scopes.ATTN])},
          "mlp": {scopes.MOE: _MOE},          # no shared expert
          "": {scopes.HEAD: (), scopes.ROTARY_TABLES: ()}}),
+    # blocks of one part: a state-space mixer, relu^2 experts beside a
+    # shared one, or attention, all three under the module name ``mixer``
+    "nemotron_h_tiny": (
+        lambda: _causal(nemotron_h.nemotron_h_tiny()),
+        {"mixer": {scopes.SSM: scopes.PARTS[scopes.SSM],
+                   scopes.ATTN: scopes.PARTS[scopes.ATTN],
+                   scopes.MOE: scopes.PARTS[scopes.MOE]},
+         "": {scopes.HEAD: ()}}),
     "gpt_tiny": (lambda: _causal(gpt.gpt_tiny(vocab_size=256)), {}),
 }
 DECODERS = sorted(set(MODELS) - {"gpt_tiny"})
+#: {model: the parts its recompute never runs}: where a block is one part
+#: (``nemotron_h``), a block's last projection feeds nothing the block
+#: computes again, and ``hvd_attn_out`` holds ``o_proj`` alone (the gated
+#: norm before ``out_proj`` and the shared expert's ``up`` are read by their
+#: products' weight gradients, so ``hvd_ssm_out`` and ``hvd_moe_shared`` do
+#: run again)
+LAST_OF_A_BLOCK = {"nemotron_h_tiny": {scopes.ATTN_OUT}}
 
 
 def _lowered(name):
@@ -133,11 +152,13 @@ def ops_of(lowered_of):
     def read(name):
         if name not in got:
             text = lowered_of(name).compile().as_text()
+            # an instruction XLA made of several keeps each one's path,
+            # joined by ";" (two reshapes merged): each is a path
             got[name] = [
-                (op, path) for op, path in re.findall(
+                (op, path) for op, paths in re.findall(
                     r"^\s*(?:ROOT )?\S+ = .*? ([a-z][a-z-]*)\(.*"
                     r"op_name=\"([^\"]+)\"", text, re.M)
-                if FORWARD in path]
+                for path in paths.split(";") if FORWARD in path]
         return got[name]
     return read
 
@@ -227,7 +248,7 @@ def test_the_recompute_is_marked_and_holds_every_part_it_runs(ops_of, name):
         assert path.startswith(f"jit(forward)/transpose(jvp({FORWARD}))/")
     held = {c for path in marked for c in _hvd(path)}
     layer_leaves = _all_leaves(name) - {scopes.HEAD}
-    want = layer_leaves - NOT_RECOMPUTED
+    want = layer_leaves - NOT_RECOMPUTED - LAST_OF_A_BLOCK.get(name, set())
     # the recompute's layout swaps: the CPU's compiler folds them into the
     # interpreted kernels' slices, the chip's runs them
     assert want - {flash.LAYOUT_SCOPE} <= held & layer_leaves <= want

@@ -26,6 +26,7 @@ from horovod_tpu.models import (gpt, kanana2, qwen3_next, recompute, scopes,
 from horovod_tpu.models.gpt import next_token_loss
 from horovod_tpu.ops import flash_attention as flash
 from horovod_tpu.ops import gated_delta as gdn
+from horovod_tpu.ops import ssd
 from horovod_tpu.parallel import moe
 
 TOKENS = 64
@@ -125,7 +126,7 @@ def test_no_budget_where_the_devices_memory_is_not_known():
     assert recompute.keep_budget(0) == 0
     assert recompute.KERNEL_RESIDUALS == (
         flash.FLASH_OUT, flash.FLASH_LSE, gdn.GDN_OUT, gdn.GDN_STATES,
-        gdn.GDN_INVERSES)
+        gdn.GDN_INVERSES, ssd.SSD_OUT)
 
 
 def _ids(seed, vocab=256):
