@@ -431,18 +431,24 @@ def scan_phase() -> None:
 
 
 def ssd_phase(s: int = 8192, h: int = 64, p: int = 64, g: int = 8,
-              n: int = 128, chunk: int = 128) -> None:
-    """The chunked state-space scan (``ops/ssd.py``, XLA) alone at the shape
+              n: int = 128, chunk: int = 128, per_step=None) -> None:
+    """The chunked state-space scan (``ops/ssd.py``) alone at the shape
     ``nemotron3-8k``'s Mamba-2 blocks run it, ``[1, 8192, 64, 64]`` bf16
-    with B and C in 8 groups of 128: against the recurrence itself,
-    forward and gradients, and its time forward and forward + backward
-    with the share of its roofline that is (the recurrence's three ``P x
-    N`` products a head a token and x, dt, B, C, y and their gradients once
-    each: what ``benchmarks/harness/nemotron_h_parts.scan_train_required``
-    charges a block), for the ``perf_opt`` that follows."""
+    with B and C in 8 groups of 128, in both of its forms: the Pallas
+    kernels (what ``ssd`` takes here) and the XLA ops they stand beside
+    (``_chunked``: what it takes off a TPU or where the shapes do not
+    tile).  Each against the recurrence itself, forward and gradients, and
+    its time forward and forward + backward with the share of its roofline
+    that is (the recurrence's three ``P x N`` products a head a token and
+    x, dt, B, C, y and their gradients once each: what
+    ``benchmarks/harness/nemotron_h_parts.scan_train_required`` charges a
+    block).  ``per_step``: chunks a grid step to time the kernels at
+    beside ``ssd.CHUNKS_PER_STEP`` (:func:`ssd_sweep`)."""
     import jax
     import jax.numpy as jnp
 
+    from horovod_tpu import metrics
+    from horovod_tpu.ops import ssd as ssd_ops
     from horovod_tpu.ops.ssd import ssd, ssd_recurrence
     from horovod_tpu.utils import flops
 
@@ -459,46 +465,93 @@ def ssd_phase(s: int = 8192, h: int = 64, p: int = 64, g: int = 8,
     skip = jnp.ones((h,), jnp.float32)
     args = (x, dt, rate, bm, cm, skip)
 
-    def loss_of(fn):
+    def loss_of(fn, weight):
         return lambda *a: jnp.sum(fn(*a).astype(jnp.float32)
-                                  * w.astype(jnp.float32))
+                                  * weight.astype(jnp.float32))
 
-    chunked = functools.partial(ssd, chunk=chunk)
+    def xla(*a):
+        with jax.named_scope(ssd_ops.SCAN_SCOPE):
+            return ssd_ops._scan(chunk, *a)
+
+    def as_the_model_holds_them(fn):
+        """x, B and C arrive as ``[b, s, columns]`` slices of the
+        convolution's output and y leaves as ``[b, s, heads p]``: the
+        reshapes round the call are then free, as in the model (a 4-d
+        array with 64 columns at its end is padded to 128 in HBM, and
+        turning it into ``[b, s, 4096]`` is a copy)."""
+        def call(x, dt, rate, bm, cm, skip):
+            rows = x.shape[:2]
+            return fn(x.reshape(*rows, h, p), dt, rate,
+                      bm.reshape(*rows, g, n), cm.reshape(*rows, g, n),
+                      skip).reshape(*rows, h * p)
+        return call
+
+    forms = {"kernels": functools.partial(ssd, chunk=chunk), "xla": xla}
+    forms = {k: as_the_model_holds_them(f) for k, f in forms.items()}
+    recurrence = as_the_model_holds_them(ssd_recurrence)
+    x, w, bm, cm = (a.reshape(1, s, -1) for a in (x, w, bm, cm))
+    args = (x, dt, rate, bm, cm, skip)
     # against the recurrence over the first ``checked`` tokens: its
     # gradient keeps a float32 state a token (2 MB: 17 GB at 8192)
     checked = 1024
     short = tuple(a[:, :checked] if a.ndim > 1 else a for a in args)
-    w_full, w = w, w[:, :checked]
     got = {}
-    for name, fn in (("chunked", chunked), ("recurrence", ssd_recurrence)):
+    for name, fn in (*forms.items(), ("recurrence", recurrence)):
         with jax.default_matmul_precision(
                 "highest" if name == "recurrence" else "default"):
             out = jax.jit(fn)(*short)
-            grads = jax.jit(jax.grad(loss_of(fn), argnums=range(6)))(*short)
+            grads = jax.jit(jax.grad(loss_of(fn, w[:, :checked]),
+                                     argnums=range(6)))(*short)
         got[name] = [np.asarray(a, np.float32) for a in (out, *grads)]
-    w = w_full
-    errs = {}
-    for label, a, b_ in zip(("y", "dx", "ddt", "dA", "dB", "dC", "dD"),
-                            got["chunked"], got["recurrence"]):
-        check(np.isfinite(a).all(), f"chunked ssd {label} is not finite")
-        errs[label] = float(np.abs(a - b_).max() / np.abs(b_).max())
-        check(errs[label] <= SCAN_TOL,
-              f"chunked ssd {label} differs from the recurrence by "
-              f"{errs[label]:.3g} of its largest element (> {SCAN_TOL})")
-    forward = jax.jit(chunked)
-    both = jax.jit(jax.grad(loss_of(chunked), argnums=range(6)))
-    fwd_ms, both_ms = _ms_a_call(forward, *args), _ms_a_call(both, *args)
     products = 2.0 * 3 * s * h * p * n
     tensors = s * ((h * p * 2 + 2 * g * n) * 2 + h * 4)
     peak, hbm = flops.require_peak_flops(), flops.hbm_bytes_per_sec()
-    least = {"fwd": max(products / peak, tensors / hbm),
-             "fwd_bwd": max(3 * products / peak, 3 * tensors / hbm)}
+    least = {"fwd": max(products / peak, tensors / hbm) * 1e3,
+             "fwd_bwd": max(3 * products / peak, 3 * tensors / hbm) * 1e3}
+
+    def timed(fn):
+        fwd_ms = _ms_a_call(jax.jit(fn), *args)
+        both_ms = _ms_a_call(
+            jax.jit(jax.grad(loss_of(fn, w), argnums=range(6))), *args)
+        return dict(fwd_ms=fwd_ms, fwd_bwd_ms=both_ms,
+                    fwd_roofline_pct=100 * least["fwd"] / fwd_ms,
+                    fwd_bwd_roofline_pct=100 * least["fwd_bwd"] / both_ms)
+
+    by_form = {}
+    for name, fn in forms.items():
+        errs = {}
+        for label, a, b_ in zip(("y", "dx", "ddt", "dA", "dB", "dC", "dD"),
+                                got[name], got["recurrence"]):
+            check(np.isfinite(a).all(), f"ssd ({name}) {label} is not finite")
+            errs[label] = float(np.abs(a - b_).max() / np.abs(b_).max())
+            check(errs[label] <= SCAN_TOL,
+                  f"ssd ({name}) {label} differs from the recurrence by "
+                  f"{errs[label]:.3g} of its largest element (> {SCAN_TOL})")
+        by_form[name] = dict(rel_max_err=errs, **timed(fn))
+    by_form["kernels"]["chunks_per_grid_step"] = ssd_ops.CHUNKS_PER_STEP
+    swept = {}
+    for chunks in per_step or ():
+        # read where a call is traced: another count is another program
+        ssd_ops.CHUNKS_PER_STEP, ours = chunks, ssd_ops.CHUNKS_PER_STEP
+        try:
+            swept[chunks] = timed(forms["kernels"])
+        except Exception as e:  # noqa: BLE001 — a block Mosaic refuses
+            swept[chunks] = str(e)[-300:]
+        ssd_ops.CHUNKS_PER_STEP = ours
+    paths = {"/".join(labels.values()): int(child.get())
+             for labels, child in metrics.SSM_SCAN_CHUNKS.samples()}
+    check(any(k.endswith("/mosaic") for k in paths),
+          f"ssd took no Mosaic kernel on the chip: {paths}")
     report("ssd_vs_recurrence", shape=[1, s, h, p], groups=g, state=n,
            chunk=chunk, dtype="bfloat16", checked_tokens=checked,
-           tolerance=SCAN_TOL,
-           rel_max_err=errs, fwd_ms=fwd_ms, fwd_bwd_ms=both_ms,
-           fwd_roofline_pct=100 * least["fwd"] * 1e3 / fwd_ms,
-           fwd_bwd_roofline_pct=100 * least["fwd_bwd"] * 1e3 / both_ms)
+           tolerance=SCAN_TOL, least_ms=least, chunks_traced=paths,
+           swept_chunks_per_grid_step=swept, **by_form)
+
+
+def ssd_sweep() -> None:
+    """:func:`ssd_phase` with the kernels timed at 2 and 8 chunks a grid
+    step too (not part of ``main``: ``python chip_smoke.py ssd_sweep``)."""
+    ssd_phase(per_step=(2, 8))
 
 
 def ring_phase(n: int) -> None:

@@ -204,6 +204,15 @@ GDN_SCAN_CHUNKS = registry.counter(
     "walks (per compile, not per step): kernel fwd or bwd, path mosaic "
     "(compiled for the TPU) or interpret (Pallas interpreter mode).",
     ("kernel", "path"))
+SSM_SCAN_CHUNKS = registry.counter(
+    "hvd_ssm_scan_chunks_traced_total",
+    "Chunks (of every head) each traced call of the state-space scan "
+    "(ops/ssd.py) walks (per compile, not per step): kernel fwd, states "
+    "(the backward rule's pass that makes the chunks' start states again) "
+    "or bwd; path mosaic (the Pallas kernels compiled for the TPU), "
+    "interpret (Pallas interpreter mode) or xla (the chunked form as XLA "
+    "ops: off a TPU, or shapes that do not tile; it has no states pass).",
+    ("kernel", "path"))
 KERNEL_RESIDUAL_BYTES = registry.counter(
     "hvd_kernel_residual_bytes_traced_total",
     "Bytes each traced differentiated forward of a Pallas kernel hands its "
@@ -605,6 +614,17 @@ def record_gdn_scan_chunks(kernel: str, path: str, chunks: int) -> None:
         return
     try:
         GDN_SCAN_CHUNKS.labels(kernel, path).inc(chunks)
+    except Exception:  # noqa: BLE001 — tracing must never fail on metrics
+        pass
+
+
+def record_ssm_scan_chunks(kernel: str, path: str, chunks: int) -> None:
+    """One traced call of the state-space scan (ops/ssd.py) — which path
+    it took, over how many chunks."""
+    if not registry.enabled:
+        return
+    try:
+        SSM_SCAN_CHUNKS.labels(kernel, path).inc(chunks)
     except Exception:  # noqa: BLE001 — tracing must never fail on metrics
         pass
 

@@ -167,3 +167,168 @@ def test_residual_bytes_count_what_the_names_hold():
     assert ssd_ops.residual_bytes(1, 8192, 64, 64, 2) == 8192 * 4096 * 2
     assert ssd_ops.operand_bytes(1, 8192, 64, 64, 8, 128, 2) \
         == 8192 * ((4096 + 2048) * 2 + 64 * 4)
+
+
+# ---------------------------------------------------------------------------
+# the Pallas kernels (what ``ssd`` takes on a TPU where the shapes tile), in
+# interpreter mode: the same cases through ``interpret=True``
+# ---------------------------------------------------------------------------
+
+
+def _counted(monkeypatch):
+    """A reader of ``hvd_ssm_scan_chunks_traced_total``: ``{(kernel, path):
+    chunks}``."""
+    from horovod_tpu import metrics
+
+    monkeypatch.setattr(metrics.registry, "enabled", True)
+
+    def read():
+        return {tuple(labels.values()): child.get()
+                for labels, child in metrics.SSM_SCAN_CHUNKS.samples()}
+
+    return read
+
+
+def _gradients(fn, args, weight):
+    return jax.jit(jax.grad(
+        lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * weight),
+        argnums=range(6)))(*args)
+
+
+# 12 chunks of 16 are three grid steps of CHUNKS_PER_STEP; 150 tokens are
+# padded to 192 with rows of dt = 0 and cut; 1, 2 and 8 heads a group (a
+# bundle of one head, and of two)
+@pytest.mark.parametrize("seq,heads,dtype,tol", [
+    (192, 2, jnp.float32, 1e-5), (192, 4, jnp.float32, 1e-5),
+    (192, 16, jnp.float32, 1e-5), (150, 2, jnp.float32, 1e-5),
+    (150, 4, jnp.float32, 1e-5), (150, 16, jnp.float32, 1e-5),
+    (192, 4, jnp.bfloat16, 0.02), (150, 16, jnp.bfloat16, 0.02)])
+def test_the_kernels_match_the_recurrence_and_the_xla_form(
+        rng, seq, heads, dtype, tol):
+    """Values and all six gradients, against the recurrence in float32 and
+    against ``_chunked``; bfloat16 operands inside the band of
+    ``test_bfloat16_operands_stay_inside_their_band``."""
+    args = _operands(rng, seq, dtype, heads=heads)
+    exact = tuple(a.astype(jnp.float32) for a in args)
+    weight = jnp.asarray(rng.normal(size=(B, seq, heads, P)), jnp.float32)
+    kernels = lambda *a: ssd(*a, chunk=16, interpret=True)  # noqa: E731
+    got = jax.jit(kernels)(*args)
+    assert got.dtype == dtype
+    _close(got, jax.jit(ssd_recurrence)(*exact), tol)
+    _close(got, jax.jit(lambda *a: ssd(*a, chunk=16))(*args), tol)
+    grads = _gradients(kernels, args, weight)
+    for g, w, x in zip(grads, _gradients(ssd_recurrence, exact, weight),
+                       _gradients(lambda *a: ssd(*a, chunk=16), args,
+                                  weight)):
+        assert g.shape == x.shape and g.dtype == x.dtype
+        _close(g, w, 2 * tol)
+        _close(g, x, 2 * tol)
+
+
+def test_the_kernels_under_a_step_of_zero_and_a_large_one(rng):
+    x, dt, rate, b, c, skip = _operands(rng, 64)
+    ones = -jnp.ones_like(rate)
+    still = dt.at[:, 20:].set(0.0)
+    _close(ssd(x, still, rate, b, c, skip, chunk=16, interpret=True),
+           ssd_recurrence(x, still, rate, b, c, skip))
+    big = dt.at[:, 30].set(60.0)
+    y = ssd(x, big, ones, b, c, skip, chunk=16, interpret=True)
+    _close(y, ssd_recurrence(x, big, ones, b, c, skip), tol=1e-4)
+    # one of 1e4 is an exact zero of the state and still no NaN, forward
+    # or backward
+    big = dt.at[:, 30].set(1e4)
+    grads = jax.grad(lambda dt, x: jnp.sum(ssd(
+        x, dt, ones, b, c, skip, chunk=16, interpret=True)),
+        argnums=(0, 1))(big, x)
+    assert all(np.isfinite(np.asarray(g)).all() for g in grads)
+
+
+@pytest.mark.parametrize("at", [1, 16, 33])
+def test_no_later_token_moves_an_earlier_output_of_the_kernels(rng, at):
+    x, dt, rate, b, c, skip = _operands(rng, 48)
+    before = np.asarray(ssd(x, dt, rate, b, c, skip, chunk=16,
+                            interpret=True))
+    after = np.asarray(ssd(
+        x.at[:, at:].add(1.0), dt.at[:, at:].mul(2.0), rate,
+        b.at[:, at:].add(1.0), c.at[:, at:].add(-1.0), skip, chunk=16,
+        interpret=True))
+    np.testing.assert_array_equal(after[:, :at], before[:, :at])
+    assert np.abs(after[:, at:] - before[:, at:]).max() > 1e-3
+
+
+def test_the_launch_is_read_from_the_platform_and_the_shapes(
+        rng, monkeypatch):
+    """Off a TPU, and on one where the shapes do not tile, ``ssd`` takes
+    the XLA form and the counter says so; the kernels take a bundle of
+    heads, a state and a chunk that are whole lane tiles."""
+    read = _counted(monkeypatch)
+    args = _operands(rng, 32)
+    before = read()
+    jax.jit(jax.grad(lambda *a: jnp.sum(ssd(*a, chunk=16))))(*args)
+    delta = {k: v - before.get(k, 0) for k, v in read().items()
+             if v != before.get(k, 0)}
+    # two chunks of 16 cover 32 tokens; 2 rows x 4 heads
+    assert delta == {("fwd", "xla"): 16, ("bwd", "xla"): 16}
+    before = read()
+    jax.jit(jax.grad(lambda *a: jnp.sum(ssd(*a, chunk=16, interpret=True))))(
+        *args)
+    delta = {k: v - before.get(k, 0) for k, v in read().items()
+             if v != before.get(k, 0)}
+    assert delta == {("fwd", "interpret"): 16, ("states", "interpret"): 16,
+                     ("bwd", "interpret"): 16}
+
+    def shaped(heads, p, groups, n):
+        return (jax.ShapeDtypeStruct((1, 256, heads, p), jnp.bfloat16),
+                jax.ShapeDtypeStruct((1, 256, groups, n), jnp.bfloat16))
+
+    monkeypatch.setattr(ssd_ops, "_on_tpu", lambda: True)
+    assert ssd_ops._path(*shaped(64, 64, 8, 128), 128, None) == "mosaic"
+    assert ssd_ops._path(*shaped(8, 128, 8, 128), 128, None) == "mosaic"
+    for case, chunk in ((shaped(8, 64, 8, 128), 128),      # one head of 64
+                        (shaped(64, 64, 8, 64), 128),      # half a lane tile
+                        (shaped(64, 64, 8, 128), 64),
+                        (shaped(4, 8, 2, 16), 16)):
+        assert ssd_ops._path(*case, chunk, None) == "xla"
+        with pytest.raises(ValueError, match="multiples of 128"):
+            ssd_ops._path(*case, chunk, False)
+    monkeypatch.setattr(ssd_ops, "_on_tpu", lambda: False)
+    assert ssd_ops._path(*shaped(64, 64, 8, 128), 128, None) == "xla"
+
+
+def test_a_checkpoint_that_saves_the_names_runs_no_kernel_again(rng):
+    """As the XLA form: with the output and the operands saved by name the
+    traced gradient holds each kernel once (forward; the backward rule's
+    states pass and backward kernel), with nothing saved the forward
+    kernel a second time; and the rule keeps no state: what a checkpoint
+    that saves the names holds is ``residual_bytes`` of output and
+    ``operand_bytes`` of operands."""
+    args = _operands(rng, 64)
+    project = jnp.asarray(rng.normal(size=(P, P)), jnp.float32)
+
+    def layer(x, *rest):
+        return jnp.sum(ssd(jnp.tanh(x @ project), *rest, chunk=16,
+                           interpret=True) ** 2)
+
+    def kernels(policy):
+        f = jax.value_and_grad(jax.checkpoint(layer, policy=policy))
+        text = str(jax.make_jaxpr(f)(*args))
+        return [text.count(f"name={k}\n") + text.count(f"name={k} ")
+                for k in (ssd_ops.FWD_KERNEL, ssd_ops.STATES_KERNEL,
+                          ssd_ops.BWD_KERNEL)], f(*args)[1]
+
+    save = jax.checkpoint_policies.save_only_these_names
+    kept, grad_kept = kernels(save(ssd_ops.SSD_OUT, ssd_ops.SSD_IN))
+    nothing, grad_nothing = kernels(save())
+    assert (kept, nothing) == ([1, 1, 1], [2, 1, 1])
+    _close(grad_kept, grad_nothing, tol=1e-6)
+    _close(grad_kept, jax.grad(layer)(*args), tol=1e-6)
+    from jax._src.ad_checkpoint import saved_residuals
+
+    saved = saved_residuals(
+        jax.checkpoint(layer, policy=save(ssd_ops.SSD_OUT, ssd_ops.SSD_IN)),
+        *args)
+    # beside the layer's own arguments and constants: what the names hold
+    named = [aval for aval, why in saved if why.startswith("output of")]
+    assert sum(a.size * a.dtype.itemsize for a in named) \
+        == ssd_ops.residual_bytes(B, 64, H, P, 4) \
+        + ssd_ops.operand_bytes(B, 64, H, P, G, N, 4)
