@@ -247,6 +247,38 @@ def _ms_a_call(fn, *args, calls: int = 10) -> float:
     return (time.perf_counter() - start) * 1e3 / calls
 
 
+def _flash_kernels_alone(q, k, v, do, mask, block_q, block_k,
+                         static_offs=(0, 0), repeats: int = 1):
+    """Each of the three flash kernels alone on ``[b, h, s, d]`` operands at
+    offsets 0, traced anew (the sweeps change what the launchers read
+    while they trace): ``(ms a call of forward, dq and dkv, the least of
+    ``repeats`` readings; what they returned)``."""
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash_attention as fa
+
+    for launcher in (fa._fwd_call, fa._dq_call, fa._dkv_call):
+        launcher.clear_cache()
+    offs = fa._offsets(0, 0)
+    kw = dict(mask=mask, scale=q.shape[-1] ** -0.5, block_q=block_q,
+              block_k=block_k, interpret=None, static_offs=static_offs)
+    o, m, l = fa._mha_fwd(q, k, v, offs, normalize=True, **kw)
+    lse = m + jnp.log(jnp.maximum(l, 1e-30))
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1, keepdims=True)
+    back = (q, k, v, do, lse, delta, offs)
+    calls = {
+        "fwd": lambda: fa._mha_fwd(q, k, v, offs, normalize=True, **kw)[0],
+        "dq": lambda: fa._mha_bwd_dq(*back, out_dtype=q.dtype, **kw),
+        "dkv": lambda: fa._mha_bwd_dkv(*back[:4], lse[..., 0],
+                                       delta[..., 0], offs,
+                                       out_dtype=q.dtype, **kw)}
+    ms = {name: min(_ms_a_call(call) for _ in range(repeats))
+          for name, call in calls.items()}
+    return ms, [np.asarray(x, np.float32) for x in (
+        calls["fwd"](), calls["dq"](), *calls["dkv"]())]
+
+
 def sliding_window_phase(window: int = 1024, s: int = 16384, h: int = 32,
                          d: int = 128) -> None:
     """The flash kernels under the sliding-window mask at the shape the
@@ -312,31 +344,12 @@ def sliding_window_sweep(window: int = 1024, s: int = 16384, h: int = 32,
     mask = fa.sliding_window_mask(window)
     q, k, v, do = (jax.random.normal(kk, (1, h, s, d), jnp.bfloat16)
                    for kk in jax.random.split(jax.random.PRNGKey(5), 4))
-    offs = fa._offsets(0, 0)
     allowed_tiles = h * (s * window - window * (window - 1) // 2) / 512 ** 2
     fitted = (fa._tiles_per_step, fa._window_steps, fa._row_tiles)
 
     def kernels(block_q, block_k, static_offs=(0, 0)):
-        """ms a call of forward, dq and dkv, and what they returned."""
-        kw = dict(mask=mask, scale=d ** -0.5, block_q=block_q,
-                  block_k=block_k, interpret=None, static_offs=static_offs)
-        for launcher in (fa._fwd_call, fa._dq_call, fa._dkv_call):
-            launcher.clear_cache()
-        o, m, l = fa._mha_fwd(q, k, v, offs, normalize=True, **kw)
-        lse = m + jnp.log(jnp.maximum(l, 1e-30))
-        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=-1, keepdims=True)
-        back = (q, k, v, do, lse, delta, offs)
-        calls = {
-            "fwd": (lambda: fa._mha_fwd(q, k, v, offs, normalize=True,
-                                        **kw)[0]),
-            "dq": lambda: fa._mha_bwd_dq(*back, out_dtype=q.dtype, **kw),
-            "dkv": lambda: fa._mha_bwd_dkv(*back[:4], lse[..., 0],
-                                           delta[..., 0], offs,
-                                           out_dtype=q.dtype, **kw)}
-        ms = {name: _ms_a_call(call) for name, call in calls.items()}
-        return ms, [np.asarray(x, np.float32) for x in (
-            calls["fwd"](), calls["dq"](), *calls["dkv"]())]
+        return _flash_kernels_alone(q, k, v, do, mask, block_q, block_k,
+                                    static_offs)
 
     reference = None
     try:
@@ -373,6 +386,68 @@ def sliding_window_sweep(window: int = 1024, s: int = 16384, h: int = 32,
                    for name in whole})
     finally:
         fa._tiles_per_step, fa._window_steps, fa._row_tiles = fitted
+        for launcher in (fa._fwd_call, fa._dq_call, fa._dkv_call):
+            launcher.clear_cache()
+
+
+def flash_grid_phase(cells=None) -> None:
+    """Each of the three flash kernels alone on the flattened grid of live
+    (resident block, streamed block) pairs and on the rectangle it replaced
+    (``MAX_PAIRS`` 0: the launchers then take the parent's path, body for
+    body), in one process, at the shapes the benchmark's cells call them
+    with (``cells``: name, ``(b, h, s)``, q's and v's head size, mask): ms
+    a call, the steps each grid launches a head
+    (``grid_census``), what a step the rectangle visited to do nothing
+    cost, and that the two grids give the same bits.  Not part of
+    ``main``: ``python chip_smoke.py flash_grid_phase``."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash_attention as fa
+
+    cells = cells or (
+        ("sdar-bd4-8k", (1, 32, 16384), 128, 128,
+         fa.block_diffusion_mask(4, 8192)),
+        ("gpt2s-16k", (1, 12, 16384), 64, 64, fa.CAUSAL),
+        ("mellum2-16k full layer", (1, 32, 16384), 128, 128, fa.CAUSAL),
+        ("kanana2-8k", (1, 32, 8192), 192, 128, fa.CAUSAL),
+        ("gpt2s-4k", (2, 12, 4096), 64, 64, fa.CAUSAL),
+        ("gpt2s-1k", (8, 12, 1024), 64, 64, fa.CAUSAL),
+    )
+    most = fa.MAX_PAIRS
+    try:
+        for cell, (b, h, s), d, dv, mask in cells:
+            keys = jax.random.split(jax.random.PRNGKey(7), 4)
+            q, k = (jax.random.normal(kk, (b, h, s, d), jnp.bfloat16)
+                    for kk in keys[:2])
+            v, do = (jax.random.normal(kk, (b, h, s, dv), jnp.bfloat16)
+                     for kk in keys[2:])
+            blocks = fa.default_blocks(d, mask)
+            fa.MAX_PAIRS = most
+            flat, got = _flash_kernels_alone(q, k, v, do, mask, *blocks,
+                                             repeats=3)
+            steps = fa.grid_census(s, s, *blocks, mask)
+            fa.MAX_PAIRS = 0
+            whole, want = _flash_kernels_alone(q, k, v, do, mask, *blocks,
+                                               repeats=3)
+            every = fa.grid_census(s, s, *blocks, mask)
+            for a, b_ in zip(got, want):
+                check(np.array_equal(a, b_),
+                      f"{cell}: the flattened grid and the rectangle "
+                      "differ")
+            idle = {name: every[name]["launched"] - steps[name]["launched"]
+                    for name in flat}
+            report("flash_grid", cell=cell, shape=[b, h, s, d, dv],
+                   mask=mask.label, blocks=list(blocks),
+                   flattened_ms=flat, rectangle_ms=whole,
+                   flattened_steps_a_head=steps,
+                   rectangle_steps_a_head=every,
+                   us_an_idle_step={
+                       name: (whole[name] - flat[name]) * 1e3
+                       / (b * h * idle[name]) if idle[name] else None
+                       for name in flat})
+    finally:
+        fa.MAX_PAIRS = most
         for launcher in (fa._fwd_call, fa._dq_call, fa._dkv_call):
             launcher.clear_cache()
 

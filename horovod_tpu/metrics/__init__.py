@@ -193,10 +193,12 @@ FLASH_TILES = registry.counter(
 FLASH_GRID_STEPS = registry.counter(
     "hvd_flash_grid_steps_traced_total",
     "Steps of the streamed grid axis of each traced flash-attention kernel "
-    "call (per compile, not per step): launched (the grid's extent) and "
-    "live (the step's block holds a tile some row sees; every other step "
-    "is visited to compute and fetch nothing; not counted when the offsets "
-    "are traced); mask as hvd_flash_tiles_traced_total's.",
+    "call (per compile, not per step): launched (the grid's extent), "
+    "live (the step's block holds a tile some row sees) and idle (launched "
+    "- live: visited to compute and fetch nothing; 0 on the flattened grid "
+    "of a causal or block-diffusion call with static offsets); live and "
+    "idle are not counted when the offsets are traced; mask as "
+    "hvd_flash_tiles_traced_total's.",
     ("kernel", "kind", "mask"))
 GDN_SCAN_CHUNKS = registry.counter(
     "hvd_gdn_scan_chunks_traced_total",
@@ -601,7 +603,7 @@ def record_flash_tiles(kernel: str, counts, mask: str) -> None:
 
 
 def record_flash_grid_steps(kernel: str, counts, mask: str) -> None:
-    """Launched and live steps of the streamed grid axis of one traced
+    """Launched, live and idle steps of the streamed grid axis of one traced
     flash kernel call (ops/flash_attention.py) under the mask ``mask`` —
     how closely the grid fits the blocks the mask leaves live."""
     _count_by_kind(FLASH_GRID_STEPS, kernel, counts, mask)

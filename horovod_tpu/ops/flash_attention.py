@@ -18,7 +18,8 @@ same code:
 
 * the resident rows see none of it (wholly past the diagonal): nothing is
   computed, and the block's index map names the nearest block that is, so
-  nothing is fetched either;
+  nothing is fetched either; such a step still costs 0.3-0.5 us, and which
+  grid a call runs decides how many there are (below);
 * forward and dq take the tiles their rows see at all as *one* block of
   static width (a body for the whole block and one for half of it), so the
   online softmax's statistics move once a grid step and not once a tile;
@@ -38,6 +39,29 @@ that Mosaic unrolls and every layer's kernel carries, so the bodies are
 few, the resident rows are walked ``ROW_CHUNK`` at a time in a loop, and
 the launchers are jitted, which lets a model's layers share one trace and
 one lowering.
+
+Which steps the grid has is decided from the call's mask and offsets, and
+from nothing else (:func:`grid_census` counts them, the counter
+``hvd_flash_grid_steps_traced_total`` records them):
+
+* causal or block diffusion with offsets that are Python ints (every
+  model's call): a *flattened grid* ``(batch, heads, pairs)`` over the live
+  (resident block, streamed block) pairs, in the resident block's order.
+  A table behind the two offsets in the scalar-prefetched operand tells
+  the index maps and the body which pair a step is and whether it is the
+  resident block's first (zero the accumulators) or last (write the
+  outputs): :func:`_pair_table`, :func:`_grid`, :func:`_grid_step`.  No
+  step is visited to do nothing;
+* a sliding window: the grid fitted to the window (below), with static and
+  with traced offsets;
+* traced offsets under a causal mask (ring attention's calls), no mask,
+  a rectangle without an idle step (a block or two of keys: 1024 rows at
+  the default tiles) or more pairs than scalar memory holds
+  (``MAX_PAIRS``): the rectangle ``(batch, heads, resident blocks,
+  streamed blocks)``.
+
+One body serves all three: what differs is where it reads its block
+positions from.
 
 Measured on one TPU v5e ("TPU v5 lite"), PR 25, each kernel alone on the
 device's clock at the two shapes the benchmark's GPT cells run, bf16,
@@ -98,8 +122,10 @@ cut into blocks of ``block``; a clean row sees the clean blocks up to its
 own, a noised row the clean blocks before its own and the noised tokens of
 its own block.  The kernels are the same ones: the mask says which tiles of
 a grid step's block are live and which of those are full (scalar
-arithmetic, :func:`_kv_tiles_seen` / :func:`_q_tiles_seen`), which block a
-skipped step names so that nothing is fetched (:func:`_nearest_live`;
+arithmetic, :func:`_kv_tiles_seen` / :func:`_q_tiles_seen`), which blocks
+the grid visits at all (:func:`_kv_blocks_seen` / :func:`_q_blocks_seen`
+for the flattened grid's table; on the rectangle the block a skipped step
+names so that nothing is fetched, :func:`_nearest_live`;
 :func:`_window_block` on a fitted grid), and which pairs of a crossed tile
 count (:func:`_seen`).  Tiles are fitted to
 the noised half so that none straddles the two copies: of the ``2L x 2L``
@@ -392,12 +418,14 @@ def _tiles_per_step(seq: int, tile: int, mask: Mask = NO_MASK) -> int:
 def _kv_grid(sq, sk, block_q, block_k, mask, static_offs=None):
     """Forward's and dq's ``(block_q, tile_k, block_k, steps, chunk)``: the
     tile fitted to the lengths, the keys one grid step streams, how many
-    steps a query block takes over them (every block of keys there is, or
-    under a sliding window the few its rows can reach: :func:`_window_steps`;
-    with offsets that are Python ints, the most any query block does reach)
-    and the rows the kernels work on at a time.  Under a sliding window the
-    resident block is several tiles of rows (:func:`_row_tiles`), each of
-    which takes the key tiles its own rows reach."""
+    steps a query block takes over them on the rectangle (every block of
+    keys there is, or under a sliding window the few its rows can reach:
+    :func:`_window_steps`; with offsets that are Python ints, the most any
+    query block does reach; a call on the flattened grid takes its steps
+    from :func:`_pair_table` instead) and the rows the kernels work on at a
+    time.  Under a sliding window the resident block is several tiles of
+    rows (:func:`_row_tiles`), each of which takes the key tiles its own
+    rows reach."""
     block_q, tile_k = _check_blocks(sq, sk, block_q, block_k, mask)
     chunk = _fit_block(block_q, ROW_CHUNK)
     block_k = tile_k * _tiles_per_step(sk, tile_k, mask)
@@ -406,8 +434,8 @@ def _kv_grid(sq, sk, block_q, block_k, mask, static_offs=None):
         block_q *= _row_tiles(sq, block_q)
         steps = _window_steps(mask, block_q, block_k, steps)
         if static_offs is not None:
-            steps = max(1, *_live_kv_blocks(mask, sq, sk, block_q, block_k,
-                                            static_offs))
+            steps = max(1, *map(len, _kv_blocks_seen(
+                mask, sq, sk, block_q, block_k, static_offs)))
     return block_q, tile_k, block_k, steps, chunk
 
 
@@ -430,8 +458,8 @@ def _q_grid(sq, sk, block_q, block_k, mask, static_offs=None):
     if mask.kind == _SW:
         steps = _window_steps(mask, block_k, block_q, steps)
         if static_offs is not None:
-            steps = max(1, *_live_q_blocks(mask, sq, sk, block_q, block_k,
-                                           static_offs))
+            steps = max(1, *map(len, _q_blocks_seen(
+                mask, sq, sk, block_q, block_k, static_offs)))
     return tile_q, block_q, block_k, steps
 
 
@@ -557,27 +585,86 @@ def _nearest_live(i, ranges):
         jnp.where(b0 < b1, jnp.clip(i, b0, b1 - 1), jnp.maximum(a1 - 1, 0)))
 
 
-def _live_kv_blocks(mask, sq, sk, rows, keys, offs):
-    """How many blocks of ``keys`` keys each block of ``rows`` query rows
-    sees any of, at the Python-int offsets ``offs``: the live steps of
-    forward's and dq's grid, a query block at a time (both copies' under
-    block diffusion)."""
+def _kv_blocks_seen(mask, sq, sk, rows, keys, offs):
+    """The blocks of ``keys`` keys that each block of ``rows`` query rows
+    sees any of, in their order, at the Python-int offsets ``offs``: the
+    live steps of forward's and dq's grid, a list a query block (both
+    copies' under block diffusion)."""
     copies = 2 if mask.kind == _BD else 1
-    return [sum(_kv_tiles_seen(mask, offs[0] + i * rows, rows,
-                               offs[1] + part * mask.noised,
-                               sk // keys // copies, keys)[2]
-                for part in range(copies)) for i in range(sq // rows)]
+    n = sk // keys // copies
+    seen = []
+    for i in range(sq // rows):
+        seen.append([])
+        for part in range(copies):
+            first, _, live = _kv_tiles_seen(
+                mask, offs[0] + i * rows, rows, offs[1] + part * mask.noised,
+                n, keys)
+            seen[-1] += range(part * n + first, part * n + first + live)
+    return seen
 
 
-def _live_q_blocks(mask, sq, sk, rows, keys, offs):
-    """dkv's side of the same: how many blocks of ``rows`` query rows see
-    any of each block of ``keys`` keys."""
+def _q_blocks_seen(mask, sq, sk, rows, keys, offs):
+    """dkv's side of the same: the blocks of ``rows`` query rows that see
+    any of each block of ``keys`` keys (under block diffusion two ranges:
+    the noised rows of the keys' own blocks and the clean rows after
+    them)."""
     copies = 2 if mask.kind == _BD else 1
-    seen = [[_q_tiles_seen(mask, offs[1] + j * keys, keys,
-                           offs[0] + part * mask.noised,
-                           sq // rows // copies, rows)
-             for part in range(copies)] for j in range(sk // keys)]
-    return [sum(hi - lo for lo, hi in parts) for parts in seen]
+    n = sq // rows // copies
+    seen = []
+    for j in range(sk // keys):
+        seen.append([])
+        for part in range(copies):
+            lo, hi = _q_tiles_seen(
+                mask, offs[1] + j * keys, keys, offs[0] + part * mask.noised,
+                n, rows)
+            seen[-1] += range(part * n + lo, part * n + hi)
+    return seen
+
+
+# The most (resident block, streamed block) pairs a flattened grid's table
+# holds: three int32 words a pair of the scalar memory the table is
+# prefetched into.  A v5e has 1 MiB of it: compiled for a described one, a
+# causal call of 33 024 pairs (65 536 rows in 128 x 512 blocks, 387 KiB)
+# passes and one of 131 584 is refused (PR 43).  The cells' calls have
+# 48-80 pairs a head; 131 072 rows at the default tiles have 16 512.
+MAX_PAIRS = 32768
+
+
+def _pair_table(seen, steps, mask, sq, sk, rows, keys, static_offs):
+    """The table of a flattened grid, or ``None`` where the launchers keep
+    the rectangle of ``steps`` steps a resident block.  Where the mask is
+    causal or block diffusion and the offsets are Python ints, the live
+    (resident block, streamed block) pairs are known when the call is
+    traced, and the streamed axis of the grid is the list of them, in the
+    resident block's order: ``seen`` gives that list, a resident block at a
+    time (:func:`_kv_blocks_seen` for forward and dq, a block of ``rows``
+    query rows resident; :func:`_q_blocks_seen` for dkv, a block of
+    ``keys`` keys).  The table
+    is ``[resident block of every step | streamed block of every step |
+    edge of every step]``, int32, ``edge`` 1 at a resident block's first
+    pair (the accumulators are zeroed) plus 2 at its last (the outputs are
+    written); a resident block's pairs are consecutive, so its blocks are
+    fetched and its outputs written back once.  A resident block that sees
+    nothing keeps one step, whose body finds no tile to compute, for its
+    outputs' zeros.  A sliding window keeps its fitted grid (its steps are
+    a range a block, found by arithmetic: :func:`_window_steps`), traced
+    offsets the rectangle (where the live blocks start is data), and so do
+    a call of more than ``MAX_PAIRS`` pairs and one whose rectangle has no
+    idle step (the short calls, a block or two of keys: the table would
+    drop nothing, and they keep the programs they had)."""
+    if static_offs is None or mask.kind not in ("causal", _BD):
+        return None
+    blocks = seen(mask, sq, sk, rows, keys, static_offs)
+    resident, streamed, edge = [], [], []
+    for block, live in enumerate(blocks):
+        live = live or [0]
+        resident += [block] * len(live)
+        streamed += live
+        edge += [(n == 0) + 2 * (n == len(live) - 1)
+                 for n in range(len(live))]
+    if len(resident) > MAX_PAIRS or len(resident) == len(blocks) * steps:
+        return None
+    return np.array(resident + streamed + edge, np.int32)
 
 
 def _window_steps(mask, resident, block, n):
@@ -682,17 +769,23 @@ def grid_census(sq, sk, block_q, block_k, causal, q_offset=0, kv_offset=0):
     visited to compute nothing and fetch nothing (``live`` is ``None`` where
     an offset is not a Python int: the launched extent is static, where the
     live blocks start is data).  ``causal`` is a :class:`Mask` or the flag;
-    blocks and tiles a step are fitted as the launchers fit them."""
+    blocks and tiles a step are fitted as the launchers fit them, and the
+    launched extent is that of the grid they run: the pairs of a flattened
+    grid's table (:func:`_pair_table`) where there is one."""
     mask = _as_mask(causal)
     offs = _static_offsets(q_offset, kv_offset)
+
+    def side(seen, resident, rows, keys, steps):
+        table = _pair_table(seen, steps, mask, sq, sk, rows, keys, offs)
+        return {"launched": (resident * steps if table is None
+                             else len(table) // 3),
+                "live": offs and sum(map(len, seen(mask, sq, sk, rows, keys,
+                                                   offs)))}
+
     rows, _, keys, steps, _ = _kv_grid(sq, sk, block_q, block_k, mask, offs)
-    kv_side = {"launched": sq // rows * steps,
-               "live": offs and sum(_live_kv_blocks(mask, sq, sk, rows, keys,
-                                                    offs))}
+    kv_side = side(_kv_blocks_seen, sq // rows, rows, keys, steps)
     _, rows, keys, steps = _q_grid(sq, sk, block_q, block_k, mask, offs)
-    q_side = {"launched": sk // keys * steps,
-              "live": offs and sum(_live_q_blocks(mask, sq, sk, rows, keys,
-                                                  offs))}
+    q_side = side(_q_blocks_seen, sk // keys, rows, keys, steps)
     return {"fwd": kv_side, "dq": dict(kv_side), "dkv": q_side}
 
 
@@ -703,6 +796,8 @@ def _count_tiles(kernel, static_offs, q, k, block_q, block_k, mask):
     (b, h, sq, _), sk = q.shape, k.shape[2]
     steps = grid_census(sq, sk, block_q, block_k, mask,
                         *(static_offs or (None, None)))[kernel]
+    if steps["live"] is not None:
+        steps["idle"] = steps["launched"] - steps["live"]
     metrics.record_flash_grid_steps(
         kernel, {kind: n * b * h for kind, n in steps.items()
                  if n is not None}, mask.label)
@@ -806,6 +901,51 @@ def _each_kind_of_block(block, n_tiles, first, full, live, unmasked):
                               _clip(first, 0, n_tiles - width)))
 
 
+def _grid_step(offs_ref, pairs):
+    """Which blocks a grid step of a kernel's body works on: ``(streamed
+    block, resident block, first, last)``, the block of the streamed side
+    and three functions, each of which emits its scalar arithmetic where
+    the body asks: the resident block, and whether the step is the
+    resident block's first (zero the accumulators) and its last (write the
+    outputs).  On the rectangle the grid's two inner indices say; on a
+    flattened grid of ``pairs`` steps the table behind the two offsets
+    does (:func:`_pair_table`)."""
+    if pairs:
+        t = pl.program_id(2)
+        return (offs_ref[2 + pairs + t], lambda: offs_ref[2 + t],
+                lambda: offs_ref[2 + 2 * pairs + t] & 1 == 1,
+                lambda: offs_ref[2 + 2 * pairs + t] >= 2)
+    j = pl.program_id(3)
+    return (j, lambda: pl.program_id(2), lambda: j == 0,
+            lambda: j == pl.num_programs(3) - 1)
+
+
+def _grid(offs, static_offs, table, rectangle, streamed):
+    """What a launcher runs its kernel over: ``(scalar operand, grid, pairs,
+    resident, streamed)``, the last two functions of the grid's indices and
+    the scalar operand that give a step's block on either side.  Without a
+    table the ``rectangle`` ``(b, h, resident blocks, steps)`` under the
+    offsets, step ``j`` naming what ``streamed`` says, and ``pairs`` 0.
+    With one (:func:`_pair_table`) a grid of a step a pair, the table
+    behind the offsets, and both sides read from it: the same resident
+    block through a block's pairs, so nothing of it is fetched or written
+    back before its last."""
+    if table is None:
+        return (offs, rectangle, 0, lambda b_, h_, i, j, offs: i, streamed)
+    pairs = len(table) // 3
+    return (jnp.asarray(np.concatenate([np.array(static_offs, np.int32),
+                                        table])),
+            (*rectangle[:2], pairs), pairs,
+            lambda b_, h_, t, offs: offs[2 + t],
+            lambda b_, h_, t, offs: offs[2 + pairs + t])
+
+
+def _at(block, *tail):
+    """Index map of the ``[1, 1, rows, ...]`` blocks of a ``[b, h, s, ...]``
+    operand whose block of rows a grid step finds by ``block``."""
+    return lambda *g: (*g[:2], block(*g), *tail)
+
+
 def _jit_kernel(fn):
     """The three launchers are jitted with everything but the arrays
     static, so that a model's layers share one trace of the kernel and one
@@ -822,7 +962,7 @@ def _launch(name, kernel, offs, grid, ins, outs, scratch, interpret):
     index map), ``scratch`` float32 VMEM shapes.  The blocks as they are
     fit Mosaic's 16 MiB of scoped VMEM up to head_dim 256 in float32."""
     params = None if interpret else pltpu.CompilerParams(
-        dimension_semantics=_DIM_SEMANTICS)
+        dimension_semantics=_DIM_SEMANTICS[-len(grid):])
     call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -851,11 +991,11 @@ def _launch(name, kernel, offs, grid, ins, outs, scratch, interpret):
 
 def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                 acc_ref, mi_ref, li_ref, *,
-                mask, scale, normalize, tile_k, blocks, chunk):
+                mask, scale, normalize, tile_k, blocks, chunk, pairs):
     bq = q_ref.shape[2]
     n_tiles = k_ref.shape[2] // tile_k
-    j = pl.program_id(3)
-    q_first = offs_ref[0] + pl.program_id(2) * bq
+    j, resident, first_step, last_step = _grid_step(offs_ref, pairs)
+    q_first = offs_ref[0] + resident() * bq
     if mask.kind == _SW:
         named, live_step = _window_kv_block(
             mask, q_first, bq, offs_ref[1], blocks, k_ref.shape[2], j)
@@ -864,7 +1004,7 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         k_first = offs_ref[1] + j * k_ref.shape[2]
     q_scale, s_scale = _scale_parts(scale, q_ref.dtype)
 
-    @pl.when(j == 0)
+    @pl.when(first_step())
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         mi_ref[:] = jnp.full_like(mi_ref, M_INIT)
@@ -918,7 +1058,7 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
             *_kv_tiles_seen(mask, q_first, bq, k_first, n_tiles, tile_k),
             unmasked=True)
 
-    @pl.when(j == pl.num_programs(3) - 1)
+    @pl.when(last_step())
     def _():
         acc = acc_ref[:]
         l = jnp.sum(li_ref[:], axis=-1, keepdims=True)
@@ -929,8 +1069,9 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         l_ref[0, 0] = l
 
 
-def _kv_block_index(mask, block_q, block_k, sk):
-    """Index map of the streamed k / v blocks of forward and dq.  A grid
+def _kv_block(mask, block_q, block_k, sk):
+    """The streamed k / v block a step of forward's and dq's rectangle
+    names.  A grid
     step wholly past the diagonal computes nothing, so it names the last
     block its query rows do see: the index does not change and nothing is
     fetched.  Under a sliding window the grid has a step for each block the
@@ -953,7 +1094,7 @@ def _kv_block_index(mask, block_q, block_k, sk):
                     block_k)
                 live.append((part * n + first, part * n + first + count))
             j = _nearest_live(j, live)
-        return (b_, h_, j, 0)
+        return j
 
     return index
 
@@ -975,19 +1116,23 @@ def _fwd_call(q, k, v, offs, *, mask, scale, block_q, block_k, normalize,
     sk, dv = k.shape[2], v.shape[3]
     block_q, tile_k, block_k, steps, chunk = _kv_grid(
         sq, sk, block_q, block_k, mask, static_offs)
+    offs, grid, pairs, resident, streamed = _grid(
+        offs, static_offs,
+        _pair_table(_kv_blocks_seen, steps, mask, sq, sk, block_q, block_k,
+                    static_offs),
+        (b, h, sq // block_q, steps), _kv_block(mask, block_q, block_k, sk))
+    q_index, kv_index = _at(resident, 0), _at(streamed, 0)
     kernel = functools.partial(
         _fwd_kernel, mask=mask, scale=scale, normalize=normalize,
-        tile_k=tile_k, blocks=sk // block_k, chunk=chunk,
+        tile_k=tile_k, blocks=sk // block_k, chunk=chunk, pairs=pairs,
     )
     out_dtype = q.dtype if normalize else jnp.float32
-    q_index = lambda b_, h_, i, j, offs: (b_, h_, i, 0)  # noqa: E731
-    kv_index = _kv_block_index(mask, block_q, block_k, sk)
     q_block, k_block, row = ((1, 1, block_q, d), (1, 1, block_k, d),
                              (1, 1, block_q, 1))
     # o, like v, is dv wide (the scores come from d columns and weigh dv)
     o_block, v_block = (1, 1, block_q, dv), (1, 1, block_k, dv)
     return _launch(
-        FWD_KERNEL, kernel, offs, (b, h, sq // block_q, steps),
+        FWD_KERNEL, kernel, offs, grid,
         ins=[(q, q_block, q_index), (k, k_block, kv_index),
              (v, v_block, kv_index)],
         outs=[((b, h, sq, dv), out_dtype, o_block, q_index),
@@ -1004,11 +1149,11 @@ def _fwd_call(q, k, v, offs, *, mask, scale, block_q, block_k, normalize,
 
 def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_acc_ref, *, mask, scale, tile_k, blocks,
-                   chunk):
+                   chunk, pairs):
     bq = q_ref.shape[2]
     n_tiles = k_ref.shape[2] // tile_k
-    j = pl.program_id(3)
-    q_first = offs_ref[0] + pl.program_id(2) * bq
+    j, resident, first_step, last_step = _grid_step(offs_ref, pairs)
+    q_first = offs_ref[0] + resident() * bq
     if mask.kind == _SW:
         named, live_step = _window_kv_block(
             mask, q_first, bq, offs_ref[1], blocks, k_ref.shape[2], j)
@@ -1017,7 +1162,7 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k_first = offs_ref[1] + j * k_ref.shape[2]
     q_scale, s_scale = _scale_parts(scale, q_ref.dtype)
 
-    @pl.when(j == 0)
+    @pl.when(first_step())
     def _():
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
@@ -1067,7 +1212,7 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             *_kv_tiles_seen(mask, q_first, bq, k_first, n_tiles, tile_k),
             unmasked=False)
 
-    @pl.when(j == pl.num_programs(3) - 1)
+    @pl.when(last_step())
     def _():
         # ds was left without the logit scale: once here, on [block_q, d]
         dq_ref[0, 0] = (dq_acc_ref[:] * scale).astype(dq_ref.dtype)
@@ -1075,24 +1220,24 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *,
-                    mask, scale, tile_q, blocks):
+                    mask, scale, tile_q, blocks, pairs):
     """Scores transposed, keys down the rows and queries along the lanes:
     every product is in a form the MXU takes as it is (k q^T, v do^T, p^T
     do, ds^T q), and the row statistics come in as lane-dense rows."""
     bk = k_ref.shape[2]
     n_tiles = q_ref.shape[2] // tile_q
-    i = pl.program_id(3)
+    i, resident, first_step, last_step = _grid_step(offs_ref, pairs)
     if mask.kind == _SW:
         named, live_step = _window_q_block(
-            mask, offs_ref[1] + pl.program_id(2) * bk, bk, offs_ref[0],
+            mask, offs_ref[1] + resident() * bk, bk, offs_ref[0],
             blocks, q_ref.shape[2], i)
         q_first = offs_ref[0] + named * q_ref.shape[2]
     else:
         q_first = offs_ref[0] + i * q_ref.shape[2]
-    k_first = offs_ref[1] + pl.program_id(2) * bk
+    k_first = offs_ref[1] + resident() * bk
     k_scale, s_scale = _scale_parts(scale, k_ref.dtype)
 
-    @pl.when(i == 0)
+    @pl.when(first_step())
     def _():
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
@@ -1132,7 +1277,7 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         hi = jnp.where(live_step, hi, lo)
     _loop(lo, hi, tile)
 
-    @pl.when(i == pl.num_programs(3) - 1)
+    @pl.when(last_step())
     def _():
         dk_ref[0, 0] = (dk_acc_ref[:] * scale).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc_ref[:].astype(dv_ref.dtype)
@@ -1154,16 +1299,20 @@ def _dq_call(q, k, v, do, lse, delta, offs, *, mask, scale, block_q,
     sk, dv = k.shape[2], v.shape[3]
     block_q, tile_k, block_k, steps, chunk = _kv_grid(
         sq, sk, block_q, block_k, mask, static_offs)
+    offs, grid, pairs, resident, streamed = _grid(
+        offs, static_offs,
+        _pair_table(_kv_blocks_seen, steps, mask, sq, sk, block_q, block_k,
+                    static_offs),
+        (b, h, sq // block_q, steps), _kv_block(mask, block_q, block_k, sk))
+    q_index, kv_index = _at(resident, 0), _at(streamed, 0)
     kernel = functools.partial(_bwd_dq_kernel, mask=mask, scale=scale,
                                tile_k=tile_k, blocks=sk // block_k,
-                               chunk=chunk)
-    q_index = lambda b_, h_, i, j, offs: (b_, h_, i, 0)  # noqa: E731
-    kv_index = _kv_block_index(mask, block_q, block_k, sk)
+                               chunk=chunk, pairs=pairs)
     q_block, k_block, row = ((1, 1, block_q, d), (1, 1, block_k, d),
                              (1, 1, block_q, 1))
     do_block, v_block = (1, 1, block_q, dv), (1, 1, block_k, dv)
     return _launch(
-        DQ_KERNEL, kernel, offs, (b, h, sq // block_q, steps),
+        DQ_KERNEL, kernel, offs, grid,
         ins=[(q, q_block, q_index), (k, k_block, kv_index),
              (v, v_block, kv_index), (do, do_block, q_index),
              (lse, row, q_index), (delta, row, q_index)],
@@ -1189,8 +1338,6 @@ def _dkv_call(q, k, v, do, lse, delta, offs, *, mask, scale, block_q,
     tile_q, block_q, block_k, steps = _q_grid(sq, sk, block_q, block_k,
                                               mask, static_offs)
     n_tiles = block_q // tile_q
-    kernel = functools.partial(_bwd_dkv_kernel, mask=mask, scale=scale,
-                               tile_q=tile_q, blocks=sq // block_q)
     lse, delta = (x.reshape(b, h, sq // tile_q, 1, tile_q)
                   for x in (lse, delta))
 
@@ -1214,14 +1361,21 @@ def _dkv_call(q, k, v, do, lse, delta, offs, *, mask, scale, block_q,
                 for part in range(2)])
         return i
 
-    q_index = lambda *g: (*g[:2], first_seen(*g), 0)  # noqa: E731
-    row_index = lambda *g: (*g[:2], first_seen(*g), 0, 0)  # noqa: E731
-    kv_index = lambda b_, h_, jk, i, offs: (b_, h_, jk, 0)  # noqa: E731
+    offs, grid, pairs, resident, streamed = _grid(
+        offs, static_offs,
+        _pair_table(_q_blocks_seen, steps, mask, sq, sk, block_q, block_k,
+                    static_offs),
+        (b, h, sk // block_k, steps), first_seen)
+    kv_index, q_index, row_index = (_at(resident, 0), _at(streamed, 0),
+                                    _at(streamed, 0, 0))
+    kernel = functools.partial(_bwd_dkv_kernel, mask=mask, scale=scale,
+                               tile_q=tile_q, blocks=sq // block_q,
+                               pairs=pairs)
     q_block, k_block, rows = ((1, 1, block_q, d), (1, 1, block_k, d),
                               (1, 1, n_tiles, 1, tile_q))
     do_block, v_block = (1, 1, block_q, dv), (1, 1, block_k, dv)
     return _launch(
-        DKV_KERNEL, kernel, offs, (b, h, sk // block_k, steps),
+        DKV_KERNEL, kernel, offs, grid,
         ins=[(q, q_block, q_index), (k, k_block, kv_index),
              (v, v_block, kv_index), (do, do_block, q_index),
              (lse, rows, row_index), (delta, rows, row_index)],

@@ -457,12 +457,14 @@ def test_grid_step_counter_of_the_window_cell(monkeypatch):
              for k, v in _counted(name, mask.label).items()}
     assert delta == {(kernel, kind): 32 * n * (2 if kernel == "dkv" else 1)
                      for kernel in ("fwd", "dq", "dkv")
-                     for kind, n in (("launched", 32), ("live", 31))}
+                     for kind, n in (("launched", 32), ("live", 31),
+                                     ("idle", 1))}
     assert all(delta[k, "launched"] <= 1.5 * delta[k, "live"]
                for k in ("fwd", "dq", "dkv"))
-    # the grid over every block of the parent's tiles, for the record
+    # the causal grid at the tiles before the window's: a step every block
+    # (128) until PR 43, a step every live pair since
     assert fa.grid_census(16384, 16384, 1024, 512, fa.CAUSAL)["fwd"] == {
-        "launched": 128, "live": 72}
+        "launched": 72, "live": 72}
     # with traced offsets the extent is what a block can reach, and what
     # is live is data
     traced = _counted(name, "sliding_window_w24")
