@@ -39,13 +39,13 @@ sys.path.insert(0, __file__.rsplit("/", 1)[0])
 
 BASELINE_IMG_SEC_PER_DEVICE = 1656.82 / 16  # docs/benchmarks.rst:27-41
 # MFU constants live in horovod_tpu/utils/flops.py (single-sourced with
-# the hvd_mfu gauge and the comm report; HVD_PEAK_FLOPS overrides the
-# peak) — every leg's mfu field routes through _mfu() below
+# the comm report; HVD_PEAK_FLOPS overrides the peak) — every leg's mfu
+# field routes through _mfu() below
 
 
 def _mfu(img_sec_per_chip: float) -> float:
     """MFU for a bench leg, computed through utils/flops so the bench
-    JSON and the ``hvd_mfu`` gauge can never disagree.  Runs in the
+    JSON and the comm report can never disagree.  Runs in the
     measurement child after ``hvd.init()``; a device with no known peak
     raises (utils/flops.require_peak_flops)."""
     from horovod_tpu.utils import flops as _flops

@@ -35,8 +35,7 @@ def prefetch_to_device(iterator: Iterable, depth: Optional[int] = None
     The producer thread does the host work (index/pad/copy) AND the
     ``device_put`` dispatch — JAX transfers are async, so by the time
     the training loop pops a batch its H2D copy has been in flight for
-    a full step (the double-buffering the compute-anatomy profiler's
-    host-gap metric flags when it is missing, docs/profiling.md).
+    a full step.
     ``depth`` defaults to ``HVD_PREFETCH_DEPTH`` (2); 0 degrades to the
     plain synchronous iterator.  Item order is preserved (single
     producer, FIFO queue) and a producer exception re-raises at the

@@ -66,12 +66,6 @@ hvd_autotune_realized_speedup   gauge      realized speedup of the applied
                                            plan vs its baseline window (pct)
 hvd_autotune_plans_applied_total counter   profile-guided plans applied live
 hvd_autotune_rollbacks_total    counter    plans rolled back past guard band
-hvd_mfu                         gauge      measured MFU over the compute-
-                                           anatomy profiler's window
-hvd_step_phase_fraction         gauge      share of profiled step wall time
-                                           per phase (by ``phase`` label)
-hvd_host_gap_us                 gauge      per-step device-idle-on-host time
-                                           from inter-dispatch gaps
 hvd_serve_requests_total        counter    inference requests, by ``outcome``
 hvd_serve_latency_seconds       histogram  request submit→complete latency
 hvd_serve_queue_wait_seconds    histogram  request submit→pull queue wait
@@ -410,22 +404,6 @@ AUTOTUNE_ROLLBACKS = registry.counter(
     "Applied plans rolled back because realized speedup lagged the "
     "prediction past the guard band.")
 
-MFU = registry.gauge(
-    "hvd_mfu",
-    "Model-FLOPs utilization measured by the compute-anatomy profiler "
-    "over its capture window (timeline/profiler.py: cost_analysis flops "
-    "over measured step wall time, divided by utils/flops.peak_flops — "
-    "the same single-sourced peak the bench JSON divides by).")
-STEP_PHASE_FRACTION = registry.gauge(
-    "hvd_step_phase_fraction",
-    "Fraction of the profiled step's wall time spent in each phase "
-    "(forward/backward/grad_allreduce/optimizer_update/host_gap).",
-    ("phase",))
-HOST_GAP_US = registry.gauge(
-    "hvd_host_gap_us",
-    "Per-step device-idle-waiting-on-host time detected from "
-    "inter-dispatch gaps inside the profiled window.")
-
 SERVE_REQUESTS = registry.counter(
     "hvd_serve_requests_total",
     "Inference requests by outcome (ok/error/timeout/rejected) — "
@@ -485,7 +463,7 @@ PROJECTION_ERR_PCT = registry.gauge(
 ALERTS_TOTAL = registry.counter(
     "hvd_alerts_total",
     "Online-watchdog alerts raised by the observe/ detectors, by signal "
-    "(step_time_regression/straggler/mfu_drop/comm_beta_drift/slo_burn) "
+    "(step_time_regression/straggler/comm_beta_drift/slo_burn) "
     "and severity (warning/critical) — docs/observe.md.",
     ("signal", "severity"))
 WATCH_ARMS = registry.counter(

@@ -4,10 +4,9 @@ The registry (registry.py) answers "what is the value NOW"; this module
 answers "what was it over the last N steps" — the history the online
 anomaly watchdog (horovod_tpu/observe/) runs its detectors on, without
 anyone having picked a trace window in advance.  Every diagnostic
-surface before this one (BYTEPS_TRACE step windows, the compute-anatomy
-profiler, the replay twin) is operator-initiated; the time-series plane
-is the cheap always-on substrate that tells the operator *when* to
-spend those.
+surface before this one (BYTEPS_TRACE step windows, the replay twin)
+is operator-initiated; the time-series plane is the cheap always-on
+substrate that tells the operator *when* to spend those.
 
 Design constraints, in order:
 
@@ -53,15 +52,13 @@ log = get_logger(__name__)
 #: the runtime.  Kept here so the watchdog, hvd_watch, and the docs
 #: enumerate one list.
 STEP_SECONDS = "step_seconds"              # train-step cadence (training.py)
-MFU_SERIES = "mfu"                         # profiler window MFU
-HOST_GAP_US_SERIES = "host_gap_us"         # profiler host-gap per step
 DISPATCH_US_PER_MIB = "dispatch_us_per_mib"  # eager collective cost density
 SERVE_P99_MS_SERIES = "serve_p99_ms"       # serving windowed p99
 RESIDUAL_NORM_SERIES = "residual_norm"     # compression error-feedback norm
 
 KNOWN_SERIES = (
-    STEP_SECONDS, MFU_SERIES, HOST_GAP_US_SERIES, DISPATCH_US_PER_MIB,
-    SERVE_P99_MS_SERIES, RESIDUAL_NORM_SERIES,
+    STEP_SECONDS, DISPATCH_US_PER_MIB, SERVE_P99_MS_SERIES,
+    RESIDUAL_NORM_SERIES,
 )
 
 

@@ -5,11 +5,10 @@ Detectors (detectors.py) run over the always-on telemetry time-series
 evidence, window}``; the watchdog (watchdog.py) runs them next to the
 launcher's rendezvous server, publishes alerts to the ``alerts`` KV
 scope (``GET /alerts``, ``hvd_alerts_total``), and closes the loop: a
-confirmed step-time or straggler alert auto-arms a trace+profile
-window — the existing ``HVD_TRACE_*``/``HVD_PROFILE_*`` machinery,
-armed rank-consistently via a KV-broadcast start step (autoarm.py) —
-so the alert ships with replay/anatomy attribution instead of a bare
-number.
+confirmed step-time or straggler alert auto-arms the timeline's trace
+window — the existing ``HVD_TRACE_*`` machinery, armed
+rank-consistently via a KV-broadcast start step (autoarm.py) — so the
+alert ships with a trace to replay instead of a bare number.
 """
 
 from __future__ import annotations
@@ -17,7 +16,6 @@ from __future__ import annotations
 from .detectors import (  # noqa: F401
     comm_beta_drift,
     ewma_mad_regression,
-    mfu_drop,
     slo_burn_rate,
     straggler_drift,
     straggler_from_verdicts,

@@ -1,9 +1,9 @@
-"""Rank-consistent auto-arming of trace+profile windows.
+"""Rank-consistent auto-arming of the timeline's trace window.
 
 A confirmed step-time or straggler alert should ship with attribution,
 not a bare number — so the watchdog broadcasts an *arm record* through
-the rendezvous KV store and every rank moves its trace+profile window
-to the same future training step:
+the rendezvous KV store and every rank moves its trace window to the
+same future training step:
 
 * :func:`broadcast_arm` (watchdog side) writes
   ``{"id", "start_step", "end_step", "signal", "trace_dir", "ts"}``
@@ -12,9 +12,9 @@ to the same future training step:
 * :func:`poll_and_apply` (worker side) runs on the telemetry flusher
   thread (metrics/timeseries.py), never the step path.  Each arm id is
   applied at most once per process: the rank's current training step
-  is read off its cadence series and passed to ``timeline.arm`` /
-  ``ComputeProfiler.arm`` as the translation anchor, so the broadcast
-  *global* step window lands on the same steps everywhere.
+  is read off its cadence series and passed to ``timeline.arm`` as the
+  translation anchor, so the broadcast *global* step window lands on
+  the same steps everywhere.
 
 ``start_step`` is chosen by the watchdog as ``max(last cadence step
 across ranks) + HVD_WATCH_ARM_MARGIN_STEPS`` — far enough ahead that
@@ -26,7 +26,7 @@ from __future__ import annotations
 import json
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Optional
 
 from ..utils import env as env_util
 from ..utils.logging import get_logger
@@ -38,28 +38,12 @@ ARM_SCOPE = "observe"
 ARM_KEY = "arm"
 
 _lock = threading.Lock()
-_profilers: List[Any] = []
 _applied_ids: set = set()
 
 
-def register_profiler(profiler: Any) -> None:
-    """Training registers its ComputeProfiler here so an arm record can
-    reach it (make_train_step holds it as a closure variable)."""
-    with _lock:
-        if profiler not in _profilers:
-            _profilers.append(profiler)
-
-
-def unregister_profiler(profiler: Any) -> None:
-    with _lock:
-        if profiler in _profilers:
-            _profilers.remove(profiler)
-
-
 def reset() -> None:
-    """Test seam: forget registered profilers and applied arm ids."""
+    """Test seam: forget applied arm ids."""
     with _lock:
-        _profilers.clear()
         _applied_ids.clear()
 
 
@@ -86,9 +70,9 @@ def broadcast_arm(server: Any, arm_id: str, start_step: int, end_step: int,
 
 
 def apply_arm(record: Dict[str, Any]) -> bool:
-    """Apply one arm record to this process's timeline + profilers.
+    """Apply one arm record to this process's timeline.
 
-    Idempotent per arm id; returns True when this call armed anything.
+    Idempotent per arm id; returns True when this call armed it.
     """
     arm_id = str(record.get("id", ""))
     if not arm_id:
@@ -97,7 +81,6 @@ def apply_arm(record: Dict[str, Any]) -> bool:
         if arm_id in _applied_ids:
             return False
         _applied_ids.add(arm_id)
-        profilers = list(_profilers)
     try:
         start = int(record["start_step"])
         end = int(record["end_step"])
@@ -117,17 +100,11 @@ def apply_arm(record: Dict[str, Any]) -> bool:
         from ..timeline.timeline import timeline
 
         armed = timeline.arm(start, end, current_step=current,
-                             directory=trace_dir) or armed
+                             directory=trace_dir)
     except Exception as e:  # noqa: BLE001 — arming must never kill the flusher
         log.debug("timeline arm failed: %s", e)
-    for prof in profilers:
-        try:
-            prof.arm(start, end, current_step=current, trace_dir=trace_dir)
-            armed = True
-        except Exception as e:  # noqa: BLE001
-            log.debug("profiler arm failed: %s", e)
     if armed:
-        log.info("auto-armed trace+profile window [%d, %d] (%s, arm %s)",
+        log.info("auto-armed trace window [%d, %d] (%s, arm %s)",
                  start, end, record.get("signal"), arm_id)
     return armed
 

@@ -26,14 +26,12 @@ MAD_SIGMA = 1.4826
 
 SIGNAL_STEP_TIME = "step_time_regression"
 SIGNAL_STRAGGLER = "straggler_drift"
-SIGNAL_MFU = "mfu_drop"
 SIGNAL_BETA = "comm_beta_drift"
 SIGNAL_SLO_BURN = "slo_burn_rate"
 
 SIGNALS = (
     SIGNAL_STEP_TIME,
     SIGNAL_STRAGGLER,
-    SIGNAL_MFU,
     SIGNAL_BETA,
     SIGNAL_SLO_BURN,
 )
@@ -215,41 +213,6 @@ def straggler_from_verdicts(
         },
         "window": {"start_step": 0, "end_step": 0, "samples": 0},
     }
-
-
-def mfu_drop(
-    samples: Sequence[Sample],
-    *,
-    drop_pct: float = 20.0,
-    min_samples: int = 8,
-) -> Optional[Dict[str, Any]]:
-    """MFU drop: trailing-quarter median vs first-half median.
-
-    Fires when the recent median sits more than ``drop_pct`` percent
-    below the baseline median; critical past ``2 * drop_pct``.
-    """
-    if len(samples) < min_samples:
-        return None
-    values = [v for _, v in samples]
-    baseline = _median(values[: len(values) // 2])
-    recent = _median(values[-max(1, len(values) // 4):])
-    if baseline <= 0:
-        return None
-    drop = 100.0 * (baseline - recent) / baseline
-    if drop <= drop_pct:
-        return None
-    severity = "critical" if drop > 2.0 * drop_pct else "warning"
-    return _alert(
-        SIGNAL_MFU,
-        severity,
-        {
-            "baseline_mfu": baseline,
-            "recent_mfu": recent,
-            "drop_pct": drop,
-            "threshold_pct": drop_pct,
-        },
-        samples,
-    )
 
 
 def comm_beta_drift(

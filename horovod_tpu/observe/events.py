@@ -1,8 +1,8 @@
 """Control-plane flight recorder: correlated cross-subsystem events.
 
 The data plane got first-class tracing in PRs 1–17 (timelines,
-anatomies, timeseries, alerts); this module gives the *control* plane
-the same treatment.  Every lifecycle actor — the elastic driver,
+timeseries, alerts); this module gives the *control* plane the same
+treatment.  Every lifecycle actor — the elastic driver,
 heartbeat/abort protocol, serving autoscaler, profile-guided tuner,
 compression guard, checkpoint writer, watchdog, and the launcher's
 restart loop — emits structured events through one API::

@@ -13,8 +13,6 @@ on them (thresholds, fire steps, severities), derived by hand:
   0.117625 > 0.115826.
 * straggler: ranks 0-3 at 0.100 s except rank 1 at 0.140 s — world
   median 0.100, ratio 1.4 > skew 1.3 but < critical bar 1.6.
-* mfu: 8 x 0.40 then 8 x 0.30 — baseline median 0.40, trailing-
-  quarter median 0.30, drop 25% > 20% but < 40%.
 * beta: measured 120 us/MiB vs predicted 50 — ratio 2.4 > 2 but < 4.
 * burn: 3 of 50 samples above the 250 ms SLO — breach fraction 0.06
   over budget 0.01 = burn 6.0 > 2 * threshold(2.0), critical.
@@ -43,7 +41,6 @@ Sample = Tuple[int, float]
 
 REGRESSION_PARAMS = {"alpha": 0.5, "k": 5.0, "warmup": 40, "confirm": 3}
 STRAGGLER_PARAMS = {"skew": 1.3, "min_samples": 8, "window": 64}
-MFU_PARAMS = {"drop_pct": 20.0, "min_samples": 8}
 BETA_PARAMS = {"drift": 2.0, "min_samples": 8}
 BETA_PREDICTED_US_PER_MIB = 50.0
 BURN_PARAMS = {"budget": 0.01, "burn_threshold": 2.0, "min_samples": 16}
@@ -63,12 +60,6 @@ WATCH_EXPECTED: Dict[str, Any] = {
         "rank": "1",
         "ratio": 1.4,
         "world_median": 0.100,
-    },
-    "mfu": {
-        "severity": "warning",
-        "baseline_mfu": 0.40,
-        "recent_mfu": 0.30,
-        "drop_pct": 25.0,
     },
     "beta": {
         "severity": "warning",
@@ -99,9 +90,6 @@ def watch_fixture() -> Dict[str, Any]:
         for rank in ("0", "1", "2", "3")
     }
 
-    mfu = [(i + 1, 0.40) for i in range(8)]
-    mfu += [(9 + i, 0.30) for i in range(8)]
-
     beta = [(i + 1, 120.0) for i in range(16)]
 
     burn = [(i + 1, 200.0) for i in range(47)]
@@ -113,7 +101,6 @@ def watch_fixture() -> Dict[str, Any]:
             rank: [(i + 1, 0.100) for i in range(16)]
             for rank in ("0", "1", "2", "3")
         },
-        "mfu": [(i + 1, 0.40) for i in range(16)],
         "beta": [(i + 1, 60.0) for i in range(16)],
         "burn": [(i + 1, 200.0) for i in range(50)],
     }
@@ -121,7 +108,6 @@ def watch_fixture() -> Dict[str, Any]:
     return {
         "regression": regression,
         "straggler": straggler,
-        "mfu": mfu,
         "beta": beta,
         "burn": burn,
         "quiet": quiet,
@@ -141,7 +127,6 @@ def evaluate_fixture(fixture: Dict[str, Any] = None) -> Dict[str, Any]:
             fx["regression"], **REGRESSION_PARAMS),
         "straggler": detectors.straggler_drift(
             fx["straggler"], **STRAGGLER_PARAMS),
-        "mfu": detectors.mfu_drop(fx["mfu"], **MFU_PARAMS),
         "beta": detectors.comm_beta_drift(
             fx["beta"], BETA_PREDICTED_US_PER_MIB, **BETA_PARAMS),
         "burn": detectors.slo_burn_rate(
@@ -154,7 +139,6 @@ def evaluate_fixture(fixture: Dict[str, Any] = None) -> Dict[str, Any]:
                 quiet["regression"], **REGRESSION_PARAMS),
             detectors.straggler_drift(
                 quiet["straggler"], **STRAGGLER_PARAMS),
-            detectors.mfu_drop(quiet["mfu"], **MFU_PARAMS),
             detectors.comm_beta_drift(
                 quiet["beta"], BETA_PREDICTED_US_PER_MIB, **BETA_PARAMS),
             detectors.slo_burn_rate(quiet["burn"], BURN_SLO_MS,

@@ -1,4 +1,4 @@
-"""The launcher-side watchdog thread: detect → alert → arm → attribute.
+"""The launcher-side watchdog thread: detect → alert → arm.
 
 Runs next to the rendezvous server (run/run.py starts one per job,
 ``HVD_WATCH=0`` disables).  Every ``HVD_WATCH_INTERVAL_SECONDS`` tick:
@@ -7,22 +7,18 @@ Runs next to the rendezvous server (run/run.py starts one per job,
    server handle (``server.timeseries_report()`` — no HTTP round
    trip) and runs the pure detectors (detectors.py) over it:
    EWMA/MAD step-time regression and comm-β drift per rank, straggler
-   cadence skew across ranks, MFU drop, serving SLO burn rate;
+   cadence skew across ranks, serving SLO burn rate;
 2. publishes each fired alert to the ``alerts`` KV scope (key = a
    monotonically increasing id, so ``GET /alerts`` renders newest
    first) and bumps ``hvd_alerts_total{signal,severity}``; a
    per-signal cooldown (``HVD_WATCH_ARM_COOLDOWN_SECONDS``) stops a
    persisting condition from flooding the log;
-3. a confirmed step-time or straggler alert **auto-arms** a
-   trace+profile window: the arm record is broadcast through
+3. a confirmed step-time or straggler alert **auto-arms** the
+   timeline's trace window: the arm record is broadcast through
    ``observe/arm`` (autoarm.py) with a start step far enough ahead
    (``HVD_WATCH_ARM_MARGIN_STEPS`` past the newest cadence step) that
    every rank applies it before the window opens;
-4. once the armed window's anatomies land in the ``profile`` scope,
-   the alert record is re-published with an ``attribution`` block —
-   top segment, its slowest rank, mean MFU, worst host gap — so the
-   alert names the block or rank instead of a bare number;
-5. a *critical* straggler alert optionally feeds the elastic driver's
+4. a *critical* straggler alert optionally feeds the elastic driver's
    removal path (``HVD_WATCH_EVICT=1`` + an attached driver).
 
 The watchdog never touches the step path: workers only pay the
@@ -44,7 +40,7 @@ log = get_logger(__name__)
 
 ALERTS_SCOPE = "alerts"
 
-#: signals whose confirmed alerts auto-arm a trace+profile window
+#: signals whose confirmed alerts auto-arm a trace window
 ARMING_SIGNALS = (detectors.SIGNAL_STEP_TIME, detectors.SIGNAL_STRAGGLER)
 
 
@@ -85,9 +81,6 @@ class Watchdog(threading.Thread):
                                         env_util.DEFAULT_WATCH_CONFIRM)
         self.skew = env_util.get_float(env_util.HVD_WATCH_STRAGGLER_SKEW,
                                        env_util.DEFAULT_WATCH_STRAGGLER_SKEW)
-        self.mfu_drop_pct = env_util.get_float(
-            env_util.HVD_WATCH_MFU_DROP_PCT,
-            env_util.DEFAULT_WATCH_MFU_DROP_PCT)
         self.beta_drift = env_util.get_float(env_util.HVD_WATCH_BETA_DRIFT,
                                              env_util.DEFAULT_WATCH_BETA_DRIFT)
         self.slo_ms = env_util.get_float(env_util.HVD_SERVE_SLO_MS,
@@ -111,7 +104,6 @@ class Watchdog(threading.Thread):
         self._last_emit: Dict[str, float] = {}   # signal key -> mono time
         self._last_arm = 0.0
         self._arm_seq = 0
-        self._pending_attribution: List[Dict[str, Any]] = []
         self.alerts_emitted = 0
         self.arms = 0
         self.evictions = 0
@@ -164,15 +156,8 @@ class Watchdog(threading.Thread):
             fired.append((f"{alert['signal']}:{alert['evidence']['rank']}",
                           alert))
 
-        # MFU drop + comm-beta drift + SLO burn, per reporting rank
+        # comm-beta drift + SLO burn, per reporting rank
         for rank, doc in ranks.items():
-            mfu = _samples(doc, "mfu")
-            alert = detectors.mfu_drop(mfu[-self.window:],
-                                       drop_pct=self.mfu_drop_pct)
-            if alert:
-                alert["evidence"]["rank"] = rank
-                fired.append((f"{alert['signal']}:{rank}", alert))
-
             beta = _samples(doc, "dispatch_us_per_mib")
             if len(beta) >= 16:
                 # self-calibrated model point: the window's own early
@@ -204,7 +189,6 @@ class Watchdog(threading.Thread):
                 continue
             self._last_emit[key] = now
             published.append(self._publish(alert, cadence))
-        self._enrich_pending()
         return published
 
     # -- publish / arm / evict ----------------------------------------------
@@ -300,7 +284,6 @@ class Watchdog(threading.Thread):
                 cause_id=record.get("event_id"))
         except Exception:  # noqa: BLE001 — recording is best-effort
             pass
-        self._pending_attribution.append(record)
         try:
             from .. import metrics
 
@@ -308,44 +291,9 @@ class Watchdog(threading.Thread):
                 metrics.WATCH_ARMS.inc()
         except Exception as e:  # noqa: BLE001
             log.debug("arm counter failed: %s", e)
-        log.warning("watchdog armed trace+profile window [%d, %d] "
+        log.warning("watchdog armed trace window [%d, %d] "
                     "(%s, alert #%s)", start, end, record["signal"],
                     record["id"])
-
-    def _enrich_pending(self) -> None:
-        """Attach profile attribution to armed alerts once the window's
-        anatomies land in the ``profile`` scope, then re-publish."""
-        if not self._pending_attribution:
-            return
-        try:
-            profile = self._server.profile_report()
-        except Exception as e:  # noqa: BLE001
-            log.debug("profile report read failed: %s", e)
-            return
-        agg = (profile or {}).get("aggregate") or {}
-        top = agg.get("top_segments") or []
-        if not top:
-            return
-        segments = agg.get("segments") or {}
-        top_name = top[0]
-        seg = segments.get(top_name) or {}
-        mfu = agg.get("mfu") or {}
-        gap = agg.get("host_gap_per_step_us") or {}
-        attribution = {
-            "top_segment": top_name,
-            "slowest_rank": seg.get("slowest_rank"),
-            "spread_us": seg.get("spread_us"),
-            "mean_device_us": seg.get("mean_device_us"),
-            "mfu_mean": mfu.get("mean"),
-            "host_gap_max_rank": gap.get("max_rank"),
-        }
-        for record in self._pending_attribution:
-            record["attribution"] = attribution
-            self._put_alert(record)
-            log.info("alert #%s attributed: top segment %s (slowest "
-                     "rank %s)", record["id"], top_name,
-                     seg.get("slowest_rank"))
-        self._pending_attribution = []
 
     def _maybe_evict(self, record: Dict[str, Any]) -> None:
         """Critical straggler + HVD_WATCH_EVICT=1 + an attached elastic

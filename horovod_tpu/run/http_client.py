@@ -532,30 +532,6 @@ def put_autotune_plan(addr: str, port: int, seq: int, record: dict,
            json.dumps(record).encode(), secret=secret, retry=True)
 
 
-def put_profile_summary(addr: str, port: int, rank, summary: dict,
-                        secret: Optional[bytes] = None) -> None:
-    """Publish one rank's compute-anatomy summary (timeline/profiler.py
-    window anatomy) under the rendezvous ``profile`` scope so
-    ``GET /profile`` renders the cross-rank aggregate.  Single writer
-    per key (the rank), last-writer-wins → safe to retry."""
-    import json
-
-    put_kv(addr, port, "profile", str(rank),
-           json.dumps(summary).encode(), secret=secret, retry=True)
-
-
-def get_profile(addr: str, port: int, secret: Optional[bytes] = None,
-                timeout: float = 10.0) -> dict:
-    """The aggregated compute-anatomy report from ``GET /profile``:
-    per-rank anatomies plus the cross-rank aggregate (per-segment
-    slowest rank, mean MFU, worst host gap — docs/profiling.md)."""
-    import json
-
-    with _request("GET", addr, port, "/profile", secret=secret,
-                  timeout=timeout) as resp:
-        return json.loads(resp.read().decode())
-
-
 def get_timeseries(addr: str, port: int, secret: Optional[bytes] = None,
                    timeout: float = 10.0) -> dict:
     """The telemetry time-series table from ``GET /timeseries``:

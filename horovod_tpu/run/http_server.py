@@ -100,13 +100,6 @@ REPLAY_SUMMARY_KEY = "summary"
 PROJECTION_SCOPE = "projection"
 PROJECTION_SUMMARY_KEY = "summary"
 
-# compute-anatomy profiler (timeline/profiler.py): each rank pushes its
-# window anatomy under profile/<rank> at finalize; GET /profile renders
-# the per-rank anatomies plus the cross-rank aggregate (per-segment
-# slowest rank, mean MFU, worst host gap — docs/profiling.md)
-PROFILE_SCOPE = "profile"
-_PROFILE_PREFIX = f"/{PROFILE_SCOPE}/"
-
 # profile-guided autotune loop (optim/profile_guided.py): the tuner (or
 # scripts/hvd_autotune.py --push) publishes one record per plan event
 # under plan.<n>; GET /autotune renders the per-plan table plus the
@@ -124,7 +117,7 @@ _TIMESERIES_PREFIX = f"/{TIMESERIES_SCOPE}/"
 
 # online anomaly watchdog (horovod_tpu/observe/): alert records live
 # under alerts/<id> (GET /alerts renders them newest-first), and the
-# auto-arm broadcast — the KV-broadcast trace+profile start step every
+# auto-arm broadcast — the KV-broadcast trace-window start step every
 # rank applies consistently — lives at observe/arm
 ALERTS_SCOPE = "alerts"
 _ALERTS_PREFIX = f"/{ALERTS_SCOPE}/"
@@ -364,29 +357,6 @@ def build_peerstate_report(store: Dict[str, bytes]) -> Dict[str, object]:
         "generations": {str(g): r for g, r in sorted(gens.items())},
         "newest_committed": newest_committed,
     }
-
-
-def build_profile_report(store: Dict[str, bytes]) -> Dict[str, object]:
-    """The compute-anatomy table from a store snapshot: every pushed
-    per-rank anatomy plus the cross-rank aggregate, computed by the SAME
-    :func:`~horovod_tpu.timeline.profiler.aggregate_anatomies` the
-    offline CLI uses (``GET /profile``, docs/profiling.md)."""
-    per_rank: Dict[str, object] = {}
-    for k, v in store.items():
-        if not k.startswith(_PROFILE_PREFIX):
-            continue
-        rank = k[len(_PROFILE_PREFIX):]
-        try:
-            per_rank[rank] = json.loads(v)
-        except (ValueError, TypeError):
-            per_rank[rank] = "<undecodable>"
-    valid = {r: a for r, a in per_rank.items() if isinstance(a, dict)}
-    aggregate = None
-    if valid:
-        from ..timeline.profiler import aggregate_anatomies
-
-        aggregate = aggregate_anatomies(valid)
-    return {"ranks": per_rank, "aggregate": aggregate}
 
 
 def build_timeseries_report(store: Dict[str, bytes]) -> Dict[str, object]:
@@ -897,11 +867,6 @@ class KVStoreHandler(BaseHTTPRequestHandler):
             self._reply(200, json.dumps(build_autotune_report(store))
                         .encode(), content_type="application/json")
             return
-        if path == "/profile":
-            store = self.server.store.items()  # type: ignore
-            self._reply(200, json.dumps(build_profile_report(store))
-                        .encode(), content_type="application/json")
-            return
         if path == "/timeseries":
             store = self.server.store.items()  # type: ignore
             self._reply(200, json.dumps(build_timeseries_report(store))
@@ -1250,10 +1215,6 @@ class RendezvousServer:
     def autotune_report(self) -> Dict[str, object]:
         """In-process equivalent of GET /autotune."""
         return build_autotune_report(self.store.items())
-
-    def profile_report(self) -> Dict[str, object]:
-        """In-process equivalent of GET /profile."""
-        return build_profile_report(self.store.items())
 
     def timeseries_report(self) -> Dict[str, object]:
         """In-process equivalent of GET /timeseries (the watchdog's

@@ -55,7 +55,7 @@ log = get_logger(__name__)
 #: snapshot, and must never bloat a journal — only their manifests
 #: (the journaled ``peerstate`` scope) need to survive a failover.
 JOURNAL_EXCLUDED_SCOPES = frozenset(
-    {"metrics", "sanitizer", "profile", "health", "shard"})
+    {"metrics", "sanitizer", "health", "shard"})
 
 
 class Journal:

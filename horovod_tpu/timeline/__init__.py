@@ -12,12 +12,4 @@ def __getattr__(name):
         from . import recorder
 
         return getattr(recorder, name)
-    if name == "ComputeProfiler":
-        from . import profiler
-
-        return profiler.ComputeProfiler
-    if name == "profiler":
-        import importlib
-
-        return importlib.import_module(".profiler", __name__)
     raise AttributeError(name)

@@ -556,8 +556,8 @@ def collective_report(
     # None → utils/flops.peak_flops(): the ONE peak table (keyed by the
     # mesh devices' kind, HVD_PEAK_FLOPS overrides) every MFU number
     # divides by — a hardware change can't desync this report from
-    # bench.py or the compute-anatomy profiler.  On a device with no
-    # known peak the flops/peak fallback below has nothing to divide by:
+    # bench.py.  On a device with no known peak the flops/peak fallback
+    # below has nothing to divide by:
     # pass measured_step_seconds (or the peak of the chip being modelled)
     peak_flops: Optional[float] = None,
     ici_bytes_per_sec: float = DEFAULT_ICI_BYTES_PER_SEC,

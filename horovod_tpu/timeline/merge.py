@@ -35,18 +35,13 @@ NEGOTIATE_PREFIX = "NEGOTIATE_"
 #: per-rank clock-offset sidecar written by Timeline.initialize
 CLOCK_SYNC_FILE = "clock_sync.json"
 
-#: per-rank compute-anatomy artifact written by the profiler
-#: (timeline/profiler.py); its segment events merge into the Chrome
-#: trace as their own per-rank row group
-COMPUTE_JSON = "compute.json"
-
 #: control-plane flight-recorder dump (``hvd_events --json >
 #: <dir>/events.json``, or a raw ``GET /events`` report); its events
 #: merge as one row of Chrome instant events above the rank rows
 EVENTS_JSON = "events.json"
 
 #: pid of the flight-recorder row — negative so it can never collide
-#: with a rank pid or a COMPUTE_PID_BASE row, sorted above rank 0
+#: with a rank pid, sorted above rank 0
 EVENTS_PID = -1
 
 
@@ -65,20 +60,6 @@ def load_events_artifact(trace_dir: str) -> List[dict]:
     if isinstance(d, dict):
         d = d.get("events") or []
     return [e for e in d if isinstance(e, dict)]
-
-
-def load_profile_artifact(trace_dir: str, rank: int) -> dict:
-    """One rank's parsed ``compute.json`` (``{}`` when absent or
-    undecodable — a rank that never profiled is normal, not an error)."""
-    p = os.path.join(trace_dir, str(rank), COMPUTE_JSON)
-    if not os.path.isfile(p):
-        return {}
-    try:
-        with open(p) as f:
-            d = json.load(f)
-        return d if isinstance(d, dict) else {}
-    except (ValueError, OSError):
-        return {}
 
 
 def load_rank_events(path: str) -> List[dict]:
@@ -166,8 +147,6 @@ def merge_traces(trace_dir: str, align_clocks: bool = True) -> dict:
         aligned, shift, offsets = clock_shifts(trace_dir, ranks)
     else:
         aligned, shift, offsets = False, {}, {}
-    from .profiler import COMPUTE_PID_BASE
-
     events: List[dict] = []
     for rank, path in ranks.items():
         events.append({"name": "process_name", "ph": "M", "pid": rank,
@@ -180,26 +159,6 @@ def merge_traces(trace_dir: str, align_clocks: bool = True) -> dict:
             if aligned and "ts" in ev:
                 ev["ts"] = float(ev["ts"]) + shift[rank]
             events.append(ev)
-        # compute-anatomy segments (compute.json): own row group per
-        # rank, shifted onto the shared clock exactly like comm events.
-        # A 'local'-clock artifact (profiler ran without the timeline)
-        # shares no origin with comm.json — merging it would place the
-        # rows at nonsense offsets, so it is skipped.
-        artifact = load_profile_artifact(trace_dir, rank)
-        prof = artifact.get("events", []) \
-            if artifact.get("clock") != "local" else []
-        if prof:
-            cpid = COMPUTE_PID_BASE + rank
-            events.append({"name": "process_name", "ph": "M", "pid": cpid,
-                           "args": {"name": f"rank {rank} compute"}})
-            events.append({"name": "process_sort_index", "ph": "M",
-                           "pid": cpid, "args": {"sort_index": rank}})
-            for ev in prof:
-                ev = dict(ev)
-                ev["pid"] = cpid
-                if aligned and "ts" in ev:
-                    ev["ts"] = float(ev["ts"]) + shift[rank]
-                events.append(ev)
     # Control-plane flight-recorder events (events.json): ONE row of
     # Chrome instant events above the rank rows, so "epoch.commit" or
     # "abort.publish" lines up against what the device timelines were
@@ -318,13 +277,6 @@ def straggler_report(trace_dir: str, top: Optional[int] = None) -> dict:
     stragglered, its total negotiation wait (a chronically low
     total = chronically late rank), and ``unmatched_spans`` — B/E pairs
     that never closed, the signature of a truncated live trace.
-
-    When any rank carries a ``compute.json`` (the compute-anatomy
-    profiler, timeline/profiler.py), ``segments`` extends the straggler
-    story to the compute side: per profiled step block, each rank's
-    device time, the SLOWEST rank, and the max−min spread — so "rank 3
-    is late" localizes to "rank 3's backward is 10% slower", not just a
-    negotiation wait.
     """
     per_rank: Dict[int, Dict[str, dict]] = {}
     unmatched: Dict[int, int] = {}
@@ -370,83 +322,30 @@ def straggler_report(trace_dir: str, top: Optional[int] = None) -> dict:
             for r in per_rank
         },
     }
-    segments = segment_straggler_report(trace_dir, per_rank.keys())
-    if segments:
-        report["segments"] = segments
     report["verdicts"] = straggler_verdicts(report)
     return report
 
 
-def straggler_verdicts(report: dict, *,
-                       skew_threshold: float = 1.3) -> dict:
+def straggler_verdicts(report: dict) -> dict:
     """Machine-readable per-rank verdict block from a straggler report —
     the shape the watchdog's drift detector consumes
     (``observe.detectors.straggler_from_verdicts``), so offline trace
     analysis and the live watchdog agree on who is late.
 
     Each rank gets ``{"verdict": "straggler" | "ok", "skew", "basis"}``:
-
-    * with profiled compute (``segments``), ``skew`` is the rank's
-      total segment device time over the cross-rank median
-      (basis ``segment_device_us``) — late because *slow*;
-    * otherwise ``skew`` is ``1 + times_straggler / contested_tensors``
-      (basis ``negotiate_wait``) — a rank that arrived last for every
-      contested tensor scores 2.0, one never late scores 1.0.
+    ``skew`` is ``1 + times_straggler / contested_tensors`` (basis
+    ``negotiate_wait``) — a rank that arrived last for every contested
+    tensor scores 2.0, one never late scores 1.0; a rank last for half
+    of them or more is the straggler.
     """
     verdicts: Dict[str, dict] = {}
-    segments = report.get("segments") or {}
-    totals: Dict[str, float] = {}
-    for seg in segments.values():
-        for rank, us in (seg.get("per_rank_device_us") or {}).items():
-            totals[str(rank)] = totals.get(str(rank), 0.0) + float(us)
-    if len(totals) >= 2:
-        ordered = sorted(totals.values())
-        mid = len(ordered) // 2
-        median = ordered[mid] if len(ordered) % 2 \
-            else (ordered[mid - 1] + ordered[mid]) / 2.0
-        for rank, total in totals.items():
-            ratio = total / median if median > 0 else 1.0
-            verdicts[rank] = {
-                "verdict": "straggler" if ratio >= skew_threshold else "ok",
-                "skew": round(ratio, 4),
-                "basis": "segment_device_us",
-            }
     contested = len(report.get("tensors") or [])
     for rank, d in (report.get("ranks") or {}).items():
-        if rank in verdicts:
-            continue
         frac = (d.get("times_straggler", 0) / contested) if contested else 0.0
         verdicts[rank] = {
             "verdict": "straggler" if contested and frac >= 0.5 else "ok",
             "skew": round(1.0 + frac, 4),
             "basis": "negotiate_wait",
         }
-    return {"ranks": verdicts, "skew_threshold": skew_threshold}
+    return {"ranks": verdicts}
 
-
-def segment_straggler_report(trace_dir: str, ranks) -> Dict[str, dict]:
-    """Per-compute-segment slowest-rank table from the ranks'
-    ``compute.json`` anatomies: ``{segment: {per_rank_device_us,
-    slowest_rank, spread_us}}`` (empty when nobody profiled).  The
-    reduction is :func:`~horovod_tpu.timeline.profiler
-    .aggregate_anatomies` — the same one behind ``GET /profile`` and
-    ``hvd_profile`` — so this table can never disagree with them on
-    who the slowest rank is."""
-    from .profiler import aggregate_anatomies
-
-    anatomies = {}
-    for rank in ranks:
-        anatomy = load_profile_artifact(trace_dir, rank).get("anatomy")
-        if isinstance(anatomy, dict):
-            anatomies[str(rank)] = anatomy
-    if not anatomies:
-        return {}
-    agg = aggregate_anatomies(anatomies)
-    return {
-        name: {
-            "per_rank_device_us": s["per_rank_device_us"],
-            "slowest_rank": int(s["slowest_rank"]),
-            "spread_us": s["spread_us"],
-        }
-        for name, s in agg["segments"].items()
-    }

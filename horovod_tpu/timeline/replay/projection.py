@@ -455,27 +455,12 @@ def source_world_of(result) -> int:
     return world
 
 
-def _source_mfu(trace_dir: str) -> Optional[float]:
-    """Mean profiled MFU across ranks (compute.json anatomies), or None
-    when the trace was captured without the compute-anatomy profiler."""
-    try:
-        from ..profiler import load_compute_json
-
-        mfus = [a["mfu"] for a in load_compute_json(trace_dir).values()
-                if isinstance(a, dict) and a.get("mfu") is not None]
-    except Exception:  # noqa: BLE001 — anatomy is optional garnish
-        return None
-    return round(sum(mfus) / len(mfus), 4) if mfus else None
-
-
 def project_analysis(result, specs: List[Tuple[str, TopologySpec]],
                      mode: Optional[str] = None,
                      cost_model: Optional[CostModel] = None) -> dict:
     """The projection summary for a ``ReplayResult``: the newest stitched
     step projected onto every spec, plus the source anchor (baseline
-    replay, measured step, profiled MFU).  ``projected_mfu`` scales the
-    source MFU by the step-time ratio — per-rank work is held fixed, so
-    utilization moves inversely with the projected step."""
+    replay, measured step)."""
     mode = mode or project_mode_from_env()
     art = result.artifacts
     dag = result.dags[-1]
@@ -484,17 +469,11 @@ def project_analysis(result, specs: List[Tuple[str, TopologySpec]],
         base_spec_from_env(dag.world).with_world(dag.world))
     synth = synthesized_comm_bytes(art)
     baseline = schedule(dag).makespan
-    mfu = _source_mfu(art.trace_dir)
     rows = []
     for name, spec in specs:
         row = project_step(dag, cm, spec, mode=mode, synth_bytes=synth,
                            source_world=sw, baseline_us=baseline)
         row["name"] = name
-        if mfu is not None and row["projected_step_us"] > 0:
-            row["projected_mfu"] = round(
-                mfu * baseline / row["projected_step_us"], 4)
-        else:
-            row["projected_mfu"] = None
         rows.append(row)
     return {
         "trace_dir": art.trace_dir,
@@ -506,7 +485,6 @@ def project_analysis(result, specs: List[Tuple[str, TopologySpec]],
             "step": dag.step,
             "baseline_replay_us": round(baseline, 3),
             "measured_step_us": round(dag.measured_step_us, 3),
-            "mfu": mfu,
         },
         "projections": rows,
     }

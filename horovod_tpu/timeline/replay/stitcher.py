@@ -119,11 +119,6 @@ class Artifacts:
     dag_nodes: List[dict]                   # parsed dag.gml nodes
     dag_edges: List[Tuple[int, int]]
     metadata: dict
-    #: per-rank compute-anatomy profiler events (compute.json,
-    #: timeline/profiler.py), clock-aligned like the comm events; empty
-    #: for ranks that never profiled
-    profile_events: Dict[int, List[dict]] = dataclasses.field(
-        default_factory=dict)
 
 
 # ---------------------------------------------------------------------------
@@ -191,27 +186,6 @@ def load_artifacts(trace_dir: str) -> Artifacts:
             evs.append(ev)
         events[rank] = evs
 
-    # compute-anatomy segments (compute.json, timeline/profiler.py):
-    # per rank, shifted onto the same clock as its comm events so the
-    # stitcher can split compute chains at segment boundaries.  An
-    # artifact recorded on the profiler's own 'local' clock shares no
-    # origin with comm.json — splitting at its (meaningless here)
-    # boundaries would misattribute blocks, so the chain stays opaque.
-    profile_events: Dict[int, List[dict]] = {}
-    for rank in ranks:
-        cj = _load_json(os.path.join(trace_dir, str(rank),
-                                     "compute.json"), {})
-        if not isinstance(cj, dict) or cj.get("clock") == "local":
-            cj = {}
-        evs = []
-        for ev in cj.get("events", []):
-            ev = dict(ev)
-            if "ts" in ev:
-                ev["ts"] = float(ev["ts"]) + shift[rank]
-            evs.append(ev)
-        if evs:
-            profile_events[rank] = evs
-
     shapes: Dict[str, list] = {}
     dtypes: Dict[str, str] = {}
     grad_names: List[str] = []
@@ -244,7 +218,6 @@ def load_artifacts(trace_dir: str) -> Artifacts:
         dag_nodes=dag_nodes,
         dag_edges=dag_edges,
         metadata=metadata,
-        profile_events=profile_events,
     )
 
 
@@ -370,51 +343,6 @@ def _extract_comm_spans(events: List[dict], t0: float,
 # ---------------------------------------------------------------------------
 # DAG construction
 # ---------------------------------------------------------------------------
-def _profile_segments(events: List[dict]) -> List[Tuple[str, float, float]]:
-    """``(name, start, end)`` of one rank's profiler segment spans
-    (compute.json events minus the STEP envelopes), start-ordered."""
-    segs = []
-    for ev in events:
-        name = str(ev.get("name", ""))
-        if ev.get("ph") != "X" or not name or name == "STEP":
-            continue
-        ts = float(ev.get("ts", 0.0))
-        segs.append((name, ts, ts + float(ev.get("dur", 0.0))))
-    segs.sort(key=lambda s: s[1])
-    return segs
-
-
-def _split_compute(segs: List[Tuple[str, float, float]], lo: float,
-                   hi: float, base_label: str) -> List[Tuple[str, float]]:
-    """Split one compute range ``[lo, hi)`` at the profiler-segment
-    boundaries inside it: each overlapping segment becomes its own
-    ``<base>|<name>`` piece (clipped to the range) and uncovered time
-    becomes ``<base>|host<j>`` — so the replay DAG, the critical path,
-    and what-ifs like remove_straggler attribute to *blocks*, not
-    opaque per-rank chains.  Piece durations always sum to ``hi − lo``
-    (the measured totals the calibrated replay depends on); with no
-    overlapping segments the range stays ONE node under its original
-    label, so unprofiled traces stitch exactly as before."""
-    pieces: List[Tuple[str, float]] = []
-    cursor, host_i = lo, 0
-    for name, s, e in segs:
-        if e <= lo + 1e-9 or s >= hi - 1e-9:
-            continue
-        s2, e2 = max(s, cursor), min(e, hi)
-        if e2 <= s2 + 1e-9:
-            continue
-        if s2 > cursor + 1e-9:
-            pieces.append((f"{base_label}|host{host_i}", s2 - cursor))
-            host_i += 1
-        pieces.append((f"{base_label}|{name}", e2 - s2))
-        cursor = e2
-    if not pieces:
-        return [(base_label, hi - lo)]
-    if hi > cursor + 1e-9:
-        pieces.append((f"{base_label}|host{host_i}", hi - cursor))
-    return pieces
-
-
 def build_step_dag(art: Artifacts, step_no: int,
                    windows: Dict[int, Tuple[float, float]]) -> StepDAG:
     """One global DAG for ``step_no`` given each rank's step window."""
@@ -437,7 +365,6 @@ def build_step_dag(art: Artifacts, step_no: int,
         rank_base[rank] = r_t0 - t0
         span_us[rank] = r_t1 - r_t0
         spans = _extract_comm_spans(art.events[rank], r_t0, r_t1)
-        prof_segs = _profile_segments(art.profile_events.get(rank, []))
         chain: List[int] = []
         occ: Dict[str, int] = {}
         cursor = r_t0
@@ -446,11 +373,8 @@ def build_step_dag(art: Artifacts, step_no: int,
             occ[s.tensor] = k + 1
             seg = s.ready_us - cursor
             if seg > 1e-9:
-                for lbl, dur in _split_compute(prof_segs, cursor,
-                                               s.ready_us,
-                                               f"pre:{s.tensor}:{k}"):
-                    chain.append(add(Node(0, "compute", dur, rank=rank,
-                                          label=lbl)))
+                chain.append(add(Node(0, "compute", seg, rank=rank,
+                                      label=f"pre:{s.tensor}:{k}")))
             key = (s.tensor, k)
             if key not in comm_ids:
                 nbytes, dag_label, dtype = join_tensor(s.tensor, art)
@@ -468,10 +392,8 @@ def build_step_dag(art: Artifacts, step_no: int,
             cursor = s.start_us + s.dur_us
         tail = r_t1 - cursor
         if tail > 1e-9:
-            for lbl, dur in _split_compute(prof_segs, cursor, r_t1,
-                                           "tail"):
-                chain.append(add(Node(0, "compute", dur, rank=rank,
-                                      label=lbl)))
+            chain.append(add(Node(0, "compute", tail, rank=rank,
+                                  label="tail")))
         chains[rank] = chain
 
     return StepDAG(

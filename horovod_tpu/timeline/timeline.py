@@ -218,16 +218,6 @@ class Timeline:
             log.debug("clock sync skipped: %s", e)
 
     def shutdown(self) -> None:
-        # flush any open compute-anatomy profiler BEFORE the writer
-        # closes: compute.json events share this timeline's clock, and
-        # a job torn down mid-window must still land its artifact next
-        # to comm.json (timeline/profiler.py)
-        try:
-            from .profiler import finalize_active
-
-            finalize_active()
-        except Exception as e:  # noqa: BLE001
-            log.debug("profiler finalize on shutdown failed: %s", e)
         with self._lock:
             if self._writer is not None:
                 self._writer.close()
@@ -388,8 +378,8 @@ def host_span(name: str, *, cat: str = "train_step",
 
     Always a ``jax.profiler.TraceAnnotation("hvd_" + name, **args)``:
     written into the profiler's own trace, on the clock the device planes
-    share, whenever a profiler session is on (``HVD_PROFILE_XLA=1``,
-    ``TimelineHook(xla_profile=True)``, a benchmark's traced run); with no
+    share, whenever a profiler session is on
+    (``TimelineHook(xla_profile=True)``, a benchmark's traced run); with no
     session and no timeline the whole helper costs a few microseconds.  ``args`` (the step's number,
     the batch's index) become the event's arguments, so the spans of one
     step share an identifier.  ``annotation`` swaps in

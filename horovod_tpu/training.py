@@ -40,16 +40,14 @@ class TrainState(NamedTuple):
 
 
 class TrailingLossFetcher:
-    """The async-host-pipeline loss fetch (docs/profiling.md host-gap
-    section).
+    """The async-host-pipeline loss fetch.
 
     ``push(loss)`` is called with every dispatched step's loss handle;
     every ``every`` steps ONE handle is retained, and the retained
     handle from the PREVIOUS cadence — by then ``every`` dispatches
     old, long since complete — is fetched.  The fetch therefore never
     drains the dispatch pipeline the way a per-step ``device_get``
-    does (the serialization the compute-anatomy profiler's host-gap
-    metric flags); the freshest fetched value is ``.value`` (a float,
+    does; the freshest fetched value is ``.value`` (a float,
     ``every``..2×``every`` steps behind) and is exported as the
     ``hvd_train_loss`` gauge.  ``every <= 0`` disables entirely."""
 
@@ -125,7 +123,6 @@ def make_train_step(
     autotune: Optional[bool] = None,
     autotune_log_file: Optional[str] = None,
     profile_guided: Optional[bool] = None,
-    profile: Optional[bool] = None,
     in_graph_steps: int = 1,
     loss_fetch_steps: Optional[int] = None,
 ):
@@ -171,16 +168,6 @@ def make_train_step(
       past the guard band).  Exposed as ``step.profile_guided_tuner``.
       The GP prior is warm-started from the α–β cost model
       (HVD_AUTOTUNE_WARM_START=0 disables).
-    * ``profile`` (default: the ``HVD_PROFILE`` env, docs/profiling.md)
-      arms the compute-anatomy profiler: inside its step window
-      (``HVD_PROFILE_START_STEP``/``END_STEP``) the step runs DECOMPOSED
-      — forward / backward / grad_allreduce / optimizer_update as
-      separately-jitted programs with a device sync at each boundary —
-      so each block's device time, ``cost_analysis`` flops/bytes, and
-      the inter-dispatch host gaps are measured and reduced into a
-      per-rank ``compute.json`` next to ``comm.json``.  Window steps pay
-      the decomposition (no cross-block fusion, one sync per block);
-      steps outside it run the normal fused program untouched.
     * ``in_graph_steps > 1`` compiles a ``lax.scan`` of that many
       optimizer steps over the SAME batch into one program, so host
       dispatch is amortized away (the synthetic-benchmark mode: the
@@ -191,9 +178,8 @@ def make_train_step(
       fetches loss/metrics through a TRAILING async handle every N
       steps (``step.loss_fetcher.value``) instead of a per-step
       ``device_get`` — the dispatch pipeline stays deep; the forced
-      per-step sync survives only inside profiler/tuner measuring
-      windows, which need it for honest timing (docs/profiling.md
-      host-gap section is the before/after proof).  0 disables.
+      per-step sync survives only inside the tuners' measuring
+      windows, which need it for honest timing.  0 disables.
     """
     from .ops import collectives
     from .parallel.hierarchical import (
@@ -233,12 +219,10 @@ def make_train_step(
         ef = (isinstance(comp, ErrorFeedback) or plan_comp) \
             and not hier and not tlvl
 
-        # The step's four blocks as shared helpers: per_rank_step (the
-        # fused program) and the compute-anatomy profiler's decomposed
-        # segments (make_profile_fns) both call THESE, so the profiled
-        # window runs the same math it attributes.  jax.named_scope
-        # threads the block names into HLO op metadata, so a real
-        # jax.profiler capture (HVD_PROFILE_XLA=1) carries them too.
+        # The step's blocks as helpers for their named scopes:
+        # jax.named_scope threads hvd_forward / hvd_loss /
+        # hvd_grad_allreduce / hvd_optimizer_update into HLO op metadata,
+        # so any jax.profiler capture attributes device time to them.
         def _compute_loss(params, model_state, x, y):
             with jax.named_scope("hvd_forward"):
                 variables = {"params": params, **model_state}
@@ -331,69 +315,7 @@ def make_train_step(
             donate_argnums=(0,) if donate else (),
         )
 
-        def make_profile_fns():
-            """Separately-jitted step segments for the compute-anatomy
-            profiler (timeline/profiler.py): the SAME block helpers as
-            per_rank_step, split at block boundaries so each block's
-            device time is host-visible.  Per-rank intermediates (loss,
-            gradients, batch-stat updates) cross segment boundaries as
-            stacked arrays — leading axis = rank, sharded P(AXIS) — so
-            every rank round-trips its OWN values and no collective is
-            smuggled into the wrong segment."""
-
-            def _stack(t):
-                return jax.tree_util.tree_map(lambda l: l[None], t)
-
-            def _unstack(t):
-                return jax.tree_util.tree_map(lambda l: l[0], t)
-
-            def forward_seg(state, x, y):
-                loss, _ = _compute_loss(state.params, state.model_state,
-                                        x, y)
-                return loss[None]
-
-            def backward_seg(state, x, y):
-                (loss, new_ms), grads = jax.value_and_grad(
-                    lambda p: _compute_loss(p, state.model_state, x, y),
-                    has_aux=True,
-                )(state.params)
-                return loss[None], _stack(new_ms), _stack(grads)
-
-            def reduce_seg(state, loss_st, grads_st):
-                grads, residual = _reduce_grads(_unstack(grads_st),
-                                                state.residual)
-                loss = collectives.allreduce(loss_st[0], op=Average)
-                return grads, residual, loss
-
-            def opt_seg(state, new_ms_st, grads, residual, loss):
-                return (_apply_update(state, grads, _unstack(new_ms_st),
-                                      residual), loss)
-
-            data = (P(core.AXIS), P(core.AXIS))
-            return {
-                "forward": spmd(forward_seg,
-                                in_specs=(state_spec,) + data,
-                                out_specs=P(core.AXIS)),
-                "backward": spmd(backward_seg,
-                                 in_specs=(state_spec,) + data,
-                                 out_specs=(P(core.AXIS), P(core.AXIS),
-                                            P(core.AXIS))),
-                "grad_allreduce": spmd(
-                    reduce_seg,
-                    in_specs=(state_spec, P(core.AXIS), P(core.AXIS)),
-                    out_specs=(P(), P(), P())),
-                # no donation on the decomposed path: the window-entry
-                # warm-up executes the chain once with results discarded
-                # (so compile time never reads as host gap), which a
-                # donated state buffer would not survive.  Cost: one
-                # extra live params copy during the profile window only.
-                "optimizer_update": spmd(
-                    opt_seg,
-                    in_specs=(state_spec, P(core.AXIS), P(), P(), P()),
-                    out_specs=(state_spec, P())),
-            }
-
-        return fn, ef, make_profile_fns
+        return fn, ef
 
     if autotune is None:
         autotune = env_util.get_bool(env_util.HVD_AUTOTUNE)
@@ -443,17 +365,12 @@ def make_train_step(
         # while the tuner reports the plan applied.  box keeps the
         # original hier so rollback (plan=None) restores it.
         with host_span("rebuild", reason=reason, step_num=step_count[0]):
-            fn, ef, profile_factory = _build(
+            fn, ef = _build(
                 threshold_b, hier and named is None, named,
                 comp, bucket_comp, two_level and named is None)
-        # any rebuild (new plan, elastic epoch, guard trip) invalidates
-        # the profiler's cached decomposed segments — they must re-jit
-        # against the same knobs as the fused program
-        box.pop("profile_fns", None)
         box.update(
             fn=fn, threshold=threshold_b, hier=hier, plan=plan,
             ef_active=ef, compression=comp,
-            profile_factory=profile_factory,
             core_epoch=core._require_init().epoch,
             # a new jitted function: its first call compiles, which
             # _count_compiles sees as the cache growing from nothing
@@ -481,114 +398,6 @@ def make_train_step(
         _rebuild(threshold_bytes, hierarchical)
 
     import time as _time
-
-    # Compute-anatomy profiler (timeline/profiler.py, docs/profiling.md):
-    # None when off, so steps outside a window pay a single None check.
-    if profile is None:
-        from .timeline import profiler as _profiler_mod
-
-        profiler = _profiler_mod.from_env()
-        if profiler is None and env_util.get_bool(env_util.HVD_WATCH_ARM,
-                                                  True):
-            # dormant profiler: disabled (on_step = one bool check per
-            # step) until the watchdog broadcasts an arm record, which
-            # re-enables it with a concrete window (observe/autoarm.py)
-            profiler = _profiler_mod.ComputeProfiler(enabled=False)
-    elif profile:
-        from .timeline.profiler import ComputeProfiler
-
-        profiler = ComputeProfiler(enabled=True)
-        profiler = profiler if profiler.enabled else None
-    else:
-        profiler = None
-
-    if profiler is not None:
-        from .observe import autoarm as _autoarm
-
-        _autoarm.register_profiler(profiler)
-
-    def _segment_cost(fn, args):
-        """cost_analysis flops/bytes for one decomposed segment, plus
-        the AOT-compiled executable (used for the window's calls so the
-        lowering isn't compiled twice)."""
-        try:
-            compiled = fn.lower(*args).compile()
-            ca = compiled.cost_analysis()
-            if isinstance(ca, list):
-                ca = ca[0] if ca else {}
-            ca = ca or {}
-            return {
-                "compiled": compiled,
-                "flops": float(ca["flops"]) if "flops" in ca else None,
-                "bytes": float(ca["bytes accessed"])
-                if "bytes accessed" in ca else None,
-            }
-        except Exception as e:  # noqa: BLE001 — profiling must not kill the step
-            log.debug("segment cost analysis failed: %s", e)
-            return {"compiled": None, "flops": None, "bytes": None}
-
-    def _profiled_step(state, x, y):
-        """One train step on the decomposed path: each block dispatched
-        and synced under a profiler segment span.  Identical math to
-        box['fn'] (same block helpers, same knobs); in_graph_steps > 1
-        python-loops the chain — lax.scan re-feeds the same batch, so k
-        sequential chains are exactly the scanned program's semantics."""
-        if box.get("ef_active") and not jax.tree_util.tree_leaves(
-                state.residual):
-            # materialize the lazy error-feedback residual BEFORE the
-            # segments compile: the AOT executables are pinned to the
-            # state's pytree structure, and reduce_seg's trace-time
-            # lazy init would grow it on the NEXT step's state —
-            # crashing the cached call (the fused jit path re-traces,
-            # AOT doesn't)
-            state = state._replace(residual=jax.tree_util.tree_map(
-                jnp.zeros_like, state.params))
-        first = "profile_fns" not in box
-        if first:
-            box["profile_fns"] = {"fns": box["profile_factory"](),
-                                  "costs": {}}
-        fns, costs = box["profile_fns"]["fns"], box["profile_fns"]["costs"]
-
-        def _prep(name, *args):
-            """Compile (AOT, so cost_analysis and the executable come
-            from ONE compile) and run a segment once with the result
-            discarded — the window-entry warm-up that keeps compile
-            time out of the recorded spans (it would otherwise read as
-            a giant host gap on step 1)."""
-            c = _segment_cost(fns[name], args)
-            costs[name] = c
-            out = (c["compiled"] or fns[name])(*args)
-            jax.block_until_ready(out)
-            return out
-
-        if first:
-            _prep("forward", state, x, y)
-            loss_st, new_ms_st, grads_st = _prep("backward", state, x, y)
-            grads, residual, loss = _prep("grad_allreduce",
-                                          state, loss_st, grads_st)
-            _prep("optimizer_update",
-                  state, new_ms_st, grads, residual, loss)
-
-        def run(name, *args):
-            c = costs[name]
-            return profiler.run_segment(name, c["compiled"] or fns[name],
-                                        *args, flops=c["flops"],
-                                        nbytes=c["bytes"])
-
-        with profiler.step_span():
-            for _ in range(max(in_graph_steps, 1)):
-                # timing-only extra pass: XLA fuses fwd+bwd inside
-                # value_and_grad, so a standalone forward is the only
-                # host-visible way to split them — "backward" below
-                # therefore includes a forward recompute (backward-only
-                # ≈ backward − forward; docs/profiling.md)
-                run("forward", state, x, y)
-                loss_st, new_ms_st, grads_st = run("backward", state, x, y)
-                grads, residual, loss = run("grad_allreduce",
-                                            state, loss_st, grads_st)
-                state, loss = run("optimizer_update",
-                                  state, new_ms_st, grads, residual, loss)
-        return state, loss
 
     # Step-cadence metrics: blocking on the result every step would
     # serialize the async dispatch pipeline (the very thing the compiled
@@ -746,17 +555,9 @@ def make_train_step(
                              reason="epoch")
             if metrics.on():
                 _record_step_metrics(x)
-            box["profiled_last"] = profiler is not None \
-                and profiler.on_step()
-            if box["profiled_last"]:
-                # capture window: the decomposed per-segment path, inside
-                # the same STEP span as a normal step so the comm.json
-                # window and compute.json envelopes stay aligned
-                result = _profiled_step(state, x, y)
-            else:
-                with host_span("call", step_num=n):
-                    result = box["fn"](state, x, y)
-                _count_compiles(n, x, y)
+            with host_span("call", step_num=n):
+                result = box["fn"](state, x, y)
+            _count_compiles(n, x, y)
             with host_span("guard", step_num=n):
                 _maybe_guard(result[0])
             fetcher.push(result[1])
@@ -804,7 +605,6 @@ def make_train_step(
                 "will idle in its baseline phase")
 
     if pm is None and tuner is None:
-        _invoke.compute_profiler = profiler
         _invoke.loss_fetcher = fetcher
         return _invoke
 
@@ -819,13 +619,9 @@ def make_train_step(
         if tuner is not None and tuner.active and not under_trace:
             # dispatch-to-dispatch interval: real step time in steady
             # state with zero added synchronization (same honesty
-            # argument as hvd_step_seconds).  An interval spanning a
-            # compute-profiler window step measures the decomposed
-            # path (~2x, plus the one-time segment compile) — feeding
-            # it to the loop would mis-score knobs or read as a false
-            # plan regression, so those steps don't count.
+            # argument as hvd_step_seconds)
             now = _time.perf_counter()
-            if pg_last[0] and not box.get("profiled_last"):
+            if pg_last[0]:
                 tuner.on_step(now - pg_last[0])
             pg_last[0] = now
         if pm is None or pm.frozen:
@@ -867,12 +663,6 @@ def make_train_step(
         # step chain to complete
         jax.device_get(loss)
         dt = _time.perf_counter() - t0
-        if box.get("profiled_last"):
-            # a profiler-window step ran the decomposed path: its dt is
-            # not the knob vector's step time, keep it out of the GP
-            # (the window flag is env/step-counter driven, so every
-            # process skips — and skips the sync below — in lockstep)
-            return state, loss
         if core.process_size() > 1:
             # Synchronize the measurement instead of the decision: every
             # process scores the same averaged step time, and the
@@ -892,7 +682,6 @@ def make_train_step(
 
     step_autotuned.parameter_manager = pm
     step_autotuned.profile_guided_tuner = tuner
-    step_autotuned.compute_profiler = profiler
     step_autotuned.loss_fetcher = fetcher
     return step_autotuned
 

@@ -109,15 +109,6 @@ HVD_LINT_DISABLE = "HVD_LINT_DISABLE"                  # comma list of rule IDs 
 # schedule model checker (analysis/schedule/, scripts/hvd_verify.py)
 HVD_VERIFY_MAX_PATHS = "HVD_VERIFY_MAX_PATHS"          # per-entry path budget (default 64)
 HVD_VERIFY_LOOP_BOUND = "HVD_VERIFY_LOOP_BOUND"        # loop unroll bound (default 2)
-# compute-anatomy profiler (timeline/profiler.py, docs/profiling.md):
-# per-block device-time attribution + roofline/MFU accounting + host-gap
-# detection over a BYTEPS_TRACE-style step window
-HVD_PROFILE = "HVD_PROFILE"                            # 1 enables the profiled step window
-HVD_PROFILE_START_STEP = "HVD_PROFILE_START_STEP"      # window start (default HVD_TRACE_START_STEP or 1)
-HVD_PROFILE_END_STEP = "HVD_PROFILE_END_STEP"          # window end (default start + 2: a 3-step window)
-HVD_PROFILE_XLA = "HVD_PROFILE_XLA"                    # 1 also runs jax.profiler trace capture into <rank>/xla_trace
-HVD_PROFILE_GAP_THRESHOLD_US = "HVD_PROFILE_GAP_THRESHOLD_US"  # inter-dispatch gap flagged as a host-gap span past this (default 25)
-HVD_PROFILE_HBM_GBPS = "HVD_PROFILE_HBM_GBPS"          # roofline HBM bandwidth, GB/s (default: utils/flops.DEVICE_PEAKS by device kind)
 HVD_PEAK_FLOPS = "HVD_PEAK_FLOPS"                      # per-chip peak FLOP/s for every MFU number (default: utils/flops.DEVICE_PEAKS by device kind; none for an unknown device)
 # dPRO-style replay engine (horovod_tpu/timeline/replay/)
 HVD_REPLAY_CLOCK_SYNC = "HVD_REPLAY_CLOCK_SYNC"        # 0 skips the init-time clock handshake
@@ -177,8 +168,7 @@ HVD_SERVE_MAX_REPLICAS = "HVD_SERVE_MAX_REPLICAS"      # grow ceiling (default 0
 HVD_SERVE_DRAIN_TIMEOUT_SECONDS = "HVD_SERVE_DRAIN_TIMEOUT_SECONDS"  # drain handshake budget (default elastic timeout)
 HVD_SERVE_WEIGHT_COMPRESSION = "HVD_SERVE_WEIGHT_COMPRESSION"  # none|bf16|int8|fp8 at-rest weight format
 HVD_BENCH_SERVE = "HVD_BENCH_SERVE"                    # 0 skips bench.py's serving leg
-# async host pipeline (training.py TrailingLossFetcher, data/loader.py;
-# docs/profiling.md host-gap section)
+# async host pipeline (training.py TrailingLossFetcher, data/loader.py)
 HVD_LOSS_FETCH_STEPS = "HVD_LOSS_FETCH_STEPS"          # trailing async loss fetch cadence (default 16; 0 never fetches)
 HVD_PREFETCH_DEPTH = "HVD_PREFETCH_DEPTH"              # device prefetch queue depth in data/loader.py (default 2; 0 disables)
 # hierarchical HA control plane (run/store.py, run/journal.py,
@@ -212,7 +202,6 @@ HVD_WATCH_EWMA_ALPHA = "HVD_WATCH_EWMA_ALPHA"          # step-time EWMA smoothin
 HVD_WATCH_MAD_K = "HVD_WATCH_MAD_K"                    # regression threshold, robust sigmas above baseline (default 5)
 HVD_WATCH_CONFIRM = "HVD_WATCH_CONFIRM"                # consecutive breaches before an alert (default 3)
 HVD_WATCH_STRAGGLER_SKEW = "HVD_WATCH_STRAGGLER_SKEW"  # rank cadence / world median ratio read as straggling (default 1.3)
-HVD_WATCH_MFU_DROP_PCT = "HVD_WATCH_MFU_DROP_PCT"      # relative MFU drop vs baseline read as regression (default 20)
 HVD_WATCH_BETA_DRIFT = "HVD_WATCH_BETA_DRIFT"          # measured/predicted µs-per-MiB ratio read as comm drift (default 2)
 HVD_WATCH_SLO_BUDGET = "HVD_WATCH_SLO_BUDGET"          # tolerated SLO-breach sample fraction (default 0.01)
 HVD_WATCH_BURN_RATE = "HVD_WATCH_BURN_RATE"            # breach-fraction / budget ratio that alerts (default 2)
@@ -270,9 +259,6 @@ DEFAULT_COMPRESSION_GUARD_STEPS = 25               # error-feedback residual-nor
 DEFAULT_COMPRESSION_GUARD_FACTOR = 10.0            # residual divergence threshold (x baseline)
 DEFAULT_DCN_GBPS = 25.0                            # modeled cross-host (DCN) bandwidth per host
 DEFAULT_DCN_HOP_US = 10.0                          # modeled cross-host per-hop latency
-DEFAULT_PROFILE_STEPS = 3                          # profiler window length when no end step is configured
-DEFAULT_PROFILE_GAP_THRESHOLD_US = 25.0            # host-gap span flagging threshold
-DEFAULT_PROFILE_HOST_BOUND_FRACTION = 0.2          # step verdict flips to host-bound past this gap share
 DEFAULT_METRICS_BUCKET_FLOOR = 1e-4                # first latency bucket edge, seconds
 DEFAULT_METRICS_BUCKET_FACTOR = 2.0                # geometric bucket growth
 DEFAULT_METRICS_BUCKET_COUNT = 18                  # finite bucket count
@@ -301,7 +287,6 @@ DEFAULT_WATCH_EWMA_ALPHA = 0.5                     # step-time regression EWMA s
 DEFAULT_WATCH_MAD_K = 5.0                          # regression threshold in robust sigmas
 DEFAULT_WATCH_CONFIRM = 3                          # consecutive breaches before an alert
 DEFAULT_WATCH_STRAGGLER_SKEW = 1.3                 # cadence / world-median straggler ratio
-DEFAULT_WATCH_MFU_DROP_PCT = 20.0                  # relative MFU drop threshold, percent
 DEFAULT_WATCH_BETA_DRIFT = 2.0                     # measured/predicted comm-cost drift ratio
 DEFAULT_WATCH_SLO_BUDGET = 0.01                    # tolerated SLO-breach sample fraction
 DEFAULT_WATCH_BURN_RATE = 2.0                      # breach-fraction / budget alert ratio

@@ -10,13 +10,12 @@ accounting (PaLM appendix B): 6·N FLOPs per token of parameter math
 blocks.
 
 This module is also the single source of the hardware peak numbers
-every MFU/roofline consumer divides by: bench.py, chip_smoke.py, the
-compute-anatomy profiler (timeline/profiler.py), and the comm report's
-flops/peak fallback (timeline/comm_report.py) all route through
-:func:`peak_flops` / :func:`hbm_bytes_per_sec`.  The peaks are keyed by
-the mesh devices' ``device_kind``: a device that is not in
+every MFU/roofline consumer divides by: bench.py, chip_smoke.py and the
+comm report's flops/peak fallback (timeline/comm_report.py) all route
+through :func:`peak_flops` / :func:`hbm_bytes_per_sec`.  The peaks are
+keyed by the mesh devices' ``device_kind``: a device that is not in
 :data:`DEVICE_PEAKS` has no peak (no MFU is reported) unless
-``HVD_PEAK_FLOPS`` / ``HVD_PROFILE_HBM_GBPS`` name one explicitly.
+``HVD_PEAK_FLOPS`` names one explicitly.
 """
 
 from __future__ import annotations
@@ -83,13 +82,9 @@ def peak_flops(kind: Optional[str] = None) -> Optional[float]:
 
 def hbm_bytes_per_sec(kind: Optional[str] = None) -> Optional[float]:
     """Per-chip HBM bandwidth for roofline math (the ridge point is
-    ``peak_flops / hbm_bytes_per_sec`` flops/byte), resolved like
-    :func:`peak_flops`; ``HVD_PROFILE_HBM_GBPS`` overrides, in GB/s."""
-    from .env import HVD_PROFILE_HBM_GBPS, get_float
-
-    override = get_float(HVD_PROFILE_HBM_GBPS, 0.0)
-    if override > 0:
-        return override * 1e9
+    ``peak_flops / hbm_bytes_per_sec`` flops/byte): the
+    :data:`DEVICE_PEAKS` entry for ``kind`` (default: the mesh devices'
+    kind), else None."""
     peak = _table_peak(kind)
     return peak.hbm_bytes_per_sec if peak else None
 

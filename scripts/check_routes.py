@@ -4,7 +4,7 @@ table row must name a ``run.http_client`` accessor that actually
 exists.
 
 The control plane grew one observability surface per PR (metrics,
-health, membership, sanitizer, autotune, profile, replay, projection,
+health, membership, sanitizer, autotune, replay, projection,
 serving, timeseries, alerts, events); the table in
 docs/api.md#the-signed-get-surface is the one place an operator can
 see them all.  This lint (tests/test_route_lint.py, tier-1 — the

@@ -184,17 +184,15 @@ def _print_projection(proj: dict) -> None:
     print(f"\nprojection (mode={proj['mode']}): source world "
           f"{src['world']}, baseline {src['baseline_replay_us']:.1f} us")
     print(f"  {'target':<24} {'world':>6} {'step us':>12} "
-          f"{'eff':>7} {'mfu':>6}  wire")
+          f"{'eff':>7}  wire")
     for row in proj["projections"]:
         eff = row.get("scaling_efficiency")
-        mfu = row.get("projected_mfu")
         wires = sorted(set(row.get("wire_formats", {}).values())) or ["-"]
         tag = row["name"] + (" (synth comm)" if row.get("synthesized_comm")
                              else "")
         print(f"  {tag:<24} {row['world']:>6} "
               f"{row['projected_step_us']:>12.1f} "
-              f"{eff if eff is not None else '-':>7} "
-              f"{mfu if mfu is not None else '-':>6}  "
+              f"{eff if eff is not None else '-':>7}  "
               f"{','.join(wires)}")
     val = proj.get("validation")
     if val:

@@ -74,16 +74,6 @@ def main(argv=None) -> dict:
             print(f"  rank {rank}: straggler for {d['times_straggler']} "
                   f"tensor(s), total negotiate wait "
                   f"{d['total_negotiate_wait_us']:.1f} us")
-    # the compute side of the straggler question rides compute.json and
-    # exists even when negotiation spans don't (the compiled plane)
-    if report.get("segments"):
-        print("compute segments (from compute.json; slowest rank by "
-              "device time):")
-        print(f"  {'segment':<28} {'spread_us':>10}  slowest")
-        for name, s in sorted(report["segments"].items(),
-                              key=lambda kv: -kv[1]["spread_us"]):
-            print(f"  {name:<28} {s['spread_us']:>10.1f}  "
-                  f"rank {s['slowest_rank']}")
     # the machine block the watchdog's drift detector consumes
     # (observe.detectors.straggler_from_verdicts)
     verdicts = (report.get("verdicts") or {}).get("ranks") or {}

@@ -40,7 +40,7 @@ def _approx(a, b, tol=1e-4) -> bool:
 def run_check() -> int:
     """Self-test: every detector must reproduce the fixture's
     hand-computed verdicts exactly — the regression fires at the pinned
-    step with the pinned threshold/EWMA, the straggler/MFU/beta/burn
+    step with the pinned threshold/EWMA, the straggler/beta/burn
     alerts carry the pinned evidence, and the quiet traces fire
     nothing."""
     errors = []
@@ -77,18 +77,6 @@ def run_check() -> int:
         if not _approx(ev["ratio"], e["ratio"], 1e-6) or \
                 not _approx(ev["world_median"], e["world_median"], 1e-9):
             errors.append(f"straggler ratio {ev['ratio']} != {e['ratio']}")
-
-    mf = got["mfu"]
-    if mf is None:
-        errors.append("mfu: no alert fired")
-    else:
-        e = exp["mfu"]
-        ev = mf["evidence"]
-        if mf["severity"] != e["severity"] or \
-                not _approx(ev["drop_pct"], e["drop_pct"], 1e-6) or \
-                not _approx(ev["baseline_mfu"], e["baseline_mfu"]) or \
-                not _approx(ev["recent_mfu"], e["recent_mfu"]):
-            errors.append(f"mfu alert {mf} != {e}")
 
     bt = got["beta"]
     if bt is None:
@@ -129,8 +117,7 @@ def run_check() -> int:
           f"{exp['regression']['threshold']:.7f}, "
           f"{exp['regression']['severity']}), straggler rank "
           f"{exp['straggler']['rank']} at {exp['straggler']['ratio']:.1f}x, "
-          f"mfu drop {exp['mfu']['drop_pct']:.0f}%, beta "
-          f"{exp['beta']['ratio']:.1f}x, burn "
+          f"beta {exp['beta']['ratio']:.1f}x, burn "
           f"{exp['burn']['burn_rate']:.1f}x; quiet traces silent")
     return 0
 

@@ -59,7 +59,7 @@ def test_step_path_fetches_on_cadence_not_per_step(hvd_init, rng,
                                                    monkeypatch):
     """The satellite pin: the hot path must not device_get every step —
     only the trailing cadence fetch (and it is N steps behind, so the
-    dispatch pipeline never drains).  Profiler/tuner measuring windows
+    dispatch pipeline never drains).  The tuners' measuring windows
     keep their own forced syncs (test_profile_guided pins those)."""
     import horovod_tpu.training as training
 

@@ -14,6 +14,7 @@ from horovod_tpu.timeline.comm_report import (
     collective_report, hlo_collectives,
 )
 from horovod_tpu.training import init_train_state, make_train_step, shard_batch
+from test_bench import _load_bench
 
 
 def test_hlo_parser_counts_and_bytes():
@@ -302,3 +303,54 @@ def test_model_scaling_with_compression_improves_efficiency():
     for n in (8, 16, 32, 64):
         assert eff_c[n] > eff_raw[n]
         assert 0.0 < eff_raw[n] < 1.0
+
+
+# ---------------------------------------------------------------------------
+# the one peak table (utils/flops.py) every MFU number divides by
+# ---------------------------------------------------------------------------
+def test_peak_table_keyed_by_device_kind(monkeypatch, hvd_init):
+    """Known kind -> its published figures; unknown kind -> no default
+    (the CPU mesh included); HVD_PEAK_FLOPS names a peak explicitly."""
+    from horovod_tpu.utils import flops
+
+    monkeypatch.delenv("HVD_PEAK_FLOPS", raising=False)
+    assert flops.peak_flops("TPU v5 lite") == pytest.approx(197e12)
+    assert flops.hbm_bytes_per_sec("TPU v5 lite") == pytest.approx(819e9)
+    assert flops.peak_flops("TPU v9 imaginary") is None
+    # the mesh here is 8 CPU devices: no peak, no MFU, and an error for
+    # the callers that publish one
+    assert flops.peak_flops() is None
+    assert flops.hbm_bytes_per_sec() is None
+    assert flops.image_model_mfu(2677.0) is None
+    assert flops.transformer_mfu(10.0, 124_000_000, 12, 768, 1024) is None
+    with pytest.raises(RuntimeError, match="no peak FLOP/s.*'cpu'"):
+        flops.require_peak_flops()
+    monkeypatch.setenv("HVD_PEAK_FLOPS", "123e12")
+    assert flops.peak_flops() == pytest.approx(123e12)
+    assert flops.require_peak_flops() == pytest.approx(123e12)
+
+
+def test_collective_report_peak_single_sourced(monkeypatch):
+    monkeypatch.setenv("HVD_PEAK_FLOPS", "111e12")
+    rep = collective_report(lambda x: x * 2.0, np.ones(4, np.float32))
+    assert rep["assumptions"]["peak_flops"] == pytest.approx(111e12)
+
+
+def test_bench_mfu_through_utils_flops(monkeypatch, hvd_init):
+    from horovod_tpu.utils import flops
+
+    bench = _load_bench()
+    # the comm report and the bench number share one peak: an explicit
+    # peak moves both
+    monkeypatch.setenv("HVD_PEAK_FLOPS", "197e12")
+    want = round(flops.image_model_mfu(2677.0), 4)
+    assert bench._mfu(2677.0) == pytest.approx(want)
+    assert want == pytest.approx(2677.0 * 24.30e9 / 197e12, abs=1e-4)
+    monkeypatch.setenv("HVD_PEAK_FLOPS", "98.5e12")
+    assert bench._mfu(2677.0) == pytest.approx(
+        round(2677.0 * 24.30e9 / 98.5e12, 4))
+    # a device that is not in the table is an error in bench.py, never
+    # a null and never a v5e default (this mesh is CPU)
+    monkeypatch.delenv("HVD_PEAK_FLOPS")
+    with pytest.raises(RuntimeError, match="no peak FLOP/s"):
+        bench._mfu(2677.0)

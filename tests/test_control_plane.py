@@ -713,7 +713,7 @@ def test_alerts_push_survives_relay_death(server, monkeypatch):
     rport = daemon.start()
     relay_mod._endpoint = ("127.0.0.1", rport, True)
     relay_mod.control_put("127.0.0.1", server.port, "alerts", "0",
-                          json.dumps({"id": "0", "signal": "mfu_drop",
+                          json.dumps({"id": "0", "signal": "step_time_regression",
                                       "severity": "warning"}).encode(),
                           secret=SECRET)
     assert _wait_for(lambda: server.get("alerts", "0") is not None)

@@ -321,7 +321,7 @@ def test_follow_consoles_mark_server_restart(tmp_path):
     first = RendezvousServer(secret=SECRET)
     port = first.start()
     first.put("alerts", "0", json.dumps(
-        {"id": "0", "signal": "mfu_drop", "severity": "warning",
+        {"id": "0", "signal": "step_time_regression", "severity": "warning",
          "evidence": {}, "window": {}}).encode())
     first.put(events_mod.EVENTS_SCOPE, "e0", json.dumps(
         {"id": "e0", "ts": 1.0, "kind": "epoch.commit",
@@ -337,7 +337,7 @@ def test_follow_consoles_mark_server_restart(tmp_path):
     try:
         # each console proved it polled incarnation 1 (slow interpreter
         # start must not race the restart)
-        assert _wait_for(lambda: "mfu_drop" in outs["hvd_watch"]
+        assert _wait_for(lambda: "step_time_regression" in outs["hvd_watch"]
                          .read_text(), timeout=60.0), procs
         assert _wait_for(lambda: "epoch.commit" in outs["hvd_events"]
                          .read_text(), timeout=60.0)

@@ -113,6 +113,14 @@ def test_stitch_fixture_joins_all_artifacts(fixture_dir):
     assert c.dag_label == "allreduce/g0"         # joined via dag.gml
 
 
+def test_stitcher_one_compute_node_between_two_collectives(fixture_dir):
+    """A rank's compute between two collectives (and after the last) is
+    one node: the replay fixture's own --check contract."""
+    _art, dags = stitch(fixture_dir)
+    labels = [n.label for n in dags[0].nodes if n.kind == "compute"]
+    assert labels == ["pre:g0:0", "tail", "pre:g0:0", "tail"]
+
+
 def test_read_gml_roundtrip(tmp_path):
     from horovod_tpu.timeline.recorder import structure_dag, write_gml
 

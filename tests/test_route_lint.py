@@ -78,7 +78,7 @@ def test_repo_inventory_includes_every_observability_route():
     mod = _load()
     served = mod.routes_served()
     for route in ("/metrics", "/health", "/membership", "/sanitizer",
-                  "/autotune", "/profile", "/replay", "/projection",
+                  "/autotune", "/replay", "/projection",
                   "/serving", "/timeseries", "/alerts", "/events"):
         assert route in served, f"{route} not parsed from do_GET"
 
