@@ -21,7 +21,11 @@ the kernels agree with a dense-mask float32 softmax at ``[1, 16384, 32,
 gated delta rule
 agrees with its token-by-token recurrence at ``[1, 2048, 32, 128]``, and
 the chunked state-space scan with its own at ``[1, 8192, 64, 64]`` (a state
-of 128 in 8 groups), with the scan's time and share of its roofline; on
+of 128 in 8 groups), with the scan's time and share of its roofline;
+LFM2's toy (a gated short convolution or attention, then a dense SwiGLU or
+routed experts, a tied head) takes three steps through the step builder,
+with the time of the convolution's gates and taps alone at ``[2, 8192,
+2048]``; on
 more than one chip, ring attention's Pallas variant
 agrees with it too (gradients over the whole ring); the GPT step's
 compiled module holds Mosaic custom calls; every loss is finite and the
@@ -629,6 +633,69 @@ def ssd_sweep() -> None:
     ssd_phase(per_step=(2, 8))
 
 
+def lfm2_phase(b: int = 2, s: int = 8192, d: int = 2048,
+               taps: int = 3) -> None:
+    """LFM2's toy (``models/lfm2.lfm2_tiny``: a dense convolution layer, an
+    attention layer, three convolution layers with experts, the tied head)
+    through ``init_train_state`` -> ``make_train_step`` for three steps, and
+    the gated short convolution's gates and taps alone (``B * x``, the
+    causal depthwise taps, ``C * z``: what sits between the operator's two
+    products, XLA's) at the shape ``lfm2-8k-b2`` runs them, ``[2, 8192,
+    2048]`` bf16 a tensor: time forward and forward + backward with the
+    share of the roofline that is (B, C, x in and y out forward; dy, B, x,
+    C in and dB, dC, dx out backward, once each: what
+    ``benchmarks/harness/lfm2_parts.sconv_gate_train_required`` charges a
+    layer), for the ``perf_opt`` that follows."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    import horovod_tpu as hvd
+    from horovod_tpu.models.gpt import next_token_loss
+    from horovod_tpu.models.lfm2 import lfm2_tiny
+    from horovod_tpu.models.qwen3_next import causal_depthwise_conv
+    from horovod_tpu.training import (init_train_state, make_train_step,
+                                      shard_batch)
+    from horovod_tpu.utils import flops
+
+    model, opt = lfm2_tiny(), optax.adam(1e-3)
+    state = init_train_state(model, opt, jnp.zeros((1, 256), jnp.int32))
+    step = make_train_step(
+        apply_fn=lambda v, x, train=True: model.apply(v, x),
+        loss_fn=next_token_loss, optimizer=opt)
+    ids = shard_batch(np.random.default_rng(0).integers(
+        0, model.vocab_size, (hvd.size(), 256)).astype(np.int32))
+    losses = []
+    for _ in range(3):
+        state, loss = step(state, ids, ids)
+        losses.append(float(loss))
+    check(all(np.isfinite(losses)), f"lfm2_tiny loss not finite: {losses}")
+    check(losses[2] < losses[0], f"lfm2_tiny loss did not fall: {losses}")
+
+    keys = jax.random.split(jax.random.PRNGKey(3), 5)
+    gate_in, gate_out, x, w = (
+        jax.random.normal(k, (b, s, d), jnp.float32).astype(jnp.bfloat16)
+        for k in keys[:4])
+    kernel = (0.02 * jax.random.normal(keys[4], (taps, d))).astype(
+        jnp.bfloat16)
+
+    def gates(gate_in, gate_out, x, kernel):
+        return gate_out * causal_depthwise_conv(gate_in * x, kernel)
+
+    def loss(*a):
+        return jnp.sum(gates(*a).astype(jnp.float32)
+                       * w.astype(jnp.float32))
+
+    args = (gate_in, gate_out, x, kernel)
+    fwd_ms = _ms_a_call(jax.jit(gates), *args)
+    both_ms = _ms_a_call(jax.jit(jax.grad(loss, argnums=range(4))), *args)
+    tensor, hbm = b * s * d * 2, flops.hbm_bytes_per_sec()
+    report("lfm2", tiny_losses=losses, gates_shape=[b, s, d], taps=taps,
+           dtype="bfloat16", fwd_ms=fwd_ms, fwd_bwd_ms=both_ms,
+           fwd_roofline_pct=100 * 4 * tensor / hbm * 1e3 / fwd_ms,
+           fwd_bwd_roofline_pct=100 * 11 * tensor / hbm * 1e3 / both_ms)
+
+
 def ring_phase(n: int) -> None:
     """Ring attention's Pallas variant over all ``n`` chips against
     softmax_attention on the whole sequence: the flash kernels with traced
@@ -845,6 +912,8 @@ def main() -> int:
     scan_phase()
     # Nemotron-3-Nano's Mamba-2 blocks: 64 heads of 64 over a state of 128
     ssd_phase()
+    # LFM2's toy through the step builder, and its gates and taps alone
+    lfm2_phase()
     if n > 1:
         ring_phase(n)
     gpt_phase(n)

@@ -254,6 +254,11 @@ SSM_LAYERS = registry.counter(
     "not per step), by the heads, a head's channels, the state's size, the "
     "groups B and C come in and the scan's chunk (ops/ssd.py).",
     ("heads", "head_dim", "state", "groups", "chunk"))
+SCONV_LAYERS = registry.counter(
+    "hvd_sconv_layers_traced_total",
+    "Gated short-convolution operators traced (models/lfm2.py; per compile, "
+    "not per step), by the convolution's taps and the channels it runs "
+    "over.", ("taps", "channels"))
 
 STEP_SECONDS = registry.histogram(
     "hvd_step_seconds",
@@ -685,6 +690,16 @@ def record_ssm_layer(heads: int, head_dim: int, state: int, groups: int,
     try:
         SSM_LAYERS.labels(str(heads), str(head_dim), str(state), str(groups),
                           str(chunk)).inc()
+    except Exception:  # noqa: BLE001 — tracing must never fail on metrics
+        pass
+
+
+def record_sconv_layer(taps: int, channels: int) -> None:
+    """One traced gated short convolution (models/lfm2.py)."""
+    if not registry.enabled:
+        return
+    try:
+        SCONV_LAYERS.labels(str(taps), str(channels)).inc()
     except Exception:  # noqa: BLE001 — tracing must never fail on metrics
         pass
 

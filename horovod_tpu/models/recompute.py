@@ -1,8 +1,8 @@
 """What a recomputed decoder layer keeps: ranked names inside a byte budget.
 
 ``remat`` on the decoders (``qwen3_next``, ``sdar``, ``kanana2``,
-``mellum2``, ``nemotron_h``) recomputes each layer in the backward pass from
-the layer's input.  What a layer always keeps is what the Pallas forward
+``mellum2``, ``nemotron_h``, ``lfm2``) recomputes each layer in the backward
+pass from the layer's input.  What a layer always keeps is what the Pallas forward
 kernels, and the state-space scan, wrote for their backward rules
 (:data:`KERNEL_RESIDUALS`), so a layer calls each of them once.
 Everything else the second run makes again costs time and buys memory, and a
@@ -56,9 +56,14 @@ KERNEL_RESIDUALS = (FLASH_OUT, FLASH_LSE, GDN_OUT, GDN_STATES, GDN_INVERSES,
 #: The state-space mixer's names (``nemotron_h``) stand beside the gated
 #: DeltaNet's, part for part (the same work on the same bytes: its norm, its
 #: ``in_proj``, the scan's operands, its convolution; not measured apart).
+#: So do the gated short convolution's two (``lfm2``): ``out_proj``'s
+#: operand ``C * z`` beside the gated norms, ``in_proj``'s output beside
+#: the other ``in_proj``s (not measured apart either: its taps and gates
+#: between the two are three elementwise passes and have no name).
 RANK = (ROUTING, scopes.KEEP_GDN_NORM, scopes.KEEP_SSM_NORM,
-        scopes.KEEP_OUT_PROJ, scopes.KEEP_Q_PROJ, FLASH_Q, scopes.KEEP_MLP,
-        scopes.KEEP_GDN_IN_PROJ, scopes.KEEP_SSM_IN_PROJ,
+        scopes.KEEP_SCONV_GATE, scopes.KEEP_OUT_PROJ, scopes.KEEP_Q_PROJ,
+        FLASH_Q, scopes.KEEP_MLP, scopes.KEEP_GDN_IN_PROJ,
+        scopes.KEEP_SSM_IN_PROJ, scopes.KEEP_SCONV_IN_PROJ,
         scopes.KEEP_KV_PROJ, GDN_IN, SSD_IN, scopes.KEEP_GDN_CONV,
         scopes.KEEP_SSM_CONV, FLASH_K, FLASH_V)
 

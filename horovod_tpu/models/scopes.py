@@ -3,17 +3,17 @@
 A scope is a ``jax.named_scope``: it changes an op's metadata (``op_name`` in
 the compiled module, ``tf_op`` in a device trace) and nothing the compiler
 lowers, so a program with and without them is one program.  The decoders
-(``qwen3_next``, ``sdar``, ``kanana2``, ``mellum2``, ``nemotron_h``) put
-every matrix product, convolution, scan and kernel call of a layer under
-exactly one *part*;
+(``qwen3_next``, ``sdar``, ``kanana2``, ``mellum2``, ``nemotron_h``,
+``lfm2``) put every matrix product, convolution, scan and kernel call of a
+layer under exactly one *part*;
 ``gpt`` names its head.
 A layer's two norms and its residual adds are elementwise and stay unnamed.
 ``docs/profiling.md`` has the table with each scope's reader, and
 ``tests/test_part_scopes.py`` holds the models to this list.
 
 No name here contains another block's name (readers match substrings:
-``hvd_gdn``, ``hvd_mla``, ``hvd_moe``, ``hvd_ssm``, ``hvd_loss/``) unless it
-is nested in that block.
+``hvd_gdn``, ``hvd_mla``, ``hvd_moe``, ``hvd_ssm``, ``hvd_sconv``,
+``hvd_loss/``) unless it is nested in that block.
 """
 
 from __future__ import annotations
@@ -24,7 +24,7 @@ from ..ops import ssd as _ssd
 from ..parallel import moe as _moe
 
 # softmax attention (qwen3_next.GatedAttention, sdar.BlockDiffusionAttention,
-# mellum2.Attention, nemotron_h.Attention)
+# mellum2.Attention, nemotron_h.Attention, lfm2.Attention)
 ATTN = "hvd_attn"
 ATTN_QKV = "hvd_attn_qkv"    # q / k / v projections, head norms, rotary, repeat
 ATTN_OUT = "hvd_attn_out"    # the output gate where there is one, o_proj
@@ -42,6 +42,11 @@ SSM = "hvd_ssm"
 SSM_IN = "hvd_ssm_in"        # in_proj, the split, softplus(dt + dt_bias)
 SSM_CONV = "hvd_ssm_conv"    # the causal depthwise convolution, bias and SiLU
 SSM_OUT = "hvd_ssm_out"      # the gate with z, the grouped norm, out_proj
+# the gated short convolution (lfm2.ShortConv)
+SCONV = "hvd_sconv"
+SCONV_IN = "hvd_sconv_in"    # in_proj and the split into B, C and x
+SCONV_CONV = "hvd_sconv_conv"  # B * x, the causal depthwise taps, C * z
+SCONV_OUT = "hvd_sconv_out"  # out_proj
 # latent attention (kanana2.LatentAttention)
 MLA = "hvd_mla"
 MLA_Q = "hvd_mla_q"          # q_proj and its rotary part
@@ -76,6 +81,8 @@ KEEP_MLP = "hvd_keep_mlp"              # a SwiGLU's gate and up outputs
 KEEP_SSM_IN_PROJ = "hvd_keep_ssm_in_proj"  # in_proj: z, xBC and dt
 KEEP_SSM_CONV = "hvd_keep_ssm_conv"    # the convolution's output, before SiLU
 KEEP_SSM_NORM = "hvd_keep_ssm_norm"    # the gated norm: out_proj's operand
+KEEP_SCONV_IN_PROJ = "hvd_keep_sconv_in_proj"  # in_proj: B, C and x
+KEEP_SCONV_GATE = "hvd_keep_sconv_gate"  # C * z: out_proj's operand
 
 #: ``ops/flash_attention.flash_attention``: its three kernels, and what it
 #: does round them (the layout swaps, the rows' log-sum-exp, ``delta``)
@@ -89,6 +96,7 @@ PARTS = {
     ATTN: (ATTN_QKV, *FLASH, ATTN_OUT),
     GDN: (GDN_IN, GDN_CONV, _gdn.SCAN_SCOPE, GDN_OUT),
     SSM: (SSM_IN, SSM_CONV, _ssd.SCAN_SCOPE, SSM_OUT),
+    SCONV: (SCONV_IN, SCONV_CONV, SCONV_OUT),
     MLA: (MLA_Q, MLA_LATENT, *FLASH, MLA_OUT),
     DENSE_MLP: (),
     MOE: (_moe.ROUTE_SCOPE, _moe.EXPERTS_SCOPE, MOE_SHARED),

@@ -195,16 +195,17 @@ def route_top_k(x, router_kernel, top_k: int):
 
 
 def route_sigmoid_top_k(x, router_kernel, top_k: int, *, bias,
-                        scale: float = 1.0):
+                        scale: float = 1.0, eps: float = 1e-20):
     """The same pair by the other published rule: each output's score is
     its own sigmoid (float32), the picks are the ``top_k`` largest of
     ``score + bias``, and a pick's weight is its score *without* the bias,
-    divided by the picks' sum (plus 1e-20) and multiplied by ``scale``.
+    divided by the picks' sum plus ``eps`` (1e-20 in ``deepseek_v3`` and
+    ``nemotron_h``, 1e-6 in ``lfm2_moe``) and multiplied by ``scale``.
     ``bias`` (``[E]``) moves which experts a token picks and never what it
     weighs them by: the selection bias an auxiliary-loss-free balancer
     steers, a buffer and not a parameter (no gradient reaches it).  A
-    caller binds ``bias`` and ``scale`` (``functools.partial``) and passes
-    the rule as ``route``."""
+    caller binds ``bias``, ``scale`` and ``eps`` (``functools.partial``)
+    and passes the rule as ``route``."""
     scores = jax.nn.sigmoid(_logits(x, router_kernel))
     # no derivative is taken through this top-k: its values are not used
     experts = checkpoint_name(
@@ -212,7 +213,7 @@ def route_sigmoid_top_k(x, router_kernel, top_k: int, *, bias,
         ROUTING)
     weights = checkpoint_name(
         jnp.take_along_axis(scores, experts, axis=-1), ROUTING)
-    total = jnp.sum(weights, axis=-1, keepdims=True) + 1e-20
+    total = jnp.sum(weights, axis=-1, keepdims=True) + eps
     return weights / total * scale, experts
 
 

@@ -295,3 +295,31 @@ def test_the_form_is_named_and_counted(rng, monkeypatch):
     assert count("route_top_k", 2) == before[0] + 1
     assert moe.FORMS == {"swiglu": ("gate_proj", "up_proj", "down_proj"),
                          "relu2": ("up_proj", "down_proj")}
+
+
+@pytest.mark.parametrize("eps", [None, 1e-20, 1e-6, 0.5])
+def test_the_sigmoid_rules_eps_is_what_is_added_to_the_picks_sum(eps):
+    """Scores 0.9, 0.8, 0.7 and the rest lower, top 3: the weights are
+    ``scale * (0.9, 0.8, 0.7) / (2.4 + eps)``.  Unbound, ``eps`` is 1e-20
+    and the rule's jaxpr is the one it was (the two accepted callers bind
+    ``bias`` and ``scale`` alone); ``lfm2_moe``'s 1e-6 moves a weight by
+    4e-7 of itself, and 0.5 shows that nothing else reads it."""
+    from horovod_tpu.parallel import moe
+
+    scores = np.array([0.2, 0.9, 0.1, 0.7, 0.3, 0.8, 0.6, 0.05])
+    logits = np.log(scores / (1 - scores))
+    x = jnp.asarray([[1.0, 0.0]], jnp.float32)
+    router = jnp.asarray(np.stack([logits, np.zeros(8)]), jnp.float32)
+    kw = {} if eps is None else {"eps": eps}
+    w, e = moe.route_sigmoid_top_k(x, router, 3, bias=np.zeros(8),
+                                   scale=2.5, **kw)
+    assert np.asarray(e).tolist() == [[1, 5, 3]]
+    np.testing.assert_allclose(
+        np.asarray(w)[0], 2.5 * np.array([0.9, 0.8, 0.7])
+        / (2.4 + (1e-20 if eps is None else eps)), rtol=1e-6)
+
+    def text(**kw):
+        return str(jax.make_jaxpr(lambda x, r: moe.route_sigmoid_top_k(
+            x, r, 3, bias=np.zeros(8), scale=2.5, **kw))(x, router))
+
+    assert (text(**kw) == text()) == (eps in (None, 1e-20))

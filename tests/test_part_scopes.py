@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import (gpt, kanana2, mellum2, nemotron_h,
+from horovod_tpu.models import (gpt, kanana2, lfm2, mellum2, nemotron_h,
                                 qwen3_next, scopes, sdar)
 from horovod_tpu.models.gpt import next_token_loss
 from horovod_tpu.ops import flash_attention as flash
@@ -105,6 +105,14 @@ MODELS = {
         {"mixer": {scopes.SSM: scopes.PARTS[scopes.SSM],
                    scopes.ATTN: scopes.PARTS[scopes.ATTN],
                    scopes.MOE: scopes.PARTS[scopes.MOE]},
+         "": {scopes.HEAD: ()}}),
+    # two operator kinds times two feed-forward kinds: a dense convolution
+    # layer, an attention layer and a convolution layer with experts
+    "lfm2_tiny": (
+        lambda: _causal(lfm2.lfm2_tiny(layer_types=lfm2.LAYER_TYPES[1:4])),
+        {"conv": {scopes.SCONV: scopes.PARTS[scopes.SCONV]},
+         "self_attn": {scopes.ATTN: scopes.PARTS[scopes.ATTN]},
+         "feed_forward": {scopes.DENSE_MLP: (), scopes.MOE: _MOE},
          "": {scopes.HEAD: ()}}),
     "gpt_tiny": (lambda: _causal(gpt.gpt_tiny(vocab_size=256)), {}),
 }
