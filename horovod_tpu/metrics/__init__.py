@@ -23,6 +23,11 @@ hvd_collectives_traced_total    counter    collectives emitted at trace time
 hvd_collectives_traced_bytes_total counter traced payload bytes, by ``op``
 hvd_flash_tiles_traced_total    counter    flash score tiles per traced kernel
                                            call, by ``kernel``/``kind``/``mask``
+hvd_flash_grid_steps_traced_total counter  streamed grid steps per traced flash
+                                           kernel call, by ``kernel``/``kind``/
+                                           ``mask``
+hvd_flash_kv_group_traced_total counter    traced flash kernel calls, by
+                                           ``kernel``/``q_heads``/``kv_heads``
 hvd_moe_layers_traced_total     counter    routed expert layers traced, by
                                            ``held``/``top_k``/``rule``/``groups``
 hvd_mla_layers_traced_total     counter    latent-attention layers traced, by
@@ -194,6 +199,12 @@ FLASH_GRID_STEPS = registry.counter(
     "idle are not counted when the offsets are traced; mask as "
     "hvd_flash_tiles_traced_total's.",
     ("kernel", "kind", "mask"))
+FLASH_KV_GROUP = registry.counter(
+    "hvd_flash_kv_group_traced_total",
+    "Traced flash-attention kernel calls (per compile, not per step) by "
+    "the heads q has and the heads k and v have: a kv head serves q_heads "
+    "/ kv_heads q heads by the kernels' index maps (equal counts: every "
+    "head its own).", ("kernel", "q_heads", "kv_heads"))
 GDN_SCAN_CHUNKS = registry.counter(
     "hvd_gdn_scan_chunks_traced_total",
     "Chunks (of every value head) each traced gated-delta-rule kernel call "
@@ -590,6 +601,17 @@ def record_flash_grid_steps(kernel: str, counts, mask: str) -> None:
     flash kernel call (ops/flash_attention.py) under the mask ``mask`` —
     how closely the grid fits the blocks the mask leaves live."""
     _count_by_kind(FLASH_GRID_STEPS, kernel, counts, mask)
+
+
+def record_flash_kv_group(kernel: str, q_heads: int, kv_heads: int) -> None:
+    """One traced flash kernel call (ops/flash_attention.py) — whether k
+    and v came at their own head count, and how many q heads share one."""
+    if not registry.enabled:
+        return
+    try:
+        FLASH_KV_GROUP.labels(kernel, str(q_heads), str(kv_heads)).inc()
+    except Exception:  # noqa: BLE001 — tracing must never fail on metrics
+        pass
 
 
 def record_gdn_scan_chunks(kernel: str, path: str, chunks: int) -> None:

@@ -22,9 +22,9 @@ operator stands before either feed-forward part.
   on q and on k (one weight of ``head_dim`` each, starting at
   ``qk_norm_init``) **before** the rotary; rotary embedding over all of a
   head's dims, halves paired (``rotate_half``), positions ``0 .. s - 1``;
-  causal softmax through the Pallas flash kernels with k and v repeated to
-  the q heads outside them (the kernels take equal head counts);
-  ``out_proj``.
+  causal softmax through the Pallas flash kernels, which take k and v at
+  their own head count (a kv head serves its group of q heads by the
+  kernels' index maps); ``out_proj``.
 * ``"dense"``: ``kanana2.DenseMlp``, a SwiGLU of ``intermediate_size``.
 * ``"experts"`` (:class:`SigmoidRoutedMoe`):
   ``parallel/moe.grouped_routed_experts`` over the experts held here
@@ -148,10 +148,6 @@ class Attention(nn.Module):
                 k = RMSNorm(name="k_layernorm", **norm)(k)
                 cos, sin = rotary_tables(jnp.arange(s), hd, self.rope_theta)
                 q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
-                # each kv head serves h // kv consecutive q heads; the
-                # kernels take equal head counts, so k and v are repeated
-                # outside them
-                k, v = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
             o = flash_attention(q, k, v, causal=True)
             with jax.named_scope(scopes.ATTN_OUT):
                 return checkpoint_name(
@@ -292,8 +288,8 @@ class Lfm2(nn.Module):
             * size,
             scopes.KEEP_SCONV_IN_PROJ: n_conv * rows * 3 * d * size,
             scopes.KEEP_KV_PROJ: n_attn * rows * 2 * kv * size,
-            flash.FLASH_K: n_attn * rows * q * size,
-            flash.FLASH_V: n_attn * rows * q * size,
+            flash.FLASH_K: n_attn * rows * kv * size,
+            flash.FLASH_V: n_attn * rows * kv * size,
         }
         held = (layers * rows * d * size
                 + n_attn * flash.residual_bytes(b, self.num_heads, s,

@@ -16,8 +16,9 @@ per-head norm, no attention sink.
   table of the layer's kind; the Pallas flash kernels under the kind's
   ``Mask`` — ``ops/flash_attention.sliding_window_mask(sliding_window)``
   (row ``i`` sees keys ``j`` with ``i - sliding_window < j <= i``) or the
-  causal one — with k and v repeated to the q heads outside them (the
-  kernels take equal head counts); ``o_proj``.
+  causal one — which take k and v at their ``num_kv_heads`` heads (a kv
+  head serves its group of q heads by the kernels' index maps);
+  ``o_proj``.
 * Rotary tables (:func:`rotary_frequencies`, :func:`rotary_table`): both in
   float32, made once a step outside the layers and handed to each layer by
   its kind.  The window layers rotate by ``position * rope_theta ** (-2c /
@@ -155,9 +156,6 @@ class Attention(nn.Module):
                     scopes.KEEP_KV_PROJ).reshape(b, s, kv, hd)
                     for name in ("k_proj", "v_proj"))
                 q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
-                # kv head g serves q heads g h / kv ..; the kernels take
-                # equal head counts, so k and v are repeated outside them
-                k, v = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
             o = flash_attention(
                 q, k, v, mask=sliding_window_mask(self.window)
                 if self.window else flash.CAUSAL)
@@ -246,8 +244,8 @@ class Mellum2(nn.Module):
             scopes.KEEP_Q_PROJ: rows * q * size,
             scopes.KEEP_KV_PROJ: rows * 2 * kv * size,
             flash.FLASH_Q: rows * q * size,
-            flash.FLASH_K: rows * q * size,
-            flash.FLASH_V: rows * q * size,
+            flash.FLASH_K: rows * kv * size,
+            flash.FLASH_V: rows * kv * size,
         }.items()}
         held = (self.num_layers * (rows * d * size + flash.residual_bytes(
                     b, self.num_heads, s, self.head_dim, size))

@@ -24,10 +24,10 @@ embedding (the attention layers see order through the state-space layers).
   log(1..heads)``, ``D = 1``, ``dt_bias`` the inverse softplus of a ``dt``
   drawn log-uniform on ``[dt_min, dt_max]`` and floored at ``dt_floor``.
 * ``*`` (:class:`Attention`): grouped-query attention, q to ``num_heads``
-  heads of ``head_dim``, k and v to ``num_kv_heads`` (repeated to the q
-  heads outside the kernels, which take equal head counts), causal softmax
-  through the Pallas flash kernels at scale ``head_dim ** -0.5``,
-  ``o_proj``.
+  heads of ``head_dim``, k and v to ``num_kv_heads`` (the kernels take
+  them at that count: a kv head serves its group of q heads by index
+  map), causal softmax through the Pallas flash kernels at scale
+  ``head_dim ** -0.5``, ``o_proj``.
 * ``E`` (:class:`Relu2Moe`): ``parallel/moe.grouped_routed_experts`` over
   the experts held here (``num_experts`` of the router's
   ``router_experts``, from ``first_expert``) under
@@ -203,9 +203,6 @@ class Attention(nn.Module):
                     _dense(kv * hd, name, self)(x),
                     scopes.KEEP_KV_PROJ).reshape(b, s, kv, hd)
                     for name in ("k_proj", "v_proj"))
-                # kv head g serves q heads g h / kv ..; the kernels take
-                # equal head counts, so k and v are repeated outside them
-                k, v = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
             o = flash_attention(q, k, v, causal=True)
             with jax.named_scope(scopes.ATTN_OUT):
                 return _dense(d, "o_proj", self)(o.reshape(b, s, h * hd))
@@ -380,8 +377,8 @@ class NemotronH(nn.Module):
             ssd_ops.SSD_IN: n_m * ssd_ops.operand_bytes(b, s, h, p, g, n,
                                                         size),
             scopes.KEEP_SSM_CONV: n_m * rows * conv_dim * size,
-            flash.FLASH_K: n_a * rows * q * size,
-            flash.FLASH_V: n_a * rows * q * size,
+            flash.FLASH_K: n_a * rows * kv * size,
+            flash.FLASH_V: n_a * rows * kv * size,
         }
         held = (len(kinds) * rows * self.hidden_size * size
                 + n_a * flash.residual_bytes(b, self.num_heads, s,

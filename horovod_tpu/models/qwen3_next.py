@@ -15,8 +15,9 @@ on the DeltaNet output, which has a plain weight and is gated:
   (columns ``[head][query | gate]``); per-head RMSNorm on q and k; rotary
   embedding on the first ``partial_rotary_factor`` of each head's dims,
   halves paired (``rotate_half``); causal softmax attention through the
-  Pallas flash kernels, with k and v repeated to the q heads outside them
-  (the kernels take equal head counts); ``o_proj(attn * sigmoid(gate))``.
+  Pallas flash kernels, which take k and v at their own head count (a kv
+  head serves its group of q heads by the kernels' index maps);
+  ``o_proj(attn * sigmoid(gate))``.
 * Gated DeltaNet: ``in_proj_qkvz`` columns are ``[q | k | v | z]``, heads
   contiguous inside each, ``in_proj_ba`` columns ``[b | a]`` (this
   implementation's order); a causal depthwise convolution of
@@ -167,10 +168,6 @@ class GatedAttention(nn.Module):
                 cos, sin = rotary_tables(jnp.arange(s), self.rotary_dim,
                                          self.rope_theta)
                 q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
-                # each kv head serves h // kv consecutive q heads; the
-                # kernels take equal head counts, so k and v are repeated
-                # outside them
-                k, v = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
             o = flash_attention(q, k, v, causal=True, **flash_blocks(hd))
             with jax.named_scope(scopes.ATTN_OUT):
                 o = o * jax.nn.sigmoid(gate.astype(_F32)).astype(self.dtype)
@@ -392,8 +389,8 @@ class Qwen3Next(nn.Module):
             scopes.KEEP_Q_PROJ: full * rows * 2 * q * size,
             scopes.KEEP_KV_PROJ: full * rows * 2 * kv * size,
             flash.FLASH_Q: full * rows * q * size,
-            flash.FLASH_K: full * rows * q * size,
-            flash.FLASH_V: full * rows * q * size,
+            flash.FLASH_K: full * rows * kv * size,
+            flash.FLASH_V: full * rows * kv * size,
             scopes.KEEP_GDN_IN_PROJ: linear * rows * size
             * (2 * key + 2 * value + 2 * hv),
             scopes.KEEP_GDN_CONV: linear * rows * (2 * key + value) * size,

@@ -52,7 +52,9 @@ KERNEL_RESIDUALS = (FLASH_OUT, FLASH_LSE, GDN_OUT, GDN_STATES, GDN_INVERSES,
 #: kernels take it (11-15: norms, rotary and the swap); a SwiGLU's gate and
 #: up (11-12); ``in_proj_qkvz`` (11); the k / v side's projections (11);
 #: the scan's operands (11); the convolution's output (10); k and v as the
-#: flash kernels take them (4-6: the repeat or the assembly, the swap).
+#: flash kernels take them (4-6: latent attention's assembly a q head and
+#: the swap; grouped-query attention's are k's norm and rotary and the swap
+#: at the kv heads' count, not measured apart).
 #: The state-space mixer's names (``nemotron_h``) stand beside the gated
 #: DeltaNet's, part for part (the same work on the same bytes: its norm, its
 #: ``in_proj``, the scan's operands, its convolution; not measured apart).

@@ -27,8 +27,10 @@ Every layer: ``x = x + attn(norm(x))``, ``x = x + moe(norm(x))``; RMSNorm is
   q and on k (one weight of ``head_dim`` each, shared by the heads); rotary
   embedding over all of a head's dims, halves paired (``rotate_half``),
   **angles from the rows' position ids**; the Pallas flash kernels under
-  the mask above, with k and v repeated to the q heads outside them (the
-  kernels take equal head counts); ``o_proj``.
+  the mask above, which take k and v at their ``num_kv_heads`` heads (a kv
+  head serves its ``num_heads // num_kv_heads`` consecutive q heads by the
+  kernels' index maps, and dk and dv leave the backward kernel summed over
+  the group); ``o_proj``.
 * Expert layer: ``parallel/moe.routed_experts`` over the experts held here
   (``num_experts`` of the router's ``router_experts``, from
   ``first_expert``): softmax over all the router's outputs in float32, the
@@ -47,9 +49,8 @@ other outputs a second run would make again what fits the byte budget
 ``recompute`` reckons from the device's memory and the shapes
 (:meth:`SDAR.recompute_parts`), in rank order: the router's logits, picks
 and order, ``o_proj``'s output, ``q_proj``'s, q as the kernels take it,
-``k_proj`` / ``v_proj``'s, k and v as the kernels take them (at the
-benchmark's size the last two do not fit and their repeat and swap still
-run twice).  A part kept has no op with ``rematted_computation`` on its
+``k_proj`` / ``v_proj``'s, k and v as the kernels take them (at their own
+head count: at the benchmark's size all of it fits).  A part kept has no op with ``rematted_computation`` on its
 path; counter ``hvd_recompute_kept_bytes_traced_total{name}``.  Device
 scopes (``models/scopes.py``, docs/profiling.md): ``hvd_bd_noise`` (the
 compare, the substitution, the concatenation, the position ids),
@@ -140,10 +141,6 @@ class BlockDiffusionAttention(nn.Module):
                 q = RMSNorm(name="q_norm", **norm)(q)
                 k = RMSNorm(name="k_norm", **norm)(k)
                 q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
-                # each kv head serves h // kv consecutive q heads; the
-                # kernels take equal head counts, so k and v are repeated
-                # outside them
-                k, v = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
             o = flash_attention(q, k, v, mask=block_diffusion_mask(
                 self.block_length, rows // 2))
             with jax.named_scope(scopes.ATTN_OUT):
@@ -263,8 +260,8 @@ class SDAR(nn.Module):
             scopes.KEEP_Q_PROJ: rows * q * size,
             scopes.KEEP_KV_PROJ: rows * 2 * kv * size,
             flash.FLASH_Q: rows * q * size,
-            flash.FLASH_K: rows * q * size,
-            flash.FLASH_V: rows * q * size,
+            flash.FLASH_K: rows * kv * size,
+            flash.FLASH_V: rows * kv * size,
         }.items()}
         held = (self.num_layers * (rows * d * size + flash.residual_bytes(
                     b, self.num_heads, s, self.head_dim, size))

@@ -101,6 +101,15 @@ Three public entry points:
 
 All kernels take/return the ``[batch, heads, seq, head_dim]`` layout; the
 callers transpose from the model-facing ``[batch, seq, heads, head_dim]``.
+k and v come at their own head count ``hk``, q's ``h`` a multiple of it
+(grouped-query attention; the group is read from the shapes): forward and
+dq, whose grids run over q's heads, read kv head ``i // (h // hk)`` for q
+head ``i`` through the kv blocks' index maps; dkv's grid runs over the kv
+heads, and a resident block of keys streams the query blocks of every q
+head of its group before it is written, so dk and dv are summed over the
+group in the float32 accumulators and leave the kernel as ``[b, hk, sk,
+d]``.  At equal counts every call is the program it was (counter
+``hvd_flash_kv_group_traced_total`` says which calls were grouped).
 v's head size is its own (latent attention scores 192 columns and weighs
 128): ``o``, ``do`` and ``dv`` are as wide as v, ``dq`` and ``dk`` as q and
 k, and nothing is padded in HBM; where the two sizes are equal the kernels
@@ -630,7 +639,8 @@ def _q_blocks_seen(mask, sq, sk, rows, keys, offs):
 MAX_PAIRS = 32768
 
 
-def _pair_table(seen, steps, mask, sq, sk, rows, keys, static_offs):
+def _pair_table(seen, steps, mask, sq, sk, rows, keys, static_offs,
+                group=1):
     """The table of a flattened grid, or ``None`` where the launchers keep
     the rectangle of ``steps`` steps a resident block.  Where the mask is
     causal or block diffusion and the offsets are Python ints, the live
@@ -641,12 +651,18 @@ def _pair_table(seen, steps, mask, sq, sk, rows, keys, static_offs):
     query rows resident; :func:`_q_blocks_seen` for dkv, a block of
     ``keys`` keys).  The table
     is ``[resident block of every step | streamed block of every step |
-    edge of every step]``, int32, ``edge`` 1 at a resident block's first
+    edge of every step]``, int32 rows of one array, ``edge`` 1 at a
+    resident block's first
     pair (the accumulators are zeroed) plus 2 at its last (the outputs are
     written); a resident block's pairs are consecutive, so its blocks are
     fetched and its outputs written back once.  A resident block that sees
     nothing keeps one step, whose body finds no tile to compute, for its
-    outputs' zeros.  A sliding window keeps its fitted grid (its steps are
+    outputs' zeros.  dkv under a ``group`` of q heads a kv head takes a
+    resident block's pairs once a member of the group, one member after
+    the other, between one zeroing and one write, and a fourth row says
+    which member a step is (the index maps of q's side read it; at a group
+    of 1 there is none, and the table is the one it was).  A sliding window
+    keeps its fitted grid (its steps are
     a range a block, found by arithmetic: :func:`_window_steps`), traced
     offsets the rectangle (where the live blocks start is data), and so do
     a call of more than ``MAX_PAIRS`` pairs and one whose rectangle has no
@@ -655,16 +671,20 @@ def _pair_table(seen, steps, mask, sq, sk, rows, keys, static_offs):
     if static_offs is None or mask.kind not in ("causal", _BD):
         return None
     blocks = seen(mask, sq, sk, rows, keys, static_offs)
-    resident, streamed, edge = [], [], []
+    resident, streamed, edge, member = [], [], [], []
     for block, live in enumerate(blocks):
         live = live or [0]
+        member += [m for m in range(group) for _ in live]
+        live = live * group
         resident += [block] * len(live)
         streamed += live
         edge += [(n == 0) + 2 * (n == len(live) - 1)
                  for n in range(len(live))]
-    if len(resident) > MAX_PAIRS or len(resident) == len(blocks) * steps:
+    table = [resident, streamed, edge] + [member] * (group > 1)
+    if (len(resident) * len(table) > 3 * MAX_PAIRS
+            or len(resident) == len(blocks) * steps * group):
         return None
-    return np.array(resident + streamed + edge, np.int32)
+    return np.array(table, np.int32)
 
 
 def _window_steps(mask, resident, block, n):
@@ -762,8 +782,9 @@ def tile_census(sq, sk, block_q, block_k, causal, q_offset=0, kv_offset=0):
     return counts
 
 
-def grid_census(sq, sk, block_q, block_k, causal, q_offset=0, kv_offset=0):
-    """The grid steps a head's three kernels take on their streamed axis,
+def grid_census(sq, sk, block_q, block_k, causal, q_offset=0, kv_offset=0,
+                group=1):
+    """The grid steps a q head's three kernels take on their streamed axis,
     ``{"fwd": {"launched": ..., "live": ...}, "dq": ..., "dkv": ...}``: a
     ``live`` step's block holds a tile somebody sees, every other step is
     visited to compute nothing and fetch nothing (``live`` is ``None`` where
@@ -771,31 +792,48 @@ def grid_census(sq, sk, block_q, block_k, causal, q_offset=0, kv_offset=0):
     live blocks start is data).  ``causal`` is a :class:`Mask` or the flag;
     blocks and tiles a step are fitted as the launchers fit them, and the
     launched extent is that of the grid they run: the pairs of a flattened
-    grid's table (:func:`_pair_table`) where there is one."""
+    grid's table (:func:`_pair_table`) where there is one.  ``group``: the q
+    heads a kv head; dkv's steps go by q heads as forward's and dq's do (a
+    kv head's grid takes its group's in turn), so the counts are those of
+    the call at equal head counts wherever the group's table fits."""
     mask = _as_mask(causal)
     offs = _static_offsets(q_offset, kv_offset)
 
-    def side(seen, resident, rows, keys, steps):
-        table = _pair_table(seen, steps, mask, sq, sk, rows, keys, offs)
+    def side(seen, resident, rows, keys, steps, group=1):
+        table = _pair_table(seen, steps, mask, sq, sk, rows, keys, offs,
+                            group)
         return {"launched": (resident * steps if table is None
-                             else len(table) // 3),
+                             else table.shape[1] // group),
                 "live": offs and sum(map(len, seen(mask, sq, sk, rows, keys,
                                                    offs)))}
 
     rows, _, keys, steps, _ = _kv_grid(sq, sk, block_q, block_k, mask, offs)
     kv_side = side(_kv_blocks_seen, sq // rows, rows, keys, steps)
     _, rows, keys, steps = _q_grid(sq, sk, block_q, block_k, mask, offs)
-    q_side = side(_q_blocks_seen, sk // keys, rows, keys, steps)
+    q_side = side(_q_blocks_seen, sk // keys, rows, keys, steps, group)
     return {"fwd": kv_side, "dq": dict(kv_side), "dkv": q_side}
 
 
-def _count_tiles(kernel, static_offs, q, k, block_q, block_k, mask):
+def _kv_group(q, k, v) -> int:
+    """The q heads a kv head serves, from the shapes (``[b, heads, s, d]``):
+    q head ``i`` reads kv head ``i // group``."""
+    h, hk = q.shape[1], k.shape[1]
+    if v.shape[1] != hk or h % hk:
+        raise ValueError(
+            f"{h} q heads do not share {hk} k heads and {v.shape[1]} v "
+            f"heads evenly")
+    return h // hk
+
+
+def _count_tiles(kernel, static_offs, q, k, v, block_q, block_k, mask):
     """The trace-time counters: once for every kernel call that is traced."""
     from .. import metrics
 
     (b, h, sq, _), sk = q.shape, k.shape[2]
+    group = _kv_group(q, k, v)
+    metrics.record_flash_kv_group(kernel, h, h // group)
     steps = grid_census(sq, sk, block_q, block_k, mask,
-                        *(static_offs or (None, None)))[kernel]
+                        *(static_offs or (None, None)), group=group)[kernel]
     if steps["live"] is not None:
         steps["idle"] = steps["launched"] - steps["live"]
     metrics.record_flash_grid_steps(
@@ -901,7 +939,7 @@ def _each_kind_of_block(block, n_tiles, first, full, live, unmasked):
                               _clip(first, 0, n_tiles - width)))
 
 
-def _grid_step(offs_ref, pairs):
+def _grid_step(offs_ref, pairs, group=1):
     """Which blocks a grid step of a kernel's body works on: ``(streamed
     block, resident block, first, last)``, the block of the streamed side
     and three functions, each of which emits its scalar arithmetic where
@@ -909,14 +947,18 @@ def _grid_step(offs_ref, pairs):
     resident block's first (zero the accumulators) and its last (write the
     outputs).  On the rectangle the grid's two inner indices say; on a
     flattened grid of ``pairs`` steps the table behind the two offsets
-    does (:func:`_pair_table`)."""
+    does (:func:`_pair_table`).  dkv under a ``group`` of q heads a kv head
+    streams the group's members in turn: the rectangle's inner axis is
+    ``group`` times as long and a member's step is what is left of the
+    index (the table has the members' passes written out)."""
     if pairs:
         t = pl.program_id(2)
         return (offs_ref[2 + pairs + t], lambda: offs_ref[2 + t],
                 lambda: offs_ref[2 + 2 * pairs + t] & 1 == 1,
                 lambda: offs_ref[2 + 2 * pairs + t] >= 2)
     j = pl.program_id(3)
-    return (j, lambda: pl.program_id(2), lambda: j == 0,
+    return (j if group == 1 else lax.rem(j, pl.num_programs(3) // group),
+            lambda: pl.program_id(2), lambda: j == 0,
             lambda: j == pl.num_programs(3) - 1)
 
 
@@ -932,18 +974,29 @@ def _grid(offs, static_offs, table, rectangle, streamed):
     back before its last."""
     if table is None:
         return (offs, rectangle, 0, lambda b_, h_, i, j, offs: i, streamed)
-    pairs = len(table) // 3
+    pairs = table.shape[1]
     return (jnp.asarray(np.concatenate([np.array(static_offs, np.int32),
-                                        table])),
+                                        table.ravel()])),
             (*rectangle[:2], pairs), pairs,
             lambda b_, h_, t, offs: offs[2 + t],
             lambda b_, h_, t, offs: offs[2 + pairs + t])
 
 
-def _at(block, *tail):
+def _at(block, *tail, head=None):
     """Index map of the ``[1, 1, rows, ...]`` blocks of a ``[b, h, s, ...]``
-    operand whose block of rows a grid step finds by ``block``."""
-    return lambda *g: (*g[:2], block(*g), *tail)
+    operand whose block of rows a grid step finds by ``block``, at the
+    grid's own head or at the one ``head`` finds (the other side's, where q
+    has a group of heads a kv head)."""
+    if head is None:
+        return lambda *g: (*g[:2], block(*g), *tail)
+    return lambda *g: (g[0], head(*g), block(*g), *tail)
+
+
+def _kv_head(group):
+    """``head`` of :func:`_at` for k's and v's blocks on forward's and dq's
+    grids, which run over q's heads: q head ``i`` reads kv head ``i //
+    group`` (the grid's own at a group of 1)."""
+    return None if group == 1 else lambda b_, h_, *_: lax.div(h_, group)
 
 
 def _jit_kernel(fn):
@@ -1101,9 +1154,11 @@ def _kv_block(mask, block_q, block_k, sk):
 
 def _mha_fwd(q, k, v, offs, *, mask, block_q, block_k, interpret,
              static_offs=None, **kw):
-    """q/k ``[b,h,s,d]``, v ``[b,h,sk,dv]``; returns ``(o [b,h,sq,dv], m,
-    l)`` with m/l ``[b,h,sq,1]``."""
-    _count_tiles("fwd", static_offs, q, k, block_q, block_k, mask)
+    """q ``[b,h,sq,d]``, k ``[b,hk,sk,d]``, v ``[b,hk,sk,dv]``, ``h`` a
+    multiple of ``hk`` (q head ``i`` reads kv head ``i // (h // hk)``, by
+    the kv blocks' index map); returns ``(o [b,h,sq,dv], m, l)`` with m/l
+    ``[b,h,sq,1]``."""
+    _count_tiles("fwd", static_offs, q, k, v, block_q, block_k, mask)
     return _fwd_call(q, k, v, offs, mask=mask, block_q=block_q,
                      block_k=block_k, static_offs=static_offs,
                      interpret=_resolve_interpret(interpret), **kw)
@@ -1121,7 +1176,8 @@ def _fwd_call(q, k, v, offs, *, mask, scale, block_q, block_k, normalize,
         _pair_table(_kv_blocks_seen, steps, mask, sq, sk, block_q, block_k,
                     static_offs),
         (b, h, sq // block_q, steps), _kv_block(mask, block_q, block_k, sk))
-    q_index, kv_index = _at(resident, 0), _at(streamed, 0)
+    q_index = _at(resident, 0)
+    kv_index = _at(streamed, 0, head=_kv_head(_kv_group(q, k, v)))
     kernel = functools.partial(
         _fwd_kernel, mask=mask, scale=scale, normalize=normalize,
         tile_k=tile_k, blocks=sk // block_k, chunk=chunk, pairs=pairs,
@@ -1220,13 +1276,18 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *,
-                    mask, scale, tile_q, blocks, pairs):
+                    mask, scale, tile_q, blocks, pairs, group):
     """Scores transposed, keys down the rows and queries along the lanes:
     every product is in a form the MXU takes as it is (k q^T, v do^T, p^T
-    do, ds^T q), and the row statistics come in as lane-dense rows."""
+    do, ds^T q), and the row statistics come in as lane-dense rows.  A
+    resident block of keys streams the query blocks of every q head of its
+    ``group`` between its first step and its last (the index maps hand it a
+    member's q, do, lse and delta; the body asks only which block of rows a
+    step is), so dk and dv are the group's sums, in float32 until the one
+    cast."""
     bk = k_ref.shape[2]
     n_tiles = q_ref.shape[2] // tile_q
-    i, resident, first_step, last_step = _grid_step(offs_ref, pairs)
+    i, resident, first_step, last_step = _grid_step(offs_ref, pairs, group)
     if mask.kind == _SW:
         named, live_step = _window_q_block(
             mask, offs_ref[1] + resident() * bk, bk, offs_ref[0],
@@ -1286,7 +1347,7 @@ def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _mha_bwd_dq(q, k, v, do, lse, delta, offs, *, mask, block_q, block_k,
                 interpret, static_offs=None, **kw):
     """lse/delta ``[b,h,sq,1]``."""
-    _count_tiles("dq", static_offs, q, k, block_q, block_k, mask)
+    _count_tiles("dq", static_offs, q, k, v, block_q, block_k, mask)
     return _dq_call(q, k, v, do, lse, delta, offs, mask=mask,
                     block_q=block_q, block_k=block_k, static_offs=static_offs,
                     interpret=_resolve_interpret(interpret), **kw)
@@ -1304,7 +1365,8 @@ def _dq_call(q, k, v, do, lse, delta, offs, *, mask, scale, block_q,
         _pair_table(_kv_blocks_seen, steps, mask, sq, sk, block_q, block_k,
                     static_offs),
         (b, h, sq // block_q, steps), _kv_block(mask, block_q, block_k, sk))
-    q_index, kv_index = _at(resident, 0), _at(streamed, 0)
+    q_index = _at(resident, 0)
+    kv_index = _at(streamed, 0, head=_kv_head(_kv_group(q, k, v)))
     kernel = functools.partial(_bwd_dq_kernel, mask=mask, scale=scale,
                                tile_k=tile_k, blocks=sk // block_k,
                                chunk=chunk, pairs=pairs)
@@ -1323,8 +1385,10 @@ def _dq_call(q, k, v, do, lse, delta, offs, *, mask, scale, block_q,
 def _mha_bwd_dkv(q, k, v, do, lse, delta, offs, *, mask, block_q, block_k,
                  interpret, static_offs=None, **kw):
     """lse/delta: one float32 a query row, in any shape; the kernel reads
-    them as rows of ``tile_q`` lanes."""
-    _count_tiles("dkv", static_offs, q, k, block_q, block_k, mask)
+    them as rows of ``tile_q`` lanes.  Returns ``(dk [b,hk,sk,d], dv
+    [b,hk,sk,dv])`` at k's and v's own head count, each the sum over the q
+    heads of its group."""
+    _count_tiles("dkv", static_offs, q, k, v, block_q, block_k, mask)
     return _dkv_call(q, k, v, do, lse, delta, offs, mask=mask,
                      block_q=block_q, block_k=block_k, static_offs=static_offs,
                      interpret=_resolve_interpret(interpret), **kw)
@@ -1335,6 +1399,7 @@ def _dkv_call(q, k, v, do, lse, delta, offs, *, mask, scale, block_q,
               block_k, interpret, out_dtype=jnp.float32, static_offs=None):
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
+    group = _kv_group(q, k, v)
     tile_q, block_q, block_k, steps = _q_grid(sq, sk, block_q, block_k,
                                               mask, static_offs)
     n_tiles = block_q // tile_q
@@ -1342,6 +1407,9 @@ def _dkv_call(q, k, v, do, lse, delta, offs, *, mask, scale, block_q,
                   for x in (lse, delta))
 
     def first_seen(b_, h_, jk, i, offs):
+        if group > 1:
+            # the rectangle takes a block's steps once a member of the group
+            i = lax.rem(i, steps)
         # a grid step whose query rows all lie before the kv block computes
         # nothing: it names the first block that does, and nothing is
         # fetched
@@ -1364,13 +1432,24 @@ def _dkv_call(q, k, v, do, lse, delta, offs, *, mask, scale, block_q,
     offs, grid, pairs, resident, streamed = _grid(
         offs, static_offs,
         _pair_table(_q_blocks_seen, steps, mask, sq, sk, block_q, block_k,
-                    static_offs),
-        (b, h, sk // block_k, steps), first_seen)
-    kv_index, q_index, row_index = (_at(resident, 0), _at(streamed, 0),
-                                    _at(streamed, 0, 0))
+                    static_offs, group),
+        (b, h // group, sk // block_k, group * steps), first_seen)
+    # the grid runs over the kv heads; a step's q head is its member of the
+    # kv head's group (the table's fourth row; on the rectangle the pass
+    # the step is in)
+    def member_head(b_, h_, *step):
+        *step, offs = step
+        member = (offs[2 + 3 * pairs + step[0]] if pairs
+                  else lax.div(step[1], steps))
+        return h_ * group + member
+
+    q_head = member_head if group > 1 else None
+    kv_index, q_index, row_index = (
+        _at(resident, 0), _at(streamed, 0, head=q_head),
+        _at(streamed, 0, 0, head=q_head))
     kernel = functools.partial(_bwd_dkv_kernel, mask=mask, scale=scale,
                                tile_q=tile_q, blocks=sq // block_q,
-                               pairs=pairs)
+                               pairs=pairs, group=group)
     q_block, k_block, rows = ((1, 1, block_q, d), (1, 1, block_k, d),
                               (1, 1, n_tiles, 1, tile_q))
     do_block, v_block = (1, 1, block_q, dv), (1, 1, block_k, dv)
@@ -1379,8 +1458,8 @@ def _dkv_call(q, k, v, do, lse, delta, offs, *, mask, scale, block_q,
         ins=[(q, q_block, q_index), (k, k_block, kv_index),
              (v, v_block, kv_index), (do, do_block, q_index),
              (lse, rows, row_index), (delta, rows, row_index)],
-        outs=[((b, h, sk, d), out_dtype, k_block, kv_index),
-              ((b, h, sk, dv), out_dtype, v_block, kv_index)],
+        outs=[((b, h // group, sk, d), out_dtype, k_block, kv_index),
+              ((b, h // group, sk, dv), out_dtype, v_block, kv_index)],
         scratch=[(block_k, d), (block_k, dv)], interpret=interpret)
 
 
@@ -1486,7 +1565,12 @@ def flash_attention(q, k, v, *, causal: bool = False,
     Args:
       q, k, v: ``[batch, seq, heads, head_dim]`` (the model-facing layout
         used throughout :mod:`horovod_tpu.parallel`); v's head size may
-        differ from q's and k's.
+        differ from q's and k's.  k and v may have fewer heads than q
+        (grouped-query attention): ``hk`` of them with q's ``h`` a multiple
+        (a ``ValueError`` otherwise), and q head ``i`` attends kv head ``i
+        // (h // hk)``.  Nothing is repeated in HBM: the kernels find a q
+        head's kv head by index map, and the gradients of k and v come back
+        at ``hk`` heads, summed over each group inside the dkv kernel.
       causal: apply causal masking in global positions
         (``q_offset + i >= kv_offset + j``).
       mask: a :class:`Mask` in ``causal``'s place:
@@ -1549,9 +1633,10 @@ def softmax_attention(q, k, v, *, causal: bool = False,
 # nothing lowers to the bytes it had.
 
 # The backward kernels' other residuals: the forward's own inputs as the
-# kernels take them (``[b, h, s, d]``, after the caller's norms, rotary and
-# repeats and after the swap).  A checkpoint that saves these too does none
-# of that again (``models/recompute.py`` ranks them against its budget).
+# kernels take them (q ``[b, h, s, d]``, k and v ``[b, hk, s, d]`` at their
+# own head count, after the caller's norms and rotary and after the swap).
+# A checkpoint that saves these too does none of that again
+# (``models/recompute.py`` ranks them against its budget).
 FLASH_Q = "hvd_flash_q"
 FLASH_K = "hvd_flash_k"
 FLASH_V = "hvd_flash_v"

@@ -200,9 +200,10 @@ BODIES_BEFORE_THE_TABLE = {
 }
 
 
-def _mosaic_bodies(monkeypatch, mask_kw, traced, s=2048):
+def _mosaic_bodies(monkeypatch, mask_kw, traced, s=2048, heads=2,
+                   kv_heads=None):
     """The Mosaic bodies of a call's gradient at ``s`` rows, lowered for a
-    TPU."""
+    TPU; k and v at ``kv_heads`` heads where q's ``heads`` share them."""
     from jax._src import tpu_custom_call
 
     bodies = []
@@ -213,7 +214,8 @@ def _mosaic_bodies(monkeypatch, mask_kw, traced, s=2048):
         return lower(module, **kw)
 
     monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm", keep)
-    x = jax.ShapeDtypeStruct((1, s, 2, 128), jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((1, s, heads, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, s, kv_heads or heads, 128), jnp.bfloat16)
     at = (jax.ShapeDtypeStruct((), jnp.int32),) if traced else ()
 
     def loss(q, k, v, *at):
@@ -222,7 +224,7 @@ def _mosaic_bodies(monkeypatch, mask_kw, traced, s=2048):
             **(dict(q_offset=at[0], kv_offset=at[0]) if at else {})).astype(
                 jnp.float32).sum()
 
-    jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(x, x, x, *at).lower(
+    jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(x, kv, kv, *at).lower(
         lowering_platforms=("tpu",))
     return bodies
 

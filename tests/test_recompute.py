@@ -57,8 +57,9 @@ KEPT = {
     "sdar-bd4-8k": {
         0: (),
         1: (*_MIXER_OUT, scopes.KEEP_Q_PROJ, scopes.KEEP_KV_PROJ),
+        # k and v at their own 4 heads: 67 MB each over the layers
         2: (*_MIXER_OUT, scopes.KEEP_Q_PROJ, flash.FLASH_Q,
-            scopes.KEEP_KV_PROJ),
+            scopes.KEEP_KV_PROJ, flash.FLASH_K, flash.FLASH_V),
         5: (*_MIXER_OUT, scopes.KEEP_Q_PROJ, flash.FLASH_Q,
             scopes.KEEP_KV_PROJ, flash.FLASH_K, flash.FLASH_V)},
     "kanana2-8k": {
@@ -112,12 +113,17 @@ def test_at_the_published_depth_the_same_function_keeps_what_has_room():
     """48 layers of the published widths and 3 GB: the routers' residuals
     (17.8 MB a layer) and the output projections' outputs (33.6 MB a
     layer), not the gated norms' (67.1 MB a DeltaNet layer: 2.4 GB) nor
-    anything wider; the twelve attention layers' k and v still fit."""
+    anything wider; of the twelve attention layers, k's and v's projections
+    and k and v as the kernels take them, at their own two heads, still
+    fit."""
     model = qwen3_next.Qwen3Next()
     parts, _ = model.recompute_parts(1, 8192)
     assert parts[scopes.KEEP_OUT_PROJ] == 48 * 8192 * 2048 * 2
     assert recompute.keep_within(recompute.ranked(parts), 3 * GB) == (
-        moe.ROUTING, scopes.KEEP_OUT_PROJ, scopes.KEEP_KV_PROJ)
+        moe.ROUTING, scopes.KEEP_OUT_PROJ, scopes.KEEP_KV_PROJ,
+        flash.FLASH_K, flash.FLASH_V)
+    assert parts[flash.FLASH_K] == parts[flash.FLASH_V] \
+        == 12 * 8192 * 2 * 256 * 2
 
 
 def test_no_budget_where_the_devices_memory_is_not_known():
@@ -432,16 +438,25 @@ def _residual_bytes():
         scopes.KEEP_KV_PROJ: 2 * 8192 * 512 * 2,
         gdn.GDN_IN: 3 * 8192 * (8192 * 2 + 2 * 32 * 4),
         scopes.KEEP_GDN_CONV: 3 * 8192 * 8192 * 2,
-        flash.FLASH_K: 8192 * 4096 * 2,
-        flash.FLASH_V: 8192 * 4096 * 2}, 0),
-    # what 2 GB leave of the cell that cannot keep everything
+        flash.FLASH_K: 8192 * 512 * 2,
+        flash.FLASH_V: 8192 * 512 * 2}, 0),
+    # what 1.4 GB leave of the cell whose k and v come at 4 heads: not k's
+    # and v's projections (134 MB), nor k and v as the kernels take them
+    ("sdar-bd4-8k", 14 * GB // 10, {
+        moe.ROUTING: 4 * 4 * 16384 * (128 + 3 * 8),
+        scopes.KEEP_OUT_PROJ: 4 * 16384 * 2048 * 2,
+        scopes.KEEP_Q_PROJ: 4 * 16384 * 4096 * 2,
+        flash.FLASH_Q: 4 * 16384 * 4096 * 2},
+     4 * 2 * 16384 * 512 * 2 + 2 * 4 * 16384 * 512 * 2),
+    # and the 2 GB that refused 1.07 GB of k and v at 32 heads keep it all
     ("sdar-bd4-8k", 2 * GB, {
         moe.ROUTING: 4 * 4 * 16384 * (128 + 3 * 8),
         scopes.KEEP_OUT_PROJ: 4 * 16384 * 2048 * 2,
         scopes.KEEP_Q_PROJ: 4 * 16384 * 4096 * 2,
         flash.FLASH_Q: 4 * 16384 * 4096 * 2,
-        scopes.KEEP_KV_PROJ: 4 * 2 * 16384 * 512 * 2},
-     2 * 4 * 16384 * 4096 * 2),
+        scopes.KEEP_KV_PROJ: 4 * 2 * 16384 * 512 * 2,
+        flash.FLASH_K: 4 * 16384 * 512 * 2,
+        flash.FLASH_V: 4 * 16384 * 512 * 2}, 0),
     ("kanana2-8k", 4 * GB, {
         moe.ROUTING: 4 * 4 * 8192 * (128 + 3 * 6),
         scopes.KEEP_OUT_PROJ: 5 * 8192 * 2048 * 2,
